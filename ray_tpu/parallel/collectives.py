@@ -83,7 +83,6 @@ class DeviceCollectiveGroup:
 
 
 def _static_axis_size(axis_name: str) -> int:
-    env = jax.core.get_axis_env() if hasattr(jax.core, "get_axis_env") else None
     try:
         return jax.lax.psum(1, axis_name)  # concrete under shard_map closed mesh
     except Exception as e:  # pragma: no cover

@@ -92,11 +92,11 @@ def make_mesh(
     if devices is None:
         devices = jax.devices()
         if n_devices is not None and len(devices) < n_devices:
-            # Fall back to host (virtual CPU) devices — the multi-chip dry-run path
-            # when only one real chip (or none) is attached.
-            cpu = jax.devices("cpu")
-            if len(cpu) >= n_devices:
-                devices = cpu
+            # never quietly swap in host CPU devices for missing chips; a
+            # dry run on virtual devices pins JAX_PLATFORMS=cpu itself
+            raise ValueError(
+                f"make_mesh({n_devices}) but the {devices[0].platform} backend "
+                f"has {len(devices)} device(s)")
     if n_devices is not None:
         devices = devices[:n_devices]
     return MeshSpec(data=data, pipe=pipe, fsdp=fsdp, tensor=tensor, seq=seq,

@@ -40,7 +40,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import llama
-from ray_tpu.parallel._compat import shard_map
+from ray_tpu.ops.platform import target_platform
 
 
 def layer_specs() -> dict:
@@ -104,7 +104,10 @@ def make_pp_loss_and_grad(
     M = num_microbatches
     specs = param_specs(cfg)
     if attn_fn is None:
-        attn_fn = partial(llama.auto_attention, causal=True)
+        # already per-device here (the whole loss runs inside the shard_map
+        # below), so the kernel needs no wrapping of its own
+        attn_fn = partial(llama.auto_attention, causal=True,
+                          platform=target_platform(mesh=mesh))
 
     nh_local = cfg.num_heads // T
     nkv_local = cfg.num_kv_heads // T
@@ -217,7 +220,7 @@ def make_pp_loss_and_grad(
                     grads[k], ("data", "fsdp", "pipe", "tensor"))
         return loss, reduced
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(specs, BATCH_SPEC, BATCH_SPEC),
         out_specs=(P(), specs),

@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.parallel._compat import shard_map
-
 NEG_INF = -1e30
 
 
@@ -88,7 +86,7 @@ def ring_attention(q, k, v, mesh: Mesh, *, seq_axis: str = "seq", positions=None
     pspec = P(None, seq_axis, None, None)
     pos_spec = P(None, seq_axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ring_attention_sharded, axis_name=seq_axis),
         mesh=mesh,
         in_specs=(pspec, pspec, pspec, pos_spec, pos_spec),

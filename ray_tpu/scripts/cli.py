@@ -215,14 +215,6 @@ def cmd_job_submit(args) -> int:
     return 0
 
 
-def cmd_bench(args) -> int:
-    import runpy
-
-    sys.argv = ["bench.py"]
-    runpy.run_path("bench.py", run_name="__main__")
-    return 0
-
-
 def _session_file() -> str:
     """Per-user, 0700 session dir: the file holds the control-plane token, so
     it must not be world-readable (and concurrent users must not collide)."""

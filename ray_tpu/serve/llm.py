@@ -26,6 +26,8 @@ from typing import Any, Optional
 import numpy as np
 
 from ray_tpu.models import llama
+from ray_tpu.ops.platform import target_platform
+from ray_tpu.util.compile_cache import ensure_compile_cache
 
 
 @dataclasses.dataclass
@@ -79,6 +81,11 @@ class LLMEngine:
         self._jnp = jnp
         key = jax.random.PRNGKey(seed)
         self.params = params if params is not None else llama.init(cfg, key)
+        # Where the weights actually live, so where every step runs: a
+        # CPU-pinned worker process reports "cpu" here however many chips the
+        # host has (stats() carries it to whoever has to check)
+        self.platform = target_platform(*jax.tree.leaves(self.params))
+        ensure_compile_cache(self.platform)
         B = config.max_batch_size
         self.lengths = np.zeros(B, dtype=np.int32)
         self.last_tokens = np.zeros((B, 1), dtype=np.int32)
@@ -193,10 +200,17 @@ class LLMEngine:
                 "active_slots": int(self.active.sum()),
                 "max_slots": self.config.max_batch_size,
                 "pending": self._pending.qsize(),
+                "platform": self.platform,
             }
 
     def shutdown(self) -> None:
+        """Stop the loop and wait for it: a daemon thread still inside a
+        jitted call when the interpreter tears down aborts the process
+        (status 134) with the device open."""
         self._running = False
+        t = self._loop_thread
+        if t is not None and t is not threading.current_thread():
+            t.join()
         self._fail_all_active(RuntimeError("LLM engine shut down"))
 
     # ---- engine loop ----
@@ -345,12 +359,13 @@ def build_llm_deployment(config: LLMConfig | None = None, num_replicas: int = 1)
     POST body: {"prompt_ids": [...], "max_tokens": N} -> token ids + timings.
     """
     from ray_tpu.serve.deployment import deployment
+    from ray_tpu.serve.pd import _ReplicaLifecycle
 
     cfg = config or LLMConfig()
 
     @deployment(name="LLMServer", num_replicas=num_replicas,
                 ray_actor_options={"num_tpus": 0.0})
-    class LLMServer:
+    class LLMServer(_ReplicaLifecycle):
         def __init__(self, llm_config: LLMConfig):
             self.engine = LLMEngine(llm_config)
 

@@ -89,17 +89,20 @@ class PagedLLMEngine(LLMEngine):
         self.tables = np.zeros((B, self.max_blocks_per_seq), dtype=np.int32)
         self.slot_blocks: list[list[int]] = [[] for _ in range(B)]
         self.slot_prompts: list[Optional[list[int]]] = [None] * B
+        platform = self.platform  # a local: the jitted closures must not hold self
 
         def prefill(params, pool, tokens, table, start_len):
             # B=1 row: run the suffix, return per-position logits
             logits, pool = llama.forward_paged(
-                params, tokens, cfg, pool, table, start_len, bs
+                params, tokens, cfg, pool, table, start_len, bs,
+                platform=platform
             )
             return logits[0], pool
 
         def decode(params, pool, last_tokens, lengths, tables):
             logits, pool = llama.forward_paged(
-                params, last_tokens, cfg, pool, tables, lengths, bs
+                params, last_tokens, cfg, pool, tables, lengths, bs,
+                platform=platform
             )
             return logits[:, 0], pool
 
@@ -134,15 +137,9 @@ class PagedLLMEngine(LLMEngine):
         self.slot_prompts[i] = None
 
     def stats(self) -> dict:
-        # keep the base engine's schema (dashboards read active_slots/max_slots
-        # regardless of engine type) and add the allocator's fields
-        with self._lock:
-            return {
-                "active_slots": int(self.active.sum()),
-                "max_slots": self.config.max_batch_size,
-                "pending": self._pending.qsize(),
-                **self.allocator.stats(),
-            }
+        # the base engine's schema (dashboards read active_slots/max_slots
+        # regardless of engine type) plus the allocator's fields
+        return {**super().stats(), **self.allocator.stats()}
 
     def shutdown(self) -> None:
         super().shutdown()  # stops the loop + fails active slots

@@ -60,13 +60,14 @@ def build_openai_app(config: "LLMConfig | None" = None, *,
     (reference: ray.serve.llm build_openai_app). jax-heavy imports stay inside
     this builder so `import ray_tpu.serve` never pays them."""
     from ray_tpu.serve.llm import LLMConfig, LLMEngine
+    from ray_tpu.serve.pd import _ReplicaLifecycle
 
     cfg = config or LLMConfig()
     tok = tokenizer or ByteTokenizer()
 
     @_deployment(name="OpenAIServer", num_replicas=num_replicas,
                  ray_actor_options={"num_tpus": 0.0}, max_ongoing_requests=64)
-    class OpenAIServer:
+    class OpenAIServer(_ReplicaLifecycle):
         def __init__(self, llm_config, tokenizer, model_id: str):
             from ray_tpu.serve.llm import LLMEngine as _Dense
             from ray_tpu.serve.llm_paged import PagedLLMConfig, PagedLLMEngine

@@ -64,6 +64,7 @@ class SpecDecodeLLMEngine(PagedLLMEngine):
         cfg = self.config.model_config
         dcfg = self.config.draft_model_config
         bs = self.config.block_size
+        platform = self.platform  # a local: the jitted closures must not hold self
         self.draft_params = (self._draft_params_init
                              if self._draft_params_init is not None
                              else llama.init(dcfg, jax.random.PRNGKey(7)))
@@ -72,13 +73,15 @@ class SpecDecodeLLMEngine(PagedLLMEngine):
 
         def draft_prefill(params, pool, tokens, table, start_len):
             logits, pool = llama.forward_paged(
-                params, tokens, dcfg, pool, table, start_len, bs
+                params, tokens, dcfg, pool, table, start_len, bs,
+                platform=platform
             )
             return logits[0], pool
 
         def draft_decode(params, pool, last_tokens, lengths, tables):
             logits, pool = llama.forward_paged(
-                params, last_tokens, dcfg, pool, tables, lengths, bs
+                params, last_tokens, dcfg, pool, tables, lengths, bs,
+                platform=platform
             )
             return logits[:, 0], pool
 
@@ -87,14 +90,16 @@ class SpecDecodeLLMEngine(PagedLLMEngine):
             # step's final proposal (whose draft KV was never written — the
             # classic bonus-token hole) gets its page filled before proposing
             logits, pool = llama.forward_paged(
-                params, window2, dcfg, pool, tables, lengths, bs
+                params, window2, dcfg, pool, tables, lengths, bs,
+                platform=platform
             )
             return logits[:, 1], pool
 
         def verify(params, pool, window, lengths, tables):
             # [B, K+1] window scored in one target forward
             logits, pool = llama.forward_paged(
-                params, window, cfg, pool, tables, lengths, bs
+                params, window, cfg, pool, tables, lengths, bs,
+                platform=platform
             )
             return logits, pool
 

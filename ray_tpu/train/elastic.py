@@ -369,11 +369,8 @@ class GangContext:
 
         if os.environ.get("RAY_TPU_WORKER_TPU") != "1":
             jax.config.update("jax_platforms", "cpu")
-            try:  # multi-process CPU collectives need the Gloo backend
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  "gloo")
-            except Exception:
-                pass  # newer jax: gloo is the default; flag may be gone
+            # multi-process CPU collectives need the Gloo backend
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(
             self.coordinator, num_processes=self.world_size,
             process_id=self.rank)

@@ -4,9 +4,11 @@ Parity: train/v2/jax/config.py:60 (_setup_jax_distributed_environment) — every
 train worker is an OS process that calls jax.distributed.initialize against
 the rank-0 coordinator, contributing its local devices to ONE global mesh;
 MEGASCALE env vars are injected per worker for multislice (config.py:29-35).
-On real hardware each gang member owns a TPU host's chips; in CI the members
-are CPU processes with virtual devices and the collectives ride Gloo — the
-same activation path either way.
+On real hardware each gang member owns a TPU host's chips, or its own part
+of one host's (_shared_host_chip_env); in CI the members are CPU processes
+with virtual devices and the collectives ride Gloo — the same activation
+path either way. The launching process must not have initialised a jax
+backend on the TPU, or it holds the chips the members need.
 
 Gang members run as runtime tasks (process workers) that each exec a CLEAN
 interpreter for the jax work: XLA device-count flags and the TPU platform
@@ -92,6 +94,10 @@ def _gang_member(rank: int, num_workers: int, coordinator: str,
     env.update(env_extra or {})
     if use_tpu:
         env["RAY_TPU_WORKER_TPU"] = "1"
+        # this task runs in a pool worker, which is pinned to the CPU; a
+        # member that inherits the pin trains on the CPU without a word.
+        # Naming the platform also makes jax fail if it cannot open a chip.
+        env["JAX_PLATFORMS"] = "tpu"
     else:
         env["JAX_PLATFORMS"] = "cpu"
         stripped = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
@@ -128,10 +134,8 @@ def _child_main(in_path: str, out_path: str) -> None:
         import jax
 
         jax.config.update("jax_platforms", "cpu")
-        try:  # multi-process CPU collectives need the Gloo backend
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass  # newer jax: gloo is the default; flag may be gone
+        # multi-process CPU collectives need the Gloo backend
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     else:
         import jax
     jax.distributed.initialize(
@@ -143,6 +147,52 @@ def _child_main(in_path: str, out_path: str) -> None:
     result = fn(payload["rank"])
     with open(out_path, "wb") as f:
         f.write(cloudpickle.dumps(result))
+
+
+def _shared_host_chip_env(num_workers: int, devices_per_worker: int) -> list:
+    """Per-member libtpu environment when the members of a TPU gang SHARE one
+    host's chips. A member that asks for the whole host (the multi-host
+    layout: one member a host) needs none. Otherwise every member would
+    inherit all of the host's chips and the second to start aborts on
+    libtpu's lockfile, so each is bounded to its own: the host's chips are
+    cut along their first axis, contiguous chip ids a member, and the
+    members' libtpu runtimes are told of each other. Proved on a v5e 2x2
+    host with two members of two chips (PR 21); any split that is not of
+    that form is refused here rather than left to hang on the chip."""
+    from ray_tpu.core.api import _detect_tpu_chips
+
+    host_chips = int(_detect_tpu_chips())
+    if (num_workers == 1 or host_chips == 0
+            or devices_per_worker >= host_chips):
+        return [{}] * num_workers
+    bounds = os.environ.get("TPU_CHIPS_PER_HOST_BOUNDS", "")
+    try:
+        x, y, z = (int(v) for v in bounds.split(","))
+    except ValueError:
+        x = y = z = 0
+    if (x * y * z != host_chips or x % num_workers
+            or num_workers * devices_per_worker != host_chips):
+        raise RuntimeError(
+            f"use_tpu gang: {num_workers} members of {devices_per_worker} "
+            f"chips do not tile this host's {host_chips} chips "
+            f"(TPU_CHIPS_PER_HOST_BOUNDS={bounds!r}) along their first axis. "
+            f"A chip belongs to one process; members that are not each "
+            f"bounded to their own chips fail or hang at start-up. Run one "
+            f"process over the whole host (single-controller SPMD) instead.")
+    ports = [_free_port() for _ in range(num_workers)]
+    shared = {
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": f"{x // num_workers},{y},{z}",
+        "TPU_PROCESS_BOUNDS": f"{num_workers},1,1",
+        "TPU_PROCESS_ADDRESSES": ",".join(f"localhost:{p}" for p in ports),
+    }
+    return [{
+        **shared,
+        "TPU_VISIBLE_CHIPS": ",".join(
+            str(c) for c in range(r * devices_per_worker,
+                                  (r + 1) * devices_per_worker)),
+        "TPU_PROCESS_PORT": str(ports[r]),
+        "CLOUD_TPU_TASK_ID": str(r),
+    } for r in range(num_workers)]
 
 
 def run_jax_gang(
@@ -164,10 +214,14 @@ def run_jax_gang(
     the jax work (device flags must precede jax's first import)."""
     from ray_tpu.parallel.mesh import multislice_env
 
+    chip_env = (_shared_host_chip_env(num_workers, devices_per_worker)
+                if use_tpu else [{}] * num_workers)
+
     def env_for_rank(rank: int, coordinator: str) -> dict:
-        if num_slices <= 1:
-            return {}
-        return multislice_env(coordinator, num_slices, slice_id)
+        env = dict(chip_env[rank])
+        if num_slices > 1:
+            env.update(multislice_env(coordinator, num_slices, slice_id))
+        return env
 
     return _launch_gang(
         [cloudpickle.dumps(train_fn)] * num_workers, env_for_rank,

@@ -18,7 +18,9 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import llama
+from ray_tpu.ops.platform import target_platform
 from ray_tpu.parallel import sharding as shd
+from ray_tpu.util.compile_cache import ensure_compile_cache
 
 
 @dataclasses.dataclass
@@ -81,6 +83,26 @@ def state_shardings(cfg: llama.LlamaConfig, mesh: Mesh, state: TrainState) -> Tr
     )
 
 
+def default_attn_fn(mesh: Mesh) -> Callable:
+    """The attn_fn for a step sharded over `mesh`: llama.auto_attention told
+    the mesh's platform, and on a TPU mesh of several devices run under
+    `jax.shard_map` (batch over data x fsdp, heads over tensor — the layout
+    the rule table already gives q/k/v). The SPMD partitioner cannot split a
+    Mosaic kernel itself ("Mosaic kernels cannot be automatically
+    partitioned"), and attention is independent per batch row and per KV-head
+    group, so each device runs the kernel on its own shard with no
+    communication. Axes the specs do not name (seq, expert, pipe) see the
+    whole sequence; a `seq` axis > 1 wants ring attention passed explicitly."""
+    platform = target_platform(mesh=mesh)
+    attn = partial(llama.auto_attention, causal=True, platform=platform)
+    if platform != "tpu" or mesh.size == 1:
+        return attn
+    q_spec = shd.spec_from_logical(("batch", None, "heads", None))
+    kv_spec = shd.spec_from_logical(("batch", None, "kv_heads", None))
+    return jax.shard_map(attn, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+                         out_specs=q_spec, check_vma=False)
+
+
 def make_train_step(
     cfg: llama.LlamaConfig,
     mesh: Mesh,
@@ -93,7 +115,10 @@ def make_train_step(
     param/optimizer shards (fsdp axis) are all-gathered/reduce-scattered by XLA as
     needed — the ZeRO-3 pattern without manual collectives.
     """
+    ensure_compile_cache(target_platform(mesh=mesh))
     optimizer = optimizer or make_optimizer()
+    if attn_fn is None:
+        attn_fn = default_attn_fn(mesh)
     batch_sh = NamedSharding(mesh, P(("data", "fsdp"), None))
 
     def step_fn(state: TrainState, tokens, targets):

@@ -158,3 +158,28 @@ def test_multislice_gang_dcn_mesh():
         assert r["num_devices"] == 4
         assert r["sum"] == 6.0  # 0+1+2+3 across both slices
     assert sorted(r["slice_id"] for r in out) == [0, 1]
+
+
+def test_tpu_gang_members_sharing_a_host_get_their_own_chips(monkeypatch):
+    """use_tpu members inherit ALL of a host's chips unless bounded; the
+    layout proved on a v5e 2x2 host (PR 21) is produced, one member a host
+    needs nothing, and any other split raises instead of hanging on the chip."""
+    from ray_tpu.core import api
+    from ray_tpu.train.gang import _shared_host_chip_env
+
+    monkeypatch.setattr(api, "_detect_tpu_chips", lambda: 4.0)
+    monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "2,2,1")
+    a, b = _shared_host_chip_env(2, 2)
+    assert (a["TPU_VISIBLE_CHIPS"], b["TPU_VISIBLE_CHIPS"]) == ("0,1", "2,3")
+    assert a["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,2,1"
+    assert a["TPU_PROCESS_BOUNDS"] == "2,1,1"
+    assert (a["CLOUD_TPU_TASK_ID"], b["CLOUD_TPU_TASK_ID"]) == ("0", "1")
+    assert a["TPU_PROCESS_ADDRESSES"] == b["TPU_PROCESS_ADDRESSES"]
+    assert a["TPU_PROCESS_PORT"] != b["TPU_PROCESS_PORT"]
+    assert _shared_host_chip_env(2, 4) == [{}, {}]  # a whole host each
+    assert _shared_host_chip_env(1, 2) == [{}]
+    with pytest.raises(RuntimeError, match="belongs to one process"):
+        _shared_host_chip_env(4, 1)
+    monkeypatch.delenv("TPU_CHIPS_PER_HOST_BOUNDS")
+    with pytest.raises(RuntimeError, match="belongs to one process"):
+        _shared_host_chip_env(2, 2)

@@ -4,12 +4,14 @@ Reference analog: the vLLM paged_attention kernel the reference delegates
 serving to; here native (ops/paged_attention.py), validated against the
 dense cached-attention math in models/llama.py.
 
-Triage note (ISSUE 11): long carried in ROADMAP as "the one known seed
-failure" — on the current image it passes deterministically (5/5 repeated
-standalone runs + full-suite). The historical failure was environmental
-(an older jax whose Pallas interpret path diverged), not a kernel bug; no
-xfail marker because the suite is green here. A real-TPU (non-interpret)
-run is still owed before the ragged-attention ROADMAP item closes.
+These run the kernel interpreted. Compiled, it was checked on the chip by
+chip_smoke.py's kernels phase (PR 21, 2026-09-26, TPU v5 lite, jax 0.9.0 /
+libtpu 0.0.34): a forward_paged decode step at llama_1b widths (32 query / 8
+KV heads of 64, block 16, a 128-block table, bf16, 2 layers) with ragged
+lengths 1, 2, 16, 17, 38, 1028, 2047 and 2048 gave logits within 5.5e-2 of
+the dense arm's (largest logit 5.14, tolerance 8 bf16 eps of it), and the
+compiled step held the Mosaic call. tests/test_tpu_aot.py compiles it for a
+v5e from this host at D=64 and D=128.
 """
 
 import jax

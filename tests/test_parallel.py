@@ -57,7 +57,6 @@ def test_shard_params_places_on_mesh():
 
 
 def test_device_collectives_in_shard_map():
-    from ray_tpu.parallel._compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = cpu_mesh(data=8)
@@ -70,7 +69,7 @@ def test_device_collectives_in_shard_map():
         return s, gathered, rank[None]
 
     x = jnp.arange(8.0).reshape(8, 1)
-    f = shard_map(body, mesh=mesh, in_specs=P("data", None),
+    f = jax.shard_map(body, mesh=mesh, in_specs=P("data", None),
                   out_specs=(P("data", None), P("data", None), P("data")))
     s, gathered, ranks = f(x)
     assert float(s[0, 0]) == 28.0  # sum 0..7 everywhere

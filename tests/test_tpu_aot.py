@@ -1,0 +1,124 @@
+"""Compile the device path for a TPU v5e from this CPU-only host.
+
+The installed libtpu describes a v5e host with no chip attached, and
+`jit(f).lower(<ShapeDtypeStruct placed on those devices>).compile()` then
+runs the real XLA:TPU and Mosaic compilers. The CPU tests take the dense
+attention branch and interpret the kernels, so without this nothing in
+tier-1 sees what the chip's compiler sees — e.g. "Mosaic kernels cannot be
+automatically partitioned" when a bare pallas_call meets a sharded mesh.
+Nothing is executed; `chip_smoke.py` is the check that the compiled code is
+also right.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models import llama
+from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.paged_attention import paged_decode_attention
+from ray_tpu.parallel import sharding as shd
+from ray_tpu.parallel.mesh import make_mesh
+from ray_tpu.train import spmd
+
+MOSAIC = "tpu_custom_call"  # the custom-call target of a compiled Pallas kernel
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu here: nothing to compile with
+        pytest.skip(f"no TPU topology description available: {e!r}")
+    assert len(topo.devices) == 4 and topo.devices[0].platform == "tpu"
+    # make_train_step turns the persistent compile cache on for a TPU mesh;
+    # the CPU tests that run after this module should not inherit it
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache_dir = jax.config.jax_compilation_cache_dir
+    yield topo.devices
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    compilation_cache.reset_cache()
+
+
+def _on(dev, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=SingleDeviceSharding(dev))
+
+
+@pytest.mark.parametrize("D", [64, 128])
+def test_flash_fwd_bwd_compiles(v5e, D):
+    q = _on(v5e[0], (1, 1024, 8, D))
+    kv = _on(v5e[0], (1, 1024, 2, D))
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, interpret=False).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile().as_text()
+    assert text.count(MOSAIC) >= 3  # forward, dQ, dK/dV
+
+
+@pytest.mark.parametrize("D", [64, 128])
+def test_paged_decode_compiles(v5e, D):
+    B, Hq, Hkv, BS, max_blocks = 8, 32, 8, 16, 16
+    d = v5e[0]
+    pages = _on(d, (Hkv, B * max_blocks + 1, BS, D))
+    text = jax.jit(lambda *a: paged_decode_attention(*a, interpret=False)).lower(
+        _on(d, (B, Hq, D)), pages, pages, _on(d, (B, max_blocks), jnp.int32),
+        _on(d, (B,), jnp.int32)).compile().as_text()
+    assert MOSAIC in text
+
+
+def test_paged_decode_step_takes_kernel_from_platform(v5e):
+    """forward_paged told it runs on a TPU reaches the kernel with no stub of
+    jax.devices — the decision is the caller's placement, not this host's."""
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(), dtype=jnp.bfloat16,
+                              head_dim=64)
+    B, bs, max_blocks = 4, 16, 8
+    d = v5e[0]
+    place = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(lambda: llama.init(cfg, jax.random.PRNGKey(0))))
+    pool = place(jax.eval_shape(
+        lambda: llama.init_kv_pool(cfg, B * max_blocks + 1, bs)))
+
+    def decode(params, pool, tokens, tables, lengths):
+        return llama.forward_paged(params, tokens, cfg, pool, tables, lengths,
+                                   bs, platform="tpu")
+
+    text = jax.jit(decode).lower(
+        params, pool, _on(d, (B, 1), jnp.int32),
+        _on(d, (B, max_blocks), jnp.int32), _on(d, (B,), jnp.int32),
+    ).compile().as_text()
+    assert MOSAIC in text
+
+
+def test_sharded_train_step_compiles_with_flash(v5e):
+    """On today's main path: make_train_step over fsdp=2 x tensor=2 at a
+    length that takes the flash kernel. Before default_attn_fn wrapped the
+    kernel in shard_map this failed to lower."""
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(), dtype=jnp.bfloat16,
+                              head_dim=64, max_seq_len=1024, remat=True)
+    mesh = make_mesh(4, fsdp=2, tensor=2, devices=v5e)
+    opt = spmd.make_optimizer(warmup=1)
+    state = jax.eval_shape(
+        lambda: spmd.init_state(cfg, jax.random.PRNGKey(0), optimizer=opt))
+    step = spmd.make_train_step(cfg, mesh, optimizer=opt)(state)
+    sh = spmd.state_shardings(cfg, mesh, state)
+    state_in = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), state, sh)
+    batch = jax.ShapeDtypeStruct((4, 1024), jnp.int32,
+                                 sharding=shd.batch_sharding(mesh))
+    text = step.lower(state_in, batch, batch).compile().as_text()
+    assert MOSAIC in text
+    assert "all-reduce" in text or "reduce-scatter" in text
+
+
+def test_make_mesh_refuses_missing_devices():
+    n = len(jax.devices())
+    with pytest.raises(ValueError, match="device"):
+        make_mesh(n + 1)
