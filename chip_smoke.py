@@ -21,7 +21,10 @@ entry points a user calls, at the full width of `LlamaConfig.llama_1b()`
 
 Any failed phase fails the run: no exception is downgraded. It exits 0 only
 after serve, the runtime and every engine thread are down, and then prints
-as its LAST line one JSON object, {"ok": true, "device": {...}, ...}.
+as its LAST line one JSON object with exactly these keys,
+{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}};
+what else the run learned (four_chip, compile cache, wall time) is on the
+`[smoke] summary:` line before it and in chiprun_out/chip_smoke.jsonl.
 Without a TPU backend it exits non-zero before any phase and prints no
 result; `main()` has no CPU mode. (tests/test_tpu_chip_smoke.py imports this file
 and runs each phase function at `LlamaConfig.tiny()` sizes on the CPU, so the
@@ -704,8 +707,18 @@ def main() -> int:
     os.makedirs(OUT_DIR, exist_ok=True)
     with open(os.path.join(OUT_DIR, "chip_smoke.jsonl"), "a") as f:
         f.write(json.dumps({**summary, "phases": phases}, default=str) + "\n")
-    print(json.dumps(summary), flush=True)
+    say(f"summary: {json.dumps(summary)}")
+    print(result_line(device), flush=True)
     return 0
+
+
+def result_line(device: dict) -> str:
+    """The LAST line of stdout, which the driver parses: exactly the keys
+    "ok" and "device", and in "device" exactly "platform", "kind", "count".
+    Everything else the run learned goes on the `summary:` line before it."""
+    return json.dumps({"ok": True, "device": {
+        "platform": str(device["platform"]), "kind": str(device["kind"]),
+        "count": int(device["count"])}})
 
 
 if __name__ == "__main__":

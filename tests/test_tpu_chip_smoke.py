@@ -56,6 +56,31 @@ def test_main_refuses_without_tpu():
     assert e.value.code not in (0, None) and "not 'tpu'" in str(e.value.code)
 
 
+def test_main_last_stdout_line_is_the_contract_object(monkeypatch, tmp_path,
+                                                      capsys):
+    """The driver parses the LAST line of stdout and refuses any key beyond
+    ok / device{platform, kind, count}. main()'s own control flow runs here
+    with the device and the phases stubbed (the phases have their own tests)."""
+    import json
+
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    monkeypatch.setattr(chip_smoke, "require_tpu", lambda: dict(device))
+    monkeypatch.setattr(chip_smoke, "teardown", lambda: None)
+    monkeypatch.setattr(chip_smoke, "OUT_DIR", str(tmp_path))
+    for name in ("phase_device", "phase_kernels", "phase_train", "phase_serve"):
+        monkeypatch.setattr(chip_smoke, name, lambda sizes: {})
+    monkeypatch.setattr("ray_tpu.util.compile_cache.ensure_compile_cache",
+                        lambda platform: str(tmp_path / "cache"))
+    assert chip_smoke.main() == 0
+    lines = capsys.readouterr().out.splitlines()
+    assert json.loads(lines[-1]) == {"ok": True, "device": device}
+    # what else the run learned is on the line before, not in the result
+    detail = json.loads(lines[-2].split("summary: ", 1)[1])
+    assert detail["four_chip"] == "skipped (1 devices)"
+    assert detail["claim"] is None
+    assert (tmp_path / "chip_smoke.jsonl").exists()
+
+
 def test_phase_device(session):
     assert session["platform"] == "cpu" and session["runtime_tpus"] == 0
     assert os.path.exists(session["native_store"])
