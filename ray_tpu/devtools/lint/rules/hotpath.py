@@ -147,17 +147,29 @@ HOT_PATHS = (
         ),
         missing_hint="router dispatch renamed? (update HOT_PATHS)",
     ),
+    # ISSUE-24: the step also clocks itself — one PhaseClock record per
+    # decode step and per admission (util/timeline.py). Nothing else may
+    # creep into the step: no instrument lookup, no RPC, no task submission.
     HotPath(
         file="ray_tpu/serve/llm_paged.py",
-        funcs=("_step_decode",),
-        reason="per-step decode loop; first-token stamp is one ring append",
+        funcs=("_step_decode", "_admit_one", "_decode_clock"),
+        reason="per-step decode loop and per-request admission; the "
+               "first-token stamp and the phase record are ring appends",
+        ban_rpc=True,
+        ban_submit=True,
         require_calls=(
             ("_step_decode", ("stamp",),
              "_step_decode no longer stamps decode_first_token — PD "
              "ledgers lose the first-token phase and TTFT degrades to "
              "completion time"),
+            ("_step_decode", ("_decode_clock",),
+             "_step_decode no longer clocks its phases — the engine/decode "
+             "timeline records and the engine:decode.* annotations go dark"),
+            ("_admit_one", ("PhaseClock",),
+             "_admit_one no longer clocks its phases — the engine/admit "
+             "timeline records and the engine:admit.* annotations go dark"),
         ),
-        missing_hint="paged decode step renamed? (update HOT_PATHS)",
+        missing_hint="paged engine step renamed? (update HOT_PATHS)",
     ),
     # ISSUE-12: streaming data plane pump / fetch / task bodies. May submit
     # tasks and get objects through the public API (which owns
@@ -218,8 +230,11 @@ HOT_PATHS = (
     HotPath(
         file="ray_tpu/util/timeline.py",
         funcs=("phase_reply", "stamp_task_phases", "record_span",
-               "drain_since"),
-        reason="per-task phase stamp on the worker exec path",
+               "drain_since",
+               # PhaseClock (ISSUE-24): runs inside every decode step
+               "mark", "close", "_open", "_end_phase"),
+        reason="per-task phase stamp on the worker exec path; the serving "
+               "engine's per-step phase clock",
         ban_rpc=True,
         ban_submit=True,
         forbid_imports=tuple(m for m in CONTROL_PLANE_IMPORTS

@@ -218,19 +218,20 @@ def auto_attention(q, k, v, causal: bool = True, platform: str | None = None):
 def _block(cfg: LlamaConfig, x, layer, positions, attn_fn):
     hd, nh, nkv = cfg.hd, cfg.num_heads, cfg.num_kv_heads
     B, S, h = x.shape
-    # attention
-    y = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-    q = (y @ layer["wq"]).reshape(B, S, nh, hd)
-    k = (y @ layer["wk"]).reshape(B, S, nkv, hd)
-    v = (y @ layer["wv"]).reshape(B, S, nkv, hd)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
-    o = attn_fn(q, k, v)
-    x = x + (o.reshape(B, S, nh * hd) @ layer["wo"])
-    # mlp
-    y = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
-    gate = jax.nn.silu(y @ layer["w_gate"])
-    x = x + ((gate * (y @ layer["w_up"])) @ layer["w_down"])
+    # the scopes are names in a profile and in the HLO's op_name, no more
+    with jax.named_scope("attn"):
+        y = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+        q = (y @ layer["wq"]).reshape(B, S, nh, hd)
+        k = (y @ layer["wk"]).reshape(B, S, nkv, hd)
+        v = (y @ layer["wv"]).reshape(B, S, nkv, hd)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+        o = attn_fn(q, k, v)
+        x = x + (o.reshape(B, S, nh * hd) @ layer["wo"])
+    with jax.named_scope("mlp"):
+        y = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
+        gate = jax.nn.silu(y @ layer["w_gate"])
+        x = x + ((gate * (y @ layer["w_up"])) @ layer["w_down"])
     return x
 
 
@@ -355,31 +356,39 @@ def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
 
     def body(x, layer_and_pool):
         layer, kp, vp = layer_and_pool  # kp/vp: [Hkv, NB, BS, D]
-        y = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-        q = (y @ layer["wq"]).reshape(B, S, nh, hd)
-        k = (y @ layer["wk"]).reshape(B, S, nkv, hd)
-        v = (y @ layer["wv"]).reshape(B, S, nkv, hd)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
-        # head-major scatter: kp[h, blk_idx[b,s], blk_off[b,s]] = k[b,s,h]
-        kp = kp.at[:, blk_idx, blk_off].set(k.transpose(2, 0, 1, 3).astype(kp.dtype))
-        vp = vp.at[:, blk_idx, blk_off].set(v.transpose(2, 0, 1, 3).astype(vp.dtype))
-        if use_kernel:
-            from ray_tpu.ops.paged_attention import paged_decode_attention
+        # the scopes name, in a profile, the statement behind each pool-sized
+        # copy of a step: attn/kv_write or attn/kv_read
+        with jax.named_scope("attn"):
+            y = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+            q = (y @ layer["wq"]).reshape(B, S, nh, hd)
+            k = (y @ layer["wk"]).reshape(B, S, nkv, hd)
+            v = (y @ layer["wv"]).reshape(B, S, nkv, hd)
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
+            with jax.named_scope("kv_write"):
+                # head-major scatter: kp[h, blk_idx[b,s], blk_off[b,s]] = k[b,s,h]
+                kp = kp.at[:, blk_idx, blk_off].set(
+                    k.transpose(2, 0, 1, 3).astype(kp.dtype))
+                vp = vp.at[:, blk_idx, blk_off].set(
+                    v.transpose(2, 0, 1, 3).astype(vp.dtype))
+            with jax.named_scope("kv_read"):
+                if use_kernel:
+                    from ray_tpu.ops.paged_attention import paged_decode_attention
 
-            o = paged_decode_attention(
-                q[:, 0], kp, vp, tables, lengths + 1,
-                interpret=platform != "tpu")[:, None]  # [B,1,Hq,D]
-        else:
-            k_seq = kp[:, tables].transpose(1, 2, 3, 0, 4).reshape(
-                B, max_blocks * block_size, nkv, hd)
-            v_seq = vp[:, tables].transpose(1, 2, 3, 0, 4).reshape(
-                B, max_blocks * block_size, nkv, hd)
-            o = _cached_attention(q, k_seq, v_seq, lengths, positions)
-        x = x + (o.reshape(B, S, nh * hd) @ layer["wo"])
-        y = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
-        gate = jax.nn.silu(y @ layer["w_gate"])
-        x = x + ((gate * (y @ layer["w_up"])) @ layer["w_down"])
+                    o = paged_decode_attention(
+                        q[:, 0], kp, vp, tables, lengths + 1,
+                        interpret=platform != "tpu")[:, None]  # [B,1,Hq,D]
+                else:
+                    k_seq = kp[:, tables].transpose(1, 2, 3, 0, 4).reshape(
+                        B, max_blocks * block_size, nkv, hd)
+                    v_seq = vp[:, tables].transpose(1, 2, 3, 0, 4).reshape(
+                        B, max_blocks * block_size, nkv, hd)
+                    o = _cached_attention(q, k_seq, v_seq, lengths, positions)
+            x = x + (o.reshape(B, S, nh * hd) @ layer["wo"])
+        with jax.named_scope("mlp"):
+            y = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
+            gate = jax.nn.silu(y @ layer["w_gate"])
+            x = x + ((gate * (y @ layer["w_up"])) @ layer["w_down"])
         return x, (kp, vp)
 
     x, (out_k, out_v) = jax.lax.scan(body, x, (params["layers"], pool["k"], pool["v"]))

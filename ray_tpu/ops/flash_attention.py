@@ -210,6 +210,7 @@ def _fwd_call(qbh, kbh, vbh, causal, block_q, block_k, interpret, kv_len):
         scratch_shapes=[_vmem((block_q,)), _vmem((block_q,)),
                         _vmem((block_q, D))],
         interpret=interpret,
+        name="flash_attention_fwd",
         **_compiler_params(interpret),
     )(qbh, kbh, vbh)
 
@@ -256,6 +257,7 @@ def _flash_bh_bwd(causal, block_q, block_k, interpret, kv_len, res, do):
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), qbh.dtype),
         scratch_shapes=[_vmem((block_q, D))],
         interpret=interpret,
+        name="flash_attention_dq",
         **_compiler_params(interpret),
     )(qbh, kbh, vbh, do, lse, delta)
 
@@ -283,6 +285,7 @@ def _flash_bh_bwd(causal, block_q, block_k, interpret, kv_len, res, do):
         ],
         scratch_shapes=[_vmem((block_k, D)), _vmem((block_k, D))],
         interpret=interpret,
+        name="flash_attention_dkv",
         **_compiler_params(interpret),
     )(kbh, vbh, qbh, do, lse, delta)
 

@@ -119,6 +119,7 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, gp, D), q.dtype),
         interpret=interpret,
+        name="paged_attention_decode",
         **({} if interpret else {"compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))}),
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q4, k_pages, v_pages)

@@ -27,7 +27,7 @@ import numpy as np
 
 from ray_tpu.models import llama
 from ray_tpu.ops.platform import target_platform
-from ray_tpu.util.compile_cache import ensure_compile_cache
+from ray_tpu.util.compile_cache import compile_totals, ensure_compile_cache
 
 
 @dataclasses.dataclass
@@ -195,23 +195,39 @@ class LLMEngine:
         return self.generate(prompt_ids, max_new_tokens).result(timeout)
 
     def stats(self) -> dict:
+        # compiles / compile_s are the PROCESS's (util/compile_cache.py): a
+        # step that compiles after warm-up shows as a rise between two reads
+        compiles, compile_s = compile_totals()[:2]
         with self._lock:
             return {
                 "active_slots": int(self.active.sum()),
                 "max_slots": self.config.max_batch_size,
                 "pending": self._pending.qsize(),
                 "platform": self.platform,
+                "compiles": compiles,
+                "compile_s": compile_s,
             }
 
     def shutdown(self) -> None:
         """Stop the loop and wait for it: a daemon thread still inside a
         jitted call when the interpreter tears down aborts the process
-        (status 134) with the device open."""
+        (status 134) with the device open. Requests still queued end too:
+        nothing will admit them, and a stream would wait out its poll."""
         self._running = False
         t = self._loop_thread
         if t is not None and t is not threading.current_thread():
             t.join()
-        self._fail_all_active(RuntimeError("LLM engine shut down"))
+        exc = RuntimeError("LLM engine shut down")
+        self._fail_all_active(exc)
+        while True:
+            try:
+                _, _, fut, _, tq = self._pending.get_nowait()
+            except queue.Empty:
+                break
+            if not fut.done():
+                fut.set_exception(exc)
+            if tq is not None:
+                tq.put(None)
 
     # ---- engine loop ----
     def _bucket(self, n: int) -> int:

@@ -23,6 +23,7 @@ never preempts mid-sequence (vLLM-style preemption is a later refinement).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import queue
 import time
@@ -35,6 +36,13 @@ from ray_tpu.models import llama
 from ray_tpu.serve import anatomy
 from ray_tpu.serve.llm import LLMConfig, LLMEngine, _Slot
 from ray_tpu.serve.paged_kv import BlockPool, NoFreeBlocks
+from ray_tpu.util.compile_cache import compile_totals
+from ray_tpu.util.timeline import PhaseClock
+
+# the phases of the engine's timeline records, in the order they run; each
+# is `<phase>_s` in the record and `engine:<record>.<phase>` in a profile
+_ADMIT_PHASES = ("alloc", "prefill", "wait", "copy", "sample")
+_DECODE_PHASES = ("dispatch", "wait", "copy", "sample", "finish")
 
 
 @dataclasses.dataclass
@@ -163,68 +171,87 @@ class PagedLLMEngine(LLMEngine):
     def _admit_one(self, prompt, max_new, fut, t_enq, tq, slot) -> bool:
         jnp = self._jnp
         bs = self.config.block_size
-        total_blocks = -(-(len(prompt) + max_new) // bs)
-        if total_blocks > self.pool_blocks - 1:
-            # can never fit this pool: reject now rather than requeue forever
-            if not fut.done():
-                fut.set_exception(ValueError(
-                    f"request needs {total_blocks} KV blocks but the pool has "
-                    f"{self.pool_blocks - 1}; raise num_blocks or shorten the request"
-                ))
-            if tq is not None:
-                tq.put(None)
-            return True
-        hit_ids, cached_len = self.allocator.lookup_prefix(prompt)
-        if cached_len >= len(prompt):
-            # whole prompt block-aligned-cached: recompute the last block so
-            # we still have logits to sample the first token from
-            self.allocator.free([hit_ids.pop()])
-            cached_len -= bs
+        # one engine/admit timeline record per call (PERF.md section 3): the
+        # phases tile it; `failed` stands unless a path below says otherwise
+        clock = PhaseClock("engine", "admit", _ADMIT_PHASES)
+        compile_s0 = compile_totals()[1]
+        info = {"prompt": len(prompt), "cached": 0, "bucket": 0, "slot": slot,
+                "queue_wait_s": clock.t0 - t_enq, "outcome": "failed"}
         try:
-            fresh = self.allocator.alloc(total_blocks - len(hit_ids))
-        except NoFreeBlocks:
-            for b in hit_ids:
-                self.allocator.free([b])
-            return False  # requeue: capacity frees as sequences finish
-        block_ids = hit_ids + fresh
-        suffix = prompt[cached_len:]
-        # clamp the prefill bucket so padded positions stay inside the table
-        bucket = min(self._bucket(len(suffix)),
-                     self.config.max_seq_len - cached_len)
-        padded = np.zeros((1, bucket), dtype=np.int32)
-        padded[0, : len(suffix)] = suffix
-        table_row = np.zeros((1, self.max_blocks_per_seq), dtype=np.int32)
-        table_row[0, : len(block_ids)] = block_ids
-        try:
-            logits, self.pool = self._prefill(
-                self.params, self.pool, jnp.asarray(padded),
-                jnp.asarray(table_row), jnp.asarray([cached_len], np.int32),
-            )
-            tok = self._sample(np.asarray(logits)[len(suffix) - 1])
-        except Exception as e:  # noqa: BLE001 - bad request: fail, keep serving
-            self.allocator.free(block_ids)
-            if not fut.done():
-                fut.set_exception(e)
-            if tq is not None:
-                tq.put(None)
+            total_blocks = -(-(len(prompt) + max_new) // bs)
+            if total_blocks > self.pool_blocks - 1:
+                # can never fit this pool: reject now rather than requeue forever
+                if not fut.done():
+                    fut.set_exception(ValueError(
+                        f"request needs {total_blocks} KV blocks but the pool has "
+                        f"{self.pool_blocks - 1}; raise num_blocks or shorten the request"
+                    ))
+                if tq is not None:
+                    tq.put(None)
+                info["outcome"] = "rejected"
+                return True
+            hit_ids, cached_len = self.allocator.lookup_prefix(prompt)
+            if cached_len >= len(prompt):
+                # whole prompt block-aligned-cached: recompute the last block so
+                # we still have logits to sample the first token from
+                self.allocator.free([hit_ids.pop()])
+                cached_len -= bs
+            try:
+                fresh = self.allocator.alloc(total_blocks - len(hit_ids))
+            except NoFreeBlocks:
+                for b in hit_ids:
+                    self.allocator.free([b])
+                info["outcome"] = "requeued"
+                return False  # requeue: capacity frees as sequences finish
+            block_ids = hit_ids + fresh
+            suffix = prompt[cached_len:]
+            # clamp the prefill bucket so padded positions stay inside the table
+            bucket = min(self._bucket(len(suffix)),
+                         self.config.max_seq_len - cached_len)
+            info["cached"], info["bucket"] = cached_len, bucket
+            padded = np.zeros((1, bucket), dtype=np.int32)
+            padded[0, : len(suffix)] = suffix
+            table_row = np.zeros((1, self.max_blocks_per_seq), dtype=np.int32)
+            table_row[0, : len(block_ids)] = block_ids
+            try:
+                clock.mark("prefill")  # returns when the program is enqueued
+                logits, self.pool = self._prefill(
+                    self.params, self.pool, jnp.asarray(padded),
+                    jnp.asarray(table_row), jnp.asarray([cached_len], np.int32),
+                )
+                clock.mark("wait")  # the device's part; np.asarray would wait too
+                logits.block_until_ready()
+                clock.mark("copy")  # [bucket, vocab] float32 to the host
+                logits_np = np.asarray(logits)
+                clock.mark("sample")
+                tok = self._sample(logits_np[len(suffix) - 1])
+            except Exception as e:  # noqa: BLE001 - bad request: fail, keep serving
+                self.allocator.free(block_ids)
+                if not fut.done():
+                    fut.set_exception(e)
+                if tq is not None:
+                    tq.put(None)
+                return True
+            self.allocator.register_prefix(prompt, block_ids,
+                                           skip_blocks=cached_len // bs)
+            with self._lock:
+                st = _Slot(fut, max_new, len(prompt), t_enq, tq)
+                st.generated.append(tok)
+                if tq is not None:
+                    tq.put(tok)
+                st.first_token_time = time.monotonic()
+                self.slots[slot] = st
+                self.active[slot] = True
+                self.lengths[slot] = len(prompt)
+                self.last_tokens[slot, 0] = tok
+                self.tables[slot] = table_row[0]
+                self.slot_blocks[slot] = block_ids
+                self.slot_prompts[slot] = list(prompt)
+            self._maybe_finish(slot, tok)
+            info["outcome"] = "admitted"
             return True
-        self.allocator.register_prefix(prompt, block_ids,
-                                       skip_blocks=cached_len // bs)
-        with self._lock:
-            st = _Slot(fut, max_new, len(prompt), t_enq, tq)
-            st.generated.append(tok)
-            if tq is not None:
-                tq.put(tok)
-            st.first_token_time = time.monotonic()
-            self.slots[slot] = st
-            self.active[slot] = True
-            self.lengths[slot] = len(prompt)
-            self.last_tokens[slot, 0] = tok
-            self.tables[slot] = table_row[0]
-            self.slot_blocks[slot] = block_ids
-            self.slot_prompts[slot] = list(prompt)
-        self._maybe_finish(slot, tok)
-        return True
+        finally:
+            clock.close(compile_s=compile_totals()[1] - compile_s0, **info)
 
     def _loop_step(self) -> bool:
         did_work = self._step_ops()
@@ -239,6 +266,7 @@ class PagedLLMEngine(LLMEngine):
                 kind, payload, fut = self._ops.get_nowait()
             except queue.Empty:
                 break
+            clock = PhaseClock("engine", "ops")
             try:
                 if kind == "prefill_extract":
                     fut.set_result(self._do_prefill_extract(payload))
@@ -247,6 +275,8 @@ class PagedLLMEngine(LLMEngine):
             except Exception as e:  # noqa: BLE001
                 if not fut.done():
                     fut.set_exception(e)
+            finally:
+                clock.close(kind=kind)
             did_work = True
         return did_work
 
@@ -269,35 +299,57 @@ class PagedLLMEngine(LLMEngine):
             self._pending.put(req)
         return did_work
 
+    @contextlib.contextmanager
+    def _decode_clock(self, phases: tuple):
+        """One engine/decode timeline record for the step run inside it
+        (PERF.md section 3); the caller marks the phases after the first."""
+        clock = PhaseClock("engine", "decode", phases)
+        compile_s0 = compile_totals()[1]
+        live = int(self.active.sum())
+        ctx = int(self.lengths[self.active].sum())
+        try:
+            yield clock
+        finally:
+            clock.close(live=live, ctx=ctx,
+                        compile_s=compile_totals()[1] - compile_s0)
+
     def _step_decode(self) -> bool:
         jnp = self._jnp
         if not self.active.any():
             return False
-        logits, self.pool = self._decode(
-            self.params, self.pool, jnp.asarray(self.last_tokens),
-            jnp.asarray(self.lengths), jnp.asarray(self.tables),
-        )
-        logits_np = np.asarray(logits)
-        with self._lock:
+        with self._decode_clock(_DECODE_PHASES) as clock:
+            # dispatch: three small uploads and the call, which returns when
+            # the step is enqueued
+            logits, self.pool = self._decode(
+                self.params, self.pool, jnp.asarray(self.last_tokens),
+                jnp.asarray(self.lengths), jnp.asarray(self.tables),
+            )
+            clock.mark("wait")  # the device's step; np.asarray would wait too
+            logits.block_until_ready()
+            clock.mark("copy")  # [B, vocab] float32 to the host
+            logits_np = np.asarray(logits)
+            clock.mark("sample")
+            with self._lock:
+                for i in range(self.config.max_batch_size):
+                    if not self.active[i]:
+                        continue
+                    tok = self._sample(logits_np[i])
+                    st = self.slots[i]
+                    st.generated.append(tok)
+                    if st.token_queue is not None:
+                        st.token_queue.put(tok)
+                    self.lengths[i] += 1
+                    self.last_tokens[i, 0] = tok
+            clock.mark("finish")
+            if self._anatomy_pending:  # falsy-dict check: zero cost per step
+                t_w = anatomy.now_wall()
+                for i in list(self._anatomy_pending):
+                    if self.active[i]:
+                        anatomy.stamp(self._anatomy_pending.pop(i),
+                                      "decode_first_token", t_w)
             for i in range(self.config.max_batch_size):
-                if not self.active[i]:
-                    continue
-                tok = self._sample(logits_np[i])
-                st = self.slots[i]
-                st.generated.append(tok)
-                if st.token_queue is not None:
-                    st.token_queue.put(tok)
-                self.lengths[i] += 1
-                self.last_tokens[i, 0] = tok
-        if self._anatomy_pending:  # falsy-dict check: zero cost per step
-            t_w = anatomy.now_wall()
-            for i in list(self._anatomy_pending):
                 if self.active[i]:
-                    anatomy.stamp(self._anatomy_pending.pop(i),
-                                  "decode_first_token", t_w)
-        for i in range(self.config.max_batch_size):
-            if self.active[i]:
-                self._maybe_finish(i, self.slots[i].generated[-1])
+                    self._maybe_finish(i, self.slots[i].generated[-1])
         return True
 
     # ---- PD disaggregation handoff (reference: pd_server.py + NIXL KV

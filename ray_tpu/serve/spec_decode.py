@@ -29,7 +29,10 @@ from typing import Optional
 import numpy as np
 
 from ray_tpu.models import llama
-from ray_tpu.serve.llm_paged import PagedLLMConfig, PagedLLMEngine
+from ray_tpu.serve.llm_paged import (_DECODE_PHASES, PagedLLMConfig,
+                                     PagedLLMEngine)
+
+_SPEC_DECODE_PHASES = ("draft",) + _DECODE_PHASES
 
 
 @dataclasses.dataclass
@@ -172,63 +175,72 @@ class SpecDecodeLLMEngine(PagedLLMEngine):
             return False
         K = self.config.num_speculative_tokens
         B = self.config.max_batch_size
-        proposals = np.zeros((B, K), dtype=np.int32)
-        base_lengths = self.lengths.copy()
-        # device residents hoisted out of the loop: tables/lengths don't change
-        # within a step, so upload once and derive shifted lengths on device
-        tables_dev = jnp.asarray(self.tables)
-        base_dev = jnp.asarray(base_lengths)
-        # first draft step: [prev, last] 2-token window (fills any bonus-token
-        # draft-KV hole from a fully-accepted prior step), logits propose p1
-        window2 = np.concatenate([self.prev_tokens, self.last_tokens], axis=1)
-        dlogits, self.draft_pool = self._draft_decode2(
-            self.draft_params, self.draft_pool, jnp.asarray(window2),
-            jnp.maximum(base_dev - 1, 0), tables_dev,
-        )
-        proposals[:, 0] = np.argmax(np.asarray(dlogits), axis=-1)
-        cur = proposals[:, 0:1]
-        for k in range(1, K):
-            dlogits, self.draft_pool = self._draft_decode(
-                self.draft_params, self.draft_pool, jnp.asarray(cur),
-                base_dev + k, tables_dev,
+        # the base engine's engine/decode record, with the proposals as one
+        # more phase in front; `wait` is the verify pass on the device
+        with self._decode_clock(_SPEC_DECODE_PHASES) as clock:
+            proposals = np.zeros((B, K), dtype=np.int32)
+            base_lengths = self.lengths.copy()
+            # device residents hoisted out of the loop: tables/lengths don't change
+            # within a step, so upload once and derive shifted lengths on device
+            tables_dev = jnp.asarray(self.tables)
+            base_dev = jnp.asarray(base_lengths)
+            # first draft step: [prev, last] 2-token window (fills any bonus-token
+            # draft-KV hole from a fully-accepted prior step), logits propose p1
+            window2 = np.concatenate([self.prev_tokens, self.last_tokens], axis=1)
+            dlogits, self.draft_pool = self._draft_decode2(
+                self.draft_params, self.draft_pool, jnp.asarray(window2),
+                jnp.maximum(base_dev - 1, 0), tables_dev,
             )
-            proposals[:, k] = np.argmax(np.asarray(dlogits), axis=-1)
-            cur = proposals[:, k : k + 1]
-        window = np.concatenate([self.last_tokens, proposals], axis=1)  # [B, K+1]
-        logits, self.pool = self._verify(
-            self.params, self.pool, jnp.asarray(window), base_dev, tables_dev,
-        )
-        logits_np = np.asarray(logits)  # [B, K+1, V]
-        target_preds = np.argmax(logits_np, axis=-1)  # [B, K+1]
-        finished = []
-        with self._lock:
-            for i in range(B):
-                if not self.active[i]:
-                    continue
-                st = self.slots[i]
-                # accept proposals while they match the target's greedy choice
-                a = 0
-                while a < K and proposals[i, a] == target_preds[i, a]:
-                    a += 1
-                committed = list(proposals[i, :a]) + [int(target_preds[i, a])]
-                remaining = st.max_new - len(st.generated)
-                committed = committed[: max(0, remaining)]
-                eos = self.config.eos_token_id
-                if eos >= 0 and eos in committed:
-                    committed = committed[: committed.index(eos) + 1]
-                for tok in committed:
-                    st.generated.append(int(tok))
-                    if st.token_queue is not None:
-                        st.token_queue.put(int(tok))
-                self.lengths[i] = base_lengths[i] + len(committed)
-                if len(committed) >= 2:
-                    self.prev_tokens[i, 0] = committed[-2]
-                elif committed:
-                    self.prev_tokens[i, 0] = self.last_tokens[i, 0]
-                if committed:
-                    self.last_tokens[i, 0] = committed[-1]
-                finished.append(i)
-        for i in finished:
-            if self.active[i]:
-                self._maybe_finish(i, self.slots[i].generated[-1])
+            proposals[:, 0] = np.argmax(np.asarray(dlogits), axis=-1)
+            cur = proposals[:, 0:1]
+            for k in range(1, K):
+                dlogits, self.draft_pool = self._draft_decode(
+                    self.draft_params, self.draft_pool, jnp.asarray(cur),
+                    base_dev + k, tables_dev,
+                )
+                proposals[:, k] = np.argmax(np.asarray(dlogits), axis=-1)
+                cur = proposals[:, k : k + 1]
+            clock.mark("dispatch")
+            window = np.concatenate([self.last_tokens, proposals], axis=1)  # [B, K+1]
+            logits, self.pool = self._verify(
+                self.params, self.pool, jnp.asarray(window), base_dev, tables_dev,
+            )
+            clock.mark("wait")
+            logits.block_until_ready()
+            clock.mark("copy")
+            logits_np = np.asarray(logits)  # [B, K+1, V]
+            clock.mark("sample")
+            target_preds = np.argmax(logits_np, axis=-1)  # [B, K+1]
+            finished = []
+            with self._lock:
+                for i in range(B):
+                    if not self.active[i]:
+                        continue
+                    st = self.slots[i]
+                    # accept proposals while they match the target's greedy choice
+                    a = 0
+                    while a < K and proposals[i, a] == target_preds[i, a]:
+                        a += 1
+                    committed = list(proposals[i, :a]) + [int(target_preds[i, a])]
+                    remaining = st.max_new - len(st.generated)
+                    committed = committed[: max(0, remaining)]
+                    eos = self.config.eos_token_id
+                    if eos >= 0 and eos in committed:
+                        committed = committed[: committed.index(eos) + 1]
+                    for tok in committed:
+                        st.generated.append(int(tok))
+                        if st.token_queue is not None:
+                            st.token_queue.put(int(tok))
+                    self.lengths[i] = base_lengths[i] + len(committed)
+                    if len(committed) >= 2:
+                        self.prev_tokens[i, 0] = committed[-2]
+                    elif committed:
+                        self.prev_tokens[i, 0] = self.last_tokens[i, 0]
+                    if committed:
+                        self.last_tokens[i, 0] = committed[-1]
+                    finished.append(i)
+            clock.mark("finish")
+            for i in finished:
+                if self.active[i]:
+                    self._maybe_finish(i, self.slots[i].generated[-1])
         return True

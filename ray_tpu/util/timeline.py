@@ -5,7 +5,8 @@ The consumption layer over the PR-8 telemetry plane (ISSUE 13). Two halves:
 **Recording (every process).** Worker exec paths stamp per-task PHASE clocks
 (received -> args-deserialized -> exec -> outputs-stored, monotonic reads,
 ``stamp_task_phases``) and subsystems record coarse windows (sampled
-compiled-graph steps, whole plane pulls, ``record_span``) into one bounded
+compiled-graph steps, whole plane pulls, ``record_span``; one entry per
+serving-engine decode step and admission, ``PhaseClock``) into one bounded
 in-process ring. The stamping path is bind-only by contract — a list append
 under one small lock, no instrument construction/lookup, no RPC — pinned by
 ``scripts/check_wire_schemas.py::check_phase_stamp_hot_path`` exactly like
@@ -96,13 +97,78 @@ def stamp_task_phases(task_bin: "bytes | None", worker_pid: int, clocks,
 
 def record_span(cat: str, name: str, t0_wall: float, dur_s: float,
                 args: "dict | None" = None) -> None:
-    """A coarse timeline window (sampled dag step, whole plane pull):
-    recorded at subsystem-chosen granularity, NEVER per hot event."""
+    """A coarse timeline window (sampled dag step, whole plane pull, one
+    engine decode step or admission): recorded at subsystem-chosen
+    granularity, a few entries a second at most (a decode step is a 5 Hz
+    event), NEVER per frame, per token or per task."""
     if not _ENABLED:
         return
     entry = ["span", next(_seq), cat, name, _PID, t0_wall, dur_s, args]
     with _lock:
         _ring.append(entry)
+
+
+_annotation = None  # jax.profiler.TraceAnnotation, looked up at first use
+
+
+class PhaseClock:
+    """Clocks one step of a loop through its phases and leaves ONE
+    ``record_span`` entry for it: ``args`` holds ``<phase>_s`` for every
+    declared phase (0.0 for one never reached), whatever ``close`` is given,
+    and ``profiled``. The phases tile the record: each ``mark`` ends the
+    running phase and starts the next on the same ``time.monotonic()`` read,
+    and ``close`` ends the last one on the read that ends the record.
+
+    The same intervals go to the profiler's clock as
+    ``jax.profiler.TraceAnnotation``s, ``<cat>:<name>`` around
+    ``<cat>:<name>.<phase>``, which cost a fraction of a microsecond while
+    no profiler session is on. ``profiled`` is true only if one was on both
+    when the clock opened and when it closed: such records are exactly those
+    of the interval a device trace covers. jax is imported at first use, so
+    this module still imports without it."""
+
+    __slots__ = ("_cat", "_name", "_outer", "_inner", "_key", "_phases",
+                 "_profiled", "_t", "t0")
+
+    def __init__(self, cat: str, name: str, phases: tuple = ()):
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        self._cat, self._name = cat, name
+        self._phases = {p + "_s": 0.0 for p in phases}
+        self._inner = None
+        self._profiled = _annotation.is_enabled()
+        self._outer = _annotation(f"{cat}:{name}")
+        self._outer.__enter__()
+        self.t0 = self._t = time.monotonic()
+        if phases:
+            self._open(phases[0])
+
+    def _open(self, phase: str) -> None:
+        self._key = phase + "_s"
+        self._inner = _annotation(f"{self._cat}:{self._name}.{phase}")
+        self._inner.__enter__()
+
+    def _end_phase(self, t: float) -> None:
+        if self._inner is not None:
+            self._inner.__exit__(None, None, None)
+            self._phases[self._key] = self._phases.get(self._key, 0.0) + (t - self._t)
+            self._t = t
+
+    def mark(self, phase: str) -> None:
+        self._end_phase(time.monotonic())
+        self._open(phase)
+
+    def close(self, **args) -> None:
+        t = time.monotonic()
+        self._end_phase(t)
+        self._outer.__exit__(None, None, None)
+        args.update(self._phases)
+        args["profiled"] = self._profiled and _annotation.is_enabled()
+        record_span(self._cat, self._name, self.t0 + _MONO_ANCHOR,
+                    t - self.t0, args)
 
 
 def drain_since(cursor: int) -> "tuple[list, int]":

@@ -561,6 +561,33 @@ def test_hot_path_registry_covers_post_pr8_paths():
             "ray_tpu/core/object_plane.py"} <= declared
 
 
+def test_engine_step_is_a_declared_hot_path():
+    """ISSUE 24: the paged engine's decode step, its admission and the phase
+    clock they run are declared, so a later PR cannot put an instrument
+    lookup or an RPC into the step."""
+    by_file = {spec.file: spec for spec in hotpath.HOT_PATHS}
+    engine = by_file["ray_tpu/serve/llm_paged.py"]
+    assert {"_step_decode", "_admit_one"} <= set(engine.funcs)
+    assert engine.ban_metric_construct and engine.ban_rpc
+    assert {"mark", "close"} <= set(by_file["ray_tpu/util/timeline.py"].funcs)
+    ctx = FakeCtx({"ray_tpu/serve/llm_paged.py": '''
+def _decode_clock(self, phases):
+    yield PhaseClock("engine", "decode", phases)
+
+def _step_decode(self):
+    with self._decode_clock(()) as clock:
+        self._head.notify("decode_step")
+    stamp()
+
+def _admit_one(self, prompt, max_new, fut, t_enq, tq, slot):
+    get_metric("admissions").inc()
+'''})
+    keys = {f.key for f in hotpath.hot_path_findings(
+        ctx, files={"ray_tpu/serve/llm_paged.py"})}
+    assert keys == {"_step_decode:calls:notify", "_admit_one:calls:get_metric",
+                    "_admit_one:requires:PhaseClock"}
+
+
 def test_reactor_blocking_handler_fixture():
     from ray_tpu.core.rpc import schema
 

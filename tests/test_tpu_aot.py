@@ -11,6 +11,7 @@ also right.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -74,13 +75,11 @@ def test_paged_decode_compiles(v5e, D):
     assert MOSAIC in text
 
 
-def test_paged_decode_step_takes_kernel_from_platform(v5e):
-    """forward_paged told it runs on a TPU reaches the kernel with no stub of
-    jax.devices — the decision is the caller's placement, not this host's."""
+def _decode_step_text(d) -> str:
+    """The compiled HLO of one paged decode step told it runs on a TPU."""
     cfg = dataclasses.replace(llama.LlamaConfig.tiny(), dtype=jnp.bfloat16,
                               head_dim=64)
     B, bs, max_blocks = 4, 16, 8
-    d = v5e[0]
     place = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
     params = place(jax.eval_shape(lambda: llama.init(cfg, jax.random.PRNGKey(0))))
     pool = place(jax.eval_shape(
@@ -90,11 +89,54 @@ def test_paged_decode_step_takes_kernel_from_platform(v5e):
         return llama.forward_paged(params, tokens, cfg, pool, tables, lengths,
                                    bs, platform="tpu")
 
-    text = jax.jit(decode).lower(
+    return jax.jit(decode).lower(
         params, pool, _on(d, (B, 1), jnp.int32),
         _on(d, (B, max_blocks), jnp.int32), _on(d, (B,), jnp.int32),
     ).compile().as_text()
-    assert MOSAIC in text
+
+
+def _train_attention_text(d) -> str:
+    """The compiled HLO of the loss's gradient at a length that takes the
+    flash kernel, under the remat the train step uses."""
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(), dtype=jnp.bfloat16,
+                              head_dim=64, max_seq_len=1024, remat=True)
+    attn = functools.partial(llama.auto_attention, causal=True, platform="tpu")
+    params = jax.tree.map(
+        lambda a: _on(d, a.shape, a.dtype),
+        jax.eval_shape(lambda: llama.init(cfg, jax.random.PRNGKey(0))))
+    tokens = _on(d, (1, 1024), jnp.int32)
+    return jax.jit(jax.grad(
+        lambda p, t: llama.loss_fn(p, t, t, cfg, attn_fn=attn))).lower(
+            params, tokens).compile().as_text()
+
+
+def test_paged_decode_step_takes_kernel_from_platform(v5e):
+    """forward_paged told it runs on a TPU reaches the kernel with no stub of
+    jax.devices — the decision is the caller's placement, not this host's."""
+    assert MOSAIC in _decode_step_text(v5e[0])
+
+
+@pytest.mark.parametrize("text_of, names", [
+    (_decode_step_text, ("paged_attention_decode", "attn/kv_write",
+                         "attn/kv_read/paged_attention_decode", "/mlp/")),
+    (_train_attention_text, ("flash_attention_fwd", "flash_attention_dq",
+                             "flash_attention_dkv", "/attn/flash_attention_fwd",
+                             "/mlp/")),
+], ids=["paged_decode_step", "train_attention"])
+def test_compiled_text_names_kernels_and_scopes(v5e, text_of, names):
+    """A profile of the chip shows operations under the names the compiled
+    text carries: each Pallas kernel by its own `name=`, and the model's
+    named scopes (attention, its paged KV write and read, the MLP) in
+    `op_name`. The benchmark's reduction tells kernels apart by them."""
+    text = text_of(v5e[0])
+    for name in names:
+        assert name in text, name
+    kernels = [ln for ln in text.splitlines() if MOSAIC in ln]
+    assert kernels and all(
+        ln.lstrip().startswith(("%paged_attention_decode", "%flash_attention_",
+                                "ROOT %paged_attention_decode",
+                                "ROOT %flash_attention_"))
+        for ln in kernels)
 
 
 def test_sharded_train_step_compiles_with_flash(v5e):
