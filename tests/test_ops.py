@@ -82,3 +82,97 @@ def test_flash_as_llama_attn_fn():
     out = llama.forward(params, tokens, cfg,
                         attn_fn=lambda q, k, v: flash_attention(q, k, v, block_q=32, block_k=32))
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=5e-4)
+
+
+# ---- tiles from the shapes, operands in the input's dtype, K/V by group
+
+from ray_tpu.ops import flash_attention as fa  # noqa: E402
+
+BF16_EPS = 2.0 ** -8
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize("S", [1000, 1024, 4096, 8192])
+def test_choose_tiles_divide_fit_and_keep_the_layout(S, D, dtype):
+    itemsize = jnp.dtype(dtype).itemsize
+    S_pad = fa.padded_len(S)
+    assert S_pad >= S and S_pad % 128 == 0 and S_pad - S < 128
+    for kernel in ("fwd", "dq", "dkv"):
+        bq, bk = fa.choose_tiles(S, D, itemsize, kernel)
+        assert S_pad % bq == 0 and S_pad % bk == 0
+        # a block's last two dims are (8k or 16k, 128m): tile edges are the
+        # sublane dim of a [tile, D] block and the lane dim of a [1, tile] row
+        assert bq % 128 == 0 and bk % 128 == 0
+        assert fa.tile_vmem_bytes(kernel, bq, bk, D, itemsize) <= fa.VMEM_BUDGET
+        # and they are large: 128 x 128 at S = 4096 is 528 live tiles a head
+        live = len(fa._live_tiles(S_pad, bq, bk, True))
+        assert live <= 3 * (S_pad // 1024) ** 2 + 1, (bq, bk, live)
+
+
+def test_choose_tiles_short_sequence_is_one_tile():
+    assert fa.padded_len(100) == 112 and fa.padded_len(128) == 128
+    assert fa.choose_tiles(100, 64, 2, "fwd") == (112, 112)
+    # a length whose padded form has no large divisor keeps a multiple of 128
+    assert fa.choose_tiles(1100, 128, 2, "dkv") == (384, 384)
+
+
+def _gqa_case(S, dtype=jnp.bfloat16, Hq=8, Hkv=2, D=16, seed=4):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (1, S, Hq, D), dtype),
+            jax.random.normal(ks[1], (1, S, Hkv, D), dtype),
+            jax.random.normal(ks[2], (1, S, Hkv, D), dtype),
+            jax.random.normal(ks[3], (1, S, Hq, D), jnp.float32))
+
+
+def _assert_close_to_float32_dense(q, k, v, w, causal, **blocks):
+    """Forward and all three gradients against llama.attention taken in
+    float32, at chip_smoke.py's tolerances: 4 bf16 eps of max|v| forward,
+    8 bf16 eps of the largest reference entry for each gradient."""
+    def flash_loss(q, k, v):
+        o = flash_attention(q, k, v, causal=causal, **blocks)
+        return (o.astype(jnp.float32) * w).sum(), o
+
+    def dense_loss(q, k, v):
+        o = llama.attention(*(x.astype(jnp.float32) for x in (q, k, v)),
+                            causal=causal)
+        return (o * w).sum(), o
+
+    (_, o_f), g_f = jax.value_and_grad(flash_loss, (0, 1, 2), has_aux=True)(q, k, v)
+    (_, o_d), g_d = jax.value_and_grad(dense_loss, (0, 1, 2), has_aux=True)(q, k, v)
+    assert o_f.dtype == q.dtype and all(g.dtype == q.dtype for g in g_f)
+    vmax = float(jnp.max(jnp.abs(v.astype(jnp.float32))))
+    np.testing.assert_allclose(np.asarray(o_f, np.float32), np.asarray(o_d),
+                               rtol=0, atol=4 * BF16_EPS * vmax)
+    for name, gf, gd in zip(("dq", "dk", "dv"), g_f, g_d):
+        np.testing.assert_allclose(
+            np.asarray(gf, np.float32), np.asarray(gd), rtol=0,
+            atol=8 * BF16_EPS * float(jnp.max(jnp.abs(gd))), err_msg=name)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("S", [2048, 1100], ids=["S2048", "S1100-pads"])
+def test_flash_default_tiles_bf16_gqa(S, causal):
+    """DEFAULT tiles (1024 x 1024 at 2048; 384 x 384 over 1152 at 1100, whose
+    last key tile is part padding), bf16 operands, g = 4."""
+    _assert_close_to_float32_dense(*_gqa_case(S), causal)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("bq, bk", [(64, 32), (32, 64)], ids=["q>k", "k>q"])
+def test_flash_unequal_tiles_bf16_gqa(bq, bk, causal):
+    """A query tile larger than a key tile and the reverse, at a length that
+    pads (160 -> 192): the live-tile tables, the diagonal-only masks and the
+    padded-end mask are all crossed, with the group's dK/dV summed in-kernel."""
+    _assert_close_to_float32_dense(*_gqa_case(160), causal, block_q=bq, block_k=bk)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_flash_live_tiles_cover_exactly_the_live_entries(causal):
+    bq, bk, S = 64, 32, 192
+    tiles = set(fa._live_tiles(S, bq, bk, causal))
+    for qi in range(S // bq):
+        for ki in range(S // bk):
+            has_live = not causal or ki * bk <= qi * bq + bq - 1
+            assert ((qi, ki) in tiles) == has_live
+    assert len(tiles) == (12 if causal else 18)

@@ -12,6 +12,8 @@ also right.
 
 import dataclasses
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +64,76 @@ def test_flash_fwd_bwd_compiles(v5e, D):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile().as_text()
     assert text.count(MOSAIC) >= 3  # forward, dQ, dK/dV
+
+
+def _kernel_lines(text: str) -> list[str]:
+    return [ln.lstrip() for ln in text.splitlines() if MOSAIC in ln]
+
+
+def _group_broadcasts(text: str, n_q_elems: int) -> list[str]:
+    """Instructions that broadcast an ARRAY (not a scalar) to at least q's
+    size: what `jnp.repeat` of K or V over the query group compiles to
+    (`bf16[B,S,Hkv,g,D] broadcast(...), dimensions={0,1,2,4}`)."""
+    out = []
+    for ln in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* broadcast\(", ln)
+        if (m and "dimensions={}" not in ln
+                and math.prod(map(int, m.group(1).split(","))) >= n_q_elems):
+            out.append(ln.strip()[:160])
+    return out
+
+
+def _assert_flash_at_cell_shape(text: str, n_q_elems: int):
+    kernels = _kernel_lines(text)
+    assert len(kernels) >= 3, kernels                     # forward, dQ, dK/dV
+    # outside the model's remat an instruction is named after its transform
+    # too (`%transpose_jvp_flash_attention_dq__`); inside it, the test below
+    # holds the names to what a profile of the train step shows
+    assert all(re.match(r"(ROOT )?%(\w*jvp_)?flash_attention_(fwd|dq|dkv)", ln)
+               for ln in kernels), kernels
+    assert not _group_broadcasts(text, n_q_elems)
+
+
+def test_group_broadcast_detector_sees_a_repeat(v5e):
+    """The detector is not vacuous: K repeated over the group, the layout this
+    kernel no longer needs, is found in the compiled text."""
+    kv = _on(v5e[0], (1, 1024, 2, 128))
+    text = jax.jit(lambda k: jnp.repeat(k, 4, axis=2) * 2).lower(kv).compile().as_text()
+    assert _group_broadcasts(text, 1024 * 8 * 128)
+
+
+def test_flash_compiles_at_the_one_chip_cell_shape(v5e):
+    """`train-4k-1chip`'s attention, [3, 4096, 32, 128] with 8 KV heads, with
+    the tiles the kernel works out itself: fits VMEM, keeps its names, and
+    reads K and V by group (no [B, S, Hq, D]-sized copy of either)."""
+    B, S, Hq, Hkv, D = 3, 4096, 32, 8, 128
+    q, kv = _on(v5e[0], (B, S, Hq, D)), _on(v5e[0], (B, S, Hkv, D))
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, interpret=False).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile().as_text()
+    _assert_flash_at_cell_shape(text, B * S * Hq * D)
+
+
+def test_flash_compiles_at_the_fsdp4_cell_shape(v5e):
+    """`train-4k-fsdp4`'s attention: 8 sequences over fsdp=4, so [2, 4096, 32,
+    128] a device, under `default_attn_fn`'s shard_map."""
+    B, S, Hq, Hkv, D = 8, 4096, 32, 8, 128
+    mesh = make_mesh(4, fsdp=4, devices=v5e)
+    attn = spmd.default_attn_fn(mesh)
+    sh = lambda *names: jax.sharding.NamedSharding(mesh, shd.spec_from_logical(names))
+    q = jax.ShapeDtypeStruct((B, S, Hq, D), jnp.bfloat16,
+                             sharding=sh("batch", None, "heads", None))
+    kv = jax.ShapeDtypeStruct((B, S, Hkv, D), jnp.bfloat16,
+                              sharding=sh("batch", None, "kv_heads", None))
+
+    def loss(q, k, v):
+        return attn(q, k, v).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile().as_text()
+    _assert_flash_at_cell_shape(text, B // 4 * S * Hq * D)
+    assert f"bf16[{B // 4 * Hq},{S},{D}]" in text        # a device's own shard
 
 
 @pytest.mark.parametrize("D", [64, 128])
@@ -131,11 +203,10 @@ def test_compiled_text_names_kernels_and_scopes(v5e, text_of, names):
     text = text_of(v5e[0])
     for name in names:
         assert name in text, name
-    kernels = [ln for ln in text.splitlines() if MOSAIC in ln]
+    kernels = _kernel_lines(text)
     assert kernels and all(
-        ln.lstrip().startswith(("%paged_attention_decode", "%flash_attention_",
-                                "ROOT %paged_attention_decode",
-                                "ROOT %flash_attention_"))
+        ln.startswith(("%paged_attention_decode", "%flash_attention_",
+                       "ROOT %paged_attention_decode", "ROOT %flash_attention_"))
         for ln in kernels)
 
 
