@@ -4,25 +4,28 @@ The OpenAI replica constructs its engine itself, with weights from seed 0
 made eagerly. The benchmark needs weights from `--seed`, made in one jitted
 call, and the engine object to wrap. Both come from one wrapper around the
 engine class's `__init__` (the family module names the class), put on from
-here and taken off again; the engine is not edited. Spans and captures wrap
-the engine's own bound callables: `_prefill` and `_decode` for the check of
-every run, `_admit_one` and `_step_decode` for the spans of a traced one.
-Those four names, and the layout of `_pending` (serve_cell._stop_engine),
-are the seams the yardstick stands on. Where a later PR renames one the
-command fails and names it: a yardstick that went missing is not a result.
+here and taken off again; the engine is not edited. The check of every run
+wraps two of the engine's bound callables, `_prefill` and `_decode`, to keep
+the logits the engine samples from. Those two names and `__init__`'s
+`(config, params)` are the seams the yardstick still stands on: where a
+later PR renames one the command fails and names it, because a yardstick
+that went missing is not a result. Spans need no wrapper: the engine records
+its own admissions and decode steps (`harness/engine_records.py`), so the
+loop's methods are free to be renamed, split or merged. Sampling on the
+device (ROADMAP S4) will take the logits away from the host; the PR that does
+it has to bring a seam of the program's own, such as log-probabilities
+through the API, for the check to stand on.
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
 
 
 class EngineTap:
     def __init__(self, make_params):
         self.make_params = make_params
         self.engine = None
-        self.spans: list = []        # ("admit"|"decode", t0, t1, info dict)
         self.captured: list = []     # ("prefill"|"decode", inputs, logits)
         self._undo: list = []
 
@@ -49,7 +52,7 @@ class EngineTap:
         if not callable(orig):
             raise SystemExit(
                 f"benchmark: {type(self.engine).__name__} has no callable {name!r}; "
-                f"the check and the spans wrap it (benchmarks/harness/engine_tap.py)")
+                f"the check wraps it (benchmarks/harness/engine_tap.py)")
         setattr(self.engine, name, make(orig))
         self._undo.append((name, orig))
 
@@ -81,41 +84,3 @@ class EngineTap:
 
         self._wrap("_prefill", prefill)
         self._wrap("_decode", decode)
-
-    def record_spans(self, annotate: bool) -> None:
-        """Clocks each admission (allocator, batch-1 prefill, the host copy of
-        its logits, sampling) and each decode step (device step, host copy,
-        sampling) on `time.monotonic()`, and writes the same spans into the
-        profiler's trace so that idle gaps can be named."""
-        import jax
-
-        engine, spans = self.engine, self.spans
-        note = jax.profiler.TraceAnnotation if annotate else (
-            lambda name: contextlib.nullcontext())
-
-        def admit(orig):
-            def f(prompt, max_new, fut, t_enq, tq, slot):
-                t0 = time.monotonic()
-                with note("bench:admit"):
-                    out = orig(prompt, max_new, fut, t_enq, tq, slot)
-                spans.append(("admit", t0, time.monotonic(),
-                              {"id": int(prompt[0]), "prompt_len": len(prompt),
-                               "t_enq": t_enq, "admitted": bool(out)}))
-                return out
-            return f
-
-        def decode(orig):
-            def f():
-                live = engine.active.copy()
-                info = {"context_tokens": int(engine.lengths[live].sum()),
-                        "live": int(live.sum())}
-                t0 = time.monotonic()
-                with note("bench:decode"):
-                    out = orig()
-                if out:
-                    spans.append(("decode", t0, time.monotonic(), info))
-                return out
-            return f
-
-        self._wrap("_admit_one", admit)
-        self._wrap("_step_decode", decode)

@@ -3,7 +3,10 @@ file (see spec.Cell), as a traffic `kind` and a metric's `reader` are. A
 family module is everything the cell drivers take from the program for one
 architecture, so a new architecture is a new file here, a reference under
 `benchmarks/reference/` (the configuration's `reference` key) and a
-configuration file, and edits none. It holds:
+configuration file, and edits none. A family may serve only or train only:
+it then leaves `serve_app` or `train_state_and_step` out, and a cell that
+asks for the missing one ends with a `SystemExit` that names the family and
+the cell (`spec.Cell.family_entry`). It holds:
 
 - `MODEL_KEYS`: the published config.json keys the configuration file keeps
   at its top level; `cell.config["model"]` is the view of them.
@@ -14,7 +17,8 @@ configuration file, and edits none. It holds:
   harness taps that class's `__init__`, see engine_tap.py).
 - `train_state_and_step(model, trainer, mesh, key)`: the sharded train state,
   made in one jitted call, and the compiled step `step(state, tokens, targets)
-  -> (state, {"loss": ...})`.
+  -> (state, {"loss": ..., <any other scalar>: ...})`; every scalar of that
+  dict reaches the readers as the series `step.<name>`.
 - the yardstick's shapes functions that hold for this architecture, by the
   names the metric files give (`train_flops_per_token`, ...): a reader looks
   them up here, so a family whose arithmetic differs brings its own.

@@ -21,7 +21,7 @@ import threading
 import time
 import urllib.request
 
-from benchmarks.harness import device, xplane
+from benchmarks.harness import device, engine_records, xplane
 from benchmarks.harness.engine_tap import EngineTap
 from benchmarks.harness.measure import Measurement, log
 from benchmarks.harness.spec import ROOT
@@ -44,6 +44,16 @@ def _prompt(rid: int, n: int, vocab: int, seed: int) -> list[int]:
 
     rng = random.Random(seed * 7919 + rid)
     return [rid % vocab] + [rng.randrange(vocab) for _ in range(n - 1)]
+
+
+def sampled_row(logits, prompt_len: int):
+    """The row of a prefill's logits that the engine samples the first token
+    from, whatever shape `_prefill` hands back: row `prompt_len - 1` of a
+    `[bucket, vocab]` result, or the only row of one already cut to the last
+    live position (`[vocab]` or `[1, vocab]`)."""
+    if logits.ndim == 1:
+        return logits
+    return logits[0] if logits.shape[0] == 1 else logits[prompt_len - 1]
 
 
 def check_against_reference(tap: EngineTap, url: str, cell, seed: int) -> dict:
@@ -80,7 +90,7 @@ def check_against_reference(tap: EngineTap, url: str, cell, seed: int) -> dict:
             rows = []
             for kind, inputs, logits in tap.captured:
                 if kind == "prefill":
-                    rows.append(logits[len(prompt) - 1])
+                    rows.append(sampled_row(logits, len(prompt)))
                 else:
                     rows.append(logits[int(inputs[0])])  # the one live slot
             got = np.stack(rows)                       # [new_tokens, vocab]
@@ -117,31 +127,6 @@ def warm_buckets(cell, url: str, plan: dict, vocab: int, seed: int) -> list:
     return sorted(by_bucket)
 
 
-def _stop_engine(engine) -> None:
-    """Ends the requests the window's close cut, so that the app goes down at
-    once. Queued requests are given a few seconds to reach a slot first, and
-    any still queued after the engine's own shutdown are ended by hand: the
-    engine's shutdown fails the live slots only, and a stream whose request
-    never reached a slot would wait out its 300 s poll (a fault of the
-    program, listed in PERF.md). `_pending` and the layout of its entries
-    are the engine's own: where they change, this fails and says so."""
-    import queue
-
-    deadline = time.monotonic() + 20.0
-    while engine.stats()["pending"] and time.monotonic() < deadline:
-        time.sleep(0.1)
-    engine.shutdown()
-    while True:
-        try:
-            _, _, fut, _, tq = engine._pending.get_nowait()
-        except queue.Empty:
-            break
-        if not fut.done():
-            fut.set_exception(RuntimeError("LLM engine shut down"))
-        if tq is not None:
-            tq.put(None)
-
-
 def _reduce_records(done: dict, traffic: dict, ms: Measurement) -> None:
     """Client-side samples of the window, from the generator's records."""
     lo, hi = done["t_open"], done["t_close"]
@@ -172,35 +157,6 @@ def _reduce_records(done: dict, traffic: dict, ms: Measurement) -> None:
                     first_tokens=len(ttft))
 
 
-def _reduce_spans(tap: EngineTap, done: dict, ms: Measurement,
-                  traced: tuple | None) -> None:
-    """Engine-side samples of the window, from the wrappers' spans."""
-    lo, hi = done["t_open"], done["t_close"]
-    sent = {r["id"]: r["sent"] for r in done["records"] if r["sent"]}
-    prefill, decode, wait, host_path = [], [], [], []
-    ctx_tokens, live, traced_steps = [], [], 0
-    for kind, t0, t1, info in tap.spans:
-        if not lo <= t0 <= hi:
-            continue
-        if kind == "admit" and info["admitted"]:
-            prefill.append(t1 - t0)
-            wait.append(t0 - info["t_enq"])
-            if info["id"] in sent:
-                host_path.append(info["t_enq"] - sent[info["id"]])
-        elif kind == "decode":
-            decode.append(t1 - t0)
-            if traced and traced[0] <= t0 and t1 <= traced[1]:
-                traced_steps += 1
-                ctx_tokens.append(info["context_tokens"])
-                live.append(info["live"])
-    ms.series.update(prefill_s=prefill, decode_step_s=decode,
-                     queue_wait_s=wait, host_path_s=host_path)
-    if traced_steps:
-        ms.counters["traced_decode_steps"] = traced_steps
-        ms.counters["traced_context_tokens"] = sum(ctx_tokens) / traced_steps
-        ms.counters["traced_live_slots"] = sum(live) / traced_steps
-
-
 def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         dev: dict, peaks: dict) -> Measurement:
     import jax
@@ -211,6 +167,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     ms = Measurement(config=cell.config, traffic=cell.traffic, peaks=peaks,
                      family=cell.family)
     m, eng, fam = cell.config["model"], cell.config["engine"], cell.family
+    serve_app = cell.family_entry("serve_app")
     tap = EngineTap(lambda model_config: fam.seeded_params(model_config, seed))
     plan = cell.kind.plan(cell.traffic, seed, seconds, eng["max_batch_size"])
     gen = None
@@ -218,7 +175,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     trace_dir = os.path.join(ROOT, ".bench_tmp", f"trace-{cell.name}")
     try:
         ray_tpu.init()
-        app, engine_cls = fam.serve_app(fam.model_config(m), m, eng, IdTokenizer())
+        app, engine_cls = serve_app(fam.model_config(m), m, eng, IdTokenizer())
         with tap.constructing(engine_cls):
             handle = serve.run(app, route_prefix="/v1")
             stats = ray_tpu.get(handle.stats.remote())  # the replica is up
@@ -237,9 +194,6 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         log(f"checked against the reference: {check}")
         ms.notes["buckets"] = warm_buckets(cell, url, plan, m["vocab_size"], seed)
         log(f"warmed prefill buckets {ms.notes['buckets']}")
-        if trace:
-            tap.record_spans(annotate=True)
-
         plan.update(url=url, vocab=m["vocab_size"], seed=seed, seconds=seconds)
         env = {k: v for k, v in os.environ.items() if not k.startswith(("JAX_", "XLA_", "TPU_"))}
         gen = subprocess.Popen([sys.executable, LOADGEN], stdin=subprocess.PIPE,
@@ -252,6 +206,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         log(f"window opens: {len(plan['fill'])} fill requests have a first token")
         time.sleep(max(0.0, t_open - time.monotonic()))
         at_open = engine.stats()
+        ring_mark = engine_records.mark()
         traced = None
         if trace:
             tr = cell.traffic.get("trace", {})
@@ -277,7 +232,13 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
                         fill_errors=opened.get("fill_errors", 0))
         _reduce_records(done, cell.traffic, ms)
         if trace:
-            _reduce_spans(tap, done, ms, traced)
+            # engine-side samples of the window, from the engine's own records
+            records = engine_records.since(ring_mark)
+            series, counters = engine_records.reduce(
+                records, done["t_open"], done["t_close"], done["records"], traced)
+            ms.series.update(series)
+            ms.counters.update(counters)
+            ms.notes["engine_records"] = len(records)
             ms.trace = xplane.reduce_trace_dir(trace_dir)
         ms.counters["memory_peak_bytes"] = device.memory_peak_bytes()
     finally:
@@ -287,9 +248,10 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         tap.unwrap()
         if tap.engine is not None:
             # requests cut by the window's close would otherwise be decoded to
-            # their end before the app goes down; the replica repeats the
-            # engine's shutdown harmlessly
-            _stop_engine(tap.engine)
+            # their end before the app goes down. The engine's own shutdown
+            # ends the live slots and, since PR 24, the queued requests too;
+            # the replica repeats it harmlessly
+            tap.engine.shutdown()
         serve.shutdown()
         ray_tpu.shutdown()
         for t in threading.enumerate():  # an engine thread left inside a

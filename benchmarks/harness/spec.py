@@ -3,12 +3,15 @@
 A cell is `{name, config, traffic, chips, why}`. Its configuration is
 `benchmarks/configs/<config>.json`, whose `family` names a module under
 `benchmarks/harness/families/` (what the drivers take from the program for
-that architecture) and whose `reference` a module under `benchmarks/
-reference/`; its traffic is `benchmarks/traffic/<traffic>.json`, the
-traffic's kind a module `benchmarks/harness/traffic_kinds/<kind>.py`, and
-each metric `benchmarks/metrics/<name>.json` naming a reader module under
-`benchmarks/readers/`. Adding a cell, a mix, a metric or an architecture
-adds files and entries and edits none.
+that architecture), whose `reference` a module under `benchmarks/reference/`
+and whose `published_config` the published shape it was cut from
+(`benchmarks/configs/published/`); its traffic is `benchmarks/traffic/
+<traffic>.json`, the traffic's kind a module `benchmarks/harness/
+traffic_kinds/<kind>.py`, and each metric `benchmarks/metrics/<name>.json`
+naming a reader module under `benchmarks/readers/`. Which cells report a
+metric is said in BENCHMARK.json alone (`workloads`). Adding a cell, a mix,
+a metric or an architecture adds files and appends entries and edits no file
+(`benchmarks/tests/test_new_architecture_is_additions.py` holds that).
 """
 
 from __future__ import annotations
@@ -56,6 +59,19 @@ class Cell:
         """The plain reference of this configuration (imports jax)."""
         return importlib.import_module(
             f"benchmarks.reference.{self.config['reference']}")
+
+    def family_entry(self, name: str):
+        """An entry point of the family module that this cell's driver needs.
+        A family may serve only or train only; a cell that asks it for the
+        other says so here, by name, before anything is built."""
+        fn = getattr(self.family, name, None)
+        if not callable(fn):
+            raise SystemExit(
+                f"benchmark: cell {self.name!r} needs `{name}` of the family "
+                f"{self.config['family']!r} and benchmarks/harness/families/"
+                f"{self.config['family']}.py has none: that family does not "
+                f"{'train' if name.startswith('train') else 'serve'}")
+        return fn
 
     def _load(self, *parts: str) -> dict:
         with open(os.path.join(self.root, "benchmarks", *parts)) as f:

@@ -5,6 +5,9 @@ mesh (`make_mesh`), takes the sharded state and the compiled step from the
 configuration's family module (for the Llama family `spmd.init_state`, jitted
 once with `out_shardings`, and `spmd.make_train_step`) and steps on a fresh
 seeded batch of packed sequences each step, so `device_put` is in the loop.
+Whatever scalars the step returns in its metrics dict (`loss`, `grad_norm`,
+an architecture's router load or auxiliary loss) are kept for every step of
+the window as the series `step.<name>`, which `series_quantile` reads.
 
 `correct`: a step on a batch that repeats ONE sequence, before the window,
 gives that sequence's loss; the float32 reference computes the same loss
@@ -66,25 +69,25 @@ def _loop(config: dict) -> None:
         t1 = time.monotonic()
         state, metrics = step(state, tok, tgt)
         loss = float(jax.block_until_ready(metrics["loss"]))
-        return state, loss, t1 - t0, time.monotonic() - t1
+        return state, loss, t1 - t0, time.monotonic() - t1, metrics
 
     # -- correct: one sequence, repeated, against the reference
     tokens, targets = batch_of(0, repeat_one=True)
     want = reference.loss(state.params, tokens[0], targets[0], m)
     log(f"reference loss {want}")
-    state, got, _, _ = run_step(state, tokens, targets)
+    state, got, *_ = run_step(state, tokens, targets)
     log(f"first step (compiled or loaded): loss {got}")
     check = {"loss": got, "reference_loss": want, "abs_err": abs(got - want),
              "ok": abs(got - want) <= cell_cfg["check"]["loss_abs"]}
     for i in range(1, 1 + tr["warmup_steps_before_window"]):
-        state, _, _, _ = run_step(state, *batch_of(i))
+        state, *_ = run_step(state, *batch_of(i))
 
     # -- the window: whole steps, from now until the first step that ends at
     # or after `seconds`; the rate is all their tokens over all that time. In
     # a traced run the seconds spent starting and stopping the profiler (some 6 s
     # on four chips) are taken out, or `train_mfu` would read a tenth low;
     # without a trace there are none, and the end-to-end rate is untouched.
-    steps, waits, losses, profiler_s = [], [], [], 0.0
+    steps, waits, losses, step_metrics, profiler_s = [], [], [], [], 0.0
     trace_dir, traced_steps = config["trace_dir"], 0
     tspec = traffic.get("trace", {})
     first_traced, n_traced = int(tspec.get("start_step", 3)), int(tspec.get("steps", 3))
@@ -101,10 +104,10 @@ def _loop(config: dict) -> None:
         tokens, targets = batch_of(100 + i)
         if tracing:
             with jax.profiler.StepTraceAnnotation("bench:train_step", step_num=i):
-                state, loss, wait, dur = run_step(state, tokens, targets)
+                state, loss, wait, dur, extra = run_step(state, tokens, targets)
             traced_steps += 1
         else:
-            state, loss, wait, dur = run_step(state, tokens, targets)
+            state, loss, wait, dur, extra = run_step(state, tokens, targets)
         if tracing and i == first_traced + n_traced - 1:
             t = time.monotonic()
             jax.profiler.stop_trace()
@@ -112,13 +115,23 @@ def _loop(config: dict) -> None:
         steps.append(dur)
         waits.append(wait)
         losses.append(loss)
+        step_metrics.append(extra)
         i += 1
     window_s = time.monotonic() - t_open - profiler_s
     if trace and 0 < traced_steps < n_traced:   # window ended inside the trace
         jax.profiler.stop_trace()
+    # every scalar the steps returned beside their loss, copied to the host
+    # once the window is over (a copy a step cost 0.7 ms of each 588 ms step:
+    # my chip run, PR 26): they reach a reader as the series `step.<name>`
+    scalars: dict = {}
+    for metrics in jax.device_get(step_metrics):
+        for name, value in metrics.items():
+            if np.ndim(value) == 0:
+                scalars.setdefault(name, []).append(float(value))
     train.report({
         "t_open": t_open, "window_s": window_s, "check": check,
         "step_s": steps, "data_wait_s": waits, "losses": losses,
+        "step_scalars": scalars,
         "traced_steps": traced_steps, "profiler_s": profiler_s,
         "chip_batch": tr["sequences_per_chip"],
         "tokens": len(steps) * batch * seq,
@@ -137,6 +150,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
                      family=cell.family)
     tmp = os.path.join(ROOT, ".bench_tmp")
     trace_dir = os.path.join(tmp, f"trace-{cell.name}")
+    cell.family_entry("train_state_and_step")   # the loop finds it by name
     try:
         ray_tpu.init()
         result = JaxTrainer(
@@ -159,6 +173,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         ms.attempted = len(r["step_s"])
         ms.failed = sum(1 for x in r["losses"] if not math.isfinite(x))
         ms.series.update(step_s=r["step_s"], data_wait_s=r["data_wait_s"])
+        ms.series.update({f"step.{k}": v for k, v in r["step_scalars"].items()})
         ms.counters.update(
             setup_s=r["t_open"] - t_start, window_s=r["window_s"],
             train_tok_s_chip=r["tokens"] / r["window_s"] / cell.chips,

@@ -12,19 +12,28 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-TINY_MODEL = {
-    "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
-    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
-    "vocab_size": 256, "rope_theta": 1e6, "rms_norm_eps": 1e-5,
-    "max_position_embeddings": 256, "tie_word_embeddings": False,
-    "sliding_window": None, "hidden_act": "silu", "torch_dtype": "float32"}
+TINY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures", "tiny")
+
+
+def tiny_config(family: str, does: str) -> dict:
+    """The CPU stand-in of a family's serving or training configurations:
+    `fixtures/tiny/<family>-<serve|train>.json`. A family brings its own, and
+    a traffic mix its short stand-in, `fixtures/tiny/traffic-<mix>.json`."""
+    with open(os.path.join(TINY, f"{family}-{does}.json")) as f:
+        return json.load(f)
+
+
+# the toy model the llama stand-ins share: their published-shape keys
+TINY_MODEL = {k: v for k, v in tiny_config("llama", "serve").items()
+              if k not in ("family", "reference", "chips", "mesh", "engine", "check")}
 
 
 @pytest.fixture
 def tiny_root(tmp_path):
     """A checkout-shaped directory whose BENCHMARK.json names the real cells
-    and metrics (the real metric files) over toy configurations and short
-    traffic, so the whole command runs on the CPU in seconds."""
+    and metrics (the real metric files) over toy configurations (each real
+    configuration's stand-in, `tiny_config`) and short traffic, so the whole
+    command runs on the CPU in seconds."""
     root = tmp_path / "checkout"
     bench = root / "benchmarks"
     for d in ("configs", "traffic"):
@@ -34,38 +43,20 @@ def tiny_root(tmp_path):
     def dump(path, obj):
         path.write_text(json.dumps(obj))
 
-    dump(bench / "configs" / "tiny-serve.json", {
-        **TINY_MODEL, "family": "llama", "reference": "llama_reference",
-        "chips": 1, "mesh": {},
-        "engine": {"max_batch_size": 4, "block_size": 16, "num_blocks": 0,
-                   "prefill_buckets": [32, 64]},
-        "check": {"sequences": 2, "prompt_tokens": 24, "new_tokens": 4,
-                  "rel_rms": 1e-3, "rel_max": 1e-3}})
-    dump(bench / "configs" / "tiny-train.json", {
-        **TINY_MODEL, "family": "llama", "reference": "llama_reference",
-        "chips": 1, "mesh": {},
-        "trainer": {"warmup_steps": 1, "remat_policy": "dots",
-                    "sequences_per_chip": 2, "warmup_steps_before_window": 1},
-        "check": {"loss_abs": 1e-3}})
-    prompt = {"dist": "lognormal", "median": 40, "sigma": 0.8, "min": 8,
-              "max": 150, "strata": 16}
-    output = {"dist": "lognormal", "median": 12, "sigma": 0.5, "min": 4,
-              "max": 40, "strata": 16}
-    dump(bench / "traffic" / "chat-paced.json", {
-        "kind": "open_loop_paced", "rate_rps": 6.0, "jitter": 0.2,
-        "prompt": prompt, "output": output, "fill": {"lifetime_s": 0.4},
-        "min_tokens_for_tpot": 4})
-    dump(bench / "traffic" / "docs-batch.json", {
-        "kind": "closed_loop", "clients": 4,
-        "prompt": {"dist": "uniform", "min": 65, "max": 200, "strata": 16},
-        "output": {"dist": "uniform", "min": 8, "max": 8, "strata": 1}})
-    dump(bench / "traffic" / "pretrain-4k.json",
-         {"kind": "train_job", "seq_len": 64})
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         real = json.load(f)
     for w in real["workloads"]:
-        w["config"] = "tiny-serve" if "serve" in w["config"] else "tiny-train"
+        # the stand-in is found as the cell's parts are: by the real
+        # configuration's family, and by whether it serves or trains
+        with open(os.path.join(ROOT, "benchmarks", "configs", w["config"] + ".json")) as f:
+            cfg = json.load(f)
+        does = "serve" if "engine" in cfg else "train"
+        w["config"] = f"{cfg['family']}-{does}"
         w["chips"] = 1
+        dump(bench / "configs" / (w["config"] + ".json"),
+             tiny_config(cfg["family"], does))
+        shutil.copy(os.path.join(TINY, f"traffic-{w['traffic']}.json"),
+                    bench / "traffic" / (w["traffic"] + ".json"))
     dump(root / "BENCHMARK.json", real)
     return str(root)
 

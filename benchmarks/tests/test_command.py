@@ -33,13 +33,16 @@ def test_an_unknown_device_kind_is_an_error():
         peaks_for("TPU v9 imaginary")
 
 
-@pytest.mark.parametrize("workload,metrics", [
-    ("serve-chat-steady", None),
-    ("serve-docs-batch", {"served_tok_s", "setup_s"}),
-    ("train-4k-1chip", {"train_tok_s_chip", "setup_s"}),
-])
+def _cells() -> list:
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return [w["name"] for w in json.load(f)["workloads"]]
+
+
+@pytest.mark.parametrize("workload", _cells())
 def test_last_line_has_exactly_the_contract_keys(tiny_root, cpu_as_device,
-                                                 capsys, workload, metrics):
+                                                 capsys, workload):
+    """Every cell of BENCHMARK.json, a later PR's too, through the whole
+    command on its family's CPU stand-in."""
     from benchmarks import run
 
     rc = run.main(["--workload", workload, "--seed", str(2 ** 31 + 11),
@@ -53,8 +56,8 @@ def test_last_line_has_exactly_the_contract_keys(tiny_root, cpu_as_device,
     assert set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    want = metrics or {m["name"] for m in bench["end_to_end"]
-                       if workload in m.get("workloads", [workload])}
+    want = {m["name"] for m in bench["end_to_end"]
+            if workload in m.get("workloads", [workload])}
     assert set(line["metrics"]) == want
     for m in line["metrics"].values():
         assert set(m) == {"value", "unit"} and m["value"] > 0
@@ -87,7 +90,8 @@ def test_a_declared_metric_that_cannot_be_read_fails_the_command_by_name(
 
 
 def test_a_renamed_engine_callable_fails_by_name():
-    """The four callables the tap wraps are the seams the yardstick stands on."""
+    """The two callables the check wraps are the seams the yardstick still
+    stands on; the loop's methods are none (test_engine_records.py)."""
     from benchmarks.harness.engine_tap import EngineTap
 
     class Engine:
@@ -99,5 +103,19 @@ def test_a_renamed_engine_callable_fails_by_name():
     with pytest.raises(SystemExit, match="_decode"):
         tap.capture_logits()
     tap.unwrap()
-    with pytest.raises(SystemExit, match="_admit_one"):
-        tap.record_spans(annotate=False)
+    assert not hasattr(tap, "record_spans")
+
+
+def test_a_family_that_does_not_train_says_so_by_name(tiny_root, monkeypatch):
+    """A family module may serve only or train only: a cell that asks for the
+    missing entry point ends with the family's and the cell's names."""
+    from benchmarks.harness import spec
+
+    cell = spec.Cell("train-4k-1chip", root=tiny_root)
+    assert callable(cell.family_entry("train_state_and_step"))
+    monkeypatch.delattr(cell.family, "train_state_and_step")
+    with pytest.raises(SystemExit, match=r"'train-4k-1chip'.*'llama'.*does not train"):
+        cell.kind.run(cell, 1, 1.0, False, 0.0, {"platform": "cpu"}, {})
+    monkeypatch.delattr(cell.family, "serve_app")
+    with pytest.raises(SystemExit, match="does not serve"):
+        spec.Cell("serve-docs-batch", root=tiny_root).family_entry("serve_app")

@@ -90,8 +90,11 @@ def test_every_cell_is_made_of_files_found_by_name(bench):
 def test_metric_files_say_what_benchmark_json_says(bench):
     for m in bench["end_to_end"] + bench["per_layer"]:
         f = _file("metrics", m["name"] + ".json")
-        for key in ("name", "unit", "better", "source", "layer", "moves", "workloads"):
+        for key in ("name", "unit", "better", "source", "layer", "moves"):
             assert f.get(key) == m.get(key), (m["name"], key)
+        # which cells report a metric is said once, in BENCHMARK.json: a new
+        # cell appends its name there and edits no metric file
+        assert "workloads" not in f, m["name"]
     layers = {m["layer"] for m in bench["per_layer"]}
     with open(os.path.join(ROOT, "PERF.md")) as f:
         perf = f.read()
@@ -100,17 +103,19 @@ def test_metric_files_say_what_benchmark_json_says(bench):
 
 
 def test_configurations_keep_every_published_width(bench):
-    published = {"hidden_size": 4096, "intermediate_size": 14336,
-                 "num_attention_heads": 32, "num_key_value_heads": 8,
-                 "head_dim": 128, "vocab_size": 32768, "rope_theta": 1e6,
-                 "rms_norm_eps": 1e-5, "tie_word_embeddings": False,
-                 "sliding_window": None, "num_hidden_layers": 32,
-                 "max_position_embeddings": 32768}
+    """Against the published shape each configuration names
+    (`benchmarks/configs/published/<published_config>.json`): the keys that
+    differ are exactly `reduced`, in BENCHMARK.json and in the file, none of
+    them is one of the source's `widths`, and the sources agree."""
     for c in bench["configs"]:
         cfg = _file("configs", c["name"] + ".json")
-        changed = {k for k, v in published.items() if cfg[k] != v}
+        pub = _file("configs", "published", cfg["published_config"] + ".json")
+        missing = object()
+        changed = {k for k, v in pub["config"].items() if cfg.get(k, missing) != v}
         assert changed == set(c["reduced"]) == set(cfg["reduced"]), c["name"]
-        assert cfg["source"] == c["source"]
+        assert cfg["source"] == c["source"] == pub["source"]
+        assert set(pub["widths"]) <= set(pub["config"])
+        assert not changed & set(pub["widths"]), c["name"]
         assert not any(k.endswith(("_dim", "_rank", "_size")) for k in changed)
 
 

@@ -130,7 +130,8 @@ def test_compile_counter_is_read_once_with_its_counts(monkeypatch):
 def test_the_seven_metric_files_load_and_name_a_reader_that_takes_their_arguments():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         declared = {m["name"]: m for m in json.load(f)["per_layer"]}
-    assert list(declared)[-7:] == list(NEW)   # appended, in ISSUE 24's order
+    # appended in ISSUE 24's order; what later PRs append comes after them
+    assert [n for n in declared if n in NEW] == list(NEW)
     for name, cell in NEW.items():
         with open(os.path.join(ROOT, "benchmarks", "metrics", name + ".json")) as f:
             m = json.load(f)
