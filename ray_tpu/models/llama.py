@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.models import Model
 from ray_tpu.ops.platform import target_platform
 
 
@@ -215,24 +216,50 @@ def auto_attention(q, k, v, causal: bool = True, platform: str | None = None):
     return attention(q, k, v, causal=causal)
 
 
-def _block(cfg: LlamaConfig, x, layer, positions, attn_fn):
+def attn_sublayer(cfg: LlamaConfig, x, layer, positions, attn_fn):
+    """x + attention(norm(x)): the half of a block that every decoder family
+    here shares (models/moe.py calls it too). A layer that holds `q_norm` and
+    `k_norm` (OLMoE) normalises the WHOLE projected query and key vector,
+    before the split into heads and before rope."""
     hd, nh, nkv = cfg.hd, cfg.num_heads, cfg.num_kv_heads
     B, S, h = x.shape
     # the scopes are names in a profile and in the HLO's op_name, no more
     with jax.named_scope("attn"):
         y = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-        q = (y @ layer["wq"]).reshape(B, S, nh, hd)
-        k = (y @ layer["wk"]).reshape(B, S, nkv, hd)
+        qk_norm = "q_norm" in layer
+        q = y @ layer["wq"]
+        if qk_norm:
+            q = rms_norm(q, layer["q_norm"], cfg.rms_eps)
+        q = q.reshape(B, S, nh, hd)
+        k = y @ layer["wk"]
+        if qk_norm:
+            k = rms_norm(k, layer["k_norm"], cfg.rms_eps)
+        k = k.reshape(B, S, nkv, hd)
         v = (y @ layer["wv"]).reshape(B, S, nkv, hd)
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
         o = attn_fn(q, k, v)
-        x = x + (o.reshape(B, S, nh * hd) @ layer["wo"])
+        return x + (o.reshape(B, S, nh * hd) @ layer["wo"])
+
+
+def _block(cfg: LlamaConfig, x, layer, positions, attn_fn):
+    x = attn_sublayer(cfg, x, layer, positions, attn_fn)
     with jax.named_scope("mlp"):
         y = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
         gate = jax.nn.silu(y @ layer["w_gate"])
         x = x + ((gate * (y @ layer["w_up"])) @ layer["w_down"])
     return x
+
+
+def remat_body(body, cfg: LlamaConfig):
+    """The scan body under `jax.checkpoint` by the configuration's policy."""
+    if not cfg.remat:
+        return body
+    if cfg.remat_policy not in ("full", "dots"):
+        raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}")
+    policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+              if cfg.remat_policy == "dots" else None)
+    return jax.checkpoint(body, prevent_cse=False, policy=policy)
 
 
 def forward(params, tokens, cfg: LlamaConfig, attn_fn=None, positions=None):
@@ -247,27 +274,34 @@ def forward(params, tokens, cfg: LlamaConfig, attn_fn=None, positions=None):
     def body(x, layer):
         return _block(cfg, x, layer, positions, attn_fn), None
 
-    if cfg.remat:
-        if cfg.remat_policy not in ("full", "dots"):
-            raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}")
-        policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                  if cfg.remat_policy == "dots" else None)
-        body = jax.checkpoint(body, prevent_cse=False, policy=policy)
-    x, _ = jax.lax.scan(body, x, params["layers"])
+    x, _ = jax.lax.scan(remat_body(body, cfg), x, params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     return (x @ head.astype(cfg.dtype)).astype(jnp.float32)
 
 
-def loss_fn(params, tokens, targets, cfg: LlamaConfig, attn_fn=None):
-    """Next-token cross-entropy; targets [B, S] with -100 = ignore."""
-    logits = forward(params, tokens, cfg, attn_fn)
+def next_token_loss(logits, targets):
+    """Mean cross-entropy of float32 logits [B, S, V]; targets [B, S] with
+    -100 = ignore."""
     valid = targets != -100
     tsafe = jnp.where(valid, targets, 0)
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, tsafe[..., None], axis=-1)[..., 0]
     nll = (logz - gold) * valid
     return nll.sum() / jnp.maximum(valid.sum(), 1)
+
+
+def loss_fn(params, tokens, targets, cfg: LlamaConfig, attn_fn=None):
+    """Next-token cross-entropy; targets [B, S] with -100 = ignore."""
+    return next_token_loss(forward(params, tokens, cfg, attn_fn), targets)
+
+
+def _model_loss(params, tokens, targets, cfg: LlamaConfig, attn_fn, mesh=None):
+    return loss_fn(params, tokens, targets, cfg, attn_fn), {}
+
+
+# what train/spmd.py takes of a model (ray_tpu/models/__init__.py)
+MODEL = Model(init=init, logical_axes=logical_axes, loss=_model_loss)
 
 
 def flops_per_token(cfg: LlamaConfig) -> float:

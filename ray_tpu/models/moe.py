@@ -1,34 +1,57 @@
-"""Mixture-of-Experts transformer with native expert parallelism.
+"""Sparse mixture-of-experts decoder (OLMoE, Mixtral): dropless routing by sort.
 
-BASELINE.json config #3 (Mixtral 8x7B) — where the reference places vLLM
-actors in PGs and delegates EP to the engine (SURVEY §2.5 marks EP as
-pass-through), this implements expert parallelism natively: experts are
-sharded over the `expert` mesh axis; tokens are routed with a capacity-
-bounded top-k dispatch expressed as dense einsums (MXU-friendly, no dynamic
-shapes) so XLA lowers the shuffle to all_to_all/psum over ICI.
+The Llama backbone (`llama.attn_sublayer`: pre-norm attention with rotary
+positions, optionally OLMoE's RMSNorm over the whole projected query and key)
+with the MLP of every block replaced by `num_experts` SwiGLU experts of which
+each token uses `top_k`:
 
-Design: Llama backbone (models.llama ops) with the MLP replaced by a
-switch-style top-k MoE layer in every block.
+    p = softmax(float32(y @ router))          over all experts
+    the top_k largest p and their experts     (weights renormalised to sum to
+                                               one only if `norm_topk_prob`)
+    out[t] = sum_j p[t, j] * (silu(y[t] @ gate[e_j]) * (y[t] @ up[e_j])) @ down[e_j]
+
+Every (token, choice) is computed: there is no capacity and nothing is
+dropped. Routing is done by SORT: the `T * top_k` choices are sorted by expert
+(stable), the rows of `y` are gathered in that order, the rows an expert
+received are counted (`group_sizes`), the three expert products run as grouped
+matrix products over the sorted rows (`ops/grouped_matmul.py`: Pallas kernels
+on a TPU, `jax.lax.ragged_dot` elsewhere), and the results are weighted and
+summed back in token order. Both row movements are gathers in both directions
+of autodiff (a permutation's transpose is its inverse), never a scatter-add.
+
+The auxiliary load-balancing loss is, per layer and summed over layers,
+`E * sum_e f_e * P_e` (`f_e` the share of the `T * top_k` choices that went to
+expert `e`, `P_e` the mean of `p[:, e]`), times `router_aux_coeff`.
+
+One chip holds every expert. With an `expert` mesh axis of more than one
+device the layer takes the dense path it takes off the TPU (a Mosaic kernel
+cannot be partitioned) and leaves the placement to XLA; experts spread over
+chips with an explicit all-to-all are a later four-chip issue (ROADMAP R2,
+the Moonlight pairing).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import llama
+from ray_tpu.models import Model, llama
+from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.ops.platform import target_platform
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    # attention, widths, depth, remat; its intermediate_size is ONE expert's width
     base: llama.LlamaConfig = dataclasses.field(default_factory=llama.LlamaConfig.tiny)
     num_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = False      # renormalise the top_k weights to sum to one
+    qk_norm: bool = False             # RMSNorm over the whole projected q and k
     router_aux_coeff: float = 0.01
 
     @staticmethod
@@ -43,126 +66,180 @@ class MoEConfig:
                 num_layers=32, num_heads=32, num_kv_heads=8, max_seq_len=8192,
                 rope_theta=1e6,
             ),
-            num_experts=8, top_k=2,
+            num_experts=8, top_k=2, norm_topk_prob=True,
         )
+
+    @staticmethod
+    def olmoe_1b_7b() -> "MoEConfig":
+        """allenai/OLMoE-1B-7B-0125-Instruct as its config.json has it."""
+        return MoEConfig(
+            base=llama.LlamaConfig(
+                vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+                num_layers=16, num_heads=16, num_kv_heads=16, head_dim=128,
+                max_seq_len=4096, rope_theta=10000.0, rms_eps=1e-5,
+            ),
+            num_experts=64, top_k=8, norm_topk_prob=False,
+            qk_norm=True,
+        )
+
+
+_DENSE_MLP = ("w_gate", "w_up", "w_down")   # every block is MoE: experts replace these
 
 
 def logical_axes(cfg: MoEConfig) -> dict:
     """Param sharding tree: experts lead with the `expert` axis."""
     ax = llama.logical_axes(cfg.base)
-    ax["layers"] = dict(ax["layers"])
-    ax["layers"].update({
+    layers = {k: v for k, v in ax["layers"].items() if k not in _DENSE_MLP}
+    layers.update({
         "router": (None, None, None),
         "e_gate": (None, "expert", "embed_fsdp", "mlp"),
         "e_up": (None, "expert", "embed_fsdp", "mlp"),
         "e_down": (None, "expert", "mlp", "embed_fsdp"),
     })
-    # every block is MoE: the dense MLP weights are replaced by experts
-    for dense_key in ("w_gate", "w_up", "w_down"):
-        ax["layers"].pop(dense_key, None)
-    return ax
+    if cfg.qk_norm:
+        layers.update(q_norm=(None, None), k_norm=(None, None))
+    return {**ax, "layers": layers}
 
 
 def init(cfg: MoEConfig, key: jax.Array) -> dict:
     base = cfg.base
-    params = llama.init(base, key)
+    # the dense MLP's weights are never made (width 0): for mixtral-8x7b they
+    # would be 5.6 B dead parameters
+    params = llama.init(dataclasses.replace(base, intermediate_size=0), key)
+    layers = {k: v for k, v in params["layers"].items() if k not in _DENSE_MLP}
     h, m, L, E = base.hidden_size, base.intermediate_size, base.num_layers, cfg.num_experts
     ks = jax.random.split(jax.random.fold_in(key, 7), 4)
 
     def dense(k, fan_in, *shape):
         return (jax.random.normal(k, shape, dtype=jnp.float32) / math.sqrt(fan_in)).astype(base.dtype)
 
-    params["layers"]["router"] = dense(ks[0], h, L, h, E)
-    params["layers"]["e_gate"] = dense(ks[1], h, L, E, h, m)
-    params["layers"]["e_up"] = dense(ks[2], h, L, E, h, m)
-    params["layers"]["e_down"] = dense(ks[3], m, L, E, m, h)
-    # experts replace the dense MLP — drop the unused llama weights (for
-    # mixtral-8x7b they would be ~5.6B dead params of HBM)
-    for dense_key in ("w_gate", "w_up", "w_down"):
-        params["layers"].pop(dense_key, None)
-    return params
+    layers["router"] = dense(ks[0], h, L, h, E)
+    layers["e_gate"] = dense(ks[1], h, L, E, h, m)
+    layers["e_up"] = dense(ks[2], h, L, E, h, m)
+    layers["e_down"] = dense(ks[3], m, L, E, m, h)
+    if cfg.qk_norm:
+        layers["q_norm"] = jnp.ones((L, base.num_heads * base.hd), jnp.float32)
+        layers["k_norm"] = jnp.ones((L, base.num_kv_heads * base.hd), jnp.float32)
+    return {**params, "layers": layers}
 
 
-def moe_mlp(x, router_w, e_gate, e_up, e_down, cfg: MoEConfig):
-    """Capacity-bounded top-k MoE layer; x: [B, S, H] -> ([B, S, H], aux_loss).
-
-    Dense dispatch/combine einsums over a capacity buffer [E, C]: static shapes,
-    MXU-shaped contractions; with experts sharded over the `expert` axis XLA
-    inserts the token all_to_all automatically.
-    """
-    B, S, H = x.shape
-    E, k = cfg.num_experts, cfg.top_k
-    T = B * S
-    C = max(1, int(cfg.capacity_factor * k * T / E))
-    xt = x.reshape(T, H)
-    logits = (xt @ router_w).astype(jnp.float32)  # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    # top-k expert choice per token
-    topk_p, topk_e = jax.lax.top_k(probs, k)  # [T, k]
-    # position of each (token, choice) in its expert's capacity buffer
-    onehot = jax.nn.one_hot(topk_e, E, dtype=jnp.int32)  # [T, k, E]
-    flat = onehot.reshape(T * k, E)
-    pos_in_expert = jnp.cumsum(flat, axis=0) * flat - 1  # [T*k, E]
-    pos = pos_in_expert.reshape(T, k, E)
-    within_cap = (pos >= 0) & (pos < C)
-    # dispatch tensor [T, E, C]
-    pos_clamped = jnp.clip(pos, 0, C - 1)
-    disp = (jax.nn.one_hot(pos_clamped, C, dtype=xt.dtype)
-            * within_cap[..., None].astype(xt.dtype)
-            * onehot[..., None].astype(xt.dtype))  # [T, k, E, C]
-    dispatch = disp.sum(axis=1)  # [T, E, C]
-    combine = (disp * topk_p[:, :, None, None].astype(xt.dtype)).sum(axis=1)  # [T, E, C]
-    # route tokens to expert buffers: [E, C, H]
-    expert_in = jnp.einsum("tec,th->ech", dispatch, xt)
-    # expert MLPs (batched over E — shardable on the expert axis)
-    gate = jax.nn.silu(jnp.einsum("ech,ehm->ecm", expert_in, e_gate))
-    up = jnp.einsum("ech,ehm->ecm", expert_in, e_up)
-    expert_out = jnp.einsum("ecm,emh->ech", gate * up, e_down)
-    out = jnp.einsum("tec,ech->th", combine, expert_out)
-    # load-balancing aux loss (switch-transformer style)
-    density = flat.reshape(T, k, E).sum(axis=1).astype(jnp.float32).mean(axis=0)  # [E]
-    router_mean = probs.mean(axis=0)
-    aux = (density * router_mean).sum() * (E ** 2) / k
-    return out.reshape(B, S, H), aux
+@jax.custom_vjp
+def _permute(x, perm, inverse):
+    """x[perm] for a permutation `perm` of x's rows whose inverse is
+    `inverse`: the cotangent is gathered back through the inverse, where
+    autodiff of a plain gather would scatter-add."""
+    return x[perm]
 
 
-def forward(params, tokens, cfg: MoEConfig, positions=None):
-    """Token ids [B,S] -> (logits [B,S,V], total aux loss)."""
+_permute.defvjp(lambda x, perm, inverse: (x[perm], (perm, inverse)),
+                lambda res, g: (g[res[1]], None, None))
+
+
+@jax.custom_vjp
+def _dispatch(yt, order, inverse):
+    """Rows of yt [T, H] in the order of the sorted choices: choice `c`
+    (token `c // k`) lands in row `inverse[c]`. The cotangent of a token is
+    the sum over its k rows, gathered through the inverse."""
+    return yt[order // (order.shape[0] // yt.shape[0])]
+
+
+def _dispatch_bwd(res, g):
+    order, inverse, T = res
+    return g[inverse].reshape(T, -1, g.shape[-1]).sum(axis=1), None, None
+
+
+_dispatch.defvjp(lambda yt, order, inverse: (_dispatch(yt, order, inverse),
+                                             (order, inverse, yt.shape[0])),
+                 _dispatch_bwd)
+
+
+def router_logits(yt, router_w):
+    """[T, H] x [H, E] -> float32 [T, E]: operands as they are (bfloat16 in
+    training), accumulated AND returned in float32, so that the softmax and
+    the choice of experts see no rounding of the logits."""
+    return jnp.dot(yt, router_w, preferred_element_type=jnp.float32)
+
+
+def moe_mlp(y, layer, cfg: MoEConfig, platform: str | None = None):
+    """The expert layer on normalised activations y [B, S, H] -> ([B, S, H],
+    {"aux": the layer's load-balancing term, "load": rows each expert
+    received over the mean T * k / E, [E], "experts": the chosen experts
+    [T, k]}). `platform` goes to `grouped_matmul` (kernel on "tpu", dense
+    otherwise; None: from the operands' placement)."""
+    B, S, H = y.shape
+    E, k, T = cfg.num_experts, cfg.top_k, B * S
+    yt = y.reshape(T, H)
+    with jax.named_scope("moe/route"):
+        probs = jax.nn.softmax(router_logits(yt, layer["router"]), axis=-1)  # float32
+        top_p, top_e = jax.lax.top_k(probs, k)                   # [T, k]
+        if cfg.norm_topk_prob:
+            top_p = top_p / top_p.sum(axis=-1, keepdims=True)
+        choice_e = top_e.reshape(T * k)
+        order = jnp.argsort(choice_e, stable=True).astype(jnp.int32)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32), unique_indices=True)
+        group_sizes = (choice_e[:, None] == jnp.arange(E, dtype=choice_e.dtype)
+                       ).sum(axis=0, dtype=jnp.int32)            # [E]
+    with jax.named_scope("moe/dispatch"):
+        xs = _dispatch(yt, order, inverse)                       # [T * k, H]
+    with jax.named_scope("moe/experts"):
+        # under "dots" remat a Pallas call is no saveable dot: the backward
+        # pass runs these three again. Saving their outputs by name was
+        # measured (PERF.md section 6, PR 27): 2% faster at equal batch, but
+        # it costs the memory of 3 of the 5 sequences a chip holds without.
+        gmm = partial(grouped_matmul, group_sizes=group_sizes, platform=platform)
+        hidden = jax.nn.silu(gmm(xs, layer["e_gate"])) * gmm(xs, layer["e_up"])
+        ys = gmm(hidden, layer["e_down"])                        # [T * k, H]
+    with jax.named_scope("moe/combine"):
+        per_choice = _permute(ys, inverse, order).reshape(T, k, H)
+        out = (per_choice * top_p[..., None].astype(y.dtype)).sum(axis=1)
+    share = group_sizes.astype(jnp.float32) / (T * k)            # f_e
+    stats = {"aux": E * (share * probs.mean(axis=0)).sum(), "load": share * E,
+             "experts": top_e}
+    return out.reshape(B, S, H), stats
+
+
+def forward(params, tokens, cfg: MoEConfig, attn_fn=None, positions=None, mesh=None):
+    """Token ids [B, S] -> (float32 logits [B, S, V], the layers' stats
+    stacked: "aux" [L], "load" [L, E], "experts" [L, B * S, k]).
+
+    `attn_fn` as `llama.forward`'s. `mesh` is the mesh the computation is
+    sharded over, if the caller knows it: it decides kernel or dense for the
+    expert products (None: from the placement of the arguments)."""
     base = cfg.base
+    if attn_fn is None:
+        attn_fn = partial(llama.auto_attention, causal=True)
+    platform = target_platform(tokens, params["embed"], mesh=mesh)
+    if mesh is not None and dict(mesh.shape).get("expert", 1) > 1:
+        platform = "spmd"   # experts over devices: the dense path, see the docstring
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     x = params["embed"][tokens].astype(base.dtype)
-    hd, nh, nkv = base.hd, base.num_heads, base.num_kv_heads
 
-    def body(carry, layer):
-        x, aux_total = carry
-        y = llama.rms_norm(x, layer["attn_norm"], base.rms_eps)
-        q = (y @ layer["wq"]).reshape(B, S, nh, hd)
-        kk = (y @ layer["wk"]).reshape(B, S, nkv, hd)
-        v = (y @ layer["wv"]).reshape(B, S, nkv, hd)
-        q = llama.rope(q, positions, base.rope_theta)
-        kk = llama.rope(kk, positions, base.rope_theta)
-        o = llama.attention(q, kk, v, causal=True)
-        x = x + (o.reshape(B, S, nh * hd) @ layer["wo"])
+    def body(x, layer):
+        x = llama.attn_sublayer(base, x, layer, positions, attn_fn)
         y = llama.rms_norm(x, layer["mlp_norm"], base.rms_eps)
-        mlp_out, aux = moe_mlp(y, layer["router"], layer["e_gate"], layer["e_up"],
-                               layer["e_down"], cfg)
-        return (x + mlp_out, aux_total + aux), None
+        out, stats = moe_mlp(y, layer, cfg, platform)
+        return x + out, stats
 
-    if base.remat:
-        body = jax.checkpoint(body, prevent_cse=False)
-    (x, aux_total), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), params["layers"])
+    x, stats = jax.lax.scan(llama.remat_body(body, base), x, params["layers"])
     x = llama.rms_norm(x, params["final_norm"], base.rms_eps)
     head = params["embed"].T if base.tie_embeddings else params["lm_head"]
-    return (x @ head.astype(base.dtype)).astype(jnp.float32), aux_total
+    return (x @ head.astype(base.dtype)).astype(jnp.float32), stats
 
 
-def loss_fn(params, tokens, targets, cfg: MoEConfig):
-    logits, aux = forward(params, tokens, cfg)
-    valid = targets != -100
-    tsafe = jnp.where(valid, targets, 0)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, tsafe[..., None], axis=-1)[..., 0]
-    nll = ((logz - gold) * valid).sum() / jnp.maximum(valid.sum(), 1)
-    return nll + cfg.router_aux_coeff * aux
+def loss_fn(params, tokens, targets, cfg: MoEConfig, attn_fn=None, mesh=None):
+    """(objective, scalars): next-token cross-entropy (targets -100 = ignore)
+    plus `router_aux_coeff` times the summed auxiliary loss; the scalars are
+    `nll`, `aux_loss` (unscaled) and `router_load_max` (over layers and
+    experts, the rows an expert received over the mean)."""
+    logits, stats = forward(params, tokens, cfg, attn_fn, mesh=mesh)
+    nll = llama.next_token_loss(logits, targets)
+    aux = stats["aux"].sum()
+    return nll + cfg.router_aux_coeff * aux, {
+        "nll": nll, "aux_loss": aux, "router_load_max": stats["load"].max()}
+
+
+# what train/spmd.py takes of a model (ray_tpu/models/__init__.py)
+MODEL = Model(init=init, logical_axes=logical_axes, loss=loss_fn)

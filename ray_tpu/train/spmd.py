@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_tpu.models import llama
+from ray_tpu.models import Model, llama
 from ray_tpu.ops.platform import target_platform
 from ray_tpu.parallel import sharding as shd
 from ray_tpu.util.compile_cache import ensure_compile_cache
@@ -41,9 +41,12 @@ def make_optimizer(learning_rate: float = 3e-4, weight_decay: float = 0.1, warmu
     )
 
 
-def init_state(cfg: llama.LlamaConfig, key, optimizer=None) -> TrainState:
+def init_state(cfg, key, optimizer=None, model: Model = llama.MODEL) -> TrainState:
+    """`model` is the family's record (models/__init__.py): `llama.MODEL` by
+    default, `moe.MODEL` with an `MoEConfig`; the same one goes to
+    `state_shardings` and `make_train_step`."""
     optimizer = optimizer or make_optimizer()
-    params = llama.init(cfg, key)
+    params = model.init(cfg, key)
     opt_state = optimizer.init(params)
     return TrainState(params=params, opt_state=opt_state, step=jnp.zeros((), jnp.int32))
 
@@ -71,9 +74,10 @@ def mirror_opt_shardings(opt_state, params, param_sh, rep):
     return rec(opt_state)
 
 
-def state_shardings(cfg: llama.LlamaConfig, mesh: Mesh, state: TrainState) -> TrainState:
+def state_shardings(cfg, mesh: Mesh, state: TrainState,
+                    model: Model = llama.MODEL) -> TrainState:
     """Sharding tree for TrainState: params by logical axes; opt_state mirrors params."""
-    ax = llama.logical_axes(cfg)
+    ax = model.logical_axes(cfg)
     param_sh = shd.tree_shardings(mesh, ax)
     rep = shd.replicated(mesh)
     return TrainState(
@@ -104,12 +108,16 @@ def default_attn_fn(mesh: Mesh) -> Callable:
 
 
 def make_train_step(
-    cfg: llama.LlamaConfig,
+    cfg,
     mesh: Mesh,
     optimizer=None,
     attn_fn: Callable | None = None,
+    model: Model = llama.MODEL,
 ) -> Callable:
     """Build the jitted SPMD train step: (state, tokens, targets) -> (state, metrics).
+    The metrics are `loss`, `grad_norm`, `step` and whatever scalars the
+    model's loss returns beside its objective (an MoE's `nll`, `aux_loss`,
+    `router_load_max`; Llama's none).
 
     Gradients are averaged over (data, fsdp) implicitly by XLA from the sharded loss;
     param/optimizer shards (fsdp axis) are all-gathered/reduce-scattered by XLA as
@@ -123,17 +131,18 @@ def make_train_step(
 
     def step_fn(state: TrainState, tokens, targets):
         def loss(params):
-            return llama.loss_fn(params, tokens, targets, cfg, attn_fn)
+            return model.loss(params, tokens, targets, cfg, attn_fn, mesh=mesh)
 
-        lossval, grads = jax.value_and_grad(loss)(state.params)
+        (lossval, scalars), grads = jax.value_and_grad(loss, has_aux=True)(state.params)
         updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
         new_params = optax.apply_updates(state.params, updates)
         gnorm = optax.global_norm(grads)
         new_state = TrainState(new_params, new_opt, state.step + 1)
-        return new_state, {"loss": lossval, "grad_norm": gnorm, "step": new_state.step}
+        return new_state, {**scalars, "loss": lossval, "grad_norm": gnorm,
+                           "step": new_state.step}
 
     def compile_step(state: TrainState):
-        sh = state_shardings(cfg, mesh, state)
+        sh = state_shardings(cfg, mesh, state, model)
         state_sh = TrainState(sh.params, sh.opt_state, sh.step)
         return jax.jit(
             step_fn,

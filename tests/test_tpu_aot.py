@@ -235,3 +235,48 @@ def test_make_mesh_refuses_missing_devices():
     n = len(jax.devices())
     with pytest.raises(ValueError, match="device"):
         make_mesh(n + 1)
+
+
+def test_grouped_matmul_compiles_at_the_olmoe_shapes(v5e):
+    """The three grouped kernels at the shapes of the OLMoE cell's expert
+    layer (2 sequences of 4,096: 65,536 sorted rows, 64 experts, 2048 x 1024
+    and back), tiles from `choose_tiles`, by their names."""
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+
+    sizes = _on(v5e[0], (64,), jnp.int32)
+    for k, n in ((2048, 1024), (1024, 2048)):
+        def loss(lhs, rhs, sizes):
+            return grouped_matmul(lhs, rhs, sizes, platform="tpu").astype(jnp.float32).sum()
+
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            _on(v5e[0], (65536, k)), _on(v5e[0], (64, k, n)), sizes).compile().as_text()
+        for name in ("grouped_matmul_fwd", "grouped_matmul_dlhs", "grouped_matmul_drhs"):
+            assert name in text, name
+
+
+def test_moe_train_step_compiles_with_its_kernels_and_scopes(v5e):
+    """`make_train_step` with the MoE model record on a one-chip TPU mesh, at
+    a length that takes the flash kernel: the expert layer reaches the
+    grouped kernels from the mesh's platform (this host's backend is the
+    CPU), and the compiled text carries the names a profile is read by."""
+    from ray_tpu.models import moe
+
+    base = dataclasses.replace(llama.LlamaConfig.tiny(), dtype=jnp.bfloat16,
+                               hidden_size=256, intermediate_size=128, head_dim=128, num_heads=2,
+                               num_kv_heads=2,
+                               max_seq_len=1024, remat=True, remat_policy="dots")
+    cfg = moe.MoEConfig(base=base, num_experts=8, top_k=2, qk_norm=True)
+    mesh = make_mesh(1, devices=v5e[:1])
+    opt = spmd.make_optimizer(warmup=1)
+    state = jax.eval_shape(lambda: spmd.init_state(
+        cfg, jax.random.PRNGKey(0), optimizer=opt, model=moe.MODEL))
+    step = spmd.make_train_step(cfg, mesh, optimizer=opt, model=moe.MODEL)(state)
+    sh = spmd.state_shardings(cfg, mesh, state, moe.MODEL)
+    state_in = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), state, sh)
+    batch = jax.ShapeDtypeStruct((2, 1024), jnp.int32, sharding=shd.batch_sharding(mesh))
+    text = step.lower(state_in, batch, batch).compile().as_text()
+    for name in ("grouped_matmul_fwd", "grouped_matmul_dlhs", "grouped_matmul_drhs",
+                 "flash_attention_fwd", "moe/route", "moe/dispatch",
+                 "moe/experts/grouped_matmul_fwd", "moe/combine", "/attn/"):
+        assert name in text, name
