@@ -2,19 +2,56 @@
 
 The serving-side hot op (PAPERS.md ragged/paged attention): one query token
 per sequence attends over a KV cache stored in block_size-token PAGES scattered
-through a pool. The block table rides in scalar-prefetch memory so the
-BlockSpec index_map can route each grid step's page straight from HBM into
-VMEM — pages are read IN PLACE, with none of the [B, max_blocks*block_size]
-gathered-view materialization the XLA fallback pays
+through a pool. Pages are read IN PLACE, with none of the
+[B, max_blocks*block_size] gathered-view materialization the XLA fallback pays
 (models/llama.py forward_paged).
 
+How the kernel walks the pool:
+
+* One grid step a sequence, all heads. The K and V pools stay in HBM
+  (`memory_space=pl.ANY`); the block table and the lengths ride in
+  scalar-prefetch memory, and the kernel issues its own copies. One copy of
+  `k_hbm.at[:, page]` moves a page of ALL KV heads, `(Hkv, block_size, D)`, as
+  one descriptor, so a step serves every query head of its sequence:
+  `[Hkv, Gp, D]` against `[Hkv, group, D]`, batched over heads.
+* Many pages a step. A GROUP of pages (`pages_per_group`: up to
+  `MAX_GROUP_TOKENS` tokens, inside `VMEM_BUDGET`, worked out from the shapes)
+  lands in one of two VMEM buffers; the next group's copies start before this
+  group's products.
+* Live pages only. The in-kernel loop runs to the sequence's own
+  `ceil(pages / pages_per_group)`, and inside the last group a page past the
+  sequence's end is not copied (its rows of the V buffer are zeroed, so that
+  a masked probability of 0 never meets stale bytes). A call's time follows
+  the live context, not `slots x max_blocks`; an empty slot (length 1) costs
+  one page.
+* Operands as stored, statistics in float32. q, k and v enter both products in
+  the pool's dtype (`preferred_element_type=float32`); scores, running max,
+  denominator and accumulator are float32; p is cast to the pool's dtype for
+  the second product. With a bfloat16 pool each product is one MXU pass; with
+  float32 inputs nothing is rounded.
+
+Measured on a TPU v5e (my chip runs, PR 28; PERF.md section 6;
+scripts/bench_paged_attention_ab.py), one layer's call at the serving cells'
+widths (32 slots, 32 query / 8 KV heads of 128, block 16, a 128-block table,
+a 4,097-block pool, bfloat16): 116 us with 20 live slots of ~485 tokens
+(42% of what the K/V bytes need at 819 GB/s), 325 us with 32 slots of ~1,480
+(73%), 58 us with every slot empty; the kernel this replaces (grid
+(B, Hkv, max_blocks), one (16, 128) page a step through a BlockSpec, float32
+operands) took 5,345, 13,296 and 3,333. By step: pages a group 1 -> 8 -> 16 is
+305 -> 120 -> 116 us; a copy a (head, page) in place of one over all heads
+x 1.7-2.4; dead pages walked x 4.1; float32 operands nothing (the copies bound
+it: without the products a call still takes 99 us); jax's own kernel 352-449.
+
 Reference: vLLM's paged_attention CUDA kernel is the analog (the reference
-delegates serving to vLLM); this is the TPU-native equivalent built on the
-pallas playbook (/opt/skills/guides/pallas_guide.md).
+delegates serving to vLLM); jax.experimental.pallas.ops.tpu.paged_attention
+has the same pool layout and copies one (head, page) at a time.
 
 Layout contract: pages are [Hkv, num_blocks, block_size, D] per layer (head
-major) so a (head, block) pair maps to one VMEM tile of (block_size, D) —
-Mosaic's block-shape rule needs the last two dims tile-aligned.
+major). The pool enters as a whole ref and the page is an index into its
+second axis, so a caller that keeps all layers in one [L, Hkv, NB, BS, D]
+array can later hand that over with a layer index (ROADMAP S7). A head
+narrower than 128 lanes is zero-padded to 128 before the call (see
+`paged_decode_attention`).
 """
 
 from __future__ import annotations
@@ -29,98 +66,177 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.platform import target_platform
 
-NEG_INF = -1e30
+NEG_INF = -1e30   # finite: exp(NEG_INF - m) underflows to 0, no inf - inf
+LANES = 128
+# of the 16 MiB a v5e kernel gets by default: the four group buffers (K and V,
+# two each); scores and probabilities of a group are a few hundred KB beside
+VMEM_BUDGET = 8 * 2 ** 20
+# measured on a v5e at the serving cells' shape (PERF.md section 6, PR 28): a
+# call at 128 / 256 / 512 tokens a group takes 120 / 116 / 132 us with 20
+# live slots of ~485 tokens and 373 / 325 / 329 us with 32 of ~1,480; past 256
+# the last, part-filled group of every sequence costs more than longer runs
+# of copies save
+MAX_GROUP_TOKENS = 256
 
 
-def _decode_kernel(tables_ref, lens_ref,       # scalar-prefetch
-                   q_ref, k_ref, v_ref,        # blocks
-                   o_ref,                      # output
-                   m_scr, l_scr, acc_scr, *,
-                   block_size: int, num_blocks: int):
-    """Grid (B, Hkv, seq_blocks); the page for (b, i) was DMA'd via the
-    table-driven index_map. Streaming softmax over the sequence's pages."""
+def pages_per_group(block_size: int, head_dim: int, num_kv_heads: int,
+                    itemsize: int, max_blocks: int) -> int:
+    """Pages one loop step fetches and multiplies: the most (a power of two)
+    whose tokens stay within MAX_GROUP_TOKENS and whose four buffers
+    (K and V, double buffered, all KV heads) fit VMEM_BUDGET; never more than
+    the table is wide."""
+    d = -(-head_dim // LANES) * LANES
+    page_bytes = 2 * 2 * num_kv_heads * block_size * d * itemsize
+    pages = 1
+    while (2 * pages * block_size <= MAX_GROUP_TOKENS
+           and 2 * pages * page_bytes <= VMEM_BUDGET):
+        pages *= 2
+    return min(pages, max_blocks)
+
+
+def _decode_kernel(tables_ref, lens_ref,       # scalar-prefetch (SMEM)
+                   q_ref,                      # [1, Hkv, Gp, D] block
+                   k_hbm, v_hbm,               # whole pools, in HBM
+                   o_ref,                      # [1, Hkv, Gp, D] block
+                   k_buf, v_buf, sems, *,      # [2, Hkv, group, D] x 2, DMA (2, 2)
+                   pages: int, block_size: int, max_blocks: int, scale: float):
+    """Grid (B,): streaming softmax over the live page groups of sequence b."""
     b = pl.program_id(0)
-    i = pl.program_id(2)
-
-    @pl.when(i == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
     seq_len = lens_ref[b]
-    live = i * block_size < seq_len  # pages past the ragged end are skipped
+    n_pages = pl.cdiv(seq_len, block_size)
+    n_groups = pl.cdiv(n_pages, pages)
+    group = pages * block_size
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)   # [Gp, D]
-        k = k_ref[0, 0].astype(jnp.float32)   # [BS, D]
-        v = v_ref[0, 0].astype(jnp.float32)   # [BS, D]
-        scale = 1.0 / math.sqrt(q.shape[-1])
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # [Gp, BS]
-        kpos = i * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    def is_live(gi, j):
+        return gi * pages + j < n_pages
+
+    def page_copies(gi, buf, j):
+        """The K and the V copy of page j of group gi into buffer `buf`."""
+        page = tables_ref[b * max_blocks + gi * pages + j]
+        rows = pl.ds(j * block_size, block_size)
+        return (pltpu.make_async_copy(k_hbm.at[:, page], k_buf.at[buf, :, rows],
+                                      sems.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[:, page], v_buf.at[buf, :, rows],
+                                      sems.at[1, buf]))
+
+    def start(gi, buf):
+        for j in range(pages):
+            @pl.when(is_live(gi, j))
+            def _copy():
+                for copy in page_copies(gi, buf, j):
+                    copy.start()
+
+            # a page past the end is not copied: p is 0 there, and 0 x stale
+            # bytes could be NaN
+            @pl.when(jnp.logical_not(is_live(gi, j)))
+            def _zero():
+                v_buf[buf, :, pl.ds(j * block_size, block_size)] = jnp.zeros(
+                    (v_buf.shape[1], block_size, v_buf.shape[3]), v_buf.dtype)
+
+    def wait(gi, buf):
+        for j in range(pages):
+            @pl.when(is_live(gi, j))
+            def _arrived():
+                for copy in page_copies(gi, buf, j):
+                    copy.wait()
+
+    @pl.when(n_groups > 0)
+    def _first():
+        start(0, 0)
+
+    q = q_ref[0]                                           # [Hkv, Gp, D]
+
+    def step(gi, carry):
+        m_prev, l_prev, acc = carry
+        buf = gi % 2
+
+        @pl.when(gi + 1 < n_groups)
+        def _next():
+            start(gi + 1, 1 - buf)
+
+        wait(gi, buf)
+        k, v = k_buf[buf], v_buf[buf]                      # [Hkv, group, D]
+        s = jnp.einsum("hgd,htd->hgt", q, k,
+                       preferred_element_type=jnp.float32) * scale
+        kpos = gi * group + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(kpos < seq_len, s, NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        alive = (m_new > NEG_INF / 2).astype(jnp.float32)
-        m_safe = m_new * alive
-        p = jnp.exp(s - m_safe[:, None]) * alive[:, None]
-        corr = jnp.exp(m_prev - m_safe) * alive
-        l_scr[:] = l_scr[:] * corr + p.sum(axis=1)
-        acc_scr[:] = acc_scr[:] * corr[:, None] + jax.lax.dot(p, v)
-        m_scr[:] = m_new
+        # a group that is walked holds a live key in its first row, so the
+        # running max is finite from the first group on
+        m_new = jnp.maximum(m_prev, s.max(axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + p.sum(axis=2, keepdims=True)
+        acc = acc * corr + jnp.einsum("hgt,htd->hgd", p.astype(v.dtype), v,
+                                      preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
 
-    @pl.when(i == num_blocks - 1)
-    def _finalize():
-        o_ref[0, 0] = (acc_scr[:] /
-                       jnp.maximum(l_scr[:], 1e-30)[:, None]).astype(o_ref.dtype)
+    stat = q.shape[:2] + (1,)
+    _, l, acc = jax.lax.fori_loop(
+        0, n_groups, step,
+        (jnp.full(stat, NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32),
+         jnp.zeros(q.shape, jnp.float32)))
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def _decode_call(q4, k_pages, v_pages, tables, lengths, *, pages: int,
+                 scale: float, interpret: bool):
+    """q4 [B, Hkv, Gp, D] -> [B, Hkv, Gp, D]: softmax(scale * q k^T) v,
+    `pages` pages a group."""
+    B, Hkv, gp, D = q4.shape
+    BS = k_pages.shape[2]
+    max_blocks = tables.shape[1]
+    kernel = functools.partial(_decode_kernel, pages=pages, block_size=BS,
+                               max_blocks=max_blocks, scale=scale)
+    q_spec = pl.BlockSpec((1, Hkv, gp, D), lambda b, tab, lens: (b, 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2, Hkv, pages * BS, D), k_pages.dtype),
+            pltpu.VMEM((2, Hkv, pages * BS, D), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
+        interpret=interpret,
+        name="paged_attention_decode",
+        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel",))}),
+    )(tables.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
+      q4, k_pages, v_pages)
 
 
 def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
                            interpret: bool | None = None):
     """q [B, Hq, D]; k/v_pages [Hkv, NB, BS, D]; tables [B, max_blocks]
-    (pool block id per sequence block; unused entries must be a valid id —
-    their reads are masked); lengths [B] = valid KV tokens (incl. the token
-    being decoded). Returns [B, Hq, D]. `interpret=None` compiles the kernel
-    when the inputs are placed on a TPU and interprets it anywhere else.
+    (pool block id per sequence block; entries past a sequence's last live
+    page are never read); lengths [B] = valid KV tokens (incl. the token being
+    decoded). Returns [B, Hq, D]. `interpret=None` compiles the kernel when
+    the inputs are placed on a TPU and interprets it anywhere else.
     """
     if interpret is None:
         interpret = target_platform(q, k_pages, v_pages) != "tpu"
     B, Hq, D = q.shape
-    Hkv, NB, BS, _ = k_pages.shape
-    max_blocks = tables.shape[1]
+    Hkv, _, BS, _ = k_pages.shape
     g = Hq // Hkv
     gp = -(-g // 8) * 8  # pad the per-kv-head query group to a sublane multiple
-    # [B, Hkv, Gp, D] query groups
-    q4 = q.reshape(B, Hkv, g, D)
-    if gp != g:
-        q4 = jnp.pad(q4, [(0, 0), (0, 0), (0, gp - g), (0, 0)])
-
-    kernel = functools.partial(_decode_kernel, block_size=BS,
-                               num_blocks=max_blocks)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Hkv, max_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, gp, D), lambda b, h, i, tab, lens: (b, h, 0, 0)),
-            # the table routes sequence-block i of sequence b to its pool page
-            pl.BlockSpec((1, 1, BS, D), lambda b, h, i, tab, lens: (h, tab[b, i], 0, 0)),
-            pl.BlockSpec((1, 1, BS, D), lambda b, h, i, tab, lens: (h, tab[b, i], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, gp, D), lambda b, h, i, tab, lens: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((gp,), jnp.float32),
-            pltpu.VMEM((gp,), jnp.float32),
-            pltpu.VMEM((gp, D), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, gp, D), q.dtype),
-        interpret=interpret,
-        name="paged_attention_decode",
-        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))}),
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q4, k_pages, v_pages)
-    return out[:, :, :g].reshape(B, Hq, D)
+    q4 = q.reshape(B, Hkv, g, D).astype(k_pages.dtype)
+    # Mosaic slices an HBM ref only in whole lane tiles: a head under 128 wide
+    # is zero-padded to 128 (scores and outputs do not change). That is a copy
+    # of the pool a call; such a pool is lane-padded in HBM as it is, so the
+    # owner of a narrow-head pool should allocate it 128 wide (ROADMAP S7).
+    dp = -(-D // LANES) * LANES
+    q4 = jnp.pad(q4, [(0, 0), (0, 0), (0, gp - g), (0, dp - D)])
+    if dp != D:
+        lanes = [(0, 0), (0, 0), (0, 0), (0, dp - D)]
+        k_pages, v_pages = jnp.pad(k_pages, lanes), jnp.pad(v_pages, lanes)
+    pages = pages_per_group(BS, dp, Hkv, k_pages.dtype.itemsize, tables.shape[1])
+    out = _decode_call(q4, k_pages, v_pages, tables, lengths, pages=pages,
+                       scale=1.0 / math.sqrt(D), interpret=interpret)
+    return out[:, :, :g, :D].reshape(B, Hq, D).astype(q.dtype)

@@ -5,13 +5,14 @@ serving to; here native (ops/paged_attention.py), validated against the
 dense cached-attention math in models/llama.py.
 
 These run the kernel interpreted. Compiled, it was checked on the chip by
-chip_smoke.py's kernels phase (PR 21, 2026-09-26, TPU v5 lite, jax 0.9.0 /
-libtpu 0.0.34): a forward_paged decode step at llama_1b widths (32 query / 8
-KV heads of 64, block 16, a 128-block table, bf16, 2 layers) with ragged
-lengths 1, 2, 16, 17, 38, 1028, 2047 and 2048 gave logits within 5.5e-2 of
-the dense arm's (largest logit 5.14, tolerance 8 bf16 eps of it), and the
-compiled step held the Mosaic call. tests/test_tpu_aot.py compiles it for a
-v5e from this host at D=64 and D=128.
+chip_smoke.py's kernels phase (PR 28, 2026-09-27, TPU v5 lite, jax 0.9.0): a
+forward_paged decode step at llama_1b widths (32 query / 8 KV heads of 64,
+block 16, a 128-block table, bf16, 2 layers) with ragged lengths 1, 2, 16, 17,
+38, 1028, 2047 and 2048 gave logits within 7.2e-2 of the dense arm's (largest
+logit 5.14, tolerance 8 bf16 eps of it = 1.6e-1; the kernel before PR 28 read
+5.5e-2: p now enters the second product in bf16), and the compiled step held
+the Mosaic call. tests/test_tpu_aot.py compiles it for a v5e from this host
+at D=64, D=128 and the serving cells' exact shape.
 """
 
 import jax
@@ -20,7 +21,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import llama
-from ray_tpu.ops.paged_attention import paged_decode_attention
+from ray_tpu.ops.paged_attention import pages_per_group, paged_decode_attention
 
 
 def _scatter_pages(k_seq, tables, block_size, num_pool_blocks):
@@ -34,35 +35,111 @@ def _scatter_pages(k_seq, tables, block_size, num_pool_blocks):
     return jnp.asarray(pages)
 
 
-@pytest.mark.parametrize("g", [1, 2, 4])
-def test_paged_decode_matches_dense(g):
-    rng = np.random.default_rng(0)
-    B, Hkv, D, BS, max_blocks = 3, 2, 16, 8, 4
-    Hq = Hkv * g
-    NB = B * max_blocks + 1
-    lengths = np.array([5, 17, 32], np.int32)  # ragged, incl. full table
-    # non-trivial table: pages deliberately out of order across the pool
-    perm = rng.permutation(np.arange(1, NB))
-    tables = perm[: B * max_blocks].reshape(B, max_blocks).astype(np.int32)
+def _case(lengths, *, g=4, Hkv=8, D=128, BS=16, max_blocks, dtype=jnp.bfloat16,
+          empty=()):
+    return dict(lengths=lengths, g=g, Hkv=Hkv, D=D, BS=BS, max_blocks=max_blocks,
+                dtype=dtype, empty=empty)
 
-    S = max_blocks * BS
-    k_seq = rng.standard_normal((B, S, Hkv, D), np.float32)
-    v_seq = rng.standard_normal((B, S, Hkv, D), np.float32)
-    q = jnp.asarray(rng.standard_normal((B, Hq, D), np.float32))
 
-    k_pages = _scatter_pages(k_seq, tables, BS, NB)
-    v_pages = _scatter_pages(v_seq, tables, BS, NB)
+# The cell's head shape (8 KV heads, 4 query heads each, 128 wide, block 16,
+# bfloat16) walks 16 pages = 256 tokens a group; the small float32 shape
+# (block 8) 32 pages = 256 tokens, or the whole table if that is narrower.
+_CASES = {
+    # the three cases this test had: a 4-block table, one group, exact
+    "g1": _case([5, 17, 32], g=1, Hkv=2, D=16, BS=8, max_blocks=4, dtype=jnp.float32),
+    "g2": _case([5, 17, 32], g=2, Hkv=2, D=16, BS=8, max_blocks=4, dtype=jnp.float32),
+    "g4": _case([5, 17, 32], g=4, Hkv=2, D=16, BS=8, max_blocks=4, dtype=jnp.float32),
+    # several groups, exact: 1 token, a page, a page + 1, one group, one group
+    # + 1, a full table that is not a multiple of the group (40 pages of 8
+    # against 32 a group; 40 of 16 against 16)
+    "f32-groups": _case([1, 8, 9, 256, 257, 320], g=2, Hkv=2, D=16, BS=8,
+                        max_blocks=40, dtype=jnp.float32),
+    "cell-bf16": _case([1, 16, 17, 256, 257, 640], max_blocks=40),
+    "cell-bf16-d64": _case([1, 16, 17, 256, 257, 640], D=64, max_blocks=40),
+    "olmoe-bf16-g1": _case([1, 17, 129, 320], g=1, Hkv=16, max_blocks=20),
+    "table-narrower-than-a-group": _case([1, 17, 64], max_blocks=4),
+    "table-of-one-group": _case([255, 256], max_blocks=16),
+    # an empty slot as the engine leaves it: lengths + 1 == 1, table all zeros
+    # (it reads the garbage page 0; nobody reads its row, which must be finite)
+    "empty-slots": _case([1, 1, 33], max_blocks=20, empty=(0, 1)),
+}
 
-    out = paged_decode_attention(q, k_pages, v_pages, jnp.asarray(tables),
-                                 jnp.asarray(lengths), interpret=True)
+
+def _inputs(case, seed=0):
+    """q, k_seq, v_seq [B, S, Hkv, D] as float32 arrays of values the case's
+    dtype holds exactly (the float32 reference sees what the kernel sees), a
+    table with its pages out of order across the pool, and the pools scattered
+    from it in the case's dtype."""
+    rng = np.random.default_rng(seed)
+    lengths = np.asarray(case["lengths"], np.int32)
+    B, Hkv, D, BS, mb = len(lengths), case["Hkv"], case["D"], case["BS"], case["max_blocks"]
+    NB = B * mb + 1
+    tables = rng.permutation(np.arange(1, NB)).reshape(B, mb).astype(np.int32)
+    tables[list(case["empty"])] = 0
+    to = lambda a: jnp.asarray(a).astype(case["dtype"])
+    exact = lambda shape: np.asarray(
+        to(rng.standard_normal(shape, np.float32)).astype(jnp.float32))
+    q = exact((B, Hkv * case["g"], D))
+    k_seq, v_seq = exact((B, mb * BS, Hkv, D)), exact((B, mb * BS, Hkv, D))
+    pools = (to(q), to(_scatter_pages(k_seq, tables, BS, NB)),
+             to(_scatter_pages(v_seq, tables, BS, NB)))
+    return q, k_seq, v_seq, tables, lengths, pools
+
+
+def _run_kernel(pools, tables, lengths):
+    return np.asarray(paged_decode_attention(
+        *pools, jnp.asarray(tables), jnp.asarray(lengths),
+        interpret=True).astype(jnp.float32))
+
+
+@pytest.mark.parametrize("name", list(_CASES))
+def test_paged_decode_matches_dense(name):
+    case = _CASES[name]
+    q, k_seq, v_seq, tables, lengths, pools = _inputs(case)
+    out = _run_kernel(pools, tables, lengths)
 
     # dense reference: q position = lengths-1, KV valid prefix = lengths
     ref = llama._cached_attention(
-        q[:, None], jnp.asarray(k_seq), jnp.asarray(v_seq),
+        jnp.asarray(q)[:, None], jnp.asarray(k_seq), jnp.asarray(v_seq),
         jnp.asarray(lengths - 1),
         jnp.asarray(lengths - 1)[:, None],
     )[:, 0]
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    # float32: nothing is rounded. bfloat16: p and the output are rounded to 8
+    # bits, on values up to ~4 (a length-1 row is a row of v itself)
+    atol = 2e-5 if case["dtype"] == jnp.float32 else 3e-2
+    real = [b for b in range(len(lengths)) if b not in case["empty"]]
+    np.testing.assert_allclose(out[real], np.asarray(ref)[real], atol=atol)
+    assert np.isfinite(out).all()
+
+
+def test_paged_decode_group_follows_the_shapes():
+    # the cell: 16 pages (256 tokens); OLMoE's 16 KV heads the same; float32
+    # pages of 32 KV heads halve it (VMEM); never wider than the table
+    assert pages_per_group(16, 128, 8, 2, 128) == 16
+    assert pages_per_group(16, 64, 8, 2, 128) == 16
+    assert pages_per_group(16, 128, 16, 2, 128) == 16
+    assert pages_per_group(16, 128, 32, 4, 128) == 8
+    assert pages_per_group(8, 16, 2, 4, 40) == 32
+    assert pages_per_group(16, 128, 8, 2, 4) == 4
+    assert pages_per_group(256, 128, 8, 2, 8) == 1
+
+
+@pytest.mark.parametrize("name", ["f32-groups", "cell-bf16"])
+def test_paged_decode_never_reads_past_the_last_live_page(name):
+    """Table entries past a sequence's last live page may hold anything valid
+    (the allocator's stale ids, another sequence's pages): the output is the
+    same to the bit, because those pages are not read at all."""
+    case = _CASES[name]
+    *_, tables, lengths, pools = _inputs(case)
+    # the pools are scattered from the ORIGINAL table; only what the kernel is
+    # told about the dead entries changes
+    stale = tables.copy()
+    rng = np.random.default_rng(1)
+    for b, n in enumerate(-(-lengths // case["BS"])):
+        stale[b, n:] = rng.integers(0, pools[1].shape[1], case["max_blocks"] - n)
+    assert (stale != tables).any()
+    np.testing.assert_array_equal(_run_kernel(pools, stale, lengths),
+                                  _run_kernel(pools, tables, lengths))
 
 
 def test_forward_paged_kernel_path_matches_gather_path():

@@ -136,15 +136,24 @@ def test_flash_compiles_at_the_fsdp4_cell_shape(v5e):
     assert f"bf16[{B // 4 * Hq},{S},{D}]" in text        # a device's own shard
 
 
-@pytest.mark.parametrize("D", [64, 128])
-def test_paged_decode_compiles(v5e, D):
-    B, Hq, Hkv, BS, max_blocks = 8, 32, 8, 16, 16
+@pytest.mark.parametrize("B, Hq, Hkv, D, max_blocks, pool_blocks", [
+    (8, 32, 8, 64, 16, 129), (8, 32, 8, 128, 16, 129),
+    # `serve-chat-steady` and `serve-docs-batch`: 32 slots, a 128-block table
+    # over the engine's 4,097-block pool; a VMEM or Mosaic limit at the real
+    # size fails here and not on the chip
+    (32, 32, 8, 128, 128, 4097),
+    # OLMoE's attention at the same engine sizes: 16 KV heads, one query head each
+    (32, 16, 16, 128, 128, 4097),
+], ids=["D64", "D128", "serving-cells", "olmoe-heads"])
+def test_paged_decode_compiles(v5e, B, Hq, Hkv, D, max_blocks, pool_blocks):
+    BS = 16
     d = v5e[0]
-    pages = _on(d, (Hkv, B * max_blocks + 1, BS, D))
+    pages = _on(d, (Hkv, pool_blocks, BS, D))
     text = jax.jit(lambda *a: paged_decode_attention(*a, interpret=False)).lower(
         _on(d, (B, Hq, D)), pages, pages, _on(d, (B, max_blocks), jnp.int32),
         _on(d, (B,), jnp.int32)).compile().as_text()
     assert MOSAIC in text
+    assert "paged_attention_decode" in text
 
 
 def _decode_step_text(d) -> str:
