@@ -216,39 +216,66 @@ def auto_attention(q, k, v, causal: bool = True, platform: str | None = None):
     return attention(q, k, v, causal=causal)
 
 
-def attn_sublayer(cfg: LlamaConfig, x, layer, positions, attn_fn):
-    """x + attention(norm(x)): the half of a block that every decoder family
-    here shares (models/moe.py calls it too). A layer that holds `q_norm` and
-    `k_norm` (OLMoE) normalises the WHOLE projected query and key vector,
-    before the split into heads and before rope."""
-    hd, nh, nkv = cfg.hd, cfg.num_heads, cfg.num_kv_heads
-    B, S, h = x.shape
+def dense_mlp(y, layer):
+    """The MLP strategy of the dense families: the gated MLP on normalised
+    activations y [B, S, H] -> ([B, S, H], {}: it has no stats)."""
+    with jax.named_scope("mlp"):
+        gate = jax.nn.silu(y @ layer["w_gate"])
+        return (gate * (y @ layer["w_up"])) @ layer["w_down"], {}
+
+
+def plain_attend(attn_fn=None):
+    """The attention strategy that keeps no cache (training): `attn_fn`
+    (default: `auto_attention`, causal) over the whole sequence."""
+    attn_fn = attn_fn or partial(auto_attention, causal=True)
+    return lambda q, k, v, cache: (attn_fn(q, k, v), None)
+
+
+def decoder_layer(cfg: LlamaConfig, x, layer, cache, positions, attend,
+                  mlp=dense_mlp, reduce=lambda t: t):
+    """x [B, S, H] through one pre-norm decoder block -> (x, the layer's
+    updated cache, the MLP's stats). The one spelling that every family, both
+    cached forwards and the pipeline's stage run; they differ in two
+    strategies:
+
+    - `attend(q, k, v, cache) -> (o, cache)`: attention over the rotated heads
+      q [B, S, Hq, D], k/v [B, S, Hkv, D] and what the layer carries out:
+      nothing (`plain_attend`), its pages of the pool (`forward_paged`), its
+      slots (`forward_with_cache`);
+    - `mlp(y, layer) -> (out, stats)` on the normalised activations:
+      `dense_mlp`, or `moe.moe_mlp`, whose scopes stand beside `mlp`.
+
+    Head counts come from the projected widths, so a tensor-sharded stage
+    passes its local weights and, as `reduce`, the sum over its axis of the
+    two row-sharded products. A layer that holds `q_norm` and `k_norm` (OLMoE)
+    normalises the WHOLE projected query and key vector, before the split
+    into heads and before rope."""
+    B, S, _ = x.shape
+    eps, hd = cfg.rms_eps, cfg.hd
     # the scopes are names in a profile and in the HLO's op_name, no more
     with jax.named_scope("attn"):
-        y = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+        y = rms_norm(x, layer["attn_norm"], eps)
         qk_norm = "q_norm" in layer
         q = y @ layer["wq"]
         if qk_norm:
-            q = rms_norm(q, layer["q_norm"], cfg.rms_eps)
-        q = q.reshape(B, S, nh, hd)
+            q = rms_norm(q, layer["q_norm"], eps)
+        q = q.reshape(B, S, -1, hd)
         k = y @ layer["wk"]
         if qk_norm:
-            k = rms_norm(k, layer["k_norm"], cfg.rms_eps)
-        k = k.reshape(B, S, nkv, hd)
-        v = (y @ layer["wv"]).reshape(B, S, nkv, hd)
+            k = rms_norm(k, layer["k_norm"], eps)
+        k = k.reshape(B, S, -1, hd)
+        v = (y @ layer["wv"]).reshape(B, S, -1, hd)
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
-        o = attn_fn(q, k, v)
-        return x + (o.reshape(B, S, nh * hd) @ layer["wo"])
-
-
-def _block(cfg: LlamaConfig, x, layer, positions, attn_fn):
-    x = attn_sublayer(cfg, x, layer, positions, attn_fn)
+        o, cache = attend(q, k, v, cache)
+        x = x + reduce(o.reshape(B, S, -1) @ layer["wo"])
+    # `mlp` is opened around the strategy, not over it: the expert layer's
+    # scopes are read by name as siblings of `attn` and `mlp`, not children
     with jax.named_scope("mlp"):
-        y = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
-        gate = jax.nn.silu(y @ layer["w_gate"])
-        x = x + ((gate * (y @ layer["w_up"])) @ layer["w_down"])
-    return x
+        y = rms_norm(x, layer["mlp_norm"], eps)
+    out, stats = mlp(y, layer)
+    with jax.named_scope("mlp"):
+        return x + reduce(out), cache, stats
 
 
 def remat_body(body, cfg: LlamaConfig):
@@ -262,33 +289,59 @@ def remat_body(body, cfg: LlamaConfig):
     return jax.checkpoint(body, prevent_cse=False, policy=policy)
 
 
-def forward(params, tokens, cfg: LlamaConfig, attn_fn=None, positions=None):
-    """Token ids [B, S] → logits [B, S, vocab] (fp32)."""
-    if attn_fn is None:
-        attn_fn = partial(auto_attention, causal=True)
-    B, S = tokens.shape
-    if positions is None:
-        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    x = params["embed"][tokens].astype(cfg.dtype)
-
-    def body(x, layer):
-        return _block(cfg, x, layer, positions, attn_fn), None
-
-    x, _ = jax.lax.scan(remat_body(body, cfg), x, params["layers"])
+def lm_head(params, x, cfg: LlamaConfig):
+    """Final norm and the tied or untied output head: [..., H] -> float32
+    logits [..., V]."""
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     return (x @ head.astype(cfg.dtype)).astype(jnp.float32)
 
 
-def next_token_loss(logits, targets):
-    """Mean cross-entropy of float32 logits [B, S, V]; targets [B, S] with
-    -100 = ignore."""
+def decoder_trunk(params, tokens, cfg: LlamaConfig, attend, mlp=dense_mlp,
+                  cache=None, positions=None):
+    """Token ids [B, S] -> (float32 logits [B, S, V], the updated cache, the
+    layers' stats stacked): embedding, `lax.scan` of `decoder_layer` over the
+    layers and their slices of `cache` (leaves [L, ...], handed to `attend`
+    layer by layer), `lm_head`. Without a cache it is a training forward and
+    the body runs under `remat_body`; a cached forward scans the bare body (a
+    `checkpoint` in a decode step would be a different program)."""
+    B, S = tokens.shape
+    if positions is None:
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    x = params["embed"][tokens].astype(cfg.dtype)
+
+    def body(x, layer_and_cache):
+        layer, layer_cache = layer_and_cache
+        x, layer_cache, stats = decoder_layer(
+            cfg, x, layer, layer_cache, positions, attend, mlp)
+        return x, (layer_cache, stats)
+
+    x, (cache, stats) = jax.lax.scan(
+        remat_body(body, cfg) if cache is None else body, x, (params["layers"], cache))
+    return lm_head(params, x, cfg), cache, stats
+
+
+def forward(params, tokens, cfg: LlamaConfig, attn_fn=None, positions=None):
+    """Token ids [B, S] → logits [B, S, vocab] (fp32)."""
+    return decoder_trunk(params, tokens, cfg, plain_attend(attn_fn),
+                         positions=positions)[0]
+
+
+def token_nll(logits, targets):
+    """(Summed cross-entropy, number of targets that count) of float32 logits
+    [B, S, V]; targets [B, S] with -100 = ignore."""
     valid = targets != -100
     tsafe = jnp.where(valid, targets, 0)
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, tsafe[..., None], axis=-1)[..., 0]
-    nll = (logz - gold) * valid
-    return nll.sum() / jnp.maximum(valid.sum(), 1)
+    return ((logz - gold) * valid).sum(), valid.sum()
+
+
+def next_token_loss(logits, targets):
+    """Mean cross-entropy of float32 logits [B, S, V]; targets [B, S] with
+    -100 = ignore."""
+    nll_sum, count = token_nll(logits, targets)
+    return nll_sum / jnp.maximum(count, 1)
 
 
 def loss_fn(params, tokens, targets, cfg: LlamaConfig, attn_fn=None):
@@ -302,13 +355,6 @@ def _model_loss(params, tokens, targets, cfg: LlamaConfig, attn_fn, mesh=None):
 
 # what train/spmd.py takes of a model (ray_tpu/models/__init__.py)
 MODEL = Model(init=init, logical_axes=logical_axes, loss=_model_loss)
-
-
-def flops_per_token(cfg: LlamaConfig) -> float:
-    """Approximate fwd+bwd FLOPs/token (6N + attention terms)."""
-    n = param_count_analytic(cfg)
-    attn = 12 * cfg.num_layers * cfg.hidden_size * cfg.max_seq_len  # rough seq term
-    return 6 * n + attn
 
 
 def param_count_analytic(cfg: LlamaConfig) -> int:
@@ -385,51 +431,34 @@ def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
     blk_idx = tables[jnp.arange(B)[:, None], jnp.where(oob, 0, seq_blk)]  # [B,S]
     blk_idx = jnp.where(oob, 0, blk_idx)
     blk_off = positions % block_size
-    x = params["embed"][tokens].astype(cfg.dtype)
-    hd, nh, nkv = cfg.hd, cfg.num_heads, cfg.num_kv_heads
 
-    def body(x, layer_and_pool):
-        layer, kp, vp = layer_and_pool  # kp/vp: [Hkv, NB, BS, D]
+    def attend(q, k, v, pages):  # the layer's pages of the pool, k/v: [Hkv, NB, BS, D]
+        kp, vp = pages["k"], pages["v"]
         # the scopes name, in a profile, the statement behind each pool-sized
         # copy of a step: attn/kv_write or attn/kv_read
-        with jax.named_scope("attn"):
-            y = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-            q = (y @ layer["wq"]).reshape(B, S, nh, hd)
-            k = (y @ layer["wk"]).reshape(B, S, nkv, hd)
-            v = (y @ layer["wv"]).reshape(B, S, nkv, hd)
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
-            with jax.named_scope("kv_write"):
-                # head-major scatter: kp[h, blk_idx[b,s], blk_off[b,s]] = k[b,s,h]
-                kp = kp.at[:, blk_idx, blk_off].set(
-                    k.transpose(2, 0, 1, 3).astype(kp.dtype))
-                vp = vp.at[:, blk_idx, blk_off].set(
-                    v.transpose(2, 0, 1, 3).astype(vp.dtype))
-            with jax.named_scope("kv_read"):
-                if use_kernel:
-                    from ray_tpu.ops.paged_attention import paged_decode_attention
+        with jax.named_scope("kv_write"):
+            # head-major scatter: kp[h, blk_idx[b,s], blk_off[b,s]] = k[b,s,h]
+            kp = kp.at[:, blk_idx, blk_off].set(
+                k.transpose(2, 0, 1, 3).astype(kp.dtype))
+            vp = vp.at[:, blk_idx, blk_off].set(
+                v.transpose(2, 0, 1, 3).astype(vp.dtype))
+        with jax.named_scope("kv_read"):
+            if use_kernel:
+                from ray_tpu.ops.paged_attention import paged_decode_attention
 
-                    o = paged_decode_attention(
-                        q[:, 0], kp, vp, tables, lengths + 1,
-                        interpret=platform != "tpu")[:, None]  # [B,1,Hq,D]
-                else:
-                    k_seq = kp[:, tables].transpose(1, 2, 3, 0, 4).reshape(
-                        B, max_blocks * block_size, nkv, hd)
-                    v_seq = vp[:, tables].transpose(1, 2, 3, 0, 4).reshape(
-                        B, max_blocks * block_size, nkv, hd)
-                    o = _cached_attention(q, k_seq, v_seq, lengths, positions)
-            x = x + (o.reshape(B, S, nh * hd) @ layer["wo"])
-        with jax.named_scope("mlp"):
-            y = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
-            gate = jax.nn.silu(y @ layer["w_gate"])
-            x = x + ((gate * (y @ layer["w_up"])) @ layer["w_down"])
-        return x, (kp, vp)
+                o = paged_decode_attention(
+                    q[:, 0], kp, vp, tables, lengths + 1,
+                    interpret=platform != "tpu")[:, None]  # [B,1,Hq,D]
+            else:
+                k_seq = kp[:, tables].transpose(1, 2, 3, 0, 4).reshape(
+                    B, max_blocks * block_size, *k.shape[2:])
+                v_seq = vp[:, tables].transpose(1, 2, 3, 0, 4).reshape(
+                    B, max_blocks * block_size, *v.shape[2:])
+                o = _cached_attention(q, k_seq, v_seq, lengths, positions)
+        return o, {"k": kp, "v": vp}
 
-    x, (out_k, out_v) = jax.lax.scan(body, x, (params["layers"], pool["k"], pool["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head.astype(cfg.dtype)).astype(jnp.float32)
-    return logits, {"k": out_k, "v": out_v}
+    return decoder_trunk(params, tokens, cfg, attend, cache=pool,
+                         positions=positions)[:2]
 
 
 def _cached_attention(q, k_cache, v_cache, lengths, q_positions):
@@ -463,30 +492,13 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache: dict, lengths):
     (logits[B,S,V], updated cache). Works for prefill (S=prompt, lengths=0)
     and decode (S=1). lax.scan over layers keeps compile time O(1) in depth
     (same design as forward())."""
-    B, S = tokens.shape
-    positions = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    x = params["embed"][tokens].astype(cfg.dtype)
-    hd, nh, nkv = cfg.hd, cfg.num_heads, cfg.num_kv_heads
+    positions = lengths[:, None] + jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
 
-    def body(x, layer_and_cache):
-        layer, k_old, v_old = layer_and_cache
-        y = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-        q = (y @ layer["wq"]).reshape(B, S, nh, hd)
-        k = (y @ layer["wk"]).reshape(B, S, nkv, hd)
-        v = (y @ layer["wv"]).reshape(B, S, nkv, hd)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
-        k_cache = _write_cache(k_old, k, lengths)
-        v_cache = _write_cache(v_old, v, lengths)
+    def attend(q, k, v, slots):  # the layer's slice of the cache, k/v: [B, Smax, Hkv, D]
+        k_cache = _write_cache(slots["k"], k, lengths)
+        v_cache = _write_cache(slots["v"], v, lengths)
         o = _cached_attention(q, k_cache, v_cache, lengths, positions)
-        x = x + (o.reshape(B, S, nh * hd) @ layer["wo"])
-        y = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
-        gate = jax.nn.silu(y @ layer["w_gate"])
-        x = x + ((gate * (y @ layer["w_up"])) @ layer["w_down"])
-        return x, (k_cache, v_cache)
+        return o, {"k": k_cache, "v": v_cache}
 
-    x, (out_k, out_v) = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head.astype(cfg.dtype)).astype(jnp.float32)
-    return logits, {"k": out_k, "v": out_v}
+    return decoder_trunk(params, tokens, cfg, attend, cache=cache,
+                         positions=positions)[:2]

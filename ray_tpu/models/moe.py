@@ -1,8 +1,8 @@
 """Sparse mixture-of-experts decoder (OLMoE, Mixtral): dropless routing by sort.
 
-The Llama backbone (`llama.attn_sublayer`: pre-norm attention with rotary
+The Llama backbone (`llama.decoder_layer`: pre-norm attention with rotary
 positions, optionally OLMoE's RMSNorm over the whole projected query and key)
-with the MLP of every block replaced by `num_experts` SwiGLU experts of which
+with, as its MLP strategy, in every block `num_experts` SwiGLU experts of which
 each token uses `top_k`:
 
     p = softmax(float32(y @ router))          over all experts
@@ -206,27 +206,13 @@ def forward(params, tokens, cfg: MoEConfig, attn_fn=None, positions=None, mesh=N
     `attn_fn` as `llama.forward`'s. `mesh` is the mesh the computation is
     sharded over, if the caller knows it: it decides kernel or dense for the
     expert products (None: from the placement of the arguments)."""
-    base = cfg.base
-    if attn_fn is None:
-        attn_fn = partial(llama.auto_attention, causal=True)
     platform = target_platform(tokens, params["embed"], mesh=mesh)
     if mesh is not None and dict(mesh.shape).get("expert", 1) > 1:
         platform = "spmd"   # experts over devices: the dense path, see the docstring
-    B, S = tokens.shape
-    if positions is None:
-        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    x = params["embed"][tokens].astype(base.dtype)
-
-    def body(x, layer):
-        x = llama.attn_sublayer(base, x, layer, positions, attn_fn)
-        y = llama.rms_norm(x, layer["mlp_norm"], base.rms_eps)
-        out, stats = moe_mlp(y, layer, cfg, platform)
-        return x + out, stats
-
-    x, stats = jax.lax.scan(llama.remat_body(body, base), x, params["layers"])
-    x = llama.rms_norm(x, params["final_norm"], base.rms_eps)
-    head = params["embed"].T if base.tie_embeddings else params["lm_head"]
-    return (x @ head.astype(base.dtype)).astype(jnp.float32), stats
+    logits, _, stats = llama.decoder_trunk(
+        params, tokens, cfg.base, llama.plain_attend(attn_fn),
+        partial(moe_mlp, cfg=cfg, platform=platform), positions=positions)
+    return logits, stats
 
 
 def loss_fn(params, tokens, targets, cfg: MoEConfig, attn_fn=None, mesh=None):

@@ -100,7 +100,7 @@ def make_pp_loss_and_grad(
 ) -> Callable:
     """Build `(params, tokens, targets) -> (loss, grads)` — one shard_map over
     the full mesh, grads globally reduced and sharded like the params."""
-    Pst, T = _check(cfg, mesh)
+    Pst, _ = _check(cfg, mesh)
     M = num_microbatches
     specs = param_specs(cfg)
     if attn_fn is None:
@@ -109,9 +109,8 @@ def make_pp_loss_and_grad(
         attn_fn = partial(llama.auto_attention, causal=True,
                           platform=target_platform(mesh=mesh))
 
-    nh_local = cfg.num_heads // T
-    nkv_local = cfg.num_kv_heads // T
-    hd = cfg.hd
+    attend = llama.plain_attend(attn_fn)
+    tensor_sum = partial(jax.lax.psum, axis_name="tensor")
 
     def local_loss(params, tokens, targets):
         """Per-device loss; nonzero only on last-stage devices. All arrays are
@@ -124,29 +123,14 @@ def make_pp_loss_and_grad(
         toks_mb = tokens.reshape(M, Bm, S)
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (Bm, S))
 
-        def block(x, layer):
-            y = llama.rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-            q = llama.rope((y @ layer["wq"]).reshape(Bm, S, nh_local, hd),
-                           positions, cfg.rope_theta)
-            k = llama.rope((y @ layer["wk"]).reshape(Bm, S, nkv_local, hd),
-                           positions, cfg.rope_theta)
-            v = (y @ layer["wv"]).reshape(Bm, S, nkv_local, hd)
-            o = attn_fn(q, k, v).reshape(Bm, S, nh_local * hd)
-            x = x + jax.lax.psum(o @ layer["wo"], "tensor")
-            y = llama.rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
-            part = (jax.nn.silu(y @ layer["w_gate"]) * (y @ layer["w_up"])) @ layer["w_down"]
-            return x + jax.lax.psum(part, "tensor")
-
         def stage_fn(x):
+            # the stage's local heads and MLP columns through the one layer,
+            # its two row-sharded products summed over `tensor`
             def body(x, layer):
-                return block(x, layer), None
+                return llama.decoder_layer(cfg, x, layer, None, positions, attend,
+                                           reduce=tensor_sum)[0], None
 
-            if cfg.remat:
-                policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                          if cfg.remat_policy == "dots" else None)
-                body = jax.checkpoint(body, prevent_cse=False, policy=policy)
-            x, _ = jax.lax.scan(body, x, params["layers"])
-            return x
+            return jax.lax.scan(llama.remat_body(body, cfg), x, params["layers"])[0]
 
         perm = [(i, i + 1) for i in range(Pst - 1)]
 
@@ -174,16 +158,9 @@ def make_pp_loss_and_grad(
         # head + loss: computed everywhere (identical FLOPs keep stages in
         # lockstep), meaningful only on the last stage — `is_last` masks the
         # rest, which also zeroes their embed/head grads exactly.
-        x = llama.rms_norm(outputs.reshape(Bl, S, cfg.hidden_size),
-                           params["final_norm"], cfg.rms_eps)
-        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = (x @ head.astype(cfg.dtype)).astype(jnp.float32)
-        valid = targets != -100
-        tsafe = jnp.where(valid, targets, 0)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, tsafe[..., None], axis=-1)[..., 0]
-        nll_sum = ((logz - gold) * valid).sum()
-        global_count = jax.lax.psum(valid.sum(), ("data", "fsdp"))
+        logits = llama.lm_head(params, outputs.reshape(Bl, S, cfg.hidden_size), cfg)
+        nll_sum, count = llama.token_nll(logits, targets)
+        global_count = jax.lax.psum(count, ("data", "fsdp"))
         # Seed the loss on exactly ONE device per batch shard: last stage,
         # tensor rank 0. Tensor replicas compute identical losses, and SPMD
         # autodiff sums every device's seed — an unmasked loss would flow T

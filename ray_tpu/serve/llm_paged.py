@@ -28,6 +28,7 @@ import dataclasses
 import queue
 import time
 from concurrent.futures import Future
+from functools import partial
 from typing import Optional
 
 import numpy as np
@@ -61,6 +62,26 @@ class PagedLLMConfig(LLMConfig):
     kv_transfer: str = "host"
 
 
+def paged_step(name: str, cfg: llama.LlamaConfig, block_size: int, platform: str,
+               rows, table_first: bool = False):
+    """The jitted step `name` of a paged engine: `llama.forward_paged` of one
+    model configuration over a pool it donates -> (`logits[rows]` of the
+    [B, S, vocab] logits, the pool). Called as (params, pool, tokens, lengths,
+    tables), a prefill (`table_first`) as (params, pool, tokens, table,
+    start_len). A profile knows the step by `name` (`jit(decode)/while/...`).
+    The step holds its arguments only, never the engine."""
+    import jax
+
+    def step(params, pool, tokens, first, second):
+        tables, lengths = (first, second) if table_first else (second, first)
+        logits, pool = llama.forward_paged(
+            params, tokens, cfg, pool, tables, lengths, block_size, platform=platform)
+        return logits[rows], pool
+
+    step.__name__ = step.__qualname__ = name
+    return jax.jit(step, donate_argnums=(1,))
+
+
 class PagedLLMEngine(LLMEngine):
     """Continuous batching over a paged KV pool with prefix caching."""
 
@@ -83,7 +104,6 @@ class PagedLLMEngine(LLMEngine):
                          external_step=external_step)
 
     def _init_backend(self) -> None:
-        jax, jnp = self._jax, self._jnp
         cfg = self.config.model_config
         B, S, bs = (self.config.max_batch_size, self.config.max_seq_len,
                     self.config.block_size)
@@ -97,25 +117,10 @@ class PagedLLMEngine(LLMEngine):
         self.tables = np.zeros((B, self.max_blocks_per_seq), dtype=np.int32)
         self.slot_blocks: list[list[int]] = [[] for _ in range(B)]
         self.slot_prompts: list[Optional[list[int]]] = [None] * B
-        platform = self.platform  # a local: the jitted closures must not hold self
-
-        def prefill(params, pool, tokens, table, start_len):
-            # B=1 row: run the suffix, return per-position logits
-            logits, pool = llama.forward_paged(
-                params, tokens, cfg, pool, table, start_len, bs,
-                platform=platform
-            )
-            return logits[0], pool
-
-        def decode(params, pool, last_tokens, lengths, tables):
-            logits, pool = llama.forward_paged(
-                params, last_tokens, cfg, pool, tables, lengths, bs,
-                platform=platform
-            )
-            return logits[:, 0], pool
-
-        self._prefill = jax.jit(prefill, donate_argnums=(1,))
-        self._decode = jax.jit(decode, donate_argnums=(1,))
+        step = partial(paged_step, cfg=cfg, block_size=bs, platform=self.platform)
+        # prefill: a B=1 row, the suffix's per-position logits
+        self._prefill = step("prefill", rows=0, table_first=True)
+        self._decode = step("decode", rows=np.s_[:, 0])
 
     def dummy_decode(self) -> None:
         """Cadence-keeping round for DP-attention lockstep (dp_attention.py):

@@ -24,13 +24,14 @@ them (same argument for the draft pool).
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Optional
 
 import numpy as np
 
 from ray_tpu.models import llama
 from ray_tpu.serve.llm_paged import (_DECODE_PHASES, PagedLLMConfig,
-                                     PagedLLMEngine)
+                                     PagedLLMEngine, paged_step)
 
 _SPEC_DECODE_PHASES = ("draft",) + _DECODE_PHASES
 
@@ -63,53 +64,25 @@ class SpecDecodeLLMEngine(PagedLLMEngine):
 
     def _init_backend(self) -> None:
         super()._init_backend()
-        jax, jnp = self._jax, self._jnp
+        jax = self._jax
         cfg = self.config.model_config
         dcfg = self.config.draft_model_config
         bs = self.config.block_size
-        platform = self.platform  # a local: the jitted closures must not hold self
         self.draft_params = (self._draft_params_init
                              if self._draft_params_init is not None
                              else llama.init(dcfg, jax.random.PRNGKey(7)))
         # mirror pool: same block ids resolve in both pools via one table
         self.draft_pool = llama.init_kv_pool(dcfg, self.pool_blocks, bs)
 
-        def draft_prefill(params, pool, tokens, table, start_len):
-            logits, pool = llama.forward_paged(
-                params, tokens, dcfg, pool, table, start_len, bs,
-                platform=platform
-            )
-            return logits[0], pool
-
-        def draft_decode(params, pool, last_tokens, lengths, tables):
-            logits, pool = llama.forward_paged(
-                params, last_tokens, dcfg, pool, tables, lengths, bs,
-                platform=platform
-            )
-            return logits[:, 0], pool
-
-        def draft_decode2(params, pool, window2, lengths, tables):
-            # [B, 2] window: re-process [prev, last] so a fully-accepted prior
-            # step's final proposal (whose draft KV was never written — the
-            # classic bonus-token hole) gets its page filled before proposing
-            logits, pool = llama.forward_paged(
-                params, window2, dcfg, pool, tables, lengths, bs,
-                platform=platform
-            )
-            return logits[:, 1], pool
-
-        def verify(params, pool, window, lengths, tables):
-            # [B, K+1] window scored in one target forward
-            logits, pool = llama.forward_paged(
-                params, window, cfg, pool, tables, lengths, bs,
-                platform=platform
-            )
-            return logits, pool
-
-        self._draft_prefill = jax.jit(draft_prefill, donate_argnums=(1,))
-        self._draft_decode = jax.jit(draft_decode, donate_argnums=(1,))
-        self._draft_decode2 = jax.jit(draft_decode2, donate_argnums=(1,))
-        self._verify = jax.jit(verify, donate_argnums=(1,))
+        step = partial(paged_step, block_size=bs, platform=self.platform)
+        self._draft_prefill = step("draft_prefill", dcfg, rows=0, table_first=True)
+        self._draft_decode = step("draft_decode", dcfg, rows=np.s_[:, 0])
+        # [B, 2] window: re-process [prev, last] so a fully-accepted prior
+        # step's final proposal (whose draft KV was never written — the
+        # classic bonus-token hole) gets its page filled before proposing
+        self._draft_decode2 = step("draft_decode2", dcfg, rows=np.s_[:, 1])
+        # [B, K+1] window scored in one target forward
+        self._verify = step("verify", cfg, rows=())
         # second-to-last committed token per slot (the 2-token window's head)
         self.prev_tokens = np.zeros((self.config.max_batch_size, 1), dtype=np.int32)
 

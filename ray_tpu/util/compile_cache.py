@@ -6,7 +6,7 @@ from this package's location — the directory is part of every entry's key,
 so the driver, gang members and spawned workers must all resolve the same
 one, and a path from `tempfile`, a pid or the clock would never hit. Called
 where the main path first compiles (engine construction, `make_train_step`,
-`chip_smoke.py`, `bench.py`) with the platform it compiles for: only TPU
+`chip_smoke.py`) with the platform it compiles for: only TPU
 programs are worth keeping (they take seconds to minutes), and reloading
 XLA:CPU executables logs a machine-feature mismatch error for every entry.
 

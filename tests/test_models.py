@@ -95,6 +95,49 @@ def test_train_step_with_ring_attention(tiny):
     assert np.isfinite(float(metrics["loss"]))
 
 
+@pytest.mark.parametrize("path, prefill, qk_norm", [
+    pytest.param("paged", 12, False, id="paged-prefill"),
+    pytest.param("paged", 8, False, id="paged-prefill-then-decode"),
+    pytest.param("slot", 8, False, id="slot-prefill-then-decode"),
+    pytest.param("paged", 8, True, id="paged-qk-norm"),
+])
+def test_cached_forwards_give_the_plain_forwards_logits(tiny, path, prefill, qk_norm):
+    """The first `prefill` positions in one cached call, the rest a token a
+    call: the logits of `llama.forward` at the same positions. One layer
+    function runs in all three, so a layer that holds `q_norm` and `k_norm`
+    is normalised through the paged path too."""
+    cfg, params = tiny
+    B, S, bs = 2, 12, 4
+    if qk_norm:
+        L, kq, kk = cfg.num_layers, *jax.random.split(jax.random.PRNGKey(3))
+        params = {**params, "layers": {
+            **params["layers"],
+            "q_norm": 1 + 0.5 * jax.random.normal(kq, (L, cfg.num_heads * cfg.hd)),
+            "k_norm": 1 + 0.5 * jax.random.normal(kk, (L, cfg.num_kv_heads * cfg.hd))}}
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)
+    want = llama.forward(params, tokens, cfg)
+    if qk_norm:   # the case means something only if the norm's weights matter
+        assert not np.allclose(np.asarray(want), np.asarray(llama.forward(
+            {**params, "layers": {k: v for k, v in params["layers"].items()
+                                  if k not in ("q_norm", "k_norm")}}, tokens, cfg)), atol=1e-2)
+
+    if path == "paged":
+        cache = llama.init_kv_pool(cfg, 1 + B * 4, bs)     # block 0 is the garbage block
+        tables = 1 + jnp.arange(B * 4, dtype=jnp.int32).reshape(B, 4)
+        step = lambda toks, cache, lengths: llama.forward_paged(
+            params, toks, cfg, cache, tables, lengths, bs)
+    else:
+        cache = llama.init_kv_cache(cfg, B, 16)
+        step = lambda toks, cache, lengths: llama.forward_with_cache(
+            params, toks, cfg, cache, lengths)
+    got = []
+    for start, stop in [(0, prefill)] + [(i, i + 1) for i in range(prefill, S)]:
+        logits, cache = step(tokens[:, start:stop], cache, jnp.full((B,), start, jnp.int32))
+        got.append(logits)
+    np.testing.assert_allclose(np.asarray(jnp.concatenate(got, axis=1)), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+
+
 def test_graft_entry_contract():
     import importlib.util, pathlib
 
