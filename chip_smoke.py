@@ -267,9 +267,13 @@ def phase_kernels(sizes: Sizes) -> dict:
     max_blocks = sizes.serve_seq // bs
     n_blocks = Bp * max_blocks + 1
     params = llama.init(cfg, jax.random.PRNGKey(1))
-    pshape = (cfg.num_layers, cfg.num_kv_heads, n_blocks, bs, cfg.hd)
-    pool = {"k": jax.random.normal(jax.random.PRNGKey(2), pshape, cfg.dtype),
-            "v": jax.random.normal(jax.random.PRNGKey(3), pshape, cfg.dtype)}
+    # the engine's pool (token major, a head in whole 128-lane tiles), its
+    # heads' own lanes random and the padding lanes zero as the model leaves them
+    pshape = jax.eval_shape(lambda: llama.init_kv_pool(cfg, n_blocks, bs))["k"].shape
+    own_lanes = (jnp.arange(pshape[-1]) % llama.pool_head_dim(cfg.hd)) < cfg.hd
+    pool = {name: jnp.where(own_lanes, jax.random.normal(
+                jax.random.PRNGKey(seed), pshape, cfg.dtype), 0)
+            for name, seed in (("k", 2), ("v", 3))}
     full = max_blocks * bs
     # KV tokens already cached per row; the kernel sees +1 (the new token):
     # 1 token, 2, one full block, a block and one, not a block multiple,
@@ -302,6 +306,11 @@ def phase_kernels(sizes: Sizes) -> dict:
     check(bool(jnp.array_equal(pool_k["k"][0], pool_d["k"][0]))
           and bool(jnp.array_equal(pool_k["v"][0], pool_d["v"][0])),
           "kernel and dense decode steps wrote different layer-0 KV pages")
+    # a step writes one row a slot and layer and nothing else
+    changed = int((pool_k["k"] != pool["k"]).any(axis=-1).sum())
+    check(0 < changed <= cfg.num_layers * Bp,
+          f"a decode step changed {changed} rows of the K pool, "
+          f"more than {cfg.num_layers} layers x {Bp} slots")
     # Tolerance: neither arm is the truth. Both round to bf16 at every
     # matmul and differ in where (the dense arm rounds scores to bf16 before
     # the softmax, the kernel keeps them in float32), over sizes.paged_layers

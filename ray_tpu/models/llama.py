@@ -228,20 +228,22 @@ def plain_attend(attn_fn=None):
     """The attention strategy that keeps no cache (training): `attn_fn`
     (default: `auto_attention`, causal) over the whole sequence."""
     attn_fn = attn_fn or partial(auto_attention, causal=True)
-    return lambda q, k, v, cache: (attn_fn(q, k, v), None)
+    return lambda q, k, v, cache, index: (attn_fn(q, k, v), None)
 
 
 def decoder_layer(cfg: LlamaConfig, x, layer, cache, positions, attend,
-                  mlp=dense_mlp, reduce=lambda t: t):
-    """x [B, S, H] through one pre-norm decoder block -> (x, the layer's
-    updated cache, the MLP's stats). The one spelling that every family, both
-    cached forwards and the pipeline's stage run; they differ in two
-    strategies:
+                  mlp=dense_mlp, reduce=lambda t: t, index=None):
+    """x [B, S, H] through one pre-norm decoder block, the `index`-th of the
+    stack -> (x, the updated cache, the MLP's stats). The one spelling that
+    every family, both cached forwards and the pipeline's stage run; they
+    differ in two strategies:
 
-    - `attend(q, k, v, cache) -> (o, cache)`: attention over the rotated heads
-      q [B, S, Hq, D], k/v [B, S, Hkv, D] and what the layer carries out:
-      nothing (`plain_attend`), its pages of the pool (`forward_paged`), its
-      slots (`forward_with_cache`);
+    - `attend(q, k, v, cache, index) -> (o, cache)`: attention over the
+      rotated heads q [B, S, Hq, D], k/v [B, S, Hkv, D]. `cache` is the WHOLE
+      cache, every layer's, and `index` the layer's place in it: the strategy
+      writes this layer's rows in place and reads them back (`forward_paged`:
+      pages of the pool; `forward_with_cache`: slots), or keeps nothing
+      (`plain_attend`: cache and index are None);
     - `mlp(y, layer) -> (out, stats)` on the normalised activations:
       `dense_mlp`, or `moe.moe_mlp`, whose scopes stand beside `mlp`.
 
@@ -267,7 +269,7 @@ def decoder_layer(cfg: LlamaConfig, x, layer, cache, positions, attend,
         v = (y @ layer["wv"]).reshape(B, S, -1, hd)
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
-        o, cache = attend(q, k, v, cache)
+        o, cache = attend(q, k, v, cache, index)
         x = x + reduce(o.reshape(B, S, -1) @ layer["wo"])
     # `mlp` is opened around the strategy, not over it: the expert layer's
     # scopes are read by name as siblings of `attn` and `mlp`, not children
@@ -301,23 +303,34 @@ def decoder_trunk(params, tokens, cfg: LlamaConfig, attend, mlp=dense_mlp,
                   cache=None, positions=None):
     """Token ids [B, S] -> (float32 logits [B, S, V], the updated cache, the
     layers' stats stacked): embedding, `lax.scan` of `decoder_layer` over the
-    layers and their slices of `cache` (leaves [L, ...], handed to `attend`
-    layer by layer), `lm_head`. Without a cache it is a training forward and
-    the body runs under `remat_body`; a cached forward scans the bare body (a
-    `checkpoint` in a decode step would be a different program)."""
+    layers, `lm_head`.
+
+    The cache is never an `xs`/`ys` of the scan: it rides in the carry beside
+    x, whole (leaves [L, ...]), and `attend` gets it with the layer's index.
+    A scan that slices a layer out of a stacked cache and stacks the result
+    back builds a second cache and copies every layer twice a step (PERF.md
+    section 6, PR 30: 80% of a decode step's device time); a carried buffer
+    that each layer updates at `[index, ...]` stays where it is. Without a
+    cache it is a training forward: the carry's cache is None, no index is
+    scanned, and the body runs under `remat_body`; a cached forward scans the
+    bare body (a `checkpoint` in a decode step would be a different program)."""
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     x = params["embed"][tokens].astype(cfg.dtype)
 
-    def body(x, layer_and_cache):
-        layer, layer_cache = layer_and_cache
-        x, layer_cache, stats = decoder_layer(
-            cfg, x, layer, layer_cache, positions, attend, mlp)
-        return x, (layer_cache, stats)
+    def body(carry, layer_and_index):
+        x, cache = carry
+        layer, index = layer_and_index
+        x, cache, stats = decoder_layer(
+            cfg, x, layer, cache, positions, attend, mlp, index=index)
+        return (x, cache), stats
 
-    x, (cache, stats) = jax.lax.scan(
-        remat_body(body, cfg) if cache is None else body, x, (params["layers"], cache))
+    cached = cache is not None
+    (x, cache), stats = jax.lax.scan(
+        body if cached else remat_body(body, cfg), (x, cache),
+        (params["layers"],
+         jnp.arange(cfg.num_layers, dtype=jnp.int32) if cached else None))
     return lm_head(params, x, cfg), cache, stats
 
 
@@ -383,18 +396,39 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
     }
 
 
+def pool_head_dim(head_dim: int) -> int:
+    """A head's width in a row of the paged pool: whole 128-lane tiles."""
+    return -(-head_dim // 128) * 128
+
+
 def init_kv_pool(cfg: LlamaConfig, num_blocks: int, block_size: int) -> dict:
-    """Paged KV pool: [L, Hkv, N_blocks, block_size, D] per k/v.
+    """Paged KV pool: [L, N_blocks, block_size, Hkv * Dp] per k/v, token
+    major: a token's keys of all KV heads are ONE contiguous row, and a page
+    (layer, block) is one contiguous [block_size, Hkv * Dp] run.
 
     Unlike the dense per-slot cache (init_kv_cache), HBM is allocated in
     block_size-token pages handed out on demand by a host-side allocator
     (serve/paged_kv.py), so memory scales with ACTUAL tokens, full prefix
     blocks are shareable across sequences, and capacity admits many short
     sequences or few long ones interchangeably (vLLM paged-KV semantics,
-    which the reference delegates to vLLM — here native). Head-major so a
-    (head, block) pair is one contiguous page tile for the pallas decode
-    kernel (ops/paged_attention.py)."""
-    shape = (cfg.num_layers, cfg.num_kv_heads, num_blocks, block_size, cfg.hd)
+    which the reference delegates to vLLM — here native). Block 0 is the
+    garbage block that padded positions and empty slots write into.
+
+    Layout contract (with `forward_paged` and ops/paged_attention.py):
+
+    - Dp is `head_dim` rounded up to 128 lanes; head h of a row is lanes
+      [h * Dp, h * Dp + head_dim), the rest of its tile zero. Mosaic slices an
+      HBM ref in whole lane tiles only, so the pool is ALLOCATED that wide
+      (Mistral's and OLMoE's 128: no padding) and nothing pads it a call.
+    - Token major because the write decides the layout. A decode step writes
+      one token's [Hkv, D] a slot and layer; in a head-major pool
+      ([L, Hkv, NB, BS, D]) that is Hkv strided pieces, XLA:TPU then keeps the
+      carried pool in a layout with the written window minor-most and copies
+      ALL of it back to the default layout for the kernel in every layer
+      (PERF.md section 6, PR 30, the second ahead-of-time finding). Here the
+      write is a row scatter in the default layout, which the kernel reads."""
+    row = cfg.num_kv_heads * pool_head_dim(cfg.hd)
+    shape = (cfg.num_layers, num_blocks, block_size, row)
     return {
         "k": jnp.zeros(shape, dtype=cfg.dtype),
         "v": jnp.zeros(shape, dtype=cfg.dtype),
@@ -403,19 +437,24 @@ def init_kv_pool(cfg: LlamaConfig, num_blocks: int, block_size: int) -> dict:
 
 def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
                   block_size: int, use_kernel: bool | None = None,
-                  platform: str | None = None):
-    """Cached forward over a PAGED pool. tokens [B,S] append at positions
-    [lengths, lengths+S); tables [B, max_blocks] map sequence-block index ->
-    pool block id. Returns (logits [B,S,V], updated pool).
+                  platform: str | None = None, mlp=dense_mlp):
+    """Cached forward over a PAGED pool (`init_kv_pool`'s layout). tokens
+    [B,S] append at positions [lengths, lengths+S); tables [B, max_blocks] map
+    sequence-block index -> pool block id. Returns (logits [B,S,V], updated
+    pool).
 
-    New K/V scatter into their pages ([B,S]-indexed .at[] scatter). The
-    decode step (S==1) runs the pallas paged-attention kernel on TPU —
-    pages are read in place via the scalar-prefetched block table
-    (ops/paged_attention.py). Prefill (and the off-TPU default) reads a
-    gathered per-sequence view (pool[:, tables]). `platform` is where the
+    The pool rides whole in the layer scan's carry (`decoder_trunk`) and each
+    layer touches only its own pages of it: new K/V rows scatter into
+    `pool[layer, block, offset]` in place, and the decode step (S==1) on a TPU
+    reads the live pages of `pool[layer]` through the block table in the
+    pallas kernel (ops/paged_attention.py). Prefill (and the off-TPU default)
+    reads a gathered per-sequence view (`pool[layer, tables]`). A step that
+    donates the pool compiles to a program with no pool-sized temporary
+    (tests/test_tpu_aot.py holds it to that). `platform` is where the
     computation runs (the engine passes its own); None derives it from the
     inputs' placement. It picks the default for `use_kernel` and, when the
-    kernel is used off-TPU (tests), interpret mode."""
+    kernel is used off-TPU (tests), interpret mode. `mlp` is `decoder_layer`'s
+    strategy (the expert layer of a MoE family)."""
     B, S = tokens.shape
     max_blocks = tables.shape[1]
     if platform is None:
@@ -431,33 +470,35 @@ def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
     blk_idx = tables[jnp.arange(B)[:, None], jnp.where(oob, 0, seq_blk)]  # [B,S]
     blk_idx = jnp.where(oob, 0, blk_idx)
     blk_off = positions % block_size
+    hd, dp = cfg.hd, pool_head_dim(cfg.hd)
 
-    def attend(q, k, v, pages):  # the layer's pages of the pool, k/v: [Hkv, NB, BS, D]
-        kp, vp = pages["k"], pages["v"]
-        # the scopes name, in a profile, the statement behind each pool-sized
-        # copy of a step: attn/kv_write or attn/kv_read
+    def rows(t, dtype):  # [B, S, Hkv, D] -> the pool's rows [B, S, Hkv * Dp]
+        if dp != hd:
+            t = jnp.pad(t, [(0, 0)] * 3 + [(0, dp - hd)])
+        return t.reshape(B, S, -1).astype(dtype)
+
+    def attend(q, k, v, pool, layer):  # the whole pool and this layer's index
+        kp, vp = pool["k"], pool["v"]
+        # the scopes name, in a profile, the statement behind each pool-shaped
+        # operation of a step: attn/kv_write or attn/kv_read
         with jax.named_scope("kv_write"):
-            # head-major scatter: kp[h, blk_idx[b,s], blk_off[b,s]] = k[b,s,h]
-            kp = kp.at[:, blk_idx, blk_off].set(
-                k.transpose(2, 0, 1, 3).astype(kp.dtype))
-            vp = vp.at[:, blk_idx, blk_off].set(
-                v.transpose(2, 0, 1, 3).astype(vp.dtype))
+            # a row scatter: kp[layer, blk_idx[b,s], blk_off[b,s]] = k[b,s]
+            kp = kp.at[layer, blk_idx, blk_off].set(rows(k, kp.dtype))
+            vp = vp.at[layer, blk_idx, blk_off].set(rows(v, vp.dtype))
         with jax.named_scope("kv_read"):
             if use_kernel:
                 from ray_tpu.ops.paged_attention import paged_decode_attention
 
                 o = paged_decode_attention(
-                    q[:, 0], kp, vp, tables, lengths + 1,
+                    q[:, 0], kp, vp, tables, lengths + 1, layer=layer,
                     interpret=platform != "tpu")[:, None]  # [B,1,Hq,D]
             else:
-                k_seq = kp[:, tables].transpose(1, 2, 3, 0, 4).reshape(
-                    B, max_blocks * block_size, *k.shape[2:])
-                v_seq = vp[:, tables].transpose(1, 2, 3, 0, 4).reshape(
-                    B, max_blocks * block_size, *v.shape[2:])
-                o = _cached_attention(q, k_seq, v_seq, lengths, positions)
+                view = lambda p: p[layer, tables].reshape(
+                    B, max_blocks * block_size, -1, dp)[..., :hd]
+                o = _cached_attention(q, view(kp), view(vp), lengths, positions)
         return o, {"k": kp, "v": vp}
 
-    return decoder_trunk(params, tokens, cfg, attend, cache=pool,
+    return decoder_trunk(params, tokens, cfg, attend, mlp, cache=pool,
                          positions=positions)[:2]
 
 
@@ -478,27 +519,23 @@ def _cached_attention(q, k_cache, v_cache, lengths, q_positions):
     return out.reshape(B, S, Hq, D)
 
 
-def _write_cache(cache_l, new, lengths):
-    """Insert new [B,S,H,D] at per-row offsets lengths[b] into cache [B,Smax,H,D].
-
-    vmapped dynamic_update_slice: O(S) per write (no one-hot over Smax)."""
-    return jax.vmap(
-        lambda c, n, l: jax.lax.dynamic_update_slice_in_dim(c, n.astype(c.dtype), l, axis=0)
-    )(cache_l, new, lengths)
-
-
 def forward_with_cache(params, tokens, cfg: LlamaConfig, cache: dict, lengths):
     """Append `tokens` [B,S] at positions [lengths, lengths+S) and return
     (logits[B,S,V], updated cache). Works for prefill (S=prompt, lengths=0)
-    and decode (S=1). lax.scan over layers keeps compile time O(1) in depth
-    (same design as forward())."""
+    and decode (S=1). The slot cache [L, B, Smax, Hkv, D] rides in the layer
+    scan's carry like the paged pool (`decoder_trunk`): each layer scatters
+    its new rows at `[layer, b, position]` and attends over its own slice.
+    A position past Smax is not written."""
+    B = tokens.shape[0]
     positions = lengths[:, None] + jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
+    slot = jnp.arange(B)[:, None]
 
-    def attend(q, k, v, slots):  # the layer's slice of the cache, k/v: [B, Smax, Hkv, D]
-        k_cache = _write_cache(slots["k"], k, lengths)
-        v_cache = _write_cache(slots["v"], v, lengths)
-        o = _cached_attention(q, k_cache, v_cache, lengths, positions)
-        return o, {"k": k_cache, "v": v_cache}
+    def attend(q, k, v, cache, layer):  # the whole cache and this layer's index
+        kc, vc = cache["k"], cache["v"]
+        kc = kc.at[layer, slot, positions].set(k.astype(kc.dtype))
+        vc = vc.at[layer, slot, positions].set(v.astype(vc.dtype))
+        o = _cached_attention(q, kc[layer], vc[layer], lengths, positions)
+        return o, {"k": kc, "v": vc}
 
     return decoder_trunk(params, tokens, cfg, attend, cache=cache,
                          positions=positions)[:2]

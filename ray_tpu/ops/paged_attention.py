@@ -8,12 +8,15 @@ through a pool. Pages are read IN PLACE, with none of the
 
 How the kernel walks the pool:
 
-* One grid step a sequence, all heads. The K and V pools stay in HBM
-  (`memory_space=pl.ANY`); the block table and the lengths ride in
-  scalar-prefetch memory, and the kernel issues its own copies. One copy of
-  `k_hbm.at[:, page]` moves a page of ALL KV heads, `(Hkv, block_size, D)`, as
-  one descriptor, so a step serves every query head of its sequence:
-  `[Hkv, Gp, D]` against `[Hkv, group, D]`, batched over heads.
+* One grid step a sequence, all heads. The K and V pools stay in HBM, WHOLE,
+  every layer of them (`memory_space=pl.ANY`); the block table, the lengths
+  and the layer's index ride in scalar-prefetch memory, and the kernel issues
+  its own copies. A page is `k_hbm.at[layer, page]`, `[block_size, Hkv * Dp]`:
+  ONE contiguous run of the pool (32 KB at the serving cells' shape), all KV
+  heads of its tokens side by side. Head h's keys are the lane tiles
+  `[:, h * Dp:(h + 1) * Dp]` of the buffer, a static slice; the slices are
+  stacked, and a step serves every query head of its sequence: `[Hkv, Gp,
+  Dp]` against `[Hkv, group, Dp]`, batched over heads.
 * Many pages a step. A GROUP of pages (`pages_per_group`: up to
   `MAX_GROUP_TOKENS` tokens, inside `VMEM_BUDGET`, worked out from the shapes)
   lands in one of two VMEM buffers; the next group's copies start before this
@@ -30,28 +33,43 @@ How the kernel walks the pool:
   the second product. With a bfloat16 pool each product is one MXU pass; with
   float32 inputs nothing is rounded.
 
-Measured on a TPU v5e (my chip runs, PR 28; PERF.md section 6;
+Layout contract (`models/llama.py::init_kv_pool` allocates it, `forward_paged`
+writes it): a pool is `[L, num_blocks, block_size, Hkv * Dp]`, token major, Dp
+= head_dim rounded up to 128 lanes, head h in lanes `[h * Dp, h * Dp +
+head_dim)` of a row and zeros in the rest of its tile. Mosaic slices an HBM ref
+only in whole lane tiles, which is why a narrow head is ALLOCATED 128 wide;
+nothing is padded or copied a call. The kernel takes the whole pool and a
+layer index because the model carries the whole pool through its layer scan
+and a slice of it would be a copy (ROADMAP S7, PERF.md section 6, PR 30: a
+head-major pool `[L, Hkv, NB, BS, D]` made XLA:TPU re-lay the pool around
+every layer's write, 80% of a decode step).
+
+Measured on a TPU v5e (my chip runs, PR 28 and PR 30; PERF.md section 6;
 scripts/bench_paged_attention_ab.py), one layer's call at the serving cells'
 widths (32 slots, 32 query / 8 KV heads of 128, block 16, a 128-block table,
-a 4,097-block pool, bfloat16): 116 us with 20 live slots of ~485 tokens
-(42% of what the K/V bytes need at 819 GB/s), 325 us with 32 slots of ~1,480
-(73%), 58 us with every slot empty; the kernel this replaces (grid
-(B, Hkv, max_blocks), one (16, 128) page a step through a BlockSpec, float32
-operands) took 5,345, 13,296 and 3,333. By step: pages a group 1 -> 8 -> 16 is
-305 -> 120 -> 116 us; a copy a (head, page) in place of one over all heads
-x 1.7-2.4; dead pages walked x 4.1; float32 operands nothing (the copies bound
-it: without the products a call still takes 99 us); jax's own kernel 352-449.
+a 4,097-block pool, bfloat16), us a call with 20 live slots of ~485 tokens /
+32 slots of ~1,480 / every slot empty:
+
+    this kernel, token-major pool            114 / 322 / 58   (43% / 74% of
+                                                              what the K/V bytes
+                                                              need at 819 GB/s)
+    PR 28's kernel, its head-major pages     118 / 327 / 59
+    the copies alone, no products             97 / 301 / 35   (head-major: 99 /
+                                                              306 / 39)
+    a 2-D product and a carry a head         176 / 504 / 96   (not shipped: the
+      in place of the stacked, batched one                    products then bound
+                                                              the call)
+
+PR 28, on its head-major pages: the kernel it replaced (grid (B, Hkv,
+max_blocks), one (16, 128) page a step through a BlockSpec, float32 operands)
+5,345 / 13,296 / 3,333; pages a group 1 -> 8 -> 16: 305 -> 120 -> 116 us
+(chat); a copy a (head, page) in place of one over all heads x 1.7-2.4; dead
+pages walked x 4.1; float32 operands nothing (the copies bound it: without the
+products a call still takes 99 us); jax's own kernel 352-449.
 
 Reference: vLLM's paged_attention CUDA kernel is the analog (the reference
 delegates serving to vLLM); jax.experimental.pallas.ops.tpu.paged_attention
-has the same pool layout and copies one (head, page) at a time.
-
-Layout contract: pages are [Hkv, num_blocks, block_size, D] per layer (head
-major). The pool enters as a whole ref and the page is an index into its
-second axis, so a caller that keeps all layers in one [L, Hkv, NB, BS, D]
-array can later hand that over with a layer index (ROADMAP S7). A head
-narrower than 128 lanes is zero-padded to 128 before the call (see
-`paged_decode_attention`).
+keeps head-major pages and copies one (head, page) at a time.
 """
 
 from __future__ import annotations
@@ -71,12 +89,18 @@ LANES = 128
 # of the 16 MiB a v5e kernel gets by default: the four group buffers (K and V,
 # two each); scores and probabilities of a group are a few hundred KB beside
 VMEM_BUDGET = 8 * 2 ** 20
-# measured on a v5e at the serving cells' shape (PERF.md section 6, PR 28): a
-# call at 128 / 256 / 512 tokens a group takes 120 / 116 / 132 us with 20
-# live slots of ~485 tokens and 373 / 325 / 329 us with 32 of ~1,480; past 256
-# the last, part-filled group of every sequence costs more than longer runs
-# of copies save
+# measured on a v5e at the serving cells' shape (PERF.md section 6, PR 30; PR
+# 28's head-major kernel read the same): a call at 128 / 256 / 512 tokens a
+# group takes 113 / 114 / 128 us with 20 live slots of ~485 tokens and 345 /
+# 322 / 329 us with 32 of ~1,480; past 256 the last, part-filled group of
+# every sequence costs more than longer runs of copies save
 MAX_GROUP_TOKENS = 256
+
+
+def lane_tiles(head_dim: int) -> int:
+    """A head's width in the pool and in VMEM: whole 128-lane tiles
+    (`llama.pool_head_dim`, the layout's owner, says the same)."""
+    return -(-head_dim // LANES) * LANES
 
 
 def pages_per_group(block_size: int, head_dim: int, num_kv_heads: int,
@@ -85,8 +109,7 @@ def pages_per_group(block_size: int, head_dim: int, num_kv_heads: int,
     whose tokens stay within MAX_GROUP_TOKENS and whose four buffers
     (K and V, double buffered, all KV heads) fit VMEM_BUDGET; never more than
     the table is wide."""
-    d = -(-head_dim // LANES) * LANES
-    page_bytes = 2 * 2 * num_kv_heads * block_size * d * itemsize
+    page_bytes = 2 * 2 * num_kv_heads * block_size * lane_tiles(head_dim) * itemsize
     pages = 1
     while (2 * pages * block_size <= MAX_GROUP_TOKENS
            and 2 * pages * page_bytes <= VMEM_BUDGET):
@@ -94,29 +117,32 @@ def pages_per_group(block_size: int, head_dim: int, num_kv_heads: int,
     return min(pages, max_blocks)
 
 
-def _decode_kernel(tables_ref, lens_ref,       # scalar-prefetch (SMEM)
-                   q_ref,                      # [1, Hkv, Gp, D] block
-                   k_hbm, v_hbm,               # whole pools, in HBM
-                   o_ref,                      # [1, Hkv, Gp, D] block
-                   k_buf, v_buf, sems, *,      # [2, Hkv, group, D] x 2, DMA (2, 2)
+def _decode_kernel(tables_ref, lens_ref, layer_ref,   # scalar-prefetch (SMEM)
+                   q_ref,                      # [1, Hkv, Gp, Dp] block
+                   k_hbm, v_hbm,               # whole pools [L, NB, BS, Hkv*Dp], in HBM
+                   o_ref,                      # [1, Hkv, Gp, Dp] block
+                   k_buf, v_buf, sems, *,      # [2, group, Hkv*Dp] x 2, DMA (2, 2)
                    pages: int, block_size: int, max_blocks: int, scale: float):
     """Grid (B,): streaming softmax over the live page groups of sequence b."""
     b = pl.program_id(0)
+    layer = layer_ref[0]
     seq_len = lens_ref[b]
     n_pages = pl.cdiv(seq_len, block_size)
     n_groups = pl.cdiv(n_pages, pages)
     group = pages * block_size
+    Hkv, gp, dp = q_ref.shape[1:]
 
     def is_live(gi, j):
         return gi * pages + j < n_pages
 
     def page_copies(gi, buf, j):
-        """The K and the V copy of page j of group gi into buffer `buf`."""
+        """The K and the V copy of page j of group gi into buffer `buf`: one
+        contiguous [BS, Hkv*Dp] run of the pool each."""
         page = tables_ref[b * max_blocks + gi * pages + j]
         rows = pl.ds(j * block_size, block_size)
-        return (pltpu.make_async_copy(k_hbm.at[:, page], k_buf.at[buf, :, rows],
+        return (pltpu.make_async_copy(k_hbm.at[layer, page], k_buf.at[buf, rows],
                                       sems.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[:, page], v_buf.at[buf, :, rows],
+                pltpu.make_async_copy(v_hbm.at[layer, page], v_buf.at[buf, rows],
                                       sems.at[1, buf]))
 
     def start(gi, buf):
@@ -130,8 +156,8 @@ def _decode_kernel(tables_ref, lens_ref,       # scalar-prefetch (SMEM)
             # bytes could be NaN
             @pl.when(jnp.logical_not(is_live(gi, j)))
             def _zero():
-                v_buf[buf, :, pl.ds(j * block_size, block_size)] = jnp.zeros(
-                    (v_buf.shape[1], block_size, v_buf.shape[3]), v_buf.dtype)
+                v_buf[buf, pl.ds(j * block_size, block_size)] = jnp.zeros(
+                    (block_size, v_buf.shape[2]), v_buf.dtype)
 
     def wait(gi, buf):
         for j in range(pages):
@@ -144,7 +170,12 @@ def _decode_kernel(tables_ref, lens_ref,       # scalar-prefetch (SMEM)
     def _first():
         start(0, 0)
 
-    q = q_ref[0]                                           # [Hkv, Gp, D]
+    def heads(buf_ref, buf):
+        """[group, Hkv * Dp] rows of a buffer -> [Hkv, group, Dp]: head h is
+        the lane tiles [h * Dp, (h + 1) * Dp) of every row, a static slice."""
+        return jnp.stack([buf_ref[buf, :, pl.ds(h * dp, dp)] for h in range(Hkv)])
+
+    q = q_ref[0]                                           # [Hkv, Gp, Dp]
 
     def step(gi, carry):
         m_prev, l_prev, acc = carry
@@ -155,7 +186,10 @@ def _decode_kernel(tables_ref, lens_ref,       # scalar-prefetch (SMEM)
             start(gi + 1, 1 - buf)
 
         wait(gi, buf)
-        k, v = k_buf[buf], v_buf[buf]                      # [Hkv, group, D]
+        # the heads' slices stacked and ONE batched product a side: a product
+        # and a running statistic a head, each carried on its own, took 176 us
+        # where this takes 115 (PERF.md section 6, PR 30)
+        k, v = heads(k_buf, buf), heads(v_buf, buf)        # [Hkv, group, Dp]
         s = jnp.einsum("hgd,htd->hgt", q, k,
                        preferred_element_type=jnp.float32) * scale
         kpos = gi * group + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
@@ -170,7 +204,7 @@ def _decode_kernel(tables_ref, lens_ref,       # scalar-prefetch (SMEM)
                                       preferred_element_type=jnp.float32)
         return m_new, l_new, acc
 
-    stat = q.shape[:2] + (1,)
+    stat = (Hkv, gp, 1)
     _, l, acc = jax.lax.fori_loop(
         0, n_groups, step,
         (jnp.full(stat, NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32),
@@ -178,25 +212,25 @@ def _decode_kernel(tables_ref, lens_ref,       # scalar-prefetch (SMEM)
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-def _decode_call(q4, k_pages, v_pages, tables, lengths, *, pages: int,
+def _decode_call(q4, k_pool, v_pool, tables, lengths, layer, *, pages: int,
                  scale: float, interpret: bool):
-    """q4 [B, Hkv, Gp, D] -> [B, Hkv, Gp, D]: softmax(scale * q k^T) v,
-    `pages` pages a group."""
-    B, Hkv, gp, D = q4.shape
-    BS = k_pages.shape[2]
+    """q4 [B, Hkv, Gp, Dp] -> [B, Hkv, Gp, Dp]: softmax(scale * q k^T) v over
+    layer `layer` of the pools, `pages` pages a group."""
+    B, Hkv, gp, dp = q4.shape
+    BS = k_pool.shape[2]
     max_blocks = tables.shape[1]
     kernel = functools.partial(_decode_kernel, pages=pages, block_size=BS,
                                max_blocks=max_blocks, scale=scale)
-    q_spec = pl.BlockSpec((1, Hkv, gp, D), lambda b, tab, lens: (b, 0, 0, 0))
+    q_spec = pl.BlockSpec((1, Hkv, gp, dp), lambda b, *prefetch: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B,),
         in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((2, Hkv, pages * BS, D), k_pages.dtype),
-            pltpu.VMEM((2, Hkv, pages * BS, D), v_pages.dtype),
+            pltpu.VMEM((2, pages * BS, Hkv * dp), k_pool.dtype),
+            pltpu.VMEM((2, pages * BS, Hkv * dp), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
@@ -209,34 +243,32 @@ def _decode_call(q4, k_pages, v_pages, tables, lengths, *, pages: int,
         **({} if interpret else {"compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel",))}),
     )(tables.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
-      q4, k_pages, v_pages)
+      jnp.reshape(layer, (1,)).astype(jnp.int32), q4, k_pool, v_pool)
 
 
-def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
+def paged_decode_attention(q, k_pool, v_pool, tables, lengths, *, layer,
                            interpret: bool | None = None):
-    """q [B, Hq, D]; k/v_pages [Hkv, NB, BS, D]; tables [B, max_blocks]
+    """q [B, Hq, D]; k/v_pool [L, NB, BS, Hkv * Dp], the whole pools, of which
+    layer `layer` (an int or a traced scalar) is read; tables [B, max_blocks]
     (pool block id per sequence block; entries past a sequence's last live
     page are never read); lengths [B] = valid KV tokens (incl. the token being
-    decoded). Returns [B, Hq, D]. `interpret=None` compiles the kernel when
-    the inputs are placed on a TPU and interprets it anywhere else.
+    decoded). Returns [B, Hq, D]. Dp is D rounded up to 128 lanes, and Hkv is
+    the row's width over it. `interpret=None` compiles the kernel when the
+    inputs are placed on a TPU and interprets it anywhere else.
     """
     if interpret is None:
-        interpret = target_platform(q, k_pages, v_pages) != "tpu"
+        interpret = target_platform(q, k_pool, v_pool) != "tpu"
     B, Hq, D = q.shape
-    Hkv, _, BS, _ = k_pages.shape
+    _, _, BS, row = k_pool.shape
+    dp = lane_tiles(D)
+    Hkv = row // dp
     g = Hq // Hkv
     gp = -(-g // 8) * 8  # pad the per-kv-head query group to a sublane multiple
-    q4 = q.reshape(B, Hkv, g, D).astype(k_pages.dtype)
-    # Mosaic slices an HBM ref only in whole lane tiles: a head under 128 wide
-    # is zero-padded to 128 (scores and outputs do not change). That is a copy
-    # of the pool a call; such a pool is lane-padded in HBM as it is, so the
-    # owner of a narrow-head pool should allocate it 128 wide (ROADMAP S7).
-    dp = -(-D // LANES) * LANES
+    q4 = q.reshape(B, Hkv, g, D).astype(k_pool.dtype)
+    # a head under 128 wide sits in the first D of its 128 lanes in the pool
+    # (`llama.init_kv_pool`); q's zero lanes meet whatever the others hold
     q4 = jnp.pad(q4, [(0, 0), (0, 0), (0, gp - g), (0, dp - D)])
-    if dp != D:
-        lanes = [(0, 0), (0, 0), (0, 0), (0, dp - D)]
-        k_pages, v_pages = jnp.pad(k_pages, lanes), jnp.pad(v_pages, lanes)
-    pages = pages_per_group(BS, dp, Hkv, k_pages.dtype.itemsize, tables.shape[1])
-    out = _decode_call(q4, k_pages, v_pages, tables, lengths, pages=pages,
+    pages = pages_per_group(BS, dp, Hkv, k_pool.dtype.itemsize, tables.shape[1])
+    out = _decode_call(q4, k_pool, v_pool, tables, lengths, layer, pages=pages,
                        scale=1.0 / math.sqrt(D), interpret=interpret)
     return out[:, :, :g, :D].reshape(B, Hq, D).astype(q.dtype)

@@ -166,11 +166,9 @@ class PagedLLMEngine(LLMEngine):
                 fut.set_exception(RuntimeError("LLM engine shut down"))
 
     def kv_memory_bytes(self) -> int:
-        """Persistent KV pool footprint (the headroom metric vs dense)."""
-        cfg = self.config.model_config
-        itemsize = 4 if "float32" in str(cfg.dtype) else 2
-        return (2 * cfg.num_layers * self.pool_blocks * self.config.block_size
-                * cfg.num_kv_heads * cfg.hd * itemsize)
+        """Persistent KV pool footprint (the headroom metric vs dense): the
+        pool as allocated, a head under 128 lanes in its 128-wide tile."""
+        return sum(leaf.nbytes for leaf in self.pool.values())
 
     # ---- engine loop ----
     def _admit_one(self, prompt, max_new, fut, t_enq, tq, slot) -> bool:
@@ -402,8 +400,7 @@ class PagedLLMEngine(LLMEngine):
                 from ray_tpu.experimental import rdt
 
                 kv_ticket = rdt.offer_device(
-                    {"k": self.pool["k"][:, :, idx],
-                     "v": self.pool["v"][:, :, idx]})
+                    {"k": self.pool["k"][:, idx], "v": self.pool["v"][:, idx]})
             elif self.config.kv_transfer == "plane":
                 # publish the gathered pages as one sealed plane entry
                 # (written once into the transport store's mapped slot); the
@@ -415,12 +412,13 @@ class PagedLLMEngine(LLMEngine):
                         "kv_transfer='plane' requires engine.kv_publish to "
                         "be bound to a KVTransport.publish")
                 kv_ref = self.kv_publish(
-                    np.asarray(self.pool["k"][:, :, idx]),
-                    np.asarray(self.pool["v"][:, :, idx]))
+                    np.asarray(self.pool["k"][:, idx]),
+                    np.asarray(self.pool["v"][:, idx]))
             else:
+                # the pool's own rows (`llama.init_kv_pool`): [L, n, bs, Hkv * Dp]
                 kv = {
-                    "k": np.asarray(self.pool["k"][:, :, idx]),  # [L, H, n, bs, D]
-                    "v": np.asarray(self.pool["v"][:, :, idx]),
+                    "k": np.asarray(self.pool["k"][:, idx]),
+                    "v": np.asarray(self.pool["v"][:, idx]),
                 }
         finally:
             self.allocator.free(block_ids)
@@ -481,9 +479,9 @@ class PagedLLMEngine(LLMEngine):
                     "bound to a KVTransport.pull")
             kv, ack = self.kv_pull(handoff["kv_ref"])
             expect = handoff.get("n_prefill_blocks")
-            if expect is not None and kv["k"].shape[2] != expect:
+            if expect is not None and kv["k"].shape[1] != expect:
                 raise ValueError(
-                    f"KV handoff shape mismatch: pulled {kv['k'].shape[2]} "
+                    f"KV handoff shape mismatch: pulled {kv['k'].shape[1]} "
                     f"blocks, handoff says {expect}")
         if kv is None and handoff.get("kv_ticket") is not None:
             # device path: pull the pages straight into THIS process's
@@ -497,11 +495,11 @@ class PagedLLMEngine(LLMEngine):
 
             kv = rdt.pull_device(handoff["kv_ticket"])
             expect = handoff.get("n_prefill_blocks")
-            if expect is not None and kv["k"].shape[2] != expect:
+            if expect is not None and kv["k"].shape[1] != expect:
                 raise ValueError(
-                    f"KV ticket shape mismatch: pulled {kv['k'].shape[2]} "
+                    f"KV ticket shape mismatch: pulled {kv['k'].shape[1]} "
                     f"blocks, handoff says {expect}")
-        n_prefill_blocks = kv["k"].shape[2]
+        n_prefill_blocks = kv["k"].shape[1]   # the payload is [L, n, bs, Hkv * Dp]
         table = handoff.get("block_table")
         if table is not None and len(table) != n_prefill_blocks:
             # descriptor-vs-payload consistency: the block table is the
@@ -515,10 +513,8 @@ class PagedLLMEngine(LLMEngine):
         block_ids = self.allocator.alloc(total_blocks)
         try:
             idx = np.asarray(block_ids[:n_prefill_blocks], dtype=np.int32)
-            self.pool["k"] = self.pool["k"].at[:, :, idx].set(
-                jnp.asarray(kv["k"]))
-            self.pool["v"] = self.pool["v"].at[:, :, idx].set(
-                jnp.asarray(kv["v"]))
+            self.pool["k"] = self.pool["k"].at[:, idx].set(jnp.asarray(kv["k"]))
+            self.pool["v"] = self.pool["v"].at[:, idx].set(jnp.asarray(kv["v"]))
             with self._lock:
                 st = _Slot(fut, max_new_tokens, prompt_len, time.monotonic())
                 st.generated.append(handoff["first_token"])

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""The paged decode kernel alone, by step (PERF.md section 6, PR 28).
+"""The paged decode kernel alone, by step (PERF.md section 6, PR 28 and PR 30).
 
 One layer's call of `paged_attention_decode` at the serving cells' widths
 (32 query / 8 KV heads of 128, block 16, a 128-block table, a 4,097-block
@@ -12,20 +12,26 @@ pool, bfloat16) and three fills of the 32 slots:
 and these arms, each timed as 32 dependent calls inside one jitted loop
 (a call's output is the next call's query), the median of 7 repeats:
 
-    parent        the kernel this PR replaces: grid (B, Hkv, max_blocks), one
+    parent        the kernel PR 28 replaced: grid (B, Hkv, max_blocks), one
                   (16, 128) page a step through a BlockSpec, float32 operands
     pages=N       the shipped kernel at N pages a group (shipped = what
-                  `pages_per_group` works out)
-    per-head      one copy a (head, page) in place of one over all heads
+                  `pages_per_group` works out), on the token-major pool
+                  [L, NB, BS, Hkv * D] that the model keeps since PR 30: a
+                  page is one contiguous copy, the layer an index (the last
+                  of the pool's two layers here)
+    head-major    PR 28's kernel on PR 28's pool [Hkv, NB, BS, D], a page Hkv
+                  strided pieces: the old layout against the new
+    per-head      (head-major) one copy a (head, page) in place of one over
+                  all heads
     f32-operands  q, k, v cast to float32 before both products
     walk-dead     every group of the table copied and multiplied, live or not
     copies-only   the copies without the products: what the DMAs alone cost
     upstream      jax.experimental.pallas.ops.tpu.paged_attention at 8 and 32
                   pages a compute block
 
-The ablation arms run a copy of the shipped kernel's body with one thing
-changed (`_ablation_kernel`; the arm with nothing changed should take the
-shipped kernel's time). Every arm is checked against dense attention in float32
+The head-major arms run a copy of PR 28's kernel body with one thing changed
+(`_ablation_kernel`; all but `head-major` itself are PR 28's ablations, on its
+layout). The same keys and values fill both layouts. Every arm is checked against dense attention in float32
 before it is timed. Needs the chip:
 
     chiprun --chips 1 -- python3 scripts/bench_paged_attention_ab.py
@@ -172,7 +178,7 @@ def parent(q, k_pages, v_pages, tables, lengths):
 def _ablation_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
                      k_buf, v_buf, sems, *, pages, per_head, f32_operands,
                      walk_dead, copies_only):
-    """The shipped `_decode_kernel` with one thing changed a flag."""
+    """PR 28's `_decode_kernel` (head-major pages) with one thing changed a flag."""
     b = pl.program_id(0)
     seq_len = lens_ref[b]
     if walk_dead:
@@ -271,11 +277,22 @@ def ablation(q, k_pages, v_pages, tables, lengths, *, pages, per_head=False,
     return query_heads(out)
 
 
+LAYERS = 2   # of the token-major pool; the shipped arms read the last
+
+
+def token_major(pages):
+    """Head-major pages [Hkv, NB, BS, D] -> the model's pool [L, NB, BS, Hkv*D]
+    with these pages as its last layer and other bytes before."""
+    layer = pages.transpose(1, 2, 0, 3).reshape(NB, BS, HKV * D)
+    return jnp.stack([jnp.flip(layer, 0)] * (LAYERS - 1) + [layer])
+
+
 def shipped_at(pages):
-    def f(q, k_pages, v_pages, tables, lengths):
+    def f(q, k_pool, v_pool, tables, lengths):
         return query_heads(shipped._decode_call(
-            query_groups(q), k_pages, v_pages, tables, lengths, pages=pages,
-            scale=1.0 / math.sqrt(D), interpret=False))
+            query_groups(q), k_pool, v_pool, tables, lengths, jnp.int32(LAYERS - 1),
+            pages=pages, scale=1.0 / math.sqrt(D), interpret=False))
+    f.token_major = True
     return f
 
 
@@ -326,13 +343,14 @@ def main() -> int:
     q = jax.random.normal(kq, (B, HQ, D), jnp.bfloat16)
     k_pages = jax.random.normal(kk, (HKV, NB, BS, D), jnp.bfloat16)
     v_pages = jax.random.normal(kv, (HKV, NB, BS, D), jnp.bfloat16)
+    head_major, pools = (k_pages, v_pages), (token_major(k_pages), token_major(v_pages))
 
     own = shipped.pages_per_group(BS, D, HKV, 2, MAX_BLOCKS)
     arms = [("parent", parent)]
     arms += [(f"pages={p}" + (" (shipped)" if p == own else ""), shipped_at(p))
              for p in sorted({1, 2, 4, 8, 16, 32, 64, own})]
     for p in (8, own):
-        arms += [(f"ablation pages={p} nothing changed", functools.partial(ablation, pages=p)),
+        arms += [(f"head-major pages={p}", functools.partial(ablation, pages=p)),
                  (f"per-head pages={p}", functools.partial(ablation, pages=p, per_head=True)),
                  (f"f32-operands pages={p}", functools.partial(ablation, pages=p, f32_operands=True)),
                  (f"walk-dead pages={p}", functools.partial(ablation, pages=p, walk_dead=True)),
@@ -357,8 +375,9 @@ def main() -> int:
         for name, fn in arms:
             line = {"fill": fill_name, "live": live, "ctx": ctx, "arm": name,
                     "device": device}
+            kv = pools if getattr(fn, "token_major", False) else head_major
             try:
-                got = jax.jit(fn)(q, k_pages, v_pages, tables, lengths)
+                got = jax.jit(fn)(q, *kv, tables, lengths)
                 if "copies-only" not in name:
                     err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - ref)))
                     line["max_err"] = err
@@ -366,7 +385,7 @@ def main() -> int:
                     # masked, so the answer is the same
                     assert err < 0.05, (name, err)
                 med, least, compile_s = seconds_a_call(
-                    fn, q, k_pages, v_pages, tables, lengths,
+                    fn, q, *kv, tables, lengths,
                     calls=args.calls, repeats=args.repeats)
                 line.update(us_a_call=med * 1e6, least_us=least * 1e6,
                             compile_s=compile_s, hbm_need_us=need * 1e6,

@@ -35,8 +35,9 @@ def transports():
 def _kv(nbytes_each: int, seed: int = 0):
     n = nbytes_each // 4
     rng = np.random.default_rng(seed)
-    k = rng.standard_normal(n).astype(np.float32).reshape(1, 1, -1, 4)
-    v = rng.standard_normal(n).astype(np.float32).reshape(1, 1, -1, 4)
+    # a hand-off's payload as the paged pool holds it: [L, n, block_size, row]
+    k = rng.standard_normal(n).astype(np.float32).reshape(1, -1, 4, 4)
+    v = rng.standard_normal(n).astype(np.float32).reshape(1, -1, 4, 4)
     return k, v
 
 
@@ -221,6 +222,10 @@ def test_engine_plane_handoff_in_process():
         h = pre_e.prefill_extract(prompt)
         assert h["kv"] is None and h["kv_ref"] is not None
         assert h["kv_ref"]["nbytes"] > 0
+        # the published entry is the prompt's pages in the pool's own layout,
+        # block axis 1: [L, n, block_size, Hkv * Dp]
+        assert h["kv_ref"]["k_shape"] == h["kv_ref"]["v_shape"] == [
+            mc.num_layers, h["n_prefill_blocks"], *pre_e.pool["k"].shape[2:]]
         assert pre_t.live_handoffs() == 1
         toks = dec_e.attach_sequence(h, 8).result(timeout=120).token_ids
         assert pre_t.wait_drained(10), "attach did not ack the handoff"

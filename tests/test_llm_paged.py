@@ -70,7 +70,12 @@ def test_memory_headroom_vs_dense(shared_params):
     eng = _paged(cfg, params, num_blocks=dense_blocks_equiv // 2 + 1)
     try:
         itemsize = 4 if "float32" in str(cfg.dtype) else 2
-        dense_bytes = 2 * cfg.num_layers * B * S * cfg.num_kv_heads * cfg.hd * itemsize
+        # token for token at the pool's row width: a head under 128 lanes takes
+        # a 128-lane tile (`llama.init_kv_pool`; the device pads the dense
+        # cache's rows in HBM just the same)
+        row = cfg.num_kv_heads * llama.pool_head_dim(cfg.hd)
+        assert eng.pool["k"].shape == (cfg.num_layers, dense_blocks_equiv // 2 + 1, bs, row)
+        dense_bytes = 2 * cfg.num_layers * B * S * row * itemsize
         assert eng.kv_memory_bytes() < 0.6 * dense_bytes
         # mixed short sequences: 4 concurrent x (24 prompt + 8 new) = 2 blocks
         # each -> fits the half-size pool with room to spare
@@ -111,6 +116,12 @@ def test_pd_disaggregation_handoff(shared_params):
         expect = ref_engine.generate_sync(prompt, 10).token_ids
         handoff = prefiller.prefill_extract(prompt)
         assert handoff["prompt_len"] == len(prompt)
+        # the payload is the prompt's pages as the pool holds them, block axis 1:
+        # [L, n, block_size, Hkv * Dp]
+        n = handoff["n_prefill_blocks"]
+        assert n == -(-len(prompt) // prefiller.config.block_size)
+        assert handoff["kv"]["k"].shape == handoff["kv"]["v"].shape == (
+            cfg.num_layers, n, *prefiller.pool["k"].shape[2:])
         fut = decoder.attach_sequence(handoff, 10)
         got = fut.result(timeout=120)
         assert got.token_ids == expect

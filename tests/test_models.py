@@ -138,6 +138,34 @@ def test_cached_forwards_give_the_plain_forwards_logits(tiny, path, prefill, qk_
                                rtol=1e-4, atol=1e-4)
 
 
+def test_paged_prefill_pads_past_the_table_into_block_zero(tiny):
+    """A bucketed prefill whose padding runs past the table (a near-full
+    sequence): the pad positions' rows go to the garbage block 0 of every
+    layer, the sequence's own last block keeps its rows, and the real
+    positions' logits are the plain forward's. The pool is `init_kv_pool`'s:
+    [L, NB, block_size, Hkv * 128] for these 16-wide heads."""
+    cfg, params = tiny
+    bs, mb, real, bucket = 4, 3, 12, 16
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, bucket), 0, cfg.vocab_size)
+    tables = jnp.asarray([[2, 3, 1]], jnp.int32)
+    empty = llama.init_kv_pool(cfg, 1 + mb, bs)
+    assert empty["k"].shape == (cfg.num_layers, 1 + mb, bs, cfg.num_kv_heads * 128)
+    zero = jnp.zeros((1,), jnp.int32)
+    logits, padded = llama.forward_paged(params, tokens, cfg, empty, tables, zero, bs)
+    _, exact = llama.forward_paged(params, tokens[:, :real], cfg, empty, tables, zero, bs)
+    np.testing.assert_allclose(np.asarray(logits[:, :real]),
+                               np.asarray(llama.forward(params, tokens[:, :real], cfg)),
+                               rtol=1e-4, atol=1e-4)
+    for name in ("k", "v"):
+        np.testing.assert_allclose(np.asarray(padded[name][:, 1:]),
+                                   np.asarray(exact[name][:, 1:]), rtol=1e-5, atol=1e-6)
+        assert np.asarray(padded[name][:, 0]).any(axis=(1, 2)).all()   # every layer's block 0
+        assert not np.asarray(exact[name][:, 0]).any()
+        # a head's own 16 lanes of its 128-lane tile are written, the rest stay zero
+        tile = np.asarray(padded[name]).reshape(*padded[name].shape[:3], cfg.num_kv_heads, 128)
+        assert tile[..., :cfg.hd].any() and not tile[..., cfg.hd:].any()
+
+
 def test_graft_entry_contract():
     import importlib.util, pathlib
 

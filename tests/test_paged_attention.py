@@ -11,9 +11,12 @@ block 16, a 128-block table, bf16, 2 layers) with ragged lengths 1, 2, 16, 17,
 38, 1028, 2047 and 2048 gave logits within 7.2e-2 of the dense arm's (largest
 logit 5.14, tolerance 8 bf16 eps of it = 1.6e-1; the kernel before PR 28 read
 5.5e-2: p now enters the second product in bf16), and the compiled step held
-the Mosaic call. tests/test_tpu_aot.py compiles it for a v5e from this host
-at D=64, D=128 and the serving cells' exact shape.
+the Mosaic call; on the token-major pool (PR 30, 2026-09-28) 5.6e-2 of 1.5e-1.
+tests/test_tpu_aot.py compiles it for a v5e from this host at D=64, D=128 and
+the serving cells' exact shape.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -24,21 +27,23 @@ from ray_tpu.models import llama
 from ray_tpu.ops.paged_attention import pages_per_group, paged_decode_attention
 
 
-def _scatter_pages(k_seq, tables, block_size, num_pool_blocks):
-    """[B, S, H, D] sequence layout -> head-major paged pool [H, NB, BS, D]."""
-    B, S, H, D = k_seq.shape
-    pages = np.zeros((H, num_pool_blocks, block_size, D), np.float32)
-    for b in range(B):
-        for s in range(S):
-            blk = tables[b, s // block_size]
-            pages[:, blk, s % block_size] = k_seq[b, s]
-    return jnp.asarray(pages)
+def _scatter_pages(seqs, tables, block_size, num_pool_blocks):
+    """A [B, S, H, D] sequence layout a layer -> the token-major paged pool
+    [L, NB, BS, H * Dp], head h in lanes [h * Dp, h * Dp + D) of a row."""
+    B, S, H, D = seqs[0].shape
+    dp = llama.pool_head_dim(D)
+    pool = np.zeros((len(seqs), num_pool_blocks, block_size, H, dp), np.float32)
+    for li, seq in enumerate(seqs):
+        for b in range(B):
+            for s in range(S):
+                pool[li, tables[b, s // block_size], s % block_size, :, :D] = seq[b, s]
+    return jnp.asarray(pool.reshape(*pool.shape[:3], H * dp))
 
 
 def _case(lengths, *, g=4, Hkv=8, D=128, BS=16, max_blocks, dtype=jnp.bfloat16,
-          empty=()):
+          empty=(), layers=1, layer=0):
     return dict(lengths=lengths, g=g, Hkv=Hkv, D=D, BS=BS, max_blocks=max_blocks,
-                dtype=dtype, empty=empty)
+                dtype=dtype, empty=empty, layers=layers, layer=layer)
 
 
 # The cell's head shape (8 KV heads, 4 query heads each, 128 wide, block 16,
@@ -55,6 +60,7 @@ _CASES = {
     "f32-groups": _case([1, 8, 9, 256, 257, 320], g=2, Hkv=2, D=16, BS=8,
                         max_blocks=40, dtype=jnp.float32),
     "cell-bf16": _case([1, 16, 17, 256, 257, 640], max_blocks=40),
+    # a 64-wide head through the pool's 128-wide tiles
     "cell-bf16-d64": _case([1, 16, 17, 256, 257, 640], D=64, max_blocks=40),
     "olmoe-bf16-g1": _case([1, 17, 129, 320], g=1, Hkv=16, max_blocks=20),
     "table-narrower-than-a-group": _case([1, 17, 64], max_blocks=4),
@@ -62,14 +68,21 @@ _CASES = {
     # an empty slot as the engine leaves it: lengths + 1 == 1, table all zeros
     # (it reads the garbage page 0; nobody reads its row, which must be finite)
     "empty-slots": _case([1, 1, 33], max_blocks=20, empty=(0, 1)),
+    # one layer of a pool that holds other bytes in every layer: length 1, a
+    # page edge, a page + 1, a full table. A wrong layer index reads the
+    # same pages of another layer
+    "first-of-3-layers": _case([1, 16, 17, 96], max_blocks=6, layers=3, layer=0),
+    "last-of-3-layers": _case([1, 16, 17, 96], max_blocks=6, layers=3, layer=2),
+    "middle-layer-f32-d64": _case([1, 8, 9, 48], g=2, Hkv=2, D=64, BS=8,
+                                  max_blocks=6, dtype=jnp.float32, layers=3, layer=1),
 }
 
 
 def _inputs(case, seed=0):
-    """q, k_seq, v_seq [B, S, Hkv, D] as float32 arrays of values the case's
-    dtype holds exactly (the float32 reference sees what the kernel sees), a
-    table with its pages out of order across the pool, and the pools scattered
-    from it in the case's dtype."""
+    """q, k_seq, v_seq [B, S, Hkv, D] of the case's layer as float32 arrays of
+    values the case's dtype holds exactly (the float32 reference sees what the
+    kernel sees), a table with its pages out of order across the pool, and the
+    pools of all layers scattered from it in the case's dtype."""
     rng = np.random.default_rng(seed)
     lengths = np.asarray(case["lengths"], np.int32)
     B, Hkv, D, BS, mb = len(lengths), case["Hkv"], case["D"], case["BS"], case["max_blocks"]
@@ -80,36 +93,52 @@ def _inputs(case, seed=0):
     exact = lambda shape: np.asarray(
         to(rng.standard_normal(shape, np.float32)).astype(jnp.float32))
     q = exact((B, Hkv * case["g"], D))
-    k_seq, v_seq = exact((B, mb * BS, Hkv, D)), exact((B, mb * BS, Hkv, D))
-    pools = (to(q), to(_scatter_pages(k_seq, tables, BS, NB)),
-             to(_scatter_pages(v_seq, tables, BS, NB)))
-    return q, k_seq, v_seq, tables, lengths, pools
+    k_seqs = [exact((B, mb * BS, Hkv, D)) for _ in range(case["layers"])]
+    v_seqs = [exact((B, mb * BS, Hkv, D)) for _ in range(case["layers"])]
+    pools = (to(q), to(_scatter_pages(k_seqs, tables, BS, NB)),
+             to(_scatter_pages(v_seqs, tables, BS, NB)))
+    return q, k_seqs[case["layer"]], v_seqs[case["layer"]], tables, lengths, pools
 
 
-def _run_kernel(pools, tables, lengths):
-    return np.asarray(paged_decode_attention(
-        *pools, jnp.asarray(tables), jnp.asarray(lengths),
-        interpret=True).astype(jnp.float32))
+def _run_kernel(pools, tables, lengths, layer=0):
+    # the layer index reaches the kernel traced, as the layer scan hands it over
+    run = jax.jit(lambda layer: paged_decode_attention(
+        *pools, jnp.asarray(tables), jnp.asarray(lengths), layer=layer,
+        interpret=True))
+    return np.asarray(run(jnp.int32(layer)).astype(jnp.float32))
+
+
+def _dense(case, q, k_seq, v_seq, lengths):
+    """Dense reference: q position = lengths-1, KV valid prefix = lengths."""
+    return np.asarray(llama._cached_attention(
+        jnp.asarray(q)[:, None], jnp.asarray(k_seq), jnp.asarray(v_seq),
+        jnp.asarray(lengths - 1), jnp.asarray(lengths - 1)[:, None])[:, 0])
+
+
+# float32: nothing is rounded. bfloat16: p and the output are rounded to 8
+# bits, on values up to ~4 (a length-1 row is a row of v itself)
+_atol = lambda case: 2e-5 if case["dtype"] == jnp.float32 else 3e-2
 
 
 @pytest.mark.parametrize("name", list(_CASES))
 def test_paged_decode_matches_dense(name):
     case = _CASES[name]
     q, k_seq, v_seq, tables, lengths, pools = _inputs(case)
-    out = _run_kernel(pools, tables, lengths)
-
-    # dense reference: q position = lengths-1, KV valid prefix = lengths
-    ref = llama._cached_attention(
-        jnp.asarray(q)[:, None], jnp.asarray(k_seq), jnp.asarray(v_seq),
-        jnp.asarray(lengths - 1),
-        jnp.asarray(lengths - 1)[:, None],
-    )[:, 0]
-    # float32: nothing is rounded. bfloat16: p and the output are rounded to 8
-    # bits, on values up to ~4 (a length-1 row is a row of v itself)
-    atol = 2e-5 if case["dtype"] == jnp.float32 else 3e-2
+    out = _run_kernel(pools, tables, lengths, case["layer"])
+    ref = _dense(case, q, k_seq, v_seq, lengths)
     real = [b for b in range(len(lengths)) if b not in case["empty"]]
-    np.testing.assert_allclose(out[real], np.asarray(ref)[real], atol=atol)
+    np.testing.assert_allclose(out[real], ref[real], atol=_atol(case))
     assert np.isfinite(out).all()
+
+
+@pytest.mark.parametrize("name", ["first-of-3-layers", "last-of-3-layers"])
+def test_paged_decode_wrong_layer_is_seen(name):
+    """The layered cases mean something: the same call on another layer's
+    pages is far from the reference."""
+    case = _CASES[name]
+    q, k_seq, v_seq, tables, lengths, pools = _inputs(case)
+    out = _run_kernel(pools, tables, lengths, 1)
+    assert np.abs(out - _dense(case, q, k_seq, v_seq, lengths)).max() > 0.5
 
 
 def test_paged_decode_group_follows_the_shapes():
@@ -163,3 +192,49 @@ def test_forward_paged_kernel_path_matches_gather_path():
                                        use_kernel=True)
     np.testing.assert_allclose(np.asarray(lg_kernel), np.asarray(lg_gather),
                                atol=2e-4)
+
+
+def _families():
+    from ray_tpu.models import moe
+
+    tiny = llama.LlamaConfig.tiny()
+    olmoe = moe.MoEConfig(base=tiny, num_experts=4, top_k=2, qk_norm=True)
+    return {
+        "llama": (tiny, lambda key: llama.init(tiny, key), llama.dense_mlp,
+                  lambda params, tokens: llama.forward(params, tokens, tiny)),
+        # OLMoE's block: QK-norm over the whole projected vector, the expert layer
+        "olmoe": (tiny, lambda key: moe.init(olmoe, key),
+                  functools.partial(moe.moe_mlp, cfg=olmoe),
+                  lambda params, tokens: moe.forward(params, tokens, olmoe)[0]),
+    }
+
+
+@pytest.mark.parametrize("use_kernel", [False, True], ids=["gathered-view", "kernel"])
+@pytest.mark.parametrize("family", ["llama", "olmoe"])
+def test_prefill_then_decode_steps_give_the_cacheless_logits(family, use_kernel):
+    """A prefill and then a token a step through `forward_paged`, the decode
+    steps through the gathered view or the kernel (interpreted): the logits of
+    the family's cache-less forward over the same tokens. The pool is the
+    carried, token-major one, 64-wide heads in 128-wide tiles, its pages out
+    of order, rows of unequal length."""
+    cfg, init, mlp, plain = _families()[family]
+    params = init(jax.random.PRNGKey(0))
+    B, S, bs, mb, prefill = 2, 14, 4, 4, 9
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)
+    want = np.asarray(plain(params, tokens))
+    pool = llama.init_kv_pool(cfg, 1 + B * mb, bs)
+    assert pool["k"].shape == (cfg.num_layers, 1 + B * mb, bs, cfg.num_kv_heads * 128)
+    tables = jnp.asarray(np.random.default_rng(0).permutation(
+        np.arange(1, 1 + B * mb)).reshape(B, mb), jnp.int32)
+    step = jax.jit(lambda toks, pool, lengths, kernel: llama.forward_paged(
+        params, toks, cfg, pool, tables, lengths, bs, use_kernel=kernel, mlp=mlp),
+        static_argnums=3)
+    got = []
+    for start, stop in [(0, prefill)] + [(i, i + 1) for i in range(prefill, S)]:
+        logits, pool = step(tokens[:, start:stop], pool, jnp.full((B,), start, jnp.int32),
+                            use_kernel and stop - start == 1)
+        got.append(logits)
+    np.testing.assert_allclose(np.asarray(jnp.concatenate(got, axis=1)), want,
+                               rtol=1e-4, atol=2e-4)
+    # the garbage block took nothing: every position was inside the table
+    assert not np.asarray(pool["k"][:, 0]).any()

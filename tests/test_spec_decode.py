@@ -148,6 +148,10 @@ def test_pd_attach_with_spec_decode():
                            max_batch_size=2, max_seq_len=128, temperature=0.0,
                            num_speculative_tokens=3)
     eng = SpecDecodeLLMEngine(cfg, params=params, draft_params=params)
+    # the draft's mirror pool is a pool like the target's: one table row
+    # addresses the same blocks of both ([L, NB, block_size, Hkv * Dp])
+    assert eng.draft_pool["k"].shape == eng.pool["k"].shape
+    assert handoff["kv"]["k"].shape[1] == handoff["n_prefill_blocks"]
     try:
         res = eng.attach_sequence(handoff, 10).result(timeout=180)
     finally:

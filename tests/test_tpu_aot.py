@@ -137,6 +137,7 @@ def test_flash_compiles_at_the_fsdp4_cell_shape(v5e):
 
 
 @pytest.mark.parametrize("B, Hq, Hkv, D, max_blocks, pool_blocks", [
+    # a 64-wide head sits in a pool allocated 128 wide (`llama.init_kv_pool`)
     (8, 32, 8, 64, 16, 129), (8, 32, 8, 128, 16, 129),
     # `serve-chat-steady` and `serve-docs-batch`: 32 slots, a 128-block table
     # over the engine's 4,097-block pool; a VMEM or Mosaic limit at the real
@@ -146,34 +147,114 @@ def test_flash_compiles_at_the_fsdp4_cell_shape(v5e):
     (32, 16, 16, 128, 128, 4097),
 ], ids=["D64", "D128", "serving-cells", "olmoe-heads"])
 def test_paged_decode_compiles(v5e, B, Hq, Hkv, D, max_blocks, pool_blocks):
-    BS = 16
+    BS, L = 16, 2
     d = v5e[0]
-    pages = _on(d, (Hkv, pool_blocks, BS, D))
-    text = jax.jit(lambda *a: paged_decode_attention(*a, interpret=False)).lower(
-        _on(d, (B, Hq, D)), pages, pages, _on(d, (B, max_blocks), jnp.int32),
-        _on(d, (B,), jnp.int32)).compile().as_text()
+    pool = _on(d, (L, pool_blocks, BS, Hkv * llama.pool_head_dim(D)))
+    text = jax.jit(
+        lambda *a: paged_decode_attention(*a[:-1], layer=a[-1], interpret=False)).lower(
+        _on(d, (B, Hq, D)), pool, pool, _on(d, (B, max_blocks), jnp.int32),
+        _on(d, (B,), jnp.int32), _on(d, (), jnp.int32)).compile().as_text()
     assert MOSAIC in text
     assert "paged_attention_decode" in text
+
+
+def _paged_step(d, cfg, *, B, S, bs=16, max_blocks=8, pool_blocks=257, donate=True):
+    """One `forward_paged` step told it runs on a TPU, lowered for device d
+    with the pool donated, as the paged engines' steps are (`paged_step`):
+    B = 1 and S > 1 is a prefill, S = 1 the decode step."""
+    place = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(lambda: llama.init(cfg, jax.random.PRNGKey(0))))
+    pool = place(jax.eval_shape(lambda: llama.init_kv_pool(cfg, pool_blocks, bs)))
+
+    def step(params, pool, tokens, tables, lengths):
+        return llama.forward_paged(params, tokens, cfg, pool, tables, lengths,
+                                   bs, platform="tpu")
+
+    return jax.jit(step, donate_argnums=(1,) if donate else ()).lower(
+        params, pool, _on(d, (B, S), jnp.int32),
+        _on(d, (B, max_blocks), jnp.int32), _on(d, (B,), jnp.int32))
 
 
 def _decode_step_text(d) -> str:
     """The compiled HLO of one paged decode step told it runs on a TPU."""
     cfg = dataclasses.replace(llama.LlamaConfig.tiny(), dtype=jnp.bfloat16,
                               head_dim=64)
-    B, bs, max_blocks = 4, 16, 8
-    place = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
-    params = place(jax.eval_shape(lambda: llama.init(cfg, jax.random.PRNGKey(0))))
-    pool = place(jax.eval_shape(
-        lambda: llama.init_kv_pool(cfg, B * max_blocks + 1, bs)))
+    return _paged_step(d, cfg, B=4, S=1, pool_blocks=33).compile().as_text()
 
-    def decode(params, pool, tokens, tables, lengths):
-        return llama.forward_paged(params, tokens, cfg, pool, tables, lengths,
-                                   bs, platform="tpu")
 
-    return jax.jit(decode).lower(
-        params, pool, _on(d, (B, 1), jnp.int32),
-        _on(d, (B, max_blocks), jnp.int32), _on(d, (B,), jnp.int32),
-    ).compile().as_text()
+def pool_sized_instructions(text: str, pool_shape: tuple) -> list[tuple[str, str]]:
+    """(opcode, line) of every instruction of a compiled program, fused
+    computations included, that produces an ARRAY with as many elements as the
+    K (or V) pool or as one layer of it, whatever shape XLA gave it (a step's
+    scatter sees the pool as [L * NB * BS, Hkv * Dp]). A fusion counts under
+    the opcode of its root."""
+    sizes = {math.prod(pool_shape), math.prod(pool_shape[1:])}
+    instr = re.compile(r"\s*(ROOT )?%\S+ = \w+\[([\d,]+)\]\S* ([\w-]+)\(")
+    roots, computation = {}, None
+    for ln in text.splitlines():
+        if m := re.match(r"(?:ENTRY )?%(\S+) \(.*\) -> .* \{$", ln):
+            computation = m.group(1)
+        elif (m := instr.match(ln)) and m.group(1):
+            roots[computation] = m.group(3)
+    out = []
+    for ln in text.splitlines():
+        m = instr.match(ln)
+        if m and math.prod(map(int, m.group(2).split(","))) in sizes:
+            called = re.search(r"calls=%([^\s,)]+)", ln)
+            op = roots.get(called.group(1), "fusion") if called else m.group(3)
+            out.append((op, ln.strip()))
+    return out
+
+
+def assert_pool_stays_in_place(compiled, pool) -> None:
+    """What ISSUE 30 holds a paged step to. `pool` is the K/V pool's shapes."""
+    text = compiled.as_text()
+    layer_bytes = math.prod(pool["k"].shape[1:]) * pool["k"].dtype.itemsize
+    # the donation is honoured: both pool leaves alias an output
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    assert aliases and len(re.findall(r"(may|must)-alias", aliases.group(1))) >= 2
+    assert "AllocateBuffer" not in text
+    # parameters, tuple elements and bitcasts move nothing; every other
+    # producer of a pool is a write into it, and the only one a step has is
+    # the row scatter of the model's own `kv_write`
+    moved = [(op, ln[:200]) for op, ln in pool_sized_instructions(text, pool["k"].shape)
+             if op not in ("parameter", "get-tuple-element", "bitcast", "scatter")]
+    assert not moved, moved
+    # nothing the size of a layer's pages is scratch either
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
+def _compiled_pool_step(d, **step):
+    """(compiled step, the pool's shapes) at the cells' structure: 16-wide
+    blocks, a table, a pool of 257 blocks, 3 layers, 128-wide heads."""
+    cfg = dataclasses.replace(
+        llama.LlamaConfig.tiny(), dtype=jnp.bfloat16, hidden_size=512,
+        intermediate_size=1024, num_heads=4, num_kv_heads=2, head_dim=128,
+        num_layers=3, vocab_size=512)
+    return (_paged_step(d, cfg, **step).compile(),
+            jax.eval_shape(lambda: llama.init_kv_pool(cfg, 257, 16)))
+
+
+@pytest.mark.parametrize("B, S", [(8, 1), (1, 128)], ids=["decode", "prefill"])
+def test_paged_step_leaves_the_pool_where_it_is(v5e, B, S):
+    """The paged decode step and a B = 1 prefill with the pool donated: the
+    pool is carried through the layer scan and written in place, so the
+    compiled program has no second pool, no copy or re-layout of the pool or
+    of one layer of it, and scratch under one layer's pages. The scan that
+    sliced and restacked a [L, Hkv, NB, BS, D] pool had 22 such instructions
+    and 5.37 GB of scratch at the cells' size, and the same pool carried
+    head-major was copied whole in every layer (PERF.md section 6, PR 30)."""
+    assert_pool_stays_in_place(*_compiled_pool_step(v5e[0], B=B, S=S))
+
+
+def test_pool_sized_instruction_detector_sees_a_copy(v5e):
+    """The detector is not vacuous: a pool that is not donated has to be
+    copied before the first write, and that copy is found."""
+    compiled, pool = _compiled_pool_step(v5e[0], B=8, S=1, donate=False)
+    with pytest.raises(AssertionError):
+        assert_pool_stays_in_place(compiled, pool)
+    assert any(op.startswith("copy") for op, _ in pool_sized_instructions(
+        compiled.as_text(), pool["k"].shape))
 
 
 def _train_attention_text(d) -> str:
