@@ -1,9 +1,12 @@
-"""Model families. What the SPMD train step (train/spmd.py) takes of one is a
-`Model`: each family module exports its own as `MODEL`."""
+"""Model families. What the SPMD train step (train/spmd.py) and the serving
+engines (serve/llm.py, serve/llm_paged.py) take of one is a `Model`: each
+family module exports its own as `MODEL`, and `model_of(cfg)` finds it from a
+family's configuration, so no engine names a family."""
 
 from __future__ import annotations
 
-from typing import Callable, NamedTuple
+import sys
+from typing import Callable, NamedTuple, Optional
 
 
 class Model(NamedTuple):
@@ -13,3 +16,24 @@ class Model(NamedTuple):
     # the objective, and the model's own scalars for the step's metrics dict;
     # `mesh` is the mesh the step is sharded over, for a kernel-or-dense choice
     loss: Callable
+    # -- what serving takes; None where the family does not serve that way.
+    # The paged engines: (params, tokens, cfg, pool, tables, lengths,
+    # block_size, platform=) -> (logits [B, S, V], pool), and (cfg,
+    # num_blocks, block_size) -> the pool it reads and writes
+    forward_paged: Optional[Callable] = None
+    init_kv_pool: Optional[Callable] = None
+    # the dense slot engine: (params, tokens, cfg, cache, lengths) ->
+    # (logits, cache), and (cfg, batch, max_len) -> its cache
+    forward_with_cache: Optional[Callable] = None
+    init_kv_cache: Optional[Callable] = None
+
+
+def model_of(cfg) -> Model:
+    """The `MODEL` of the family whose module defines `cfg`'s class."""
+    module = sys.modules.get(type(cfg).__module__)
+    if not isinstance(getattr(module, "MODEL", None), Model):
+        raise TypeError(
+            f"{type(cfg).__module__}.{type(cfg).__qualname__} is no model family's "
+            f"configuration: the module that defines it exports no `MODEL` "
+            f"(ray_tpu.models.Model)")
+    return module.MODEL

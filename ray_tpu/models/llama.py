@@ -49,6 +49,9 @@ class LlamaConfig:
     # "dots": save matmul outputs, recompute only elementwise ops (the
     # usual transformer sweet spot — ~5% extra FLOPs instead of ~33%).
     remat_policy: str = "full"
+    # passes over the WHOLE layer stack with the same weights (`decoder_trunk`);
+    # more than one in a looped family (models/ouro.py)
+    loop_steps: int = 1
 
     @property
     def hd(self) -> int:
@@ -82,13 +85,6 @@ class LlamaConfig:
         return LlamaConfig(
             vocab_size=128256, hidden_size=4096, intermediate_size=14336, num_layers=32,
             num_heads=32, num_kv_heads=8, max_seq_len=8192,
-        )
-
-    @staticmethod
-    def llama_70b() -> "LlamaConfig":
-        return LlamaConfig(
-            vocab_size=128256, hidden_size=8192, intermediate_size=28672, num_layers=80,
-            num_heads=64, num_kv_heads=8, max_seq_len=8192,
         )
 
 
@@ -249,9 +245,11 @@ def decoder_layer(cfg: LlamaConfig, x, layer, cache, positions, attend,
 
     Head counts come from the projected widths, so a tensor-sharded stage
     passes its local weights and, as `reduce`, the sum over its axis of the
-    two row-sharded products. A layer that holds `q_norm` and `k_norm` (OLMoE)
-    normalises the WHOLE projected query and key vector, before the split
-    into heads and before rope."""
+    two row-sharded products. What a layer does beyond that follows from the
+    keys it holds: with `q_norm` and `k_norm` (OLMoE) it normalises the WHOLE
+    projected query and key vector, before the split into heads and before
+    rope; with `attn_out_norm` / `mlp_out_norm` (Ouro's sandwich) it
+    normalises a sub-layer's output before adding it to the residual."""
     B, S, _ = x.shape
     eps, hd = cfg.rms_eps, cfg.hd
     # the scopes are names in a profile and in the HLO's op_name, no more
@@ -270,14 +268,20 @@ def decoder_layer(cfg: LlamaConfig, x, layer, cache, positions, attend,
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
         o, cache = attend(q, k, v, cache, index)
-        x = x + reduce(o.reshape(B, S, -1) @ layer["wo"])
+        o = reduce(o.reshape(B, S, -1) @ layer["wo"])
+        if "attn_out_norm" in layer:
+            o = rms_norm(o, layer["attn_out_norm"], eps)
+        x = x + o
     # `mlp` is opened around the strategy, not over it: the expert layer's
     # scopes are read by name as siblings of `attn` and `mlp`, not children
     with jax.named_scope("mlp"):
         y = rms_norm(x, layer["mlp_norm"], eps)
     out, stats = mlp(y, layer)
     with jax.named_scope("mlp"):
-        return x + reduce(out), cache, stats
+        out = reduce(out)
+        if "mlp_out_norm" in layer:
+            out = rms_norm(out, layer["mlp_out_norm"], eps)
+        return x + out, cache, stats
 
 
 def remat_body(body, cfg: LlamaConfig):
@@ -291,10 +295,11 @@ def remat_body(body, cfg: LlamaConfig):
     return jax.checkpoint(body, prevent_cse=False, policy=policy)
 
 
-def lm_head(params, x, cfg: LlamaConfig):
-    """Final norm and the tied or untied output head: [..., H] -> float32
-    logits [..., V]."""
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+def lm_head(params, x, cfg: LlamaConfig, normed: bool = False):
+    """Final norm (unless x is `normed` already) and the tied or untied
+    output head: [..., H] -> float32 logits [..., V]."""
+    if not normed:
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     return (x @ head.astype(cfg.dtype)).astype(jnp.float32)
 
@@ -313,7 +318,14 @@ def decoder_trunk(params, tokens, cfg: LlamaConfig, attend, mlp=dense_mlp,
     that each layer updates at `[index, ...]` stays where it is. Without a
     cache it is a training forward: the carry's cache is None, no index is
     scanned, and the body runs under `remat_body`; a cached forward scans the
-    bare body (a `checkpoint` in a decode step would be a different program)."""
+    bare body (a `checkpoint` in a decode step would be a different program).
+
+    `cfg.loop_steps` > 1 (Ouro) applies the WHOLE stack that many times in
+    sequence with the same weights: a `lax.scan` over passes around the scan
+    over layers, the final norm at the end of every pass (the last pass's is
+    the head's), and pass `r` of layer `l` at the cache index `r * L + l`, so
+    the cache has `loop_steps * L` layers (scope `loop` a pass, `loop/norm`
+    the norm between passes; stats are stacked [passes, L, ...])."""
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -327,11 +339,27 @@ def decoder_trunk(params, tokens, cfg: LlamaConfig, attend, mlp=dense_mlp,
         return (x, cache), stats
 
     cached = cache is not None
+    layer_index = jnp.arange(cfg.num_layers, dtype=jnp.int32) if cached else None
+
+    def stack(x, cache, first_index=None):  # the layers once, over the carry
+        index = layer_index if first_index is None else first_index + layer_index
+        return jax.lax.scan(body if cached else remat_body(body, cfg), (x, cache),
+                            (params["layers"], index))
+
+    if cfg.loop_steps == 1:
+        (x, cache), stats = stack(x, cache)
+        return lm_head(params, x, cfg), cache, stats
+
+    def one_pass(carry, step):
+        with jax.named_scope("loop"):
+            (x, cache), stats = stack(*carry, step * cfg.num_layers if cached else None)
+            with jax.named_scope("norm"):
+                x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        return (x, cache), stats
+
     (x, cache), stats = jax.lax.scan(
-        body if cached else remat_body(body, cfg), (x, cache),
-        (params["layers"],
-         jnp.arange(cfg.num_layers, dtype=jnp.int32) if cached else None))
-    return lm_head(params, x, cfg), cache, stats
+        one_pass, (x, cache), jnp.arange(cfg.loop_steps, dtype=jnp.int32))
+    return lm_head(params, x, cfg, normed=True), cache, stats
 
 
 def forward(params, tokens, cfg: LlamaConfig, attn_fn=None, positions=None):
@@ -366,10 +394,6 @@ def _model_loss(params, tokens, targets, cfg: LlamaConfig, attn_fn, mesh=None):
     return loss_fn(params, tokens, targets, cfg, attn_fn), {}
 
 
-# what train/spmd.py takes of a model (ray_tpu/models/__init__.py)
-MODEL = Model(init=init, logical_axes=logical_axes, loss=_model_loss)
-
-
 def param_count_analytic(cfg: LlamaConfig) -> int:
     h, m, L, v = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers, cfg.vocab_size
     hd, nh, nkv = cfg.hd, cfg.num_heads, cfg.num_kv_heads
@@ -389,7 +413,7 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
     vLLM's paged KV; a pallas ragged-paged-attention variant is the planned
     upgrade per PAPERS.md.)
     """
-    shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.hd)
+    shape = (cfg.loop_steps * cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.hd)
     return {
         "k": jnp.zeros(shape, dtype=cfg.dtype),
         "v": jnp.zeros(shape, dtype=cfg.dtype),
@@ -404,7 +428,9 @@ def pool_head_dim(head_dim: int) -> int:
 def init_kv_pool(cfg: LlamaConfig, num_blocks: int, block_size: int) -> dict:
     """Paged KV pool: [L, N_blocks, block_size, Hkv * Dp] per k/v, token
     major: a token's keys of all KV heads are ONE contiguous row, and a page
-    (layer, block) is one contiguous [block_size, Hkv * Dp] run.
+    (layer, block) is one contiguous [block_size, Hkv * Dp] run. L is the
+    (K, V) pairs a token caches: the model's layers, or passes x layers where
+    the stack runs several times (`cfg.loop_steps`, `decoder_trunk`).
 
     Unlike the dense per-slot cache (init_kv_cache), HBM is allocated in
     block_size-token pages handed out on demand by a host-side allocator
@@ -428,7 +454,7 @@ def init_kv_pool(cfg: LlamaConfig, num_blocks: int, block_size: int) -> dict:
       (PERF.md section 6, PR 30, the second ahead-of-time finding). Here the
       write is a row scatter in the default layout, which the kernel reads."""
     row = cfg.num_kv_heads * pool_head_dim(cfg.hd)
-    shape = (cfg.num_layers, num_blocks, block_size, row)
+    shape = (cfg.loop_steps * cfg.num_layers, num_blocks, block_size, row)
     return {
         "k": jnp.zeros(shape, dtype=cfg.dtype),
         "v": jnp.zeros(shape, dtype=cfg.dtype),
@@ -454,7 +480,9 @@ def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
     computation runs (the engine passes its own); None derives it from the
     inputs' placement. It picks the default for `use_kernel` and, when the
     kernel is used off-TPU (tests), interpret mode. `mlp` is `decoder_layer`'s
-    strategy (the expert layer of a MoE family)."""
+    strategy (the expert layer of a MoE family). Where `cfg.loop_steps` > 1
+    the pool holds `loop_steps * L` cache layers and `layer` below is the
+    cache layer `pass * L + layer`."""
     B, S = tokens.shape
     max_blocks = tables.shape[1]
     if platform is None:
@@ -539,3 +567,10 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache: dict, lengths):
 
     return decoder_trunk(params, tokens, cfg, attend, cache=cache,
                          positions=positions)[:2]
+
+
+# what train/spmd.py and the serving engines take of a model
+# (ray_tpu/models/__init__.py)
+MODEL = Model(init=init, logical_axes=logical_axes, loss=_model_loss,
+              forward_paged=forward_paged, init_kv_pool=init_kv_pool,
+              forward_with_cache=forward_with_cache, init_kv_cache=init_kv_cache)

@@ -26,8 +26,7 @@ expert `e`, `P_e` the mean of `p[:, e]`), times `router_aux_coeff`.
 One chip holds every expert. With an `expert` mesh axis of more than one
 device the layer takes the dense path it takes off the TPU (a Mosaic kernel
 cannot be partitioned) and leaves the placement to XLA; experts spread over
-chips with an explicit all-to-all are a later four-chip issue (ROADMAP R2,
-the Moonlight pairing).
+chips with an explicit all-to-all are a later four-chip issue (ROADMAP R2(c)).
 """
 
 from __future__ import annotations
@@ -53,6 +52,10 @@ class MoEConfig:
     norm_topk_prob: bool = False      # renormalise the top_k weights to sum to one
     qk_norm: bool = False             # RMSNorm over the whole projected q and k
     router_aux_coeff: float = 0.01
+
+    @property
+    def vocab_size(self) -> int:   # what an engine asks of any configuration
+        return self.base.vocab_size
 
     @staticmethod
     def tiny() -> "MoEConfig":
@@ -227,5 +230,19 @@ def loss_fn(params, tokens, targets, cfg: MoEConfig, attn_fn=None, mesh=None):
         "nll": nll, "aux_loss": aux, "router_load_max": stats["load"].max()}
 
 
-# what train/spmd.py takes of a model (ray_tpu/models/__init__.py)
-MODEL = Model(init=init, logical_axes=logical_axes, loss=loss_fn)
+def forward_paged(params, tokens, cfg: MoEConfig, pool, tables, lengths,
+                  block_size: int, platform: str | None = None, **kw):
+    """`llama.forward_paged` with the expert layer as its MLP strategy."""
+    return llama.forward_paged(
+        params, tokens, cfg.base, pool, tables, lengths, block_size, platform=platform,
+        mlp=partial(moe_mlp, cfg=cfg, platform=platform), **kw)
+
+
+def init_kv_pool(cfg: MoEConfig, num_blocks: int, block_size: int) -> dict:
+    return llama.init_kv_pool(cfg.base, num_blocks, block_size)
+
+
+# what train/spmd.py and the paged engines take of a model
+# (ray_tpu/models/__init__.py)
+MODEL = Model(init=init, logical_axes=logical_axes, loss=loss_fn,
+              forward_paged=forward_paged, init_kv_pool=init_kv_pool)

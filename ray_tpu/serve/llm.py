@@ -25,7 +25,7 @@ from typing import Any, Optional
 
 import numpy as np
 
-from ray_tpu.models import llama
+from ray_tpu.models import llama, model_of
 from ray_tpu.ops.platform import target_platform
 from ray_tpu.util.compile_cache import compile_totals, ensure_compile_cache
 
@@ -34,7 +34,9 @@ from ray_tpu.util.compile_cache import compile_totals, ensure_compile_cache
 class LLMConfig:
     """Reference: ray.serve.llm LLMConfig (model + engine kwargs)."""
 
-    model_config: llama.LlamaConfig = dataclasses.field(default_factory=llama.LlamaConfig.tiny)
+    # any family's configuration: the engines take the family's forward, cache
+    # and weights from its `Model` record (`ray_tpu.models.model_of`)
+    model_config: Any = dataclasses.field(default_factory=llama.LlamaConfig.tiny)
     max_batch_size: int = 8
     max_seq_len: int = 256
     max_new_tokens_default: int = 32
@@ -80,7 +82,8 @@ class LLMEngine:
         self._jax = jax
         self._jnp = jnp
         key = jax.random.PRNGKey(seed)
-        self.params = params if params is not None else llama.init(cfg, key)
+        self.model = model_of(cfg)
+        self.params = params if params is not None else self.model.init(cfg, key)
         # Where the weights actually live, so where every step runs: a
         # CPU-pinned worker process reports "cpu" here however many chips the
         # host has (stats() carries it to whoever has to check)
@@ -117,13 +120,19 @@ class LLMEngine:
         jax, jnp = self._jax, self._jnp
         cfg = self.config.model_config
         B, S = self.config.max_batch_size, self.config.max_seq_len
-        self.cache = llama.init_kv_cache(cfg, B, S)
+        forward_with_cache = self.model.forward_with_cache
+        if forward_with_cache is None or self.model.init_kv_cache is None:
+            raise TypeError(
+                f"the family of {type(cfg).__qualname__} gives no `forward_with_cache` "
+                f"/ `init_kv_cache`: it serves through the paged engine "
+                f"(serve/llm_paged.py) only")
+        self.cache = self.model.init_kv_cache(cfg, B, S)
 
         def prefill(params, cache, tokens, slot, length):
             # slice this slot's cache, run, write back (single compile per bucket)
             sl = lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1)
             sub = {"k": sl(cache["k"]), "v": sl(cache["v"])}
-            logits, sub = llama.forward_with_cache(
+            logits, sub = forward_with_cache(
                 params, tokens, cfg, sub, jnp.zeros((1,), jnp.int32)
             )
             wr = lambda c, s: jax.lax.dynamic_update_slice_in_dim(c, s, slot, axis=1)
@@ -133,7 +142,7 @@ class LLMEngine:
             return last, cache
 
         def decode(params, cache, last_tokens, lengths):
-            logits, cache = llama.forward_with_cache(params, last_tokens, cfg, cache, lengths)
+            logits, cache = forward_with_cache(params, last_tokens, cfg, cache, lengths)
             return logits[:, 0], cache
 
         self._prefill = jax.jit(prefill)

@@ -7,7 +7,7 @@ are native:
 
 - ``PagedLLMEngine``: LLMEngine's continuous-batching shell (scheduling,
   streaming, sampling, finish/fail paths are inherited) over a block-pool KV
-  (models.llama.forward_paged + serve/paged_kv.py allocator). Memory scales
+  (the family's `Model.forward_paged` + serve/paged_kv.py allocator). Memory scales
   with actual tokens reserved per request — many short sequences or few long
   ones share one pool — and full prompt blocks are content-addressed so
   shared prefixes prefill once and occupy memory once.
@@ -33,7 +33,7 @@ from typing import Optional
 
 import numpy as np
 
-from ray_tpu.models import llama
+from ray_tpu.models import model_of
 from ray_tpu.serve import anatomy
 from ray_tpu.serve.llm import LLMConfig, LLMEngine, _Slot
 from ray_tpu.serve.paged_kv import BlockPool, NoFreeBlocks
@@ -62,19 +62,22 @@ class PagedLLMConfig(LLMConfig):
     kv_transfer: str = "host"
 
 
-def paged_step(name: str, cfg: llama.LlamaConfig, block_size: int, platform: str,
+def paged_step(name: str, cfg, block_size: int, platform: str,
                rows, table_first: bool = False):
-    """The jitted step `name` of a paged engine: `llama.forward_paged` of one
-    model configuration over a pool it donates -> (`logits[rows]` of the
-    [B, S, vocab] logits, the pool). Called as (params, pool, tokens, lengths,
-    tables), a prefill (`table_first`) as (params, pool, tokens, table,
-    start_len). A profile knows the step by `name` (`jit(decode)/while/...`).
-    The step holds its arguments only, never the engine."""
+    """The jitted step `name` of a paged engine: the `forward_paged` of the
+    configuration's family (`model_of(cfg)`) over a pool it donates ->
+    (`logits[rows]` of the [B, S, vocab] logits, the pool). Called as (params,
+    pool, tokens, lengths, tables), a prefill (`table_first`) as (params,
+    pool, tokens, table, start_len). A profile knows the step by `name`
+    (`jit(decode)/while/...`). The step holds its arguments only, never the
+    engine."""
     import jax
+
+    forward_paged = model_of(cfg).forward_paged
 
     def step(params, pool, tokens, first, second):
         tables, lengths = (first, second) if table_first else (second, first)
-        logits, pool = llama.forward_paged(
+        logits, pool = forward_paged(
             params, tokens, cfg, pool, tables, lengths, block_size, platform=platform)
         return logits[rows], pool
 
@@ -112,7 +115,7 @@ class PagedLLMEngine(LLMEngine):
         self.max_blocks_per_seq = S // bs
         n_blocks = self.config.num_blocks or (B * self.max_blocks_per_seq + 1)
         self.pool_blocks = n_blocks
-        self.pool = llama.init_kv_pool(cfg, n_blocks, bs)
+        self.pool = self.model.init_kv_pool(cfg, n_blocks, bs)
         self.allocator = BlockPool(n_blocks, bs)
         self.tables = np.zeros((B, self.max_blocks_per_seq), dtype=np.int32)
         self.slot_blocks: list[list[int]] = [[] for _ in range(B)]
@@ -284,22 +287,23 @@ class PagedLLMEngine(LLMEngine):
         return did_work
 
     def _step_admit(self) -> bool:
+        """Admit from the head of the line while slots and blocks last. A
+        request that found no blocks keeps its place at the head (requeued:
+        once a pass, then the pass ends), so admission is in order of arrival
+        and a large request is not overtaken forever by smaller ones."""
         did_work = False
         free = [i for i in range(self.config.max_batch_size) if not self.active[i]]
-        requeue = []
         while free and not self._pending.empty():
             try:
                 req = self._pending.get_nowait()
             except queue.Empty:
                 break
-            slot = free.pop(0)
-            if not self._admit_one(*req, slot):
-                requeue.append(req)
-                free.insert(0, slot)
+            if not self._admit_one(*req, free[0]):
+                with self._pending.mutex:   # back to the head, not the tail
+                    self._pending.queue.appendleft(req)
                 break  # pool exhausted: stop admitting this pass
+            free.pop(0)
             did_work = True
-        for req in requeue:
-            self._pending.put(req)
         return did_work
 
     @contextlib.contextmanager
@@ -310,10 +314,14 @@ class PagedLLMEngine(LLMEngine):
         compile_s0 = compile_totals()[1]
         live = int(self.active.sum())
         ctx = int(self.lengths[self.active].sum())
+        # the allocator's running count: `stats()` walks every cached block,
+        # 1% of a step with a 4,097-block pool of cached prompts (PERF.md
+        # section 6, PR 31)
+        blocks = self.allocator.in_use
         try:
             yield clock
         finally:
-            clock.close(live=live, ctx=ctx,
+            clock.close(live=live, ctx=ctx, blocks=blocks,
                         compile_s=compile_totals()[1] - compile_s0)
 
     def _step_decode(self) -> bool:
@@ -415,7 +423,7 @@ class PagedLLMEngine(LLMEngine):
                     np.asarray(self.pool["k"][:, idx]),
                     np.asarray(self.pool["v"][:, idx]))
             else:
-                # the pool's own rows (`llama.init_kv_pool`): [L, n, bs, Hkv * Dp]
+                # the pool's own rows (`Model.init_kv_pool`): [L, n, bs, Hkv * Dp]
                 kv = {
                     "k": np.asarray(self.pool["k"][:, idx]),
                     "v": np.asarray(self.pool["v"][:, idx]),

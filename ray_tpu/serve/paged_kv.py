@@ -31,6 +31,9 @@ class BlockPool:
         self.block_size = block_size
         self._free: list[int] = list(range(num_blocks - 1, 0, -1))
         self._ref: dict[int, int] = {}
+        # blocks a sequence holds (refcount > 0), kept as they change: what
+        # `stats()["allocated_blocks"]` finds by walking every cached block
+        self.in_use = 0
         # chain_hash -> block id, LRU-ordered for eviction; blocks here may
         # have refcount 0 (reusable) but stay allocated until evicted
         self._prefix: "OrderedDict[int, int]" = OrderedDict()
@@ -60,6 +63,7 @@ class BlockPool:
             if bid is None:
                 return None
         self._ref[bid] = 1
+        self.in_use += 1
         return bid
 
     def _evict_one(self) -> Optional[int]:
@@ -82,6 +86,7 @@ class BlockPool:
         if n > 0:
             self._ref[bid] = n
             return
+        self.in_use -= n == 0   # its last holder let go
         if bid in self._block_chain:
             # cached prefix block: keep it allocated at refcount 0 (reusable);
             # eviction reclaims it under pressure
@@ -111,6 +116,7 @@ class BlockPool:
                 hit_ids.append(bid)
                 self._prefix.move_to_end(chain)  # LRU touch
             for bid in hit_ids:
+                self.in_use += not self._ref.get(bid, 0)   # a cached block, held again
                 self._ref[bid] = self._ref.get(bid, 0) + 1
             if hit_ids:
                 self.prefix_hits += 1

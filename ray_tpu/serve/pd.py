@@ -67,14 +67,14 @@ def _init_engine(decode_cfg, prefill_cfg=None, kv_transfer: str | None = None):
 
     import jax
 
-    from ray_tpu.models import llama
+    from ray_tpu.models import model_of
     from ray_tpu.serve.llm_paged import PagedLLMEngine
 
     cfg = prefill_cfg or decode_cfg
     if kv_transfer is not None:
         cfg = dataclasses.replace(cfg, kv_transfer=kv_transfer)
     key = jax.random.PRNGKey(0)
-    params = llama.init(cfg.model_config, key)
+    params = model_of(cfg.model_config).init(cfg.model_config, key)
     return PagedLLMEngine(cfg, params=params), params
 
 

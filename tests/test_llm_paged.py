@@ -228,3 +228,51 @@ def test_engine_admission_rolls_back_cached_hit_refs_when_pool_full():
     # every cached block is back at refcount 0 -> still evictable/reusable
     st = pool.stats()
     assert st["free_blocks"] == 5 and st["allocated_blocks"] == 0
+
+
+def test_admission_is_in_order_of_arrival_when_blocks_bind(shared_params):
+    """A pool smaller than its slots (6 usable blocks of 16, 4 slots): a
+    request that finds no blocks keeps its place at the HEAD of the line. So
+    admission is in order of arrival, a request larger than the free blocks
+    is not overtaken by the smaller ones behind it (which would fit), and
+    while it waits `requeued` is recorded once a pass, for it alone."""
+    from ray_tpu.util import timeline
+
+    cfg, params = shared_params
+    eng = PagedLLMEngine(PagedLLMConfig(
+        model_config=cfg, max_batch_size=4, max_seq_len=128, block_size=16,
+        num_blocks=7, prefill_buckets=(32, 64)), params=params, external_step=True)
+    # (prompt tokens, new tokens) -> blocks: 2, 4 (the pool is full), 5, 1, 1
+    asks = [(20, 12), (40, 24), (50, 30), (10, 6), (11, 5)]
+    timeline.clear()
+
+    def admits():
+        return [(e[7]["outcome"], e[7]["prompt"]) for e in timeline.local_events()
+                if e[0] == "span" and e[2] == "engine" and e[3] == "admit"]
+
+    try:
+        futs = [eng.generate(list(range(1, n + 1)), new) for n, new in asks]
+        per_pass = []
+        for _ in range(200):
+            if all(f.done() for f in futs):
+                break
+            before = len(admits())
+            # the running count is what the walk over every block finds
+            assert eng.allocator.in_use == eng.stats()["allocated_blocks"]
+            eng.step_once()
+            per_pass.append(admits()[before:])
+        assert [f.result(0).num_generated for f in futs] == [new for _, new in asks]
+        assert eng.stats()["allocated_blocks"] == eng.allocator.in_use == 0
+    finally:
+        eng.shutdown()
+    admitted = [n for outcome, n in admits() if outcome == "admitted"]
+    assert admitted == [n for n, _ in asks]
+    # the first pass admits 20 and 40 and stops at 50; nothing behind it is tried
+    assert per_pass[0] == [("admitted", 20), ("admitted", 40), ("requeued", 50)]
+    waiting = [p for p in per_pass if ("requeued", 50) in p]
+    assert len(waiting) == 23            # until the 4-block request has finished
+    assert all(p == [("requeued", 50)] for p in waiting[1:])
+    # 50 goes in with 10 behind it (5 + 1 blocks); 11 waits its turn at the head
+    after = per_pass[len(waiting)]
+    assert after == [("admitted", 50), ("admitted", 10), ("requeued", 11)]
+    assert {n for p in per_pass for outcome, n in p if outcome == "requeued"} == {50, 11}

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.models import llama
+from ray_tpu.models import llama, model_of, ouro
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.ops.paged_attention import paged_decode_attention
 from ray_tpu.parallel import sharding as shd
@@ -161,13 +161,15 @@ def test_paged_decode_compiles(v5e, B, Hq, Hkv, D, max_blocks, pool_blocks):
 def _paged_step(d, cfg, *, B, S, bs=16, max_blocks=8, pool_blocks=257, donate=True):
     """One `forward_paged` step told it runs on a TPU, lowered for device d
     with the pool donated, as the paged engines' steps are (`paged_step`):
-    B = 1 and S > 1 is a prefill, S = 1 the decode step."""
+    B = 1 and S > 1 is a prefill, S = 1 the decode step. The forward, the
+    pool and the weights are those of `cfg`'s family (`model_of`)."""
+    model = model_of(cfg)
     place = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
-    params = place(jax.eval_shape(lambda: llama.init(cfg, jax.random.PRNGKey(0))))
-    pool = place(jax.eval_shape(lambda: llama.init_kv_pool(cfg, pool_blocks, bs)))
+    params = place(jax.eval_shape(lambda: model.init(cfg, jax.random.PRNGKey(0))))
+    pool = place(jax.eval_shape(lambda: model.init_kv_pool(cfg, pool_blocks, bs)))
 
     def step(params, pool, tokens, tables, lengths):
-        return llama.forward_paged(params, tokens, cfg, pool, tables, lengths,
+        return model.forward_paged(params, tokens, cfg, pool, tables, lengths,
                                    bs, platform="tpu")
 
     return jax.jit(step, donate_argnums=(1,) if donate else ()).lower(
@@ -206,8 +208,9 @@ def pool_sized_instructions(text: str, pool_shape: tuple) -> list[tuple[str, str
     return out
 
 
-def assert_pool_stays_in_place(compiled, pool) -> None:
-    """What ISSUE 30 holds a paged step to. `pool` is the K/V pool's shapes."""
+def assert_pool_stays_in_place(compiled, pool, scratch_under: int | None = None) -> None:
+    """What ISSUE 30 holds a paged step to. `pool` is the K/V pool's shapes;
+    `scratch_under` bounds the step's scratch (default: one layer's pages)."""
     text = compiled.as_text()
     layer_bytes = math.prod(pool["k"].shape[1:]) * pool["k"].dtype.itemsize
     # the donation is honoured: both pool leaves alias an output
@@ -221,7 +224,7 @@ def assert_pool_stays_in_place(compiled, pool) -> None:
              if op not in ("parameter", "get-tuple-element", "bitcast", "scatter")]
     assert not moved, moved
     # nothing the size of a layer's pages is scratch either
-    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < (scratch_under or layer_bytes)
 
 
 def _compiled_pool_step(d, **step):
@@ -245,6 +248,31 @@ def test_paged_step_leaves_the_pool_where_it_is(v5e, B, S):
     and 5.37 GB of scratch at the cells' size, and the same pool carried
     head-major was copied whole in every layer (PERF.md section 6, PR 30)."""
     assert_pool_stays_in_place(*_compiled_pool_step(v5e[0], B=B, S=S))
+
+
+@pytest.mark.parametrize("B, S", [(32, 1), (1, 256)], ids=["decode", "prefill-256"])
+def test_ouro_paged_step_at_its_published_widths(v5e, B, S):
+    """`serve-ouro-shortin-batch`'s two largest programs at Ouro-2.6B's
+    published widths, whole: 48 layers run 4 times, 16 heads of 128, 32 slots,
+    a 128-block table, the 321-block pool `bf16[192, 321, 16, 2048]` (8.08 GB
+    for keys and values) donated, beside 5.34 GB of weights. They compile for
+    a v5e (an out-of-HBM or Mosaic refusal fails here, not on the chip), the
+    pool carried through TWO nested scans (passes, layers) stays in place (the
+    only pool-shaped instructions are the `kv_write` scatters), and the text
+    names the scopes a profile is read by. Scratch is 806 MB, none of it the
+    pool's: XLA hoists a re-layout of the whole stacked `wq` and `wk`
+    (`bf16[48, 2048, 2048]`, 403 MB each) out of both loops, once a step where
+    a single scan does it a layer at a time (PERF.md section 6, PR 31)."""
+    cfg = dataclasses.replace(ouro.OuroConfig.ouro_2_6b(), max_seq_len=2048)
+    compiled = _paged_step(v5e[0], cfg, B=B, S=S, max_blocks=128,
+                           pool_blocks=321).compile()
+    pool = jax.eval_shape(lambda: ouro.init_kv_pool(cfg, 321, 16))
+    assert pool["k"].shape == (192, 321, 16, 2048)
+    assert_pool_stays_in_place(compiled, pool, scratch_under=2 ** 30)
+    text = compiled.as_text()
+    names = ["/loop/", "loop/norm", "attn/kv_write", "attn/kv_read"]
+    for name in names + (["paged_attention_decode", MOSAIC] if S == 1 else []):
+        assert name in text, name
 
 
 def test_pool_sized_instruction_detector_sees_a_copy(v5e):
