@@ -18,12 +18,13 @@ class Model(NamedTuple):
     loss: Callable
     # -- what serving takes; None where the family does not serve that way.
     # The paged engines: (params, tokens, cfg, pool, tables, lengths,
-    # block_size, platform=) -> (logits [B, S, V], pool), and (cfg,
-    # num_blocks, block_size) -> the pool it reads and writes
+    # block_size, platform=, head_rows=) -> (logits [B, S, V], or [B, 1, V]
+    # of the positions `head_rows` [B] names, pool), and (cfg, num_blocks,
+    # block_size) -> the pool it reads and writes
     forward_paged: Optional[Callable] = None
     init_kv_pool: Optional[Callable] = None
-    # the dense slot engine: (params, tokens, cfg, cache, lengths) ->
-    # (logits, cache), and (cfg, batch, max_len) -> its cache
+    # the dense slot engine: (params, tokens, cfg, cache, lengths,
+    # head_rows=) -> (logits, cache), and (cfg, batch, max_len) -> its cache
     forward_with_cache: Optional[Callable] = None
     init_kv_cache: Optional[Callable] = None
 

@@ -305,10 +305,18 @@ def lm_head(params, x, cfg: LlamaConfig, normed: bool = False):
 
 
 def decoder_trunk(params, tokens, cfg: LlamaConfig, attend, mlp=dense_mlp,
-                  cache=None, positions=None):
+                  cache=None, positions=None, head_rows=None):
     """Token ids [B, S] -> (float32 logits [B, S, V], the updated cache, the
     layers' stats stacked): embedding, `lax.scan` of `decoder_layer` over the
     layers, `lm_head`.
+
+    `head_rows` is which positions' logits the caller reads: None for every
+    one, or int32 [B] (traced: one program whatever its values) for position
+    `head_rows[b]` of sequence b alone. The rows are taken out of x between
+    the last layer (a looped family's last norm) and `lm_head`, so the head
+    multiplies [B, 1, H] and the logits are [B, 1, V]: a 2,048-token prefill
+    that samples from its last live position neither computes nor writes the
+    other 2,047 rows of [S, V] float32 (PERF.md section 6, PR 32).
 
     The cache is never an `xs`/`ys` of the scan: it rides in the carry beside
     x, whole (leaves [L, ...]), and `attend` gets it with the layer's index.
@@ -346,9 +354,14 @@ def decoder_trunk(params, tokens, cfg: LlamaConfig, attend, mlp=dense_mlp,
         return jax.lax.scan(body if cached else remat_body(body, cfg), (x, cache),
                             (params["layers"], index))
 
+    def head(x, normed=False):
+        if head_rows is not None:
+            x = jnp.take_along_axis(x, head_rows[:, None, None], axis=1)
+        return lm_head(params, x, cfg, normed)
+
     if cfg.loop_steps == 1:
         (x, cache), stats = stack(x, cache)
-        return lm_head(params, x, cfg), cache, stats
+        return head(x), cache, stats
 
     def one_pass(carry, step):
         with jax.named_scope("loop"):
@@ -359,7 +372,7 @@ def decoder_trunk(params, tokens, cfg: LlamaConfig, attend, mlp=dense_mlp,
 
     (x, cache), stats = jax.lax.scan(
         one_pass, (x, cache), jnp.arange(cfg.loop_steps, dtype=jnp.int32))
-    return lm_head(params, x, cfg, normed=True), cache, stats
+    return head(x, normed=True), cache, stats
 
 
 def forward(params, tokens, cfg: LlamaConfig, attn_fn=None, positions=None):
@@ -463,11 +476,12 @@ def init_kv_pool(cfg: LlamaConfig, num_blocks: int, block_size: int) -> dict:
 
 def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
                   block_size: int, use_kernel: bool | None = None,
-                  platform: str | None = None, mlp=dense_mlp):
+                  platform: str | None = None, mlp=dense_mlp, head_rows=None):
     """Cached forward over a PAGED pool (`init_kv_pool`'s layout). tokens
     [B,S] append at positions [lengths, lengths+S); tables [B, max_blocks] map
     sequence-block index -> pool block id. Returns (logits [B,S,V], updated
-    pool).
+    pool); with `head_rows` (`decoder_trunk`: int32 [B]) the logits of that
+    one position a sequence, [B,1,V].
 
     The pool rides whole in the layer scan's carry (`decoder_trunk`) and each
     layer touches only its own pages of it: new K/V rows scatter into
@@ -527,7 +541,7 @@ def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
         return o, {"k": kp, "v": vp}
 
     return decoder_trunk(params, tokens, cfg, attend, mlp, cache=pool,
-                         positions=positions)[:2]
+                         positions=positions, head_rows=head_rows)[:2]
 
 
 def _cached_attention(q, k_cache, v_cache, lengths, q_positions):
@@ -547,13 +561,15 @@ def _cached_attention(q, k_cache, v_cache, lengths, q_positions):
     return out.reshape(B, S, Hq, D)
 
 
-def forward_with_cache(params, tokens, cfg: LlamaConfig, cache: dict, lengths):
+def forward_with_cache(params, tokens, cfg: LlamaConfig, cache: dict, lengths,
+                       head_rows=None):
     """Append `tokens` [B,S] at positions [lengths, lengths+S) and return
-    (logits[B,S,V], updated cache). Works for prefill (S=prompt, lengths=0)
-    and decode (S=1). The slot cache [L, B, Smax, Hkv, D] rides in the layer
-    scan's carry like the paged pool (`decoder_trunk`): each layer scatters
-    its new rows at `[layer, b, position]` and attends over its own slice.
-    A position past Smax is not written."""
+    (logits[B,S,V], updated cache); `head_rows` as `forward_paged`'s. Works
+    for prefill (S=prompt, lengths=0) and decode (S=1). The slot cache
+    [L, B, Smax, Hkv, D] rides in the layer scan's carry like the paged pool
+    (`decoder_trunk`): each layer scatters its new rows at
+    `[layer, b, position]` and attends over its own slice. A position past
+    Smax is not written."""
     B = tokens.shape[0]
     positions = lengths[:, None] + jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
     slot = jnp.arange(B)[:, None]
@@ -566,7 +582,7 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache: dict, lengths):
         return o, {"k": kc, "v": vc}
 
     return decoder_trunk(params, tokens, cfg, attend, cache=cache,
-                         positions=positions)[:2]
+                         positions=positions, head_rows=head_rows)[:2]
 
 
 # what train/spmd.py and the serving engines take of a model

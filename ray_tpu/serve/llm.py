@@ -132,14 +132,15 @@ class LLMEngine:
             # slice this slot's cache, run, write back (single compile per bucket)
             sl = lambda c: jax.lax.dynamic_slice_in_dim(c, slot, 1, axis=1)
             sub = {"k": sl(cache["k"]), "v": sl(cache["v"])}
+            # the head runs on the last real prompt position alone (tokens
+            # are right-padded): logits [1, 1, V]
             logits, sub = forward_with_cache(
-                params, tokens, cfg, sub, jnp.zeros((1,), jnp.int32)
+                params, tokens, cfg, sub, jnp.zeros((1,), jnp.int32),
+                head_rows=jnp.reshape(length - 1, (1,)),
             )
             wr = lambda c, s: jax.lax.dynamic_update_slice_in_dim(c, s, slot, axis=1)
             cache = {"k": wr(cache["k"], sub["k"]), "v": wr(cache["v"], sub["v"])}
-            # logits at the last real prompt position (tokens are right-padded)
-            last = logits[0, length - 1]
-            return last, cache
+            return logits[0, 0], cache
 
         def decode(params, cache, last_tokens, lengths):
             logits, cache = forward_with_cache(params, last_tokens, cfg, cache, lengths)

@@ -63,23 +63,41 @@ class PagedLLMConfig(LLMConfig):
 
 
 def paged_step(name: str, cfg, block_size: int, platform: str,
-               rows, table_first: bool = False):
+               head, table_first: bool = False):
     """The jitted step `name` of a paged engine: the `forward_paged` of the
     configuration's family (`model_of(cfg)`) over a pool it donates ->
-    (`logits[rows]` of the [B, S, vocab] logits, the pool). Called as (params,
-    pool, tokens, lengths, tables), a prefill (`table_first`) as (params,
-    pool, tokens, table, start_len). A profile knows the step by `name`
-    (`jit(decode)/while/...`). The step holds its arguments only, never the
-    engine."""
+    (logits, the pool). Called as (params, pool, tokens, lengths, tables); a
+    prefill (`table_first`, B = 1) as (params, pool, tokens, table, span),
+    span int32 [2]: where the tokens start in the sequence and how many of
+    them are live (the rest pad the bucket).
+
+    `head` is which positions' logits the caller reads, and the output head
+    runs on those alone (`decoder_trunk`'s `head_rows`), so a step returns
+    nothing its caller drops: "all" -> [B, S, vocab]; "last", a prefill's last
+    live position, or an int, that position of every sequence -> [B, vocab];
+    None -> no logits (None in their place, and no head in the program). A
+    profile knows the step by `name` (`jit(decode)/while/...`). The step holds
+    its arguments only, never the engine."""
     import jax
+    import jax.numpy as jnp
 
     forward_paged = model_of(cfg).forward_paged
 
     def step(params, pool, tokens, first, second):
-        tables, lengths = (first, second) if table_first else (second, first)
+        tables, lengths = (first, second[:1]) if table_first else (second, first)
+        B, S = tokens.shape
+        if head == "last":
+            head_rows = second[1:] - 1
+        elif isinstance(head, int) and S > 1:
+            head_rows = jnp.full((B,), head, jnp.int32)
+        else:  # every position; at S == 1 the only row is every row
+            head_rows = None
         logits, pool = forward_paged(
-            params, tokens, cfg, pool, tables, lengths, block_size, platform=platform)
-        return logits[rows], pool
+            params, tokens, cfg, pool, tables, lengths, block_size,
+            platform=platform, head_rows=head_rows)
+        if head is None:
+            return None, pool  # unused, so XLA drops the head with them
+        return (logits if head == "all" else logits[:, 0]), pool
 
     step.__name__ = step.__qualname__ = name
     return jax.jit(step, donate_argnums=(1,))
@@ -121,9 +139,9 @@ class PagedLLMEngine(LLMEngine):
         self.slot_blocks: list[list[int]] = [[] for _ in range(B)]
         self.slot_prompts: list[Optional[list[int]]] = [None] * B
         step = partial(paged_step, cfg=cfg, block_size=bs, platform=self.platform)
-        # prefill: a B=1 row, the suffix's per-position logits
-        self._prefill = step("prefill", rows=0, table_first=True)
-        self._decode = step("decode", rows=np.s_[:, 0])
+        # prefill: a B=1 row, the logits of the suffix's last live position
+        self._prefill = step("prefill", head="last", table_first=True)
+        self._decode = step("decode", head=0)
 
     def dummy_decode(self) -> None:
         """Cadence-keeping round for DP-attention lockstep (dp_attention.py):
@@ -182,7 +200,8 @@ class PagedLLMEngine(LLMEngine):
         clock = PhaseClock("engine", "admit", _ADMIT_PHASES)
         compile_s0 = compile_totals()[1]
         info = {"prompt": len(prompt), "cached": 0, "bucket": 0, "slot": slot,
-                "queue_wait_s": clock.t0 - t_enq, "outcome": "failed"}
+                "queue_wait_s": clock.t0 - t_enq, "outcome": "failed",
+                "logits_bytes": 0}  # what the `copy` phase brought to the host
         try:
             total_blocks = -(-(len(prompt) + max_new) // bs)
             if total_blocks > self.pool_blocks - 1:
@@ -223,14 +242,16 @@ class PagedLLMEngine(LLMEngine):
                 clock.mark("prefill")  # returns when the program is enqueued
                 logits, self.pool = self._prefill(
                     self.params, self.pool, jnp.asarray(padded),
-                    jnp.asarray(table_row), jnp.asarray([cached_len], np.int32),
+                    jnp.asarray(table_row),
+                    jnp.asarray([cached_len, len(suffix)], np.int32),
                 )
                 clock.mark("wait")  # the device's part; np.asarray would wait too
                 logits.block_until_ready()
-                clock.mark("copy")  # [bucket, vocab] float32 to the host
+                clock.mark("copy")  # [1, vocab] float32 to the host
                 logits_np = np.asarray(logits)
+                info["logits_bytes"] = logits_np.nbytes
                 clock.mark("sample")
-                tok = self._sample(logits_np[len(suffix) - 1])
+                tok = self._sample(logits_np[0])
             except Exception as e:  # noqa: BLE001 - bad request: fail, keep serving
                 self.allocator.free(block_ids)
                 if not fut.done():
@@ -396,9 +417,10 @@ class PagedLLMEngine(LLMEngine):
         try:
             logits, self.pool = self._prefill(
                 self.params, self.pool, jnp.asarray(padded),
-                jnp.asarray(table_row), jnp.asarray([0], np.int32),
+                jnp.asarray(table_row),
+                jnp.asarray([0, len(prompt_ids)], np.int32),
             )
-            first_tok = self._sample(np.asarray(logits)[len(prompt_ids) - 1])
+            first_tok = self._sample(np.asarray(logits)[0])
             idx = np.asarray(block_ids, dtype=np.int32)
             kv = kv_ticket = kv_ref = None
             if self.config.kv_transfer == "device":

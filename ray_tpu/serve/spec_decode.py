@@ -75,14 +75,15 @@ class SpecDecodeLLMEngine(PagedLLMEngine):
         self.draft_pool = llama.init_kv_pool(dcfg, self.pool_blocks, bs)
 
         step = partial(paged_step, block_size=bs, platform=self.platform)
-        self._draft_prefill = step("draft_prefill", dcfg, rows=0, table_first=True)
-        self._draft_decode = step("draft_decode", dcfg, rows=np.s_[:, 0])
+        # the draft's prefill fills its pool and proposes nothing: no head
+        self._draft_prefill = step("draft_prefill", dcfg, head=None, table_first=True)
+        self._draft_decode = step("draft_decode", dcfg, head=0)
         # [B, 2] window: re-process [prev, last] so a fully-accepted prior
         # step's final proposal (whose draft KV was never written — the
         # classic bonus-token hole) gets its page filled before proposing
-        self._draft_decode2 = step("draft_decode2", dcfg, rows=np.s_[:, 1])
+        self._draft_decode2 = step("draft_decode2", dcfg, head=1)
         # [B, K+1] window scored in one target forward
-        self._verify = step("verify", cfg, rows=())
+        self._verify = step("verify", cfg, head="all")
         # second-to-last committed token per slot (the 2-token window's head)
         self.prev_tokens = np.zeros((self.config.max_batch_size, 1), dtype=np.int32)
 
@@ -117,7 +118,7 @@ class SpecDecodeLLMEngine(PagedLLMEngine):
         table_row = self.tables[slot][None, :]
         _, self.draft_pool = self._draft_prefill(
             self.draft_params, self.draft_pool, jnp.asarray(padded),
-            jnp.asarray(table_row), jnp.asarray([0], np.int32),
+            jnp.asarray(table_row), jnp.asarray([0, len(prompt)], np.int32),
         )
         self.prev_tokens[slot, 0] = prompt[-1]
 

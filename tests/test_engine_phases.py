@@ -71,6 +71,15 @@ def test_one_request_leaves_one_admit_record(one_request):
     assert {"alloc_s", "prefill_s", "wait_s", "copy_s", "sample_s"} <= set(a)
 
 
+def test_admit_record_counts_the_one_row_the_copy_brought(one_request, shared_params):
+    """The prefill hands the host the row it samples from and no other: the
+    `copy` phase is still there (a metric reads `copy_s`), and `logits_bytes`
+    is one float32 row of the vocabulary, not the 32 bucket's."""
+    (a, _), = _records("admit")
+    assert a["copy_s"] > 0
+    assert a["logits_bytes"] == 4 * shared_params[0].vocab_size
+
+
 def test_decode_records_follow_the_request(one_request):
     steps = [a for a, _ in _records("decode")]
     assert [s["live"] for s in steps] == [1] * 5

@@ -132,6 +132,90 @@ def test_pd_disaggregation_handoff(shared_params):
         ref_engine.shutdown()
 
 
+# ------------------------------------------------- the prefill's one row
+# A prefill runs the output head on the last live position alone and hands
+# back [1, vocab] (PERF.md section 6, PR 32). What the engines sample must be
+# what the plain forward over the whole sequence, with no cache and every
+# position's logits, says: the greedy continuation by `llama.forward`.
+def _plain_greedy(cfg, params, prompt, n):
+    import jax.numpy as jnp
+
+    seq = list(prompt)
+    for _ in range(n):
+        logits = llama.forward(params, jnp.asarray([seq], jnp.int32), cfg)
+        seq.append(int(np.argmax(np.asarray(logits[0, -1]))))
+    return seq[len(prompt):]
+
+
+def _spec(cfg, params):
+    from ray_tpu.serve.spec_decode import SpecDecodeConfig, SpecDecodeLLMEngine
+
+    return SpecDecodeLLMEngine(SpecDecodeConfig(
+        model_config=cfg, draft_model_config=cfg, max_batch_size=4, max_seq_len=128,
+        block_size=16, num_speculative_tokens=3), params=params, draft_params=params)
+
+
+@pytest.mark.parametrize("make", [
+    pytest.param(_paged, id="paged"),
+    pytest.param(lambda cfg, params: LLMEngine(LLMConfig(
+        model_config=cfg, max_batch_size=4, max_seq_len=128), params=params), id="slot"),
+    pytest.param(_spec, id="speculative"),
+])
+def test_engines_greedy_output_is_the_plain_forwards(shared_params, make):
+    """Prompts that end inside a bucket (5 of 32; 39 and 120 of 128) and on a
+    bucket's last position (32 of 32)."""
+    cfg, params = shared_params
+    eng = make(cfg, params)
+    rng = np.random.default_rng(3)
+    try:
+        for n in (5, 32, 39, 120):
+            prompt = [int(t) for t in rng.integers(1, cfg.vocab_size, n)]
+            got = eng.generate_sync(prompt, 6).token_ids
+            assert got == _plain_greedy(cfg, params, prompt, 6), f"prompt of {n}"
+    finally:
+        eng.shutdown()
+
+
+def test_whole_prompt_cached_admission_samples_from_the_recomputed_blocks_last_row(
+        shared_params):
+    """A prompt of two full blocks admitted twice: the second time all of it
+    is cached, the last block is recomputed (the span starts at 16, 16 tokens
+    live of a 32 bucket), and the first token comes from row 15 of that
+    suffix, not from the bucket's last row nor the prompt's index."""
+    from ray_tpu.util import timeline
+
+    cfg, params = shared_params
+    prompt = [int(t) for t in np.random.default_rng(4).integers(1, cfg.vocab_size, 32)]
+    want = _plain_greedy(cfg, params, prompt, 4)
+    eng = _paged(cfg, params)
+    timeline.clear()
+    try:
+        first = eng.generate_sync(prompt, 4).token_ids
+        again = eng.generate_sync(prompt, 4).token_ids
+    finally:
+        eng.shutdown()
+    assert first == want and again == want
+    admits = [e[7] for e in timeline.local_events()
+              if e[0] == "span" and e[2] == "engine" and e[3] == "admit"]
+    assert [(a["cached"], a["bucket"]) for a in admits] == [(0, 32), (16, 32)]
+
+
+def test_prefill_extract_hands_over_the_first_token_of_the_plain_forward(shared_params):
+    """`prefill_extract` reads the one row too: its first token, and what the
+    engine that attaches the pages decodes after it."""
+    cfg, params = shared_params
+    prompt = [int(t) for t in np.random.default_rng(5).integers(1, cfg.vocab_size, 37)]
+    want = _plain_greedy(cfg, params, prompt, 6)
+    prefiller, decoder = _paged(cfg, params), _paged(cfg, params)
+    try:
+        handoff = prefiller.prefill_extract(prompt)
+        assert handoff["first_token"] == want[0]
+        assert decoder.attach_sequence(handoff, 6).result(timeout=120).token_ids == want
+    finally:
+        prefiller.shutdown()
+        decoder.shutdown()
+
+
 # ------------------------------------------------- BlockPool under pressure
 def test_alloc_rollback_under_pressure_releases_evicted_cache_blocks():
     """An alloc that evicts cached-prefix blocks and STILL comes up short

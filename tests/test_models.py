@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import llama
+from ray_tpu.models import llama, model_of, moe, ouro
 from ray_tpu.parallel.mesh import make_mesh
 from ray_tpu.parallel.ring_attention import make_ring_attn_fn
 from ray_tpu.train import spmd
@@ -136,6 +136,54 @@ def test_cached_forwards_give_the_plain_forwards_logits(tiny, path, prefill, qk_
         got.append(logits)
     np.testing.assert_allclose(np.asarray(jnp.concatenate(got, axis=1)), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
+
+
+def _noisy_norms(params, key=5):
+    """Norm weights other than one, so that a row taken before the wrong norm
+    (or a norm left out) shows in the logits."""
+    noisy = lambda i, v: v * (1 + 0.2 * jax.random.normal(jax.random.PRNGKey(key + i), v.shape))
+    layers = {k: noisy(i, v) if k.endswith("_norm") else v
+              for i, (k, v) in enumerate(sorted(params["layers"].items()))}
+    return {**params, "layers": layers, "final_norm": noisy(99, params["final_norm"])}
+
+
+@pytest.mark.parametrize("cfg, path", [
+    pytest.param(llama.LlamaConfig.tiny(), "paged", id="llama"),
+    pytest.param(moe.MoEConfig.tiny(), "paged", id="moe"),
+    pytest.param(ouro.OuroConfig.tiny(), "paged", id="ouro"),
+    pytest.param(llama.LlamaConfig.tiny(), "slot", id="llama-slot"),
+])
+def test_head_rows_gives_that_row_of_the_all_positions_logits(cfg, path):
+    """`head_rows=r` is the head run on position `r[b]` of sequence b alone:
+    logits [B, 1, V] equal to row `r[b]` of the all-positions logits, another
+    row a sequence, and the same cache written. Through every family's cached
+    forward: the dense trunk, the expert MLP (`mlp=moe_mlp`) and the looped
+    trunk, whose row is taken after the LAST pass's norm."""
+    model = model_of(cfg)
+    params = _noisy_norms(model.init(cfg, jax.random.PRNGKey(0)))
+    B, S, bs = 3, 12, 4
+    vocab = getattr(cfg, "base", cfg).vocab_size
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, vocab)
+    start = jnp.asarray([0, 4, 1], jnp.int32)    # appended at another offset each
+    if path == "paged":
+        cache = model.init_kv_pool(cfg, 1 + B * 4, bs)
+        tables = 1 + jnp.arange(B * 4, dtype=jnp.int32).reshape(B, 4)
+        step = lambda **kw: model.forward_paged(params, tokens, cfg, cache, tables, start, bs, **kw)
+    else:
+        cache = model.init_kv_cache(cfg, B, 16)
+        step = lambda **kw: model.forward_with_cache(params, tokens, cfg, cache, start, **kw)
+    want, want_cache = step()
+    assert want.shape == (B, S, vocab)
+    rows = jnp.asarray([S - 1, 0, 5], jnp.int32)
+    got, got_cache = jax.jit(lambda r: step(head_rows=r))(rows)   # traced, as a step's are
+    assert got.shape == (B, 1, vocab) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got[:, 0]), np.asarray(want)[np.arange(B), rows],
+                               rtol=1e-5, atol=1e-5)
+    # the rows differ, so a row taken at the wrong place would not pass
+    assert not np.allclose(np.asarray(want)[:, S - 1], np.asarray(want)[:, 5], atol=1e-2)
+    for name in ("k", "v"):
+        np.testing.assert_allclose(np.asarray(got_cache[name]), np.asarray(want_cache[name]),
+                                   rtol=1e-5, atol=1e-6)
 
 
 def test_paged_prefill_pads_past_the_table_into_block_zero(tiny):

@@ -139,7 +139,7 @@ def test_the_engine_with_two_sequences_live_matches_the_reference(tiny):
     prefills = [l for kind, _, l in seen if kind == "prefill"]
     for slot, (prompt, res) in enumerate(zip(prompts, out)):
         assert len(res.token_ids) == 5
-        rows = [prefills[slot][len(prompt) - 1]] + [
+        rows = [prefills[slot][0]] + [  # the prefill hands back the sampled row alone
             l[slot] for kind, live, l in seen if kind == "decode" and slot in live]
         seq = prompt + res.token_ids[:-1]
         want = ouro_reference.logits(params, seq, model)[len(prompt) - 1:]

@@ -158,15 +158,22 @@ def test_paged_decode_compiles(v5e, B, Hq, Hkv, D, max_blocks, pool_blocks):
     assert "paged_attention_decode" in text
 
 
+def _placed_model(d, cfg, pool_blocks: int, bs: int):
+    """(the family's `Model`, its weights' shapes on device d, its pool's
+    shapes there, the pool's bare shapes): what a step is lowered with."""
+    model = model_of(cfg)
+    place = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
+    pool = jax.eval_shape(lambda: model.init_kv_pool(cfg, pool_blocks, bs))
+    params = jax.eval_shape(lambda: model.init(cfg, jax.random.PRNGKey(0)))
+    return model, place(params), place(pool), pool
+
+
 def _paged_step(d, cfg, *, B, S, bs=16, max_blocks=8, pool_blocks=257, donate=True):
     """One `forward_paged` step told it runs on a TPU, lowered for device d
     with the pool donated, as the paged engines' steps are (`paged_step`):
     B = 1 and S > 1 is a prefill, S = 1 the decode step. The forward, the
     pool and the weights are those of `cfg`'s family (`model_of`)."""
-    model = model_of(cfg)
-    place = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
-    params = place(jax.eval_shape(lambda: model.init(cfg, jax.random.PRNGKey(0))))
-    pool = place(jax.eval_shape(lambda: model.init_kv_pool(cfg, pool_blocks, bs)))
+    model, params, pool, _ = _placed_model(d, cfg, pool_blocks, bs)
 
     def step(params, pool, tokens, tables, lengths):
         return model.forward_paged(params, tokens, cfg, pool, tables, lengths,
@@ -273,6 +280,111 @@ def test_ouro_paged_step_at_its_published_widths(v5e, B, S):
     names = ["/loop/", "loop/norm", "attn/kv_write", "attn/kv_read"]
     for name in names + (["paged_attention_decode", MOSAIC] if S == 1 else []):
         assert name in text, name
+
+
+def _engine_step(d, cfg, name, *, B, S, max_blocks=128, pool_blocks, bs=16, **kw):
+    """(lowered, the pool's shapes): the engines' own jitted step `name`
+    (`serve/llm_paged.py::paged_step`, pool donated) told it runs on a TPU,
+    lowered for device d. A prefill (`table_first`) takes a span as its fifth
+    argument, any other step the lengths and the tables."""
+    from ray_tpu.serve.llm_paged import paged_step
+
+    _, params, pool, pool_shapes = _placed_model(d, cfg, pool_blocks, bs)
+    i32 = jnp.int32
+    rest = ((_on(d, (1, max_blocks), i32), _on(d, (2,), i32)) if kw.get("table_first")
+            else (_on(d, (B,), i32), _on(d, (B, max_blocks), i32)))
+    return (paged_step(name, cfg, bs, "tpu", **kw).lower(
+        params, pool, _on(d, (B, S), i32), *rest), pool_shapes)
+
+
+def _mistral_serve_16l():
+    """The model of `mistral-7b-v0.3-serve-16l`, from the cell's own file."""
+    import json
+    import os
+
+    from benchmarks.harness.families import llama as family
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmarks", "configs", "mistral-7b-v0.3-serve-16l.json")
+    with open(path) as f:
+        file = json.load(f)
+    return family.model_config({k: file[k] for k in family.MODEL_KEYS})
+
+
+@pytest.mark.parametrize("cfg, bucket, pool_blocks, scratch_under", [
+    pytest.param(_mistral_serve_16l(), 2048, 4097, 2 ** 29, id="mistral-16l-2048"),
+    pytest.param(dataclasses.replace(ouro.OuroConfig.ouro_2_6b(), max_seq_len=2048),
+                 256, 321, 2 ** 30, id="ouro-2.6b-256"),
+])
+def test_prefill_runs_the_head_on_the_one_row_it_hands_back(
+        v5e, cfg, bucket, pool_blocks, scratch_under):
+    """The serving cells' largest prefill, whole, as the engine builds it:
+    the head's product is `[1, H] x [H, V]`, the step returns `f32[1, V]`, and
+    NO instruction of the compiled program produces an array with both the
+    bucket and the vocabulary among its dimensions (the all-positions step
+    had the `bf16[bucket, V]` product and its `f32[bucket, V]` twin, 268 MB at
+    2,048 x 32,768, which the host then copied to read one row; PERF.md
+    section 6, PR 32). The pool stays where it is, as before. Scratch: the
+    attention's `bf16[8, 4, 2048, 2048]` probabilities (268 MB) lived in the
+    logits' output buffer and now count as scratch, so output + scratch did
+    not fall at Mistral's sizes (290.4 -> 290.7 MB)."""
+    lowered, pool = _engine_step(v5e[0], cfg, "prefill", B=1, S=bucket,
+                                 pool_blocks=pool_blocks, head="last", table_first=True)
+    H, V = cfg.hidden_size, cfg.vocab_size
+    assert re.search(rf"stablehlo\.dot_general .*tensor<1x1x{H}xbf16>, tensor<{H}x{V}xbf16>",
+                     lowered.as_text())
+    compiled = lowered.compile()
+    assert_pool_stays_in_place(compiled, pool, scratch_under=scratch_under)
+    text = compiled.as_text()
+    both = [ln.strip()[:160] for ln in text.splitlines()
+            if (m := re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]", ln))
+            and {str(bucket), str(V)} <= set(m.group(1).split(","))]
+    assert not both, both
+    assert re.search(rf"ENTRY .*-> \(f32\[1,{V}\]", text)
+    assert compiled.memory_analysis().output_size_in_bytes < (
+        2 * math.prod(pool["k"].shape) * 2 + 2 ** 20)   # the pool and one row
+
+
+def test_steps_that_read_every_row_or_none_keep_or_drop_the_head(v5e):
+    """What `paged_step(head=)` makes of the other steps, at a small size:
+    `decode` (S = 1, the only row is every row) is the all-positions program,
+    letter for letter the text of a step spelled without `head_rows`;
+    `draft_decode2` takes row 1 BEFORE the head; `draft_prefill`, whose logits
+    nobody reads, compiles to a program with no product over the vocabulary
+    at all."""
+    cfg = dataclasses.replace(
+        llama.LlamaConfig.tiny(), dtype=jnp.bfloat16, hidden_size=512,
+        intermediate_size=1024, num_heads=4, num_kv_heads=2, head_dim=128,
+        num_layers=3, vocab_size=640)
+    d, size = v5e[0], dict(max_blocks=8, pool_blocks=257)
+    decode, _ = _engine_step(d, cfg, "decode", B=8, S=1, head=0, **size)
+
+    def spelled(params, pool, tokens, lengths, tables):
+        logits, pool = llama.forward_paged(params, tokens, cfg, pool, tables, lengths,
+                                           16, platform="tpu")
+        return logits[:, 0], pool
+
+    spelled.__name__ = spelled.__qualname__ = "decode"
+    args = jax.tree.map(lambda a: _on(d, a.shape, a.dtype), decode.args_info[0],
+                        is_leaf=lambda a: hasattr(a, "shape"))
+    # the kernel's serialised body carries the source lines of who called it
+    bare = lambda lowered: re.sub(r'backend_config = "[^"]*"', "", lowered.as_text())
+    assert "tpu_custom_call" in bare(decode)
+    assert bare(decode) == bare(jax.jit(spelled, donate_argnums=(1,)).lower(*args))
+
+    two, _ = _engine_step(d, cfg, "draft_decode2", B=8, S=2, head=1, **size)
+    assert "tensor<8x1x512xbf16>, tensor<512x640xbf16>" in two.as_text()
+    assert "tensor<8x2x512xbf16>, tensor<512x640xbf16>" not in two.as_text()
+
+    none, _ = _engine_step(d, cfg, "draft_prefill", B=1, S=128, head=None,
+                           table_first=True, **size)
+    # the embedding is [640, 512]; logits, and the head's weights read, end in 640
+    wide = lambda text: [ln.strip()[:160] for ln in text.splitlines() if (m := re.match(
+        r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* ([\w-]+)\(", ln))
+        and m.group(1).split(",")[-1] == "640" and m.group(2) != "parameter"]
+    text = none.compile().as_text()
+    assert wide(two.compile().as_text()) and not wide(text), wide(text)
+    assert re.search(r"ENTRY .*-> \(bf16\[3,257,16,256\]", text)   # the pool alone comes back
 
 
 def test_pool_sized_instruction_detector_sees_a_copy(v5e):
