@@ -20,7 +20,11 @@ class Model(NamedTuple):
     # The paged engines: (params, tokens, cfg, pool, tables, lengths,
     # block_size, platform=, head_rows=) -> (logits [B, S, V], or [B, 1, V]
     # of the positions `head_rows` [B] names, pool), and (cfg, num_blocks,
-    # block_size) -> the pool it reads and writes
+    # block_size) -> the pool it reads and writes: a dict of page-shaped
+    # arrays [L, num_blocks, block_size, row], the cache (a PD hand-off moves
+    # them, whatever their names), and, if the family counts anything, one
+    # entry `counters`: {name: int32 scalar} that the last step left for the
+    # engine's records
     forward_paged: Optional[Callable] = None
     init_kv_pool: Optional[Callable] = None
     # the dense slot engine: (params, tokens, cfg, cache, lengths,

@@ -161,11 +161,13 @@ def rms_norm(x, weight, eps):
     return (out * weight).astype(x.dtype)
 
 
-def rope(x, positions, theta):
-    """Rotary embedding; x: [B, S, H, D]."""
+def rope(x, positions, theta, inv_freq=None):
+    """Rotary embedding; x: [B, S, H, D]. `inv_freq` float32 [D / 2] replaces
+    the plain `theta^(-2i/D)` (a family with scaled frequencies: kimi_k2)."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    freqs = (1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+             if inv_freq is None else inv_freq)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, half]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
@@ -220,41 +222,22 @@ def dense_mlp(y, layer):
         return (gate * (y @ layer["w_up"])) @ layer["w_down"], {}
 
 
-def plain_attend(attn_fn=None):
-    """The attention strategy that keeps no cache (training): `attn_fn`
-    (default: `auto_attention`, causal) over the whole sequence."""
-    attn_fn = attn_fn or partial(auto_attention, causal=True)
-    return lambda q, k, v, cache, index: (attn_fn(q, k, v), None)
+def gqa_attention(attend):
+    """The attention strategy of the grouped-query families, around a cache
+    strategy `attend(q, k, v, cache, index) -> (o, cache)`: the three
+    projections `wq`, `wk`, `wv` (head counts from the projected widths, so a
+    tensor-sharded stage passes its local weights), OLMoE's RMSNorm over the
+    WHOLE projected query and key vector where the layer holds `q_norm` and
+    `k_norm` (before the split into heads and before rope), rope on all of q
+    and k, and `attend` over the rotated heads q [B, S, Hq, D], k/v
+    [B, S, Hkv, D]. `cache` is the WHOLE cache, every layer's, and `index`
+    the layer's place in it: `attend` writes this layer's rows in place and
+    reads them back (`forward_paged`: pages of the pool; `forward_with_cache`:
+    slots), or keeps nothing (`plain_attend`: cache and index are None)."""
 
-
-def decoder_layer(cfg: LlamaConfig, x, layer, cache, positions, attend,
-                  mlp=dense_mlp, reduce=lambda t: t, index=None):
-    """x [B, S, H] through one pre-norm decoder block, the `index`-th of the
-    stack -> (x, the updated cache, the MLP's stats). The one spelling that
-    every family, both cached forwards and the pipeline's stage run; they
-    differ in two strategies:
-
-    - `attend(q, k, v, cache, index) -> (o, cache)`: attention over the
-      rotated heads q [B, S, Hq, D], k/v [B, S, Hkv, D]. `cache` is the WHOLE
-      cache, every layer's, and `index` the layer's place in it: the strategy
-      writes this layer's rows in place and reads them back (`forward_paged`:
-      pages of the pool; `forward_with_cache`: slots), or keeps nothing
-      (`plain_attend`: cache and index are None);
-    - `mlp(y, layer) -> (out, stats)` on the normalised activations:
-      `dense_mlp`, or `moe.moe_mlp`, whose scopes stand beside `mlp`.
-
-    Head counts come from the projected widths, so a tensor-sharded stage
-    passes its local weights and, as `reduce`, the sum over its axis of the
-    two row-sharded products. What a layer does beyond that follows from the
-    keys it holds: with `q_norm` and `k_norm` (OLMoE) it normalises the WHOLE
-    projected query and key vector, before the split into heads and before
-    rope; with `attn_out_norm` / `mlp_out_norm` (Ouro's sandwich) it
-    normalises a sub-layer's output before adding it to the residual."""
-    B, S, _ = x.shape
-    eps, hd = cfg.rms_eps, cfg.hd
-    # the scopes are names in a profile and in the HLO's op_name, no more
-    with jax.named_scope("attn"):
-        y = rms_norm(x, layer["attn_norm"], eps)
+    def attention(cfg, y, layer, cache, positions, index):
+        B, S, _ = y.shape
+        eps, hd = cfg.rms_eps, cfg.hd
         qk_norm = "q_norm" in layer
         q = y @ layer["wq"]
         if qk_norm:
@@ -267,7 +250,46 @@ def decoder_layer(cfg: LlamaConfig, x, layer, cache, positions, attend,
         v = (y @ layer["wv"]).reshape(B, S, -1, hd)
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
-        o, cache = attend(q, k, v, cache, index)
+        return attend(q, k, v, cache, index)
+
+    return attention
+
+
+def plain_attend(attn_fn=None):
+    """The attention strategy that keeps no cache (training): grouped-query
+    projections and `attn_fn` (default: `auto_attention`, causal) over the
+    whole sequence."""
+    attn_fn = attn_fn or partial(auto_attention, causal=True)
+    return gqa_attention(lambda q, k, v, cache, index: (attn_fn(q, k, v), None))
+
+
+def decoder_layer(cfg: LlamaConfig, x, layer, cache, positions, attention,
+                  mlp=dense_mlp, reduce=lambda t: t, index=None):
+    """x [B, S, H] through one pre-norm decoder block, the `index`-th of the
+    stack -> (x, the updated cache, the MLP's stats). The one spelling that
+    every family, both cached forwards and the pipeline's stage run; they
+    differ in two strategies:
+
+    - `attention(cfg, y, layer, cache, positions, index) -> (o, cache)` on
+      the normalised activations y [B, S, H]: it owns the projections, the
+      rotation and the cache, and hands back the heads' outputs o [B, S, Hq,
+      Dv] before the output projection `wo`, which is the layer's.
+      `gqa_attention(attend)` is the grouped-query families' (a cache
+      strategy inside it); `models/kimi_k2.py::latent_attention` caches one
+      latent row a token and has a prefill and an absorbed decode path;
+    - `mlp(y, layer) -> (out, stats)` on the normalised activations:
+      `dense_mlp`, or `moe.moe_mlp`, whose scopes stand beside `mlp`.
+
+    `reduce` is a tensor-sharded stage's sum over its axis of the two
+    row-sharded products. What a layer does beyond that follows from the keys
+    it holds: with `attn_out_norm` / `mlp_out_norm` (Ouro's sandwich) it
+    normalises a sub-layer's output before adding it to the residual."""
+    B, S, _ = x.shape
+    eps = cfg.rms_eps
+    # the scopes are names in a profile and in the HLO's op_name, no more
+    with jax.named_scope("attn"):
+        y = rms_norm(x, layer["attn_norm"], eps)
+        o, cache = attention(cfg, y, layer, cache, positions, index)
         o = reduce(o.reshape(B, S, -1) @ layer["wo"])
         if "attn_out_norm" in layer:
             o = rms_norm(o, layer["attn_out_norm"], eps)
@@ -304,11 +326,17 @@ def lm_head(params, x, cfg: LlamaConfig, normed: bool = False):
     return (x @ head.astype(cfg.dtype)).astype(jnp.float32)
 
 
-def decoder_trunk(params, tokens, cfg: LlamaConfig, attend, mlp=dense_mlp,
+def decoder_trunk(params, tokens, cfg: LlamaConfig, attention, mlp=dense_mlp,
                   cache=None, positions=None, head_rows=None):
     """Token ids [B, S] -> (float32 logits [B, S, V], the updated cache, the
     layers' stats stacked): embedding, `lax.scan` of `decoder_layer` over the
     layers, `lm_head`.
+
+    Where the parameters hold `lead_layers` (a family whose first layers are
+    dense ahead of its expert layers: `models/kimi_k2.py`), that shorter stack
+    runs first, a scan of its own with `dense_mlp` and the same attention
+    strategy, at the cache indices [0, its length); `layers` follows at the
+    indices after it, and the stats are the `layers` stack's.
 
     `head_rows` is which positions' logits the caller reads: None for every
     one, or int32 [B] (traced: one program whatever its values) for position
@@ -319,7 +347,7 @@ def decoder_trunk(params, tokens, cfg: LlamaConfig, attend, mlp=dense_mlp,
     other 2,047 rows of [S, V] float32 (PERF.md section 6, PR 32).
 
     The cache is never an `xs`/`ys` of the scan: it rides in the carry beside
-    x, whole (leaves [L, ...]), and `attend` gets it with the layer's index.
+    x, whole (leaves [L, ...]), and `attention` gets it with the layer's index.
     A scan that slices a layer out of a stacked cache and stacks the result
     back builds a second cache and copies every layer twice a step (PERF.md
     section 6, PR 30: 80% of a decode step's device time); a carried buffer
@@ -339,11 +367,11 @@ def decoder_trunk(params, tokens, cfg: LlamaConfig, attend, mlp=dense_mlp,
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     x = params["embed"][tokens].astype(cfg.dtype)
 
-    def body(carry, layer_and_index):
+    def body(carry, layer_and_index, mlp=mlp):
         x, cache = carry
         layer, index = layer_and_index
         x, cache, stats = decoder_layer(
-            cfg, x, layer, cache, positions, attend, mlp, index=index)
+            cfg, x, layer, cache, positions, attention, mlp, index=index)
         return (x, cache), stats
 
     cached = cache is not None
@@ -358,6 +386,20 @@ def decoder_trunk(params, tokens, cfg: LlamaConfig, attend, mlp=dense_mlp,
         if head_rows is not None:
             x = jnp.take_along_axis(x, head_rows[:, None, None], axis=1)
         return lm_head(params, x, cfg, normed)
+
+    lead = params.get("lead_layers")
+    if lead is not None:
+        if cfg.loop_steps != 1:
+            raise ValueError("leading dense layers ahead of a looped stack: no "
+                             "family has both, and the cache's indices are unsaid")
+        n_lead = jax.tree.leaves(lead)[0].shape[0]
+        lead_body = partial(body, mlp=dense_mlp)
+        with jax.named_scope("lead"):
+            (x, cache), _ = jax.lax.scan(
+                lead_body if cached else remat_body(lead_body, cfg), (x, cache),
+                (lead, jnp.arange(n_lead, dtype=jnp.int32) if cached else None))
+        (x, cache), stats = stack(x, cache, n_lead if cached else None)
+        return head(x), cache, stats
 
     if cfg.loop_steps == 1:
         (x, cache), stats = stack(x, cache)
@@ -474,6 +516,23 @@ def init_kv_pool(cfg: LlamaConfig, num_blocks: int, block_size: int) -> dict:
     }
 
 
+def page_rows(tables, lengths, S: int, block_size: int):
+    """Where S new tokens a sequence land in a paged pool: (positions,
+    blk_idx, blk_off), each [B, S]: the tokens' positions [lengths, lengths +
+    S), and the pool block and the row in it that each is written to, through
+    the block tables [B, max_blocks]."""
+    B, max_blocks = tables.shape
+    positions = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    seq_blk = positions // block_size
+    # Pad positions past the table (bucketed prefill of a near-full sequence)
+    # must scatter into the reserved garbage block 0 — jax's gather clamp
+    # would otherwise alias them onto the REAL last block and clobber it.
+    oob = seq_blk >= max_blocks
+    blk_idx = tables[jnp.arange(B)[:, None], jnp.where(oob, 0, seq_blk)]  # [B,S]
+    blk_idx = jnp.where(oob, 0, blk_idx)
+    return positions, blk_idx, positions % block_size
+
+
 def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
                   block_size: int, use_kernel: bool | None = None,
                   platform: str | None = None, mlp=dense_mlp, head_rows=None):
@@ -503,15 +562,7 @@ def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
         platform = target_platform(tokens, pool["k"])
     if use_kernel is None:
         use_kernel = S == 1 and platform == "tpu"
-    positions = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    seq_blk = positions // block_size
-    # Pad positions past the table (bucketed prefill of a near-full sequence)
-    # must scatter into the reserved garbage block 0 — jax's gather clamp
-    # would otherwise alias them onto the REAL last block and clobber it.
-    oob = seq_blk >= max_blocks
-    blk_idx = tables[jnp.arange(B)[:, None], jnp.where(oob, 0, seq_blk)]  # [B,S]
-    blk_idx = jnp.where(oob, 0, blk_idx)
-    blk_off = positions % block_size
+    positions, blk_idx, blk_off = page_rows(tables, lengths, S, block_size)
     hd, dp = cfg.hd, pool_head_dim(cfg.hd)
 
     def rows(t, dtype):  # [B, S, Hkv, D] -> the pool's rows [B, S, Hkv * Dp]
@@ -540,8 +591,8 @@ def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
                 o = _cached_attention(q, view(kp), view(vp), lengths, positions)
         return o, {"k": kp, "v": vp}
 
-    return decoder_trunk(params, tokens, cfg, attend, mlp, cache=pool,
-                         positions=positions, head_rows=head_rows)[:2]
+    return decoder_trunk(params, tokens, cfg, gqa_attention(attend), mlp,
+                         cache=pool, positions=positions, head_rows=head_rows)[:2]
 
 
 def _cached_attention(q, k_cache, v_cache, lengths, q_positions):
@@ -581,7 +632,7 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache: dict, lengths,
         o = _cached_attention(q, kc[layer], vc[layer], lengths, positions)
         return o, {"k": kc, "v": vc}
 
-    return decoder_trunk(params, tokens, cfg, attend, cache=cache,
+    return decoder_trunk(params, tokens, cfg, gqa_attention(attend), cache=cache,
                          positions=positions, head_rows=head_rows)[:2]
 
 

@@ -23,10 +23,27 @@ The auxiliary load-balancing loss is, per layer and summed over layers,
 `E * sum_e f_e * P_e` (`f_e` the share of the `T * top_k` choices that went to
 expert `e`, `P_e` the mean of `p[:, e]`), times `router_aux_coeff`.
 
-One chip holds every expert. With an `expert` mesh axis of more than one
-device the layer takes the dense path it takes off the TPU (a Mosaic kernel
-cannot be partitioned) and leaves the placement to XLA; experts spread over
-chips with an explicit all-to-all are a later four-chip issue (ROADMAP R2(c)).
+What a configuration may change of that (`models/kimi_k2.py` changes all of
+it, OLMoE and Mixtral none):
+
+- `score_func` "sigmoid": `s = sigmoid(float32(y @ router))`, the top_k are
+  chosen by `s + router_bias` (the layer's `[E]` correction bias, which
+  chooses and never weights) and weighted by `s` itself;
+- `routed_scaling`: the weights, after the renormalisation, times a constant;
+- a shared expert, where the layer holds `s_gate`, `s_up`, `s_down`: one
+  SwiGLU that every token takes, added unweighted (scope `moe/shared`);
+- `experts_held` = (first, count): this chip's SHARE of the experts. The
+  router scores and chooses over ALL `num_experts`; the layer holds the
+  weights of experts [first, first + count) alone, keeps the (token, choice)
+  pairs whose expert it holds and computes those experts' part of the sum.
+  What the absent experts would have added is left out, and that partial
+  result goes on: on one chip the layer runs without the exchange that would
+  bring the other shares in, and nothing stands in for it.
+
+With an `expert` mesh axis of more than one device the layer takes the dense
+path it takes off the TPU (a Mosaic kernel cannot be partitioned) and leaves
+the placement to XLA; experts spread over chips with an explicit all-to-all
+are a later four-chip issue (ROADMAP R2(c)).
 """
 
 from __future__ import annotations
@@ -52,6 +69,11 @@ class MoEConfig:
     norm_topk_prob: bool = False      # renormalise the top_k weights to sum to one
     qk_norm: bool = False             # RMSNorm over the whole projected q and k
     router_aux_coeff: float = 0.01
+    score_func: str = "softmax"       # or "sigmoid", chosen with the layer's `router_bias`
+    routed_scaling: float = 1.0       # the top_k weights times this
+    # (first, count): the experts whose weights this chip holds, of
+    # `num_experts` that the router chooses over; None: all of them
+    experts_held: tuple | None = None
 
     @property
     def vocab_size(self) -> int:   # what an engine asks of any configuration
@@ -163,21 +185,64 @@ def router_logits(yt, router_w):
     return jnp.dot(yt, router_w, preferred_element_type=jnp.float32)
 
 
-def moe_mlp(y, layer, cfg: MoEConfig, platform: str | None = None):
+_EXPERT_LEAVES = ("e_gate", "e_up", "e_down")
+
+
+def unstacked_experts(layers: dict) -> tuple[dict, dict]:
+    """(`layers` without the experts' leaves and with `stack_index`, the
+    experts' leaves [L, E, ...]) of a scan-stacked `layers` tree. A scan that
+    hands each layer its slice of the experts' weights makes a COPY of them a
+    layer and a step for the Pallas products to read (a custom call takes no
+    fused slice: three copies of 352 MB a layer, 30% of a decode step's device
+    time at Kimi's sizes; PERF.md section 6, PR 33). A cached forward scans
+    the first tree and closes `moe_mlp(stacked=)` over the second, which the
+    products then read in place."""
+    n = jax.tree.leaves(layers)[0].shape[0]
+    rest = {k: v for k, v in layers.items() if k not in _EXPERT_LEAVES}
+    return ({**rest, "stack_index": jnp.arange(n, dtype=jnp.int32)},
+            {k: layers[k] for k in _EXPERT_LEAVES})
+
+
+def moe_mlp(y, layer, cfg: MoEConfig, platform: str | None = None,
+            stacked: dict | None = None):
     """The expert layer on normalised activations y [B, S, H] -> ([B, S, H],
     {"aux": the layer's load-balancing term, "load": rows each expert
     received over the mean T * k / E, [E], "experts": the chosen experts
     [T, k]}). `platform` goes to `grouped_matmul` (kernel on "tpu", dense
-    otherwise; None: from the operands' placement)."""
+    otherwise; None: from the operands' placement). With `cfg.experts_held`
+    the stats are of the experts held here: "load" [count], "rows" (the pairs
+    routed to them, which is what the products are computed for) and
+    "experts"; no "aux" (the share serves). With `stacked` (`unstacked_experts`)
+    the experts' weights are EVERY layer's, [L, E, ...], and this layer's are
+    those at `layer["stack_index"]`: the products run over all L * E matrices
+    with every other layer's groups empty, so nothing is sliced out."""
     B, S, H = y.shape
     E, k, T = cfg.num_experts, cfg.top_k, B * S
     yt = y.reshape(T, H)
+    held = cfg.experts_held
     with jax.named_scope("moe/route"):
-        probs = jax.nn.softmax(router_logits(yt, layer["router"]), axis=-1)  # float32
-        top_p, top_e = jax.lax.top_k(probs, k)                   # [T, k]
+        logits = router_logits(yt, layer["router"])
+        if cfg.score_func == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)                  # float32
+            top_p, top_e = jax.lax.top_k(probs, k)                   # [T, k]
+        elif cfg.score_func == "sigmoid":
+            probs = jax.nn.sigmoid(logits)
+            top_e = jax.lax.top_k(probs + layer["router_bias"], k)[1]
+            top_p = jnp.take_along_axis(probs, top_e, axis=-1)
+        else:
+            raise ValueError(f"unknown score_func {cfg.score_func!r}")
         if cfg.norm_topk_prob:
             top_p = top_p / top_p.sum(axis=-1, keepdims=True)
+        if cfg.routed_scaling != 1.0:
+            top_p = top_p * cfg.routed_scaling
         choice_e = top_e.reshape(T * k)
+        if held is not None:
+            # a pair whose expert lives elsewhere sorts past every group held
+            # here, in no group, and weighs nothing
+            E = held[1]
+            mine = (choice_e >= held[0]) & (choice_e < held[0] + E)
+            choice_e = jnp.where(mine, choice_e - held[0], E)
+            top_p = jnp.where(mine.reshape(T, k), top_p, 0.0)
         order = jnp.argsort(choice_e, stable=True).astype(jnp.int32)
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(T * k, dtype=jnp.int32), unique_indices=True)
@@ -190,15 +255,33 @@ def moe_mlp(y, layer, cfg: MoEConfig, platform: str | None = None):
         # pass runs these three again. Saving their outputs by name was
         # measured (PERF.md section 6, PR 27): 2% faster at equal batch, but
         # it costs the memory of 3 of the 5 sequences a chip holds without.
-        gmm = partial(grouped_matmul, group_sizes=group_sizes, platform=platform)
-        hidden = jax.nn.silu(gmm(xs, layer["e_gate"])) * gmm(xs, layer["e_up"])
-        ys = gmm(hidden, layer["e_down"])                        # [T * k, H]
+        experts, sizes = layer, group_sizes
+        if stacked is not None:
+            n = stacked["e_gate"].shape[0]
+            experts = {k: v.reshape(n * E, *v.shape[2:]) for k, v in stacked.items()}
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((n * E,), jnp.int32), group_sizes, (layer["stack_index"] * E,))
+        gmm = partial(grouped_matmul, group_sizes=sizes, platform=platform)
+        hidden = jax.nn.silu(gmm(xs, experts["e_gate"])) * gmm(xs, experts["e_up"])
+        ys = gmm(hidden, experts["e_down"])                      # [T * k, H]
+        if held is not None:
+            # rows past the held pairs are in no group: `grouped_matmul`
+            # computes nothing for them and says nothing of what they hold
+            rows = group_sizes.sum()
+            ys = jnp.where(jnp.arange(T * k)[:, None] < rows, ys, 0)
     with jax.named_scope("moe/combine"):
         per_choice = _permute(ys, inverse, order).reshape(T, k, H)
         out = (per_choice * top_p[..., None].astype(y.dtype)).sum(axis=1)
+    if "s_gate" in layer:
+        with jax.named_scope("moe/shared"):
+            out = out + (jax.nn.silu(yt @ layer["s_gate"]) * (yt @ layer["s_up"])
+                         ) @ layer["s_down"]
     share = group_sizes.astype(jnp.float32) / (T * k)            # f_e
-    stats = {"aux": E * (share * probs.mean(axis=0)).sum(), "load": share * E,
-             "experts": top_e}
+    if held is not None:
+        stats = {"load": share * cfg.num_experts, "rows": rows, "experts": top_e}
+    else:
+        stats = {"aux": E * (share * probs.mean(axis=0)).sum(), "load": share * E,
+                 "experts": top_e}
     return out.reshape(B, S, H), stats
 
 
@@ -232,10 +315,14 @@ def loss_fn(params, tokens, targets, cfg: MoEConfig, attn_fn=None, mesh=None):
 
 def forward_paged(params, tokens, cfg: MoEConfig, pool, tables, lengths,
                   block_size: int, platform: str | None = None, **kw):
-    """`llama.forward_paged` with the expert layer as its MLP strategy."""
+    """`llama.forward_paged` with the expert layer as its MLP strategy, the
+    experts' weights read in place as every cached forward reads them
+    (`unstacked_experts`); the scan's slice of them is the train step's."""
+    layers, stacked = unstacked_experts(params["layers"])
     return llama.forward_paged(
-        params, tokens, cfg.base, pool, tables, lengths, block_size, platform=platform,
-        mlp=partial(moe_mlp, cfg=cfg, platform=platform), **kw)
+        {**params, "layers": layers}, tokens, cfg.base, pool, tables, lengths, block_size,
+        platform=platform,
+        mlp=partial(moe_mlp, cfg=cfg, platform=platform, stacked=stacked), **kw)
 
 
 def init_kv_pool(cfg: MoEConfig, num_blocks: int, block_size: int) -> dict:

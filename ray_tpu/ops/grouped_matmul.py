@@ -6,9 +6,15 @@ rows of `lhs` lie sorted by group, group `e` owns rows
 by its group's matrix `rhs[e]`. It is the expert layer of a sparse
 mixture-of-experts model after its tokens were sorted by expert
 (`models/moe.py`): no capacity, no padding to a fixed number of rows an
-expert, nothing dropped. `sum(group_sizes)` must be `M` (the layer's always
-is); `jax.lax.ragged_dot` has the same meaning and is the dense path off the
-TPU (`ops/platform.py` decides, as it does for attention).
+expert, nothing dropped. `sum(group_sizes)` is `M` where a layer holds every
+expert, and LESS where it holds a chip's share of them (PR 33): the rows past
+`sum(group_sizes)` are then in no group, no kernel visits a tile that holds
+only such rows, and what the result holds there is unspecified (the caller
+masks them: `moe_mlp`); a pass costs the live rows' tiles, not `M`'s. The
+backward kernels are held to `sum(group_sizes) == M` (no caller trains a
+share). `jax.lax.ragged_dot` has the same meaning, leaves those rows zero, and
+is the dense path off the TPU (`ops/platform.py` decides, as it does for
+attention).
 
 A `jax.custom_vjp`: d lhs is the same product against `rhs` transposed
 (contracted in the kernel, no transposed copy), d rhs is per group
@@ -295,7 +301,9 @@ def grouped_matmul(lhs, rhs, group_sizes, *, platform: str | None = None,
                    tiles: tuple[int, int, int] | None = None,
                    interpret: bool = False):
     """lhs [M, K] (rows sorted by group), rhs [E, K, N], group_sizes [E]
-    (whole numbers that sum to M) -> [M, N]. Differentiable in lhs and rhs.
+    (whole numbers that sum to M, or to less: the rows past the sum are in no
+    group and their result is unspecified) -> [M, N]. Differentiable in lhs
+    and rhs where the sizes sum to M.
 
     The Pallas kernels where the computation is placed on a TPU, or anywhere
     with `interpret=True` (the tests); `jax.lax.ragged_dot` otherwise.

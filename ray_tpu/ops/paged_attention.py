@@ -272,3 +272,154 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, *, layer,
     out = _decode_call(q4, k_pool, v_pool, tables, lengths, layer, pages=pages,
                        scale=1.0 / math.sqrt(D), interpret=interpret)
     return out[:, :, :g, :D].reshape(B, Hq, D).astype(q.dtype)
+
+
+# ------------------------------------------------------------ latent (MLA)
+#
+# Absorbed decode over a LATENT pool (`models/kimi_k2.py`): a token caches one
+# row `[c_kv (rank) | k_rope | zeros]` of `row` lanes (576 values in 640 at
+# the published sizes) and nothing per head. With the key up-projection
+# absorbed into the query, every query head attends over ONE shared "KV head"
+# of width `row` whose VALUES are the first `rank` lanes of its keys: one copy
+# a page where the kernel above makes two, and two products a group,
+# `[Hq, row] x [row, tokens]` and `[Hq, tokens] x [tokens, rank]`. Same walk:
+# one grid step a sequence, live pages only, many pages a group, double
+# buffered, operands as stored, float32 statistics.
+
+# Tokens a group. On a v5e at the latent cell's shape (64 sequences, 98,441
+# live tokens, rows of 640 bfloat16 lanes, 64 heads; microseconds a call, the
+# least of five timings of 20 x 8 calls, where the rows' bytes need 154):
+# 128: 575, 256: 443, 512: 378, 1,024: 382, 2,048 (the whole table one group,
+# 5.2 MB of buffers): 355. 512 is the knee (PERF.md section 6, PR 33).
+LATENT_GROUP_TOKENS = 512
+
+
+def latent_pages_per_group(block_size: int, max_blocks: int) -> int:
+    pages = 1
+    while 2 * pages * block_size <= LATENT_GROUP_TOKENS:
+        pages *= 2
+    return min(pages, max_blocks)
+
+
+def _latent_kernel(tables_ref, lens_ref, layer_ref,   # scalar-prefetch (SMEM)
+                   q_ref,                      # [1, Hp, row] block
+                   lat_hbm,                    # the whole pool [L, NB, BS, row], in HBM
+                   o_ref,                      # [1, Hp, rank] block
+                   buf, sems, *,               # [2, group, row], DMA (2,)
+                   pages: int, block_size: int, max_blocks: int, scale: float):
+    """Grid (B,): streaming softmax over the live page groups of sequence b."""
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+    seq_len = lens_ref[b]
+    n_pages = pl.cdiv(seq_len, block_size)
+    n_groups = pl.cdiv(n_pages, pages)
+    group = pages * block_size
+    rank = o_ref.shape[2]
+
+    def is_live(gi, j):
+        return gi * pages + j < n_pages
+
+    def page_copy(gi, slot, j):
+        page = tables_ref[b * max_blocks + gi * pages + j]
+        return pltpu.make_async_copy(
+            lat_hbm.at[layer, page],
+            buf.at[slot, pl.ds(j * block_size, block_size)], sems.at[slot])
+
+    def start(gi, slot):
+        for j in range(pages):
+            @pl.when(is_live(gi, j))
+            def _copy():
+                page_copy(gi, slot, j).start()
+
+            # a page past the end is not copied: its rows are values too, and
+            # a probability of 0 times stale bytes could be NaN
+            @pl.when(jnp.logical_not(is_live(gi, j)))
+            def _zero():
+                buf[slot, pl.ds(j * block_size, block_size)] = jnp.zeros(
+                    (block_size, buf.shape[2]), buf.dtype)
+
+    def wait(gi, slot):
+        for j in range(pages):
+            @pl.when(is_live(gi, j))
+            def _arrived():
+                page_copy(gi, slot, j).wait()
+
+    @pl.when(n_groups > 0)
+    def _first():
+        start(0, 0)
+
+    q = q_ref[0]                                           # [Hp, row]
+
+    def step(gi, carry):
+        m_prev, l_prev, acc = carry
+        slot = gi % 2
+
+        @pl.when(gi + 1 < n_groups)
+        def _next():
+            start(gi + 1, 1 - slot)
+
+        wait(gi, slot)
+        rows = buf[slot]                                   # [group, row]
+        s = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        kpos = gi * group + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kpos < seq_len, s, NEG_INF)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + p.sum(axis=1, keepdims=True)
+        acc = acc * corr + jnp.dot(p.astype(rows.dtype), rows[:, :rank],
+                                   preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    hp = q.shape[0]
+    _, l, acc = jax.lax.fori_loop(
+        0, n_groups, step,
+        (jnp.full((hp, 1), NEG_INF, jnp.float32), jnp.zeros((hp, 1), jnp.float32),
+         jnp.zeros((hp, rank), jnp.float32)))
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def latent_decode_attention(q, pool, tables, lengths, *, layer, rank: int,
+                            scale: float, interpret: bool | None = None):
+    """Absorbed latent decode. q [B, Hq, row]: a head's absorbed query
+    `[q_nope W_uk^T (rank) | q_rope | zeros]`, laid out as the pool's rows;
+    pool [L, NB, BS, row], the whole latent pool, of which layer `layer` (an
+    int or a traced scalar) is read; tables [B, max_blocks]; lengths [B] =
+    valid tokens (incl. the one being decoded). Returns the heads' outputs in
+    the latent space, [B, Hq, rank] = softmax(scale * q rows^T) rows[:, :rank];
+    the caller up-projects them (`W_uv`). `row` and `rank` are whole 128-lane
+    tiles. `interpret=None` compiles the kernel when the inputs are placed on
+    a TPU and interprets it anywhere else."""
+    if interpret is None:
+        interpret = target_platform(q, pool) != "tpu"
+    B, Hq, row = q.shape
+    BS, max_blocks = pool.shape[2], tables.shape[1]
+    if row != pool.shape[3] or row % LANES or rank % LANES:
+        raise ValueError(f"latent rows of {row} lanes (pool {pool.shape[3]}), rank "
+                         f"{rank}: both must be whole 128-lane tiles")
+    hp = -(-Hq // 8) * 8
+    qp = jnp.pad(q.astype(pool.dtype), [(0, 0), (0, hp - Hq), (0, 0)])
+    pages = latent_pages_per_group(BS, max_blocks)
+    kernel = functools.partial(_latent_kernel, pages=pages, block_size=BS,
+                               max_blocks=max_blocks, scale=scale)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, hp, row), lambda b, *prefetch: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, hp, rank), lambda b, *prefetch: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, pages * BS, row), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, hp, rank), q.dtype),
+        interpret=interpret,
+        name="latent_attention_decode",
+        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel",))}),
+    )(tables.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32), qp, pool)
+    return out[:, :Hq]

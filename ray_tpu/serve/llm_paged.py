@@ -103,6 +103,25 @@ def paged_step(name: str, cfg, block_size: int, platform: str,
     return jax.jit(step, donate_argnums=(1,))
 
 
+def page_leaves(pool: dict) -> dict:
+    """The cache of a family's pool (`Model.init_kv_pool`): every entry but
+    `counters`, each `[L, num_blocks, block_size, row]`, pages on the second
+    axis. They are what a PD hand-off moves, whatever the family names them
+    (`k` and `v`; a latent family's one `latent`)."""
+    return {name: leaf for name, leaf in pool.items() if name != "counters"}
+
+
+def pool_counters(pool: dict) -> dict:
+    """{name: int} of the pool's `counters` entry: what the family's last step
+    counted (`kimi_k2`: `moe_rows`), for the engine's records. Empty, and
+    nothing is read from the device, for a pool that has none."""
+    return {name: int(v) for name, v in pool.get("counters", {}).items()}
+
+
+def _n_pages(kv: dict) -> int:
+    return next(iter(kv.values())).shape[1]
+
+
 class PagedLLMEngine(LLMEngine):
     """Continuous batching over a paged KV pool with prefix caching."""
 
@@ -189,7 +208,7 @@ class PagedLLMEngine(LLMEngine):
     def kv_memory_bytes(self) -> int:
         """Persistent KV pool footprint (the headroom metric vs dense): the
         pool as allocated, a head under 128 lanes in its 128-wide tile."""
-        return sum(leaf.nbytes for leaf in self.pool.values())
+        return sum(leaf.nbytes for leaf in page_leaves(self.pool).values())
 
     # ---- engine loop ----
     def _admit_one(self, prompt, max_new, fut, t_enq, tq, slot) -> bool:
@@ -250,6 +269,7 @@ class PagedLLMEngine(LLMEngine):
                 clock.mark("copy")  # [1, vocab] float32 to the host
                 logits_np = np.asarray(logits)
                 info["logits_bytes"] = logits_np.nbytes
+                info.update(pool_counters(self.pool))
                 clock.mark("sample")
                 tok = self._sample(logits_np[0])
             except Exception as e:  # noqa: BLE001 - bad request: fail, keep serving
@@ -339,10 +359,12 @@ class PagedLLMEngine(LLMEngine):
         # 1% of a step with a 4,097-block pool of cached prompts (PERF.md
         # section 6, PR 31)
         blocks = self.allocator.in_use
+        counters: dict = {}
         try:
             yield clock
+            counters = pool_counters(self.pool)   # what the step itself counted
         finally:
-            clock.close(live=live, ctx=ctx, blocks=blocks,
+            clock.close(live=live, ctx=ctx, blocks=blocks, **counters,
                         compile_s=compile_totals()[1] - compile_s0)
 
     def _step_decode(self) -> bool:
@@ -423,14 +445,15 @@ class PagedLLMEngine(LLMEngine):
             first_tok = self._sample(np.asarray(logits)[0])
             idx = np.asarray(block_ids, dtype=np.int32)
             kv = kv_ticket = kv_ref = None
+            # the pool's own pages, leaf by leaf: [L, n, bs, row] each
+            pages = {name: leaf[:, idx] for name, leaf in page_leaves(self.pool).items()}
             if self.config.kv_transfer == "device":
                 # the gather creates independent device arrays (pool blocks
                 # free below); only a tiny ticket crosses the control plane —
                 # the decode side pulls the pages device->device
                 from ray_tpu.experimental import rdt
 
-                kv_ticket = rdt.offer_device(
-                    {"k": self.pool["k"][:, idx], "v": self.pool["v"][:, idx]})
+                kv_ticket = rdt.offer_device(pages)
             elif self.config.kv_transfer == "plane":
                 # publish the gathered pages as one sealed plane entry
                 # (written once into the transport store's mapped slot); the
@@ -441,15 +464,14 @@ class PagedLLMEngine(LLMEngine):
                     raise RuntimeError(
                         "kv_transfer='plane' requires engine.kv_publish to "
                         "be bound to a KVTransport.publish")
-                kv_ref = self.kv_publish(
-                    np.asarray(self.pool["k"][:, idx]),
-                    np.asarray(self.pool["v"][:, idx]))
+                if set(pages) != {"k", "v"}:
+                    raise RuntimeError(
+                        f"kv_transfer='plane' ships one K and one V entry "
+                        f"(serve/kv_transport.py); this family's pool has the "
+                        f"page leaves {sorted(pages)}: use 'host' or 'device'")
+                kv_ref = self.kv_publish(np.asarray(pages["k"]), np.asarray(pages["v"]))
             else:
-                # the pool's own rows (`Model.init_kv_pool`): [L, n, bs, Hkv * Dp]
-                kv = {
-                    "k": np.asarray(self.pool["k"][:, idx]),
-                    "v": np.asarray(self.pool["v"][:, idx]),
-                }
+                kv = {name: np.asarray(leaf) for name, leaf in pages.items()}
         finally:
             self.allocator.free(block_ids)
         return {
@@ -509,9 +531,9 @@ class PagedLLMEngine(LLMEngine):
                     "bound to a KVTransport.pull")
             kv, ack = self.kv_pull(handoff["kv_ref"])
             expect = handoff.get("n_prefill_blocks")
-            if expect is not None and kv["k"].shape[1] != expect:
+            if expect is not None and _n_pages(kv) != expect:
                 raise ValueError(
-                    f"KV handoff shape mismatch: pulled {kv['k'].shape[1]} "
+                    f"KV handoff shape mismatch: pulled {_n_pages(kv)} "
                     f"blocks, handoff says {expect}")
         if kv is None and handoff.get("kv_ticket") is not None:
             # device path: pull the pages straight into THIS process's
@@ -525,11 +547,15 @@ class PagedLLMEngine(LLMEngine):
 
             kv = rdt.pull_device(handoff["kv_ticket"])
             expect = handoff.get("n_prefill_blocks")
-            if expect is not None and kv["k"].shape[1] != expect:
+            if expect is not None and _n_pages(kv) != expect:
                 raise ValueError(
-                    f"KV ticket shape mismatch: pulled {kv['k'].shape[1]} "
+                    f"KV ticket shape mismatch: pulled {_n_pages(kv)} "
                     f"blocks, handoff says {expect}")
-        n_prefill_blocks = kv["k"].shape[1]   # the payload is [L, n, bs, Hkv * Dp]
+        if set(kv) != set(page_leaves(self.pool)):
+            raise ValueError(
+                f"KV handoff carries the leaves {sorted(kv)}; this engine's pool "
+                f"has {sorted(page_leaves(self.pool))}")
+        n_prefill_blocks = _n_pages(kv)   # every leaf of the payload is [L, n, bs, row]
         table = handoff.get("block_table")
         if table is not None and len(table) != n_prefill_blocks:
             # descriptor-vs-payload consistency: the block table is the
@@ -543,8 +569,8 @@ class PagedLLMEngine(LLMEngine):
         block_ids = self.allocator.alloc(total_blocks)
         try:
             idx = np.asarray(block_ids[:n_prefill_blocks], dtype=np.int32)
-            self.pool["k"] = self.pool["k"].at[:, idx].set(jnp.asarray(kv["k"]))
-            self.pool["v"] = self.pool["v"].at[:, idx].set(jnp.asarray(kv["v"]))
+            for name, leaf in kv.items():
+                self.pool[name] = self.pool[name].at[:, idx].set(jnp.asarray(leaf))
             with self._lock:
                 st = _Slot(fut, max_new_tokens, prompt_len, time.monotonic())
                 st.generated.append(handoff["first_token"])

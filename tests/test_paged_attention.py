@@ -200,11 +200,13 @@ def _families():
     tiny = llama.LlamaConfig.tiny()
     olmoe = moe.MoEConfig(base=tiny, num_experts=4, top_k=2, qk_norm=True)
     return {
-        "llama": (tiny, lambda key: llama.init(tiny, key), llama.dense_mlp,
+        "llama": (tiny, lambda key: llama.init(tiny, key),
+                  functools.partial(llama.forward_paged, cfg=tiny),
                   lambda params, tokens: llama.forward(params, tokens, tiny)),
-        # OLMoE's block: QK-norm over the whole projected vector, the expert layer
+        # OLMoE's block: QK-norm over the whole projected vector, the expert
+        # layer with its weights read in place (`moe.unstacked_experts`)
         "olmoe": (tiny, lambda key: moe.init(olmoe, key),
-                  functools.partial(moe.moe_mlp, cfg=olmoe),
+                  functools.partial(moe.forward_paged, cfg=olmoe),
                   lambda params, tokens: moe.forward(params, tokens, olmoe)[0]),
     }
 
@@ -217,7 +219,7 @@ def test_prefill_then_decode_steps_give_the_cacheless_logits(family, use_kernel)
     the family's cache-less forward over the same tokens. The pool is the
     carried, token-major one, 64-wide heads in 128-wide tiles, its pages out
     of order, rows of unequal length."""
-    cfg, init, mlp, plain = _families()[family]
+    cfg, init, forward_paged, plain = _families()[family]
     params = init(jax.random.PRNGKey(0))
     B, S, bs, mb, prefill = 2, 14, 4, 4, 9
     tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)
@@ -226,9 +228,9 @@ def test_prefill_then_decode_steps_give_the_cacheless_logits(family, use_kernel)
     assert pool["k"].shape == (cfg.num_layers, 1 + B * mb, bs, cfg.num_kv_heads * 128)
     tables = jnp.asarray(np.random.default_rng(0).permutation(
         np.arange(1, 1 + B * mb)).reshape(B, mb), jnp.int32)
-    step = jax.jit(lambda toks, pool, lengths, kernel: llama.forward_paged(
-        params, toks, cfg, pool, tables, lengths, bs, use_kernel=kernel, mlp=mlp),
-        static_argnums=3)
+    step = jax.jit(lambda toks, pool, lengths, kernel: forward_paged(
+        params, toks, pool=pool, tables=tables, lengths=lengths, block_size=bs,
+        use_kernel=kernel), static_argnums=3)
     got = []
     for start, stop in [(0, prefill)] + [(i, i + 1) for i in range(prefill, S)]:
         logits, pool = step(tokens[:, start:stop], pool, jnp.full((B,), start, jnp.int32),

@@ -215,19 +215,32 @@ def pool_sized_instructions(text: str, pool_shape: tuple) -> list[tuple[str, str
     return out
 
 
-def assert_pool_stays_in_place(compiled, pool, scratch_under: int | None = None) -> None:
-    """What ISSUE 30 holds a paged step to. `pool` is the K/V pool's shapes;
-    `scratch_under` bounds the step's scratch (default: one layer's pages)."""
+def assert_pool_stays_in_place(compiled, pool, scratch_under: int | None = None,
+                               alloc_under: int = 0) -> None:
+    """What ISSUE 30 holds a paged step to. `pool` is the pool's shapes (its
+    pages are alike: K and V, or a latent family's one leaf); `scratch_under`
+    bounds the step's scratch (default: one layer's pages). No buffer is
+    allocated inside the program, but those a caller names with `alloc_under`,
+    the bytes every one of them stays under."""
     text = compiled.as_text()
-    layer_bytes = math.prod(pool["k"].shape[1:]) * pool["k"].dtype.itemsize
-    # the donation is honoured: both pool leaves alias an output
+    pages = [leaf for name, leaf in pool.items() if name != "counters"]
+    page = pages[0]
+    layer_bytes = math.prod(page.shape[1:]) * page.dtype.itemsize
+    # the donation is honoured: every page-shaped leaf aliases an output
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
-    assert aliases and len(re.findall(r"(may|must)-alias", aliases.group(1))) >= 2
-    assert "AllocateBuffer" not in text
+    assert aliases and len(re.findall(r"(may|must)-alias", aliases.group(1))) >= len(pages)
+    if not alloc_under:
+        assert "AllocateBuffer" not in text
+    for ln in text.splitlines():
+        if "AllocateBuffer" in ln:
+            dtype, dims = re.search(r"= (\w+)\[([\d,]*)\]", ln).groups()
+            elems = math.prod(map(int, filter(None, dims.split(","))))
+            width = int(re.sub(r"\D", "", dtype) or 8) // 8   # bf16: 2, s32: 4
+            assert elems * width < alloc_under, ln[:200]
     # parameters, tuple elements and bitcasts move nothing; every other
     # producer of a pool is a write into it, and the only one a step has is
     # the row scatter of the model's own `kv_write`
-    moved = [(op, ln[:200]) for op, ln in pool_sized_instructions(text, pool["k"].shape)
+    moved = [(op, ln[:200]) for op, ln in pool_sized_instructions(text, page.shape)
              if op not in ("parameter", "get-tuple-element", "bitcast", "scatter")]
     assert not moved, moved
     # nothing the size of a layer's pages is scratch either
@@ -280,6 +293,66 @@ def test_ouro_paged_step_at_its_published_widths(v5e, B, S):
     names = ["/loop/", "loop/norm", "attn/kv_write", "attn/kv_read"]
     for name in names + (["paged_attention_decode", MOSAIC] if S == 1 else []):
         assert name in text, name
+
+
+def _kimi_serve_ep32():
+    """The model of `kimi-k2.7-code-serve-ep32-1chip`, from the cell's own
+    file, and the file's engine section."""
+    import json
+    import os
+
+    from benchmarks.harness.families import kimi_k2 as family
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmarks", "configs", "kimi-k2.7-code-serve-ep32-1chip.json")
+    with open(path) as f:
+        file = json.load(f)
+    return family.model_config({k: file[k] for k in family.MODEL_KEYS}), file["engine"]
+
+
+@pytest.mark.parametrize("name, B, S, kw, scratch_under, alloc_under", [
+    # the expert layers' sort allocates its words of scratch: s32[85] and s32[7]
+    ("decode", 64, 1, dict(head=0), 0.5e9, 512),
+    # and the prefill's map over 4 chunks of 16 heads its stacked output,
+    # bf16[4, 1, 2048, 16, 128]: ONE attention output of 33.6 MB, a fifth of
+    # a layer's pages (167.8 MB)
+    ("prefill", 1, 2048, dict(head="last", table_first=True), 1.0e9,
+     2048 * 64 * 128 * 2 + 1),
+], ids=["decode-64", "prefill-2048"])
+def test_kimi_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw, scratch_under,
+                                                           alloc_under):
+    """`serve-kimi-longin-batch`'s two largest programs as the engine builds
+    them, at Kimi-K2.7-Code's published widths and the configuration's cut (1
+    dense + 7 expert layers, 12 of 384 experts, 20,480 vocabulary rows): 64
+    slots, a 128-block table, the latent pool `bf16[8, 8193, 16, 640]` (1.34
+    GB) donated beside 11.05 GB of weights. They compile for a v5e (an
+    out-of-HBM or Mosaic refusal fails here, not on the chip); the pool,
+    carried through BOTH scans (the leading dense stack, the expert stack),
+    stays in place: the only pool-shaped instructions are the `latent_write`
+    scatters; the text names the scopes a profile is read by, and at decode
+    the latent kernel. The compiler's bytes are what the configuration
+    file's depth rule is decided by (`num_blocks_note`)."""
+    cfg, engine = _kimi_serve_ep32()
+    assert (engine["max_batch_size"], engine["num_blocks"], engine["block_size"]) == (64, 8193, 16)
+    assert 2048 in engine["prefill_buckets"]
+    lowered, pool = _engine_step(v5e[0], cfg, name, B=B, S=S,
+                                 pool_blocks=engine["num_blocks"], **kw)
+    compiled = lowered.compile()
+    assert pool["latent"].shape == (8, 8193, 16, 640) and pool["counters"]["moe_rows"].shape == ()
+    assert_pool_stays_in_place(compiled, pool, scratch_under=int(scratch_under),
+                               alloc_under=alloc_under)
+    ma = compiled.memory_analysis()
+    # weights 11.05 GB + pool 1.34 GB, and the step's scratch: under the 15.0
+    # GB of the depth rule with the room a run's reference needs beside it
+    assert 12.3e9 < ma.argument_size_in_bytes < 12.5e9
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 13.5e9
+    text = compiled.as_text()
+    names = ["lead/", "attn/latent_write", "attn/latent_read", "moe/route", "moe/dispatch",
+             "moe/experts", "moe/combine", "moe/shared", "grouped_matmul_fwd"]
+    for scope in names + (["attn/absorb", "latent_attention_decode"] if S == 1 else []):
+        assert scope in text, scope
+    assert ("latent_attention_decode" in text) == (S == 1)
+    assert "paged_attention_decode" not in text
 
 
 def _engine_step(d, cfg, name, *, B, S, max_blocks=128, pool_blocks, bs=16, **kw):
