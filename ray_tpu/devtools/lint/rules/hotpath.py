@@ -152,14 +152,14 @@ HOT_PATHS = (
     # creep into the step: no instrument lookup, no RPC, no task submission.
     HotPath(
         file="ray_tpu/serve/llm_paged.py",
-        funcs=("_step_decode", "_admit_one", "_decode_clock"),
+        funcs=("_step_decode", "_enqueue", "_emit", "_admit_one", "_decode_clock"),
         reason="per-step decode loop and per-request admission; the "
                "first-token stamp and the phase record are ring appends",
         ban_rpc=True,
         ban_submit=True,
         require_calls=(
-            ("_step_decode", ("stamp",),
-             "_step_decode no longer stamps decode_first_token — PD "
+            ("_emit", ("stamp",),
+             "_emit no longer stamps decode_first_token — PD "
              "ledgers lose the first-token phase and TTFT degrades to "
              "completion time"),
             ("_step_decode", ("_decode_clock",),

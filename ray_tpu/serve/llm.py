@@ -97,7 +97,8 @@ class LLMEngine:
         self._pending: "queue.Queue[tuple[list[int], int, Future, float]]" = queue.Queue()
         self._lock = threading.Lock()
         self._running = True
-        self._sample_key = key
+        self._sample_key = key  # the paged engine's `pick` draws from it on the device
+        self._rng = np.random.default_rng(seed)  # `_sample`'s, the engine's own
         self._init_backend()  # subclass hook: cache/pool + jitted programs
         # external_step: no internal loop thread — a coordinator drives the
         # engine via step_once() (DP-attention rank lockstep, dp_attention.py)
@@ -252,7 +253,7 @@ class LLMEngine:
         z = logits_np / self.config.temperature
         z = z - z.max()
         p = np.exp(z) / np.exp(z).sum()
-        return int(np.random.choice(len(p), p=p))
+        return int(self._rng.choice(len(p), p=p))
 
     def _loop(self) -> None:
         while self._running:

@@ -29,7 +29,7 @@ import queue
 import time
 from concurrent.futures import Future
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -44,6 +44,14 @@ from ray_tpu.util.timeline import PhaseClock
 # is `<phase>_s` in the record and `engine:<record>.<phase>` in a profile
 _ADMIT_PHASES = ("alloc", "prefill", "wait", "copy", "sample")
 _DECODE_PHASES = ("dispatch", "wait", "copy", "sample", "finish")
+
+
+class _Flight(NamedTuple):
+    """A decode step on the device's queue whose ids the host has not read."""
+
+    ids: object      # [B, 1] int32 on the device: what `pick` chose, the next step's tokens
+    rows: dict       # row -> the `_Slot` that was live in it when the step was enqueued
+    counters: dict   # the pool's `counters` as that step left them, copied on the device
 
 
 @dataclasses.dataclass
@@ -101,6 +109,44 @@ def paged_step(name: str, cfg, block_size: int, platform: str,
 
     step.__name__ = step.__qualname__ = name
     return jax.jit(step, donate_argnums=(1,))
+
+
+def pick_step(temperature: float):
+    """The jitted step `pick`: a decode step's `[B, vocab]` float32 logits ->
+    the `[B, 1]` int32 ids the next step takes as its tokens, chosen on the
+    device so that nothing of the vocabulary crosses to the host. Called as
+    (logits, counters, key) -> (ids, counters, key). At temperature 0 the
+    argmax, first index on ties as `np.argmax`; otherwise one draw a row from
+    softmax(logits / temperature) with a key split off `key`, which comes
+    back advanced. `counters` (the pool's, `{}` for a family that counts
+    nothing) come back as copies: the pool is donated to the next step, and
+    the host reads what this step counted only after that one is enqueued."""
+    import jax
+    import jax.numpy as jnp
+
+    def pick(logits, counters, key):
+        if temperature <= 0:
+            ids = jnp.argmax(logits, axis=-1)
+        else:
+            key, sub = jax.random.split(key)
+            ids = jax.random.categorical(sub, logits / temperature, axis=-1)
+        return ids.astype(jnp.int32)[:, None], jax.tree.map(jnp.copy, counters), key
+
+    return jax.jit(pick)
+
+
+def carry_step():
+    """The jitted step `carry`: the tokens of the step about to be enqueued,
+    (ids, host) -> [B, 1] int32. `ids` are the step in flight's own, still
+    unread; `host` holds the first token of a row admitted since that step was
+    enqueued and -1 in every other row."""
+    import jax
+    import jax.numpy as jnp
+
+    def carry(ids, host):
+        return jnp.where(host >= 0, host, ids)
+
+    return jax.jit(carry)
 
 
 def page_leaves(pool: dict) -> dict:
@@ -161,6 +207,11 @@ class PagedLLMEngine(LLMEngine):
         # prefill: a B=1 row, the logits of the suffix's last live position
         self._prefill = step("prefill", head="last", table_first=True)
         self._decode = step("decode", head=0)
+        self._pick = pick_step(self.config.temperature)
+        self._carry = carry_step()
+        # the decode step enqueued and not yet read (`_step_decode`); the
+        # engine thread's alone
+        self._flight: Optional[_Flight] = None
 
     def dummy_decode(self) -> None:
         """Cadence-keeping round for DP-attention lockstep (dp_attention.py):
@@ -178,7 +229,20 @@ class PagedLLMEngine(LLMEngine):
         """Free blocks AND zero the slot's rows: the batched decode scatters
         every row each step, so a stale table/length would keep writing into
         blocks after they're reallocated to other sequences (silent KV
-        corruption). Zeroed rows write into reserved garbage block 0."""
+        corruption). Zeroed rows write into reserved garbage block 0.
+
+        That holds for every step enqueued from now on. A step IN FLIGHT
+        (`_step_decode` reads one step behind) was enqueued with this slot's
+        table and length and still writes ONE row: the KV of the token it was
+        given, at a position at or past the prompt's end, so never in a block
+        the prefix cache holds by content (those are whole blocks of prompt).
+        It runs before any program enqueued later (one queue, and every step
+        takes the pool the one before returned), and whoever is given the
+        block next writes a position before it reads it (a prefill every
+        position from its suffix's start, a decode step its own position), so
+        the stale row is overwritten or never read. The id that step chose for
+        this row is dropped when it is read: the row's slot is no longer the
+        one it was enqueued for."""
         super()._release_slot(i)
         self._anatomy_pending.pop(i, None)
         self.tables[i] = 0
@@ -196,6 +260,12 @@ class PagedLLMEngine(LLMEngine):
 
     def shutdown(self) -> None:
         super().shutdown()  # stops the loop + fails active slots
+        # a step the loop left in flight: its slots are failed, so its ids go
+        # unread, but the device is not left with work behind the exit
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            with contextlib.suppress(Exception):
+                flight.ids.block_until_ready()
         # drain queued PD ops so their callers fail fast instead of timing out
         while True:
             try:
@@ -333,7 +403,8 @@ class PagedLLMEngine(LLMEngine):
         once a pass, then the pass ends), so admission is in order of arrival
         and a large request is not overtaken forever by smaller ones."""
         did_work = False
-        free = [i for i in range(self.config.max_batch_size) if not self.active[i]]
+        # a row whose last token is still in flight is occupied, not active
+        free = [i for i in range(self.config.max_batch_size) if self.slots[i] is None]
         while free and not self._pending.empty():
             try:
                 req = self._pending.get_nowait()
@@ -350,7 +421,9 @@ class PagedLLMEngine(LLMEngine):
     @contextlib.contextmanager
     def _decode_clock(self, phases: tuple):
         """One engine/decode timeline record for the step run inside it
-        (PERF.md section 3); the caller marks the phases after the first."""
+        (PERF.md section 3); the caller marks the phases after the first and
+        notes what else the step knows. `live` and `ctx` are the active rows',
+        the ones the step enqueues, taken before it advances a length."""
         clock = PhaseClock("engine", "decode", phases)
         compile_s0 = compile_totals()[1]
         live = int(self.active.sum())
@@ -359,52 +432,129 @@ class PagedLLMEngine(LLMEngine):
         # 1% of a step with a 4,097-block pool of cached prompts (PERF.md
         # section 6, PR 31)
         blocks = self.allocator.in_use
-        counters: dict = {}
         try:
             yield clock
-            counters = pool_counters(self.pool)   # what the step itself counted
         finally:
-            clock.close(live=live, ctx=ctx, blocks=blocks, **counters,
+            clock.close(live=live, ctx=ctx, blocks=blocks,
                         compile_s=compile_totals()[1] - compile_s0)
 
     def _step_decode(self) -> bool:
-        jnp = self._jnp
-        if not self.active.any():
+        """One pass of the decode loop, one step BEHIND itself: step k+1 is
+        enqueued, its tokens step k's ids still on the device (`pick` chose
+        them there), and only then does the host wait for step k's ids, hand
+        them to the streams and run the finish checks, while the device runs
+        step k+1. A pass is one `decode` record: `dispatch` (step k+1; `live`,
+        `ctx`, `ahead` are its), then `wait`, `copy`, `sample`, `finish` (step
+        k; `late_rows` and the pool's counters are its).
+
+        What the lag changes, and why each is safe. A length does not depend
+        on what was sampled, so it advances when a step is ENQUEUED. A
+        sequence that ends by count is known to before its last step is read:
+        `_enqueue` takes its row out of `active` with that step, so no step is
+        ever enqueued for a token nobody asked for, and the row stays occupied
+        (`slots[i]`) until the token is read. One that ends on `eos_token_id`
+        is found a step late, and so is a `cancel_future` from another thread:
+        the step in flight still runs that row, writes one stale KV row
+        (`_release_slot` says why nothing reads it) and chooses an id that is
+        dropped and counted, `late_rows`, because an id goes only to the
+        `_Slot` object its step was enqueued for. When the step just enqueued
+        was the last of every live sequence, or nothing is left to enqueue, no
+        step follows to be read behind: the step in flight is read in this
+        same pass, so a `step_once()` driver and `generate_sync` see every
+        token. The host's `self.last_tokens` stays what was EMITTED."""
+        before = self._flight
+        if before is None and not self.active.any():
             return False
-        with self._decode_clock(_DECODE_PHASES) as clock:
-            # dispatch: three small uploads and the call, which returns when
-            # the step is enqueued
-            logits, self.pool = self._decode(
-                self.params, self.pool, jnp.asarray(self.last_tokens),
-                jnp.asarray(self.lengths), jnp.asarray(self.tables),
-            )
-            clock.mark("wait")  # the device's step; np.asarray would wait too
-            logits.block_until_ready()
-            clock.mark("copy")  # [B, vocab] float32 to the host
-            logits_np = np.asarray(logits)
-            clock.mark("sample")
-            with self._lock:
-                for i in range(self.config.max_batch_size):
-                    if not self.active[i]:
-                        continue
-                    tok = self._sample(logits_np[i])
-                    st = self.slots[i]
-                    st.generated.append(tok)
-                    if st.token_queue is not None:
-                        st.token_queue.put(tok)
-                    self.lengths[i] += 1
-                    self.last_tokens[i, 0] = tok
-            clock.mark("finish")
-            if self._anatomy_pending:  # falsy-dict check: zero cost per step
-                t_w = anatomy.now_wall()
-                for i in list(self._anatomy_pending):
-                    if self.active[i]:
-                        anatomy.stamp(self._anatomy_pending.pop(i),
-                                      "decode_first_token", t_w)
-            for i in range(self.config.max_batch_size):
-                if self.active[i]:
-                    self._maybe_finish(i, self.slots[i].generated[-1])
+        try:
+            with self._decode_clock(_DECODE_PHASES) as clock:
+                enqueued = self.active.any()
+                if enqueued:
+                    self._flight = self._enqueue(before)
+                clock.note(ahead=bool(enqueued and before is not None))
+                late = 0 if before is None else self._emit(before, clock)
+                if not self.active.any():
+                    if enqueued:
+                        late += self._emit(self._flight, clock)
+                    self._flight = None
+                clock.note(late_rows=late)
+        except BaseException:
+            self._flight = None  # the caller fails its slots with the others
+            raise
         return True
+
+    def _enqueue(self, before: Optional[_Flight]) -> _Flight:
+        """The `dispatch` phase: enqueue a decode step for the active rows and
+        `pick` behind it; both calls return before the device has run them.
+        What goes up from the host is a copy: the rows change under a step
+        that has not run yet."""
+        unread = before.rows if before is not None else {}
+        with self._lock:
+            rows = {int(i): self.slots[i] for i in np.flatnonzero(self.active)}
+            lengths, tables = self.lengths.copy(), self.tables.copy()
+            if before is None:
+                tokens = self.last_tokens.copy()
+            else:
+                # the host knows the token of a row admitted since `before`
+                # was enqueued; every other row's is still on the device
+                tokens = np.full_like(self.last_tokens, -1)
+                for i, st in rows.items():
+                    if unread.get(i) is not st:
+                        tokens[i] = self.last_tokens[i]
+        # the host's arrays go into the calls as they are (an upload of its
+        # own for each was 0.8 ms of a chat step's dispatch; PERF.md, PR 34)
+        if before is not None:
+            tokens = self._carry(before.ids, tokens)
+        logits, self.pool = self._decode(self.params, self.pool, tokens, lengths, tables)
+        ids, counters, self._sample_key = self._pick(
+            logits, self.pool.get("counters", {}), self._sample_key)
+        for leaf in (ids, *counters.values()):
+            leaf.copy_to_host_async()   # on the host by the time `_emit` asks
+        with self._lock:
+            for i, st in rows.items():
+                if self.slots[i] is not st:
+                    continue  # released meanwhile: its rows are zero already
+                self.lengths[i] += 1
+                if len(st.generated) + (unread.get(i) is st) + 1 >= st.max_new:
+                    # that was its last step: the row sits out the steps that
+                    # follow, zeroed as a released one, and stays occupied
+                    # until `_emit` has read the token and freed it
+                    self.active[i] = False
+                    self.lengths[i] = 0
+                    self.tables[i] = 0
+        return _Flight(ids, rows, counters)
+
+    def _emit(self, flight: _Flight, clock: PhaseClock) -> int:
+        """The `wait`, `copy`, `sample` and `finish` phases of the step
+        `flight`: its ids to the slots it was enqueued for. Returns how many
+        of its rows were dropped (released or re-admitted since)."""
+        clock.mark("wait")  # the device's step, or what is left of it
+        flight.ids.block_until_ready()
+        clock.mark("copy")  # [B, 1] int32, and what the step counted
+        ids = np.asarray(flight.ids)
+        clock.note(**pool_counters(flight._asdict()))
+        clock.mark("sample")
+        late = 0
+        with self._lock:
+            for i, st in flight.rows.items():
+                if self.slots[i] is not st:
+                    late += 1
+                    continue
+                tok = int(ids[i, 0])
+                st.generated.append(tok)
+                if st.token_queue is not None:
+                    st.token_queue.put(tok)
+                self.last_tokens[i, 0] = tok
+        clock.mark("finish")
+        if self._anatomy_pending:  # falsy-dict check: zero cost per step
+            t_w = anatomy.now_wall()
+            for i in list(self._anatomy_pending):
+                if i in flight.rows and self.slots[i] is flight.rows[i]:
+                    anatomy.stamp(self._anatomy_pending.pop(i),
+                                  "decode_first_token", t_w)
+        for i, st in flight.rows.items():
+            if self.slots[i] is st:
+                self._maybe_finish(i, st.generated[-1])
+        return late
 
     # ---- PD disaggregation handoff (reference: pd_server.py + NIXL KV
     # transfer; here KV pages travel as host arrays over the object plane) ----

@@ -31,7 +31,7 @@ import numpy as np
 
 from ray_tpu.models import llama
 from ray_tpu.serve.llm_paged import (_DECODE_PHASES, PagedLLMConfig,
-                                     PagedLLMEngine, paged_step)
+                                     PagedLLMEngine, paged_step, pool_counters)
 
 _SPEC_DECODE_PHASES = ("draft",) + _DECODE_PHASES
 
@@ -183,6 +183,7 @@ class SpecDecodeLLMEngine(PagedLLMEngine):
             logits.block_until_ready()
             clock.mark("copy")
             logits_np = np.asarray(logits)  # [B, K+1, V]
+            clock.note(**pool_counters(self.pool))  # what the verify pass counted
             clock.mark("sample")
             target_preds = np.argmax(logits_np, axis=-1)  # [B, K+1]
             finished = []

@@ -114,7 +114,8 @@ _annotation = None  # jax.profiler.TraceAnnotation, looked up at first use
 class PhaseClock:
     """Clocks one step of a loop through its phases and leaves ONE
     ``record_span`` entry for it: ``args`` holds ``<phase>_s`` for every
-    declared phase (0.0 for one never reached), whatever ``close`` is given,
+    declared phase (0.0 for one never reached), whatever ``note`` (as the step
+    learns it; a later value replaces an earlier one) and ``close`` are given,
     and ``profiled``. The phases tile the record: each ``mark`` ends the
     running phase and starts the next on the same ``time.monotonic()`` read,
     and ``close`` ends the last one on the read that ends the record.
@@ -127,8 +128,8 @@ class PhaseClock:
     of the interval a device trace covers. jax is imported at first use, so
     this module still imports without it."""
 
-    __slots__ = ("_cat", "_name", "_outer", "_inner", "_key", "_phases",
-                 "_profiled", "_t", "t0")
+    __slots__ = ("_cat", "_name", "_outer", "_inner", "_key", "_noted",
+                 "_phases", "_profiled", "_t", "t0")
 
     def __init__(self, cat: str, name: str, phases: tuple = ()):
         global _annotation
@@ -138,6 +139,7 @@ class PhaseClock:
             _annotation = TraceAnnotation
         self._cat, self._name = cat, name
         self._phases = {p + "_s": 0.0 for p in phases}
+        self._noted: dict = {}
         self._inner = None
         self._profiled = _annotation.is_enabled()
         self._outer = _annotation(f"{cat}:{name}")
@@ -161,11 +163,14 @@ class PhaseClock:
         self._end_phase(time.monotonic())
         self._open(phase)
 
+    def note(self, **args) -> None:
+        self._noted.update(args)
+
     def close(self, **args) -> None:
         t = time.monotonic()
         self._end_phase(t)
         self._outer.__exit__(None, None, None)
-        args.update(self._phases)
+        args = {**self._noted, **args, **self._phases}
         args["profiled"] = self._profiled and _annotation.is_enabled()
         record_span(self._cat, self._name, self.t0 + _MONO_ANCHOR,
                     t - self.t0, args)

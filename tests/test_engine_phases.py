@@ -88,6 +88,18 @@ def test_decode_records_follow_the_request(one_request):
                for s in steps)
 
 
+def test_decode_records_say_how_far_ahead_the_loop_ran(one_request):
+    """The loop reads one step behind (ISSUE 34): the first pass enqueues a
+    step and has none to read, every later one enqueues while the step before
+    is unread (`ahead`), and the last reads two, its own too."""
+    steps = [a for a, _ in _records("decode")]
+    assert [s["ahead"] for s in steps] == [False, True, True, True, True]
+    assert [s["late_rows"] for s in steps] == [0] * 5
+    assert all(s["dispatch_s"] > 0 for s in steps)
+    assert steps[0]["wait_s"] == steps[0]["copy_s"] == steps[0]["sample_s"] == 0.0
+    assert all(s["copy_s"] > 0 and s["sample_s"] > 0 for s in steps[1:])
+
+
 @pytest.mark.parametrize("name", ["admit", "decode"])
 def test_phases_tile_the_record(one_request, name):
     recs = _records(name)
@@ -227,10 +239,13 @@ def test_phase_clock_zero_fills_and_sums_repeats():
     timeline.clear()
     clock = timeline.PhaseClock("t", "loop", ("a", "b", "c"))
     clock.mark("b")
+    clock.note(k=1, n=0)
     clock.mark("a")
+    clock.note(k=2)
     clock.close(n=1)
     (args, dur), = [(e[7], e[6]) for e in timeline.local_events() if e[2] == "t"]
     assert args["c_s"] == 0.0 and args["n"] == 1 and args["profiled"] is False
+    assert args["k"] == 2   # the later note; what `close` is given goes over both
     assert args["a_s"] + args["b_s"] == pytest.approx(dur, rel=1e-6)
     bare = timeline.PhaseClock("t", "bare")
     bare.close(kind="x")

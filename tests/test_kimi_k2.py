@@ -151,7 +151,8 @@ def test_a_prefill_cut_into_chunks_of_heads_is_the_uncut_one(tiny, monkeypatch, 
 def test_the_engine_with_two_sequences_live_matches_the_reference(tiny):
     """Through `PagedLLMEngine`: two prompts admitted one after the other and
     decoded together; the logits each was sampled from are the reference's,
-    and the engine's records carry the step's `moe_rows`."""
+    and the engine's records carry the step's `moe_rows`, read with the
+    step's ids and not before."""
     from ray_tpu.util import timeline
 
     model, cfg, params, tokens = tiny
@@ -174,12 +175,16 @@ def test_the_engine_with_two_sequences_live_matches_the_reference(tiny):
 
     eng._prefill, eng._decode = keep_prefill, keep_decode
     prompts = [list(map(int, tokens[:21])), list(map(int, tokens[21:30]))]
+    in_flight = []
     try:
         futs = [eng.generate(p, 5) for p in prompts]
         for _ in range(20):
             if all(f.done() for f in futs):
                 break
             eng.step_once()
+            if eng._flight is not None:
+                in_flight.append((eng._flight.counters["moe_rows"],
+                                  eng.pool["counters"]["moe_rows"]))
         out = [f.result(0) for f in futs]
     finally:
         eng.shutdown()
@@ -191,7 +196,16 @@ def test_the_engine_with_two_sequences_live_matches_the_reference(tiny):
         assert len(rows) == 5 and _miss(np.stack(rows), want[len(prompt) - 1:]) < TOL
     records = [e[7] for e in timeline.local_events()
                if e[0] == "span" and e[2] == "engine" and isinstance(e[7], dict)]
-    assert records and all("moe_rows" in r for r in records)
+    admits = [r for r in records if "outcome" in r]
+    steps = [r for r in records if "live" in r]
+    assert len(admits) == 2 and all(r["moe_rows"] > 0 for r in admits)
+    # what a step counted comes to the host with its ids, a pass later: the
+    # first pass enqueued a step and read nothing. Until then the count lives
+    # in a copy of its own, because the next step is given (and donates) the pool
+    assert len(steps) == 4 and "moe_rows" not in steps[0]
+    assert all(r["moe_rows"] > 0 for r in steps[1:])
+    assert len(in_flight) == 3 and all(mine is not pools for mine, pools in in_flight)
+    assert all(int(mine) > 0 for mine, _ in in_flight)
     assert eng.kv_memory_bytes() == cfg.cache_layers * 9 * BS * cfg.latent_row * 4
 
 

@@ -567,7 +567,7 @@ def test_engine_step_is_a_declared_hot_path():
     lookup or an RPC into the step."""
     by_file = {spec.file: spec for spec in hotpath.HOT_PATHS}
     engine = by_file["ray_tpu/serve/llm_paged.py"]
-    assert {"_step_decode", "_admit_one"} <= set(engine.funcs)
+    assert {"_step_decode", "_enqueue", "_emit", "_admit_one"} <= set(engine.funcs)
     assert engine.ban_metric_construct and engine.ban_rpc
     assert {"mark", "close"} <= set(by_file["ray_tpu/util/timeline.py"].funcs)
     ctx = FakeCtx({"ray_tpu/serve/llm_paged.py": '''
@@ -577,6 +577,12 @@ def _decode_clock(self, phases):
 def _step_decode(self):
     with self._decode_clock(()) as clock:
         self._head.notify("decode_step")
+        self._emit(self._enqueue(None), clock)
+
+def _enqueue(self, before):
+    return self._decode(self.params, self.pool)
+
+def _emit(self, flight, clock):
     stamp()
 
 def _admit_one(self, prompt, max_new, fut, t_enq, tq, slot):

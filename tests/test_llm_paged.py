@@ -360,3 +360,185 @@ def test_admission_is_in_order_of_arrival_when_blocks_bind(shared_params):
     after = per_pass[len(waiting)]
     assert after == [("admitted", 50), ("admitted", 10), ("requeued", 11)]
     assert {n for p in per_pass for outcome, n in p if outcome == "requeued"} == {50, 11}
+
+
+# ---- the decode loop reads one step behind itself (ISSUE 34) ----
+def _stepped(cfg, params, **kw):
+    """An engine driven from the test, a pass a `step_once()`, whose calls of
+    `_decode` are counted (through the wrapper the benchmark's check puts on)."""
+    eng = PagedLLMEngine(PagedLLMConfig(
+        model_config=cfg, max_batch_size=2, max_seq_len=128, block_size=16, **kw),
+        params=params, external_step=True)
+    eng.calls = []
+    decode = eng._decode
+
+    def counted(params, pool, last_tokens, lengths, tables):
+        eng.calls.append(np.flatnonzero(eng.active).tolist())
+        return decode(params, pool, last_tokens, lengths, tables)
+
+    eng._decode = counted
+    return eng
+
+
+def _decode_records():
+    from ray_tpu.util import timeline
+
+    return [e[7] for e in timeline.local_events()
+            if e[0] == "span" and e[2] == "engine" and e[3] == "decode"]
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 6])
+def test_n_tokens_take_n_minus_1_decode_calls_and_the_last_is_read_in_its_pass(
+        shared_params, n):
+    """A sequence that ends by count is known to before its last step is read:
+    no step is enqueued for a row whose last token is in flight, and the pass
+    that enqueues the last step of everything live reads it too, so a
+    `step_once()` driver needs no pass more than steps."""
+    from ray_tpu.util import timeline
+
+    cfg, params = shared_params
+    prompt = [5, 9, 13, 2, 7]
+    timeline.clear()
+    eng = _stepped(cfg, params)
+    try:
+        fut = eng.generate(prompt, n)
+        passes = 0
+        while not fut.done():
+            assert passes < n and eng.step_once()
+            passes += 1
+            if not fut.done():  # a step is in flight, its ids unread
+                assert eng._flight is not None and eng.stats()["active_slots"] == 1
+        assert eng._flight is None and not eng.step_once()
+        assert fut.result(0).token_ids == _plain_greedy(cfg, params, prompt, n)
+        assert (eng.calls, passes) == ([[0]] * (n - 1), max(1, n - 1))
+        assert eng.last_tokens[0, 0] == 0 and eng.stats()["allocated_blocks"] == 0
+    finally:
+        eng.shutdown()
+    records = _decode_records()
+    assert [r["ahead"] for r in records] == [False] + [True] * (n - 2) if n > 1 else not records
+    assert all(r["late_rows"] == 0 for r in records)
+
+
+def test_an_eos_is_found_one_step_late_and_the_row_in_flight_is_dropped(shared_params):
+    from ray_tpu.util import timeline
+
+    cfg, params = shared_params
+    prompt = [3, 3, 8]
+    plain = _plain_greedy(cfg, params, prompt, 8)
+    stop = next(k for k in range(2, 7) if plain[k] not in plain[:k])
+    timeline.clear()
+    eng = _stepped(cfg, params, eos_token_id=plain[stop])
+    try:
+        fut = eng.generate(prompt, 8)
+        while not fut.done():
+            assert eng.step_once()
+        out = fut.result(0)
+        assert (out.token_ids, out.finish_reason) == (plain[:stop + 1], "stop")
+        # the token after the stop was already being decoded when it was read
+        assert len(eng.calls) == stop + 1
+        assert eng._flight is None and eng.stats()["allocated_blocks"] == 0
+    finally:
+        eng.shutdown()
+    assert [r["late_rows"] for r in _decode_records()] == [0] * stop + [1]
+
+
+@pytest.mark.parametrize("readmit", [False, True], ids=["cancelled", "re-admitted"])
+def test_a_step_in_flight_gives_nothing_to_a_row_released_since(shared_params, readmit):
+    """An id goes only to the `_Slot` its step was enqueued for: a row
+    cancelled while its step was in flight, and a new request admitted into
+    that row before the step is read, get nothing from it; the new request's
+    first step takes its first token from the host, not the stale id."""
+    from ray_tpu.util import timeline
+
+    cfg, params = shared_params
+    timeline.clear()
+    eng = _stepped(cfg, params)
+    try:
+        gone = eng.generate(list(range(1, 30)), 20)
+        assert eng.step_once()                    # admitted, step 1 enqueued
+        st = eng.slots[0]
+        assert eng._flight.rows == {0: st} and len(st.generated) == 1
+        assert eng.cancel_future(gone)
+        if readmit:
+            prompt = [5, 9, 13, 2, 7]
+            fut = eng.generate(prompt, 5)
+            while not fut.done():
+                assert eng.step_once()
+            assert fut.result(0).token_ids == _plain_greedy(cfg, params, prompt, 5)
+            assert eng.calls == [[0]] * 5         # one of the old row, four of the new
+        else:
+            assert eng.step_once() and not eng.step_once()   # the stale step is read, once
+            assert eng.calls == [[0]]
+        assert len(st.generated) == 1 and not gone.done()
+        assert eng._flight is None and eng.stats()["allocated_blocks"] == 0
+    finally:
+        eng.shutdown()
+    records = _decode_records()
+    assert [r["late_rows"] for r in records[:2]] == [0, 1]
+    assert sum(r["late_rows"] for r in records) == 1
+    if not readmit:
+        assert (records[1]["live"], records[1]["ahead"]) == (0, False)
+
+
+def test_a_row_admitted_beside_a_step_in_flight_starts_from_its_own_token(shared_params):
+    """Two requests a pass apart, decoded together from the second's first
+    step on: each reads what it reads alone (the tokens of the step enqueued
+    are the device's ids with the newcomer's first token written over its
+    row), and the shorter one's row sits out the steps after its last."""
+    cfg, params = shared_params
+    asks = [(list(range(1, 40)), 7), ([5, 9, 13, 2, 7], 4)]
+    eng = _stepped(cfg, params)
+    try:
+        first = eng.generate(*asks[0])
+        assert eng.step_once()
+        second = eng.generate(*asks[1])
+        while not (first.done() and second.done()):
+            assert eng.step_once()
+        for fut, (prompt, n) in zip((first, second), asks):
+            assert fut.result(0).token_ids == _plain_greedy(cfg, params, prompt, n)
+        assert eng.calls == [[0]] + [[0, 1]] * 3 + [[0]] * 2
+    finally:
+        eng.shutdown()
+
+
+def test_shutdown_leaves_no_step_unread(shared_params):
+    cfg, params = shared_params
+    eng = _stepped(cfg, params)
+    fut = eng.generate([5, 9, 13, 2, 7], 10)
+    assert eng.step_once() and eng._flight is not None
+    eng.shutdown()
+    assert eng._flight is None
+    with pytest.raises(RuntimeError, match="shut down"):
+        fut.result(0)
+
+
+def test_a_temperature_draws_from_the_softmax_on_the_device():
+    """Temperature 0.7 over a vocabulary of 8: 600 second tokens after one
+    prompt, drawn by `pick` from the engine's own key, against the softmax of
+    the plain forward's logits, by count. The bound is 5 standard deviations
+    of a count, and 600 equal draws (a key that never advances) would miss it."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(), vocab_size=8)
+    params = llama.init(cfg, jax.random.PRNGKey(11))
+    prompt, first, T, draws = [1, 2, 3, 4, 5], {}, 0.7, 600
+    eng = PagedLLMEngine(PagedLLMConfig(
+        model_config=cfg, max_batch_size=4, max_seq_len=64, block_size=16,
+        temperature=T), params=params, seed=5)
+    try:
+        futs = [eng.generate(prompt, 2) for _ in range(draws)]
+        for f in futs:
+            a, b = f.result(timeout=300).token_ids
+            first.setdefault(a, []).append(b)
+    finally:
+        eng.shutdown()
+    a, seconds = max(first.items(), key=lambda kv: len(kv[1]))
+    logits = llama.forward(params, jnp.asarray([prompt + [a]], jnp.int32), cfg)
+    p = np.asarray(jax.nn.softmax(logits[0, -1] / T))
+    n = len(seconds)
+    counts = np.bincount(seconds, minlength=8)
+    assert n > 100 and (np.abs(counts - n * p) <= 5 * np.sqrt(n * p * (1 - p)) + 1).all()
+    assert (counts > 0).sum() >= 3
