@@ -165,7 +165,7 @@ HOT_PATHS = (
             ("_step_decode", ("_decode_clock",),
              "_step_decode no longer clocks its phases — the engine/decode "
              "timeline records and the engine:decode.* annotations go dark"),
-            ("_admit_one", ("PhaseClock",),
+            ("_admit_one", ("clock",),
              "_admit_one no longer clocks its phases — the engine/admit "
              "timeline records and the engine:admit.* annotations go dark"),
         ),
@@ -231,8 +231,10 @@ HOT_PATHS = (
         file="ray_tpu/util/timeline.py",
         funcs=("phase_reply", "stamp_task_phases", "record_span",
                "drain_since",
-               # PhaseClock (ISSUE-24): runs inside every decode step
-               "mark", "close", "_open", "_end_phase"),
+               # PhaseClock (ISSUE-24): runs inside every decode step;
+               # PhaseLoop (ISSUE-35) chains the engine thread's records
+               "mark", "stop", "close", "_open", "_end_phase", "clock",
+               "_closed", "rest", "profiling"),
         reason="per-task phase stamp on the worker exec path; the serving "
                "engine's per-step phase clock",
         ban_rpc=True,
@@ -240,6 +242,18 @@ HOT_PATHS = (
         forbid_imports=tuple(m for m in CONTROL_PLANE_IMPORTS
                              if m != "ray_tpu.core.runtime"),
         missing_hint="phase recording path renamed? (update HOT_PATHS)",
+    ),
+    # ISSUE-35: a stream's cell is written once a token by every stage of
+    # the token's way to the socket and scanned once a decode step.
+    HotPath(
+        file="ray_tpu/serve/stream_cell.py",
+        funcs=("stream_cell", "counts"),
+        reason="per-token counts of the streams' stages; the engine thread's "
+               "per-step scan of them",
+        ban_rpc=True,
+        ban_submit=True,
+        forbid_imports=CONTROL_PLANE_IMPORTS,
+        missing_hint="stream cell API renamed? (update HOT_PATHS)",
     ),
     # ISSUE-17: the front door's ingress dispatch fast path. Per-REQUEST:
     # route lookup, replica pick, and the admission predictor read ONLY the

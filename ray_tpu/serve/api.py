@@ -327,6 +327,8 @@ class HttpProxy:
         from aiohttp import web
 
         from ray_tpu.serve import anatomy
+        from ray_tpu.serve.stream_cell import stream_cell
+        from ray_tpu.util import timeline
 
         resp = web.StreamResponse(headers={
             "Content-Type": "text/event-stream",
@@ -336,15 +338,24 @@ class HttpProxy:
         loop = asyncio.get_running_loop()
         method = body.get("stream_method", "stream_tokens")
         rid = anatomy.rid_of(body)
+        # what the stream costs this proxy's threads goes into its request's
+        # cell, which an engine in this process reads (serve/stream_cell.py);
+        # with the replica in another process nobody reads it
+        cell = stream_cell(rid)
+        cell.sink = 1
         it = handle.stream(body, method_name=method)
         nframes = 0
         err = None
 
         def next_item():
+            c0 = time.thread_time() if timeline.profiling() else None
             try:
                 return next(it)
             except StopIteration:
                 return _STREAM_END
+            finally:
+                if c0 is not None:   # the pool thread's CPU; waiting costs none
+                    cell.fetch_cpu += time.thread_time() - c0
 
         try:
             while True:
@@ -363,12 +374,17 @@ class HttpProxy:
                     anatomy.stamp(rid, "decode_first_token",
                                   anatomy.now_wall())
                 nframes += 1
+                c0 = time.thread_time() if timeline.profiling() else None
                 await resp.write(f"data: {json.dumps(item)}\n\n".encode())
+                if c0 is not None:   # the event loop's CPU: a write that had
+                    # to wait for the socket counts what ran meanwhile too
+                    cell.write_cpu += time.thread_time() - c0
             await resp.write(b"data: [DONE]\n\n")
             await resp.write_eof()
         except (ConnectionError, ConnectionResetError, asyncio.CancelledError):
             err = err or "client_disconnected"
         finally:
+            cell.sink = 2
             it.close()  # releases the router's in-flight slot (GeneratorExit)
             if rid is not None:
                 anatomy.complete(rid, handle.deployment_name,

@@ -27,6 +27,7 @@ import numpy as np
 
 from ray_tpu.models import llama, model_of
 from ray_tpu.ops.platform import target_platform
+from ray_tpu.serve.stream_cell import StreamCell
 from ray_tpu.util.compile_cache import compile_totals, ensure_compile_cache
 
 
@@ -53,6 +54,20 @@ class GenerationResult:
     ttft_s: float
     total_s: float
     finish_reason: str = "length"
+
+
+class _TokenQueue(queue.Queue):
+    """A stream's tokens on their way from the engine thread to the stream's
+    thread: `(token, the time.monotonic() of its put)`, then None at the end.
+    `cell` counts both ends (`serve/stream_cell.py`)."""
+
+    def __init__(self, cell: StreamCell):
+        super().__init__()
+        self.cell = cell
+
+    def emit(self, tok: int, t: float) -> None:
+        self.cell.put += 1
+        self.put((tok, t))
 
 
 class _Slot:
@@ -94,8 +109,15 @@ class LLMEngine:
         self.last_tokens = np.zeros((B, 1), dtype=np.int32)
         self.active = np.zeros(B, dtype=bool)
         self.slots: list[Optional[_Slot]] = [None] * B
-        self._pending: "queue.Queue[tuple[list[int], int, Future, float]]" = queue.Queue()
+        # (prompt, max_new, future, enqueue time, token queue | None, request id | None)
+        self._pending: "queue.Queue[tuple]" = queue.Queue()
         self._lock = threading.Lock()
+        # the cells of the live streams (`generate_stream` appends its own),
+        # the sums of those folded away, and the sums as the last `decode`
+        # record noted them (`_stream_sums`, the engine thread's alone)
+        self._streams: list[StreamCell] = []
+        self._streams_lock = threading.Lock()
+        self._st_folded = self._st_noted = (0,) * len(StreamCell.COUNTS)
         self._running = True
         self._sample_key = key  # the paged engine's `pick` draws from it on the device
         self._rng = np.random.default_rng(seed)  # `_sample`'s, the engine's own
@@ -175,15 +197,21 @@ class LLMEngine:
         if max_new <= 0:
             fut.set_result(GenerationResult([], len(prompt_ids), 0, 0.0, 0.0))
             return fut
-        self._pending.put((list(prompt_ids), max_new, fut, time.monotonic(), None))
+        self._pending.put((list(prompt_ids), max_new, fut, time.monotonic(), None, None))
         return fut
 
-    def generate_stream(self, prompt_ids: list[int], max_new_tokens: int | None = None):
+    def generate_stream(self, prompt_ids: list[int], max_new_tokens: int | None = None,
+                        rid: str | None = None, cell: StreamCell | None = None):
         """Yield token ids as they are decoded (streaming TTFT path).
 
         Validation matches generate(); every engine path (completion, request
         failure, engine failure, shutdown) terminates the stream via the None
-        sentinel so consumers never hang."""
+        sentinel so consumers never hang.
+
+        `rid` is the request's id where it has one (`serve/anatomy.py`): the
+        paged engine's `admit` record carries it. The stream counts what it
+        costs into `cell`: the caller's, if it has stages of its own to count
+        there (`openai_api.py::_stream_deltas`), else a new one."""
         fut: Future = Future()
         max_new = self.config.max_new_tokens_default if max_new_tokens is None else max_new_tokens
         err = self._validate(prompt_ids, max_new)
@@ -191,15 +219,25 @@ class LLMEngine:
             raise err
         if max_new <= 0:
             return
-        tq: "queue.Queue" = queue.Queue()
-        self._pending.put((list(prompt_ids), max_new, fut, time.monotonic(), tq))
-        while True:
-            item = tq.get(timeout=300)
-            if item is None:
-                if fut.done() and fut.exception() is not None:
-                    raise fut.exception()
-                return
-            yield item
+        if cell is None:
+            cell = StreamCell()
+        tq = _TokenQueue(cell)
+        with self._streams_lock:
+            self._streams.append(cell)
+        self._pending.put((list(prompt_ids), max_new, fut, time.monotonic(), tq, rid))
+        try:
+            while True:
+                item = tq.get(timeout=300)
+                if item is None:
+                    if fut.done() and fut.exception() is not None:
+                        raise fut.exception()
+                    return
+                tok, t_put = item
+                cell.taken += 1
+                cell.wake += time.monotonic() - t_put
+                yield tok
+        finally:
+            cell.ended = True
 
     def generate_sync(self, prompt_ids: list[int], max_new_tokens: int | None = None,
                       timeout: float = 120.0) -> GenerationResult:
@@ -214,6 +252,8 @@ class LLMEngine:
                 "active_slots": int(self.active.sum()),
                 "max_slots": self.config.max_batch_size,
                 "pending": self._pending.qsize(),
+                # tokens put on their streams' queues and not yet taken
+                "stream_backlog": self._stream_totals()[1],
                 "platform": self.platform,
                 "compiles": compiles,
                 "compile_s": compile_s,
@@ -232,7 +272,7 @@ class LLMEngine:
         self._fail_all_active(exc)
         while True:
             try:
-                _, _, fut, _, tq = self._pending.get_nowait()
+                _, _, fut, _, tq, _ = self._pending.get_nowait()
             except queue.Empty:
                 break
             if not fut.done():
@@ -254,6 +294,40 @@ class LLMEngine:
         z = z - z.max()
         p = np.exp(z) / np.exp(z).sum()
         return int(self._rng.choice(len(p), p=p))
+
+    def _stream_totals(self) -> tuple[tuple, int]:
+        """(the sums of `StreamCell.COUNTS` over every stream this engine has
+        fed, the tokens put on live streams' queues and not yet taken). A
+        cell that has ended with no sink open is folded into `_st_folded`
+        here and dropped, so nothing of its tail is lost: whether it is over
+        is read BEFORE its counts, so the counts folded are its last."""
+        with self._streams_lock:
+            rows, ended, live, backlog = [self._st_folded], [], [], 0
+            for cell in self._streams:
+                over = cell.ended and cell.sink != 1
+                row = cell.counts()
+                rows.append(row)
+                if over:
+                    ended.append(row)
+                else:
+                    live.append(cell)
+                    if not cell.ended:
+                        backlog += cell.put - cell.taken
+            if ended:
+                self._st_folded = tuple(map(sum, zip(self._st_folded, *ended)))
+                self._streams = live
+        return tuple(map(sum, zip(*rows))), backlog
+
+    def _stream_sums(self) -> dict:
+        """For a `decode` record: `st_<count>` for each of `StreamCell.COUNTS`,
+        what this engine's streams gained since the record before, and
+        `st_backlog` as it stands. The engine thread's alone."""
+        totals, backlog = self._stream_totals()
+        noted, self._st_noted = self._st_noted, totals
+        out = {"st_" + name: now - was for name, now, was in
+               zip(StreamCell.COUNTS, totals, noted)}
+        out["st_backlog"] = backlog
+        return out
 
     def _loop(self) -> None:
         while self._running:
@@ -301,7 +375,7 @@ class LLMEngine:
         free = [i for i in range(self.config.max_batch_size) if not self.active[i]]
         while free and not self._pending.empty():
             try:
-                prompt, max_new, fut, t_enq, tq = self._pending.get_nowait()
+                prompt, max_new, fut, t_enq, tq, _ = self._pending.get_nowait()
             except queue.Empty:
                 break
             slot = free.pop(0)
@@ -323,9 +397,9 @@ class LLMEngine:
             with self._lock:
                 st = _Slot(fut, max_new, len(prompt), t_enq, tq)
                 st.generated.append(tok)
-                if tq is not None:
-                    tq.put(tok)
                 st.first_token_time = time.monotonic()
+                if tq is not None:
+                    tq.emit(tok, st.first_token_time)
                 self.slots[slot] = st
                 self.active[slot] = True
                 self.lengths[slot] = len(prompt)
@@ -339,6 +413,7 @@ class LLMEngine:
                 jnp.asarray(self.last_tokens), jnp.asarray(self.lengths),
             )
             logits_np = np.asarray(logits)
+            t_put = time.monotonic()
             with self._lock:
                 for i in range(self.config.max_batch_size):
                     if not self.active[i]:
@@ -347,7 +422,7 @@ class LLMEngine:
                     st = self.slots[i]
                     st.generated.append(tok)
                     if st.token_queue is not None:
-                        st.token_queue.put(tok)
+                        st.token_queue.emit(tok, t_put)
                     self.lengths[i] += 1
                     self.last_tokens[i, 0] = tok
             for i in range(self.config.max_batch_size):

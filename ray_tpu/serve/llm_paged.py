@@ -38,7 +38,7 @@ from ray_tpu.serve import anatomy
 from ray_tpu.serve.llm import LLMConfig, LLMEngine, _Slot
 from ray_tpu.serve.paged_kv import BlockPool, NoFreeBlocks
 from ray_tpu.util.compile_cache import compile_totals
-from ray_tpu.util.timeline import PhaseClock
+from ray_tpu.util.timeline import PhaseClock, PhaseLoop
 
 # the phases of the engine's timeline records, in the order they run; each
 # is `<phase>_s` in the record and `engine:<record>.<phase>` in a profile
@@ -186,6 +186,9 @@ class PagedLLMEngine(LLMEngine):
         # ({"k","v"}, ack) lands a remote handoff (KVTransport.pull)
         self.kv_publish = None
         self.kv_pull = None
+        # the engine thread's records in a row (PERF.md section 3): the time
+        # between two of them, while the loop is busy, is the later one's `turn`
+        self._records = PhaseLoop("engine")
         super().__init__(config or PagedLLMConfig(), params=params, seed=seed,
                          external_step=external_step)
 
@@ -281,16 +284,17 @@ class PagedLLMEngine(LLMEngine):
         return sum(leaf.nbytes for leaf in page_leaves(self.pool).values())
 
     # ---- engine loop ----
-    def _admit_one(self, prompt, max_new, fut, t_enq, tq, slot) -> bool:
+    def _admit_one(self, prompt, max_new, fut, t_enq, tq, rid, slot) -> bool:
         jnp = self._jnp
         bs = self.config.block_size
         # one engine/admit timeline record per call (PERF.md section 3): the
         # phases tile it; `failed` stands unless a path below says otherwise
-        clock = PhaseClock("engine", "admit", _ADMIT_PHASES)
+        clock = self._records.clock("admit", _ADMIT_PHASES)
         compile_s0 = compile_totals()[1]
         info = {"prompt": len(prompt), "cached": 0, "bucket": 0, "slot": slot,
-                "queue_wait_s": clock.t0 - t_enq, "outcome": "failed",
-                "logits_bytes": 0}  # what the `copy` phase brought to the host
+                "queue_wait_s": clock.t0 - t_enq, "outcome": "failed"}
+        if rid is not None:
+            info["rid"] = rid  # the request's spans elsewhere carry it (serve/anatomy.py)
         try:
             total_blocks = -(-(len(prompt) + max_new) // bs)
             if total_blocks > self.pool_blocks - 1:
@@ -338,7 +342,6 @@ class PagedLLMEngine(LLMEngine):
                 logits.block_until_ready()
                 clock.mark("copy")  # [1, vocab] float32 to the host
                 logits_np = np.asarray(logits)
-                info["logits_bytes"] = logits_np.nbytes
                 info.update(pool_counters(self.pool))
                 clock.mark("sample")
                 tok = self._sample(logits_np[0])
@@ -354,9 +357,9 @@ class PagedLLMEngine(LLMEngine):
             with self._lock:
                 st = _Slot(fut, max_new, len(prompt), t_enq, tq)
                 st.generated.append(tok)
-                if tq is not None:
-                    tq.put(tok)
                 st.first_token_time = time.monotonic()
+                if tq is not None:
+                    tq.emit(tok, st.first_token_time)
                 self.slots[slot] = st
                 self.active[slot] = True
                 self.lengths[slot] = len(prompt)
@@ -374,6 +377,8 @@ class PagedLLMEngine(LLMEngine):
         did_work = self._step_ops()
         did_work = self._step_admit() or did_work
         did_work = self._step_decode() or did_work
+        if not did_work:
+            self._records.rest()  # the sleep that follows is no turn
         return did_work
 
     def _step_ops(self) -> bool:
@@ -383,7 +388,7 @@ class PagedLLMEngine(LLMEngine):
                 kind, payload, fut = self._ops.get_nowait()
             except queue.Empty:
                 break
-            clock = PhaseClock("engine", "ops")
+            clock = self._records.clock("ops")
             try:
                 if kind == "prefill_extract":
                     fut.set_result(self._do_prefill_extract(payload))
@@ -423,8 +428,9 @@ class PagedLLMEngine(LLMEngine):
         """One engine/decode timeline record for the step run inside it
         (PERF.md section 3); the caller marks the phases after the first and
         notes what else the step knows. `live` and `ctx` are the active rows',
-        the ones the step enqueues, taken before it advances a length."""
-        clock = PhaseClock("engine", "decode", phases)
+        the ones the step enqueues, taken before it advances a length; the
+        `st_*` fields are what the streams counted since the record before."""
+        clock = self._records.clock("decode", phases)
         compile_s0 = compile_totals()[1]
         live = int(self.active.sum())
         ctx = int(self.lengths[self.active].sum())
@@ -435,8 +441,12 @@ class PagedLLMEngine(LLMEngine):
         try:
             yield clock
         finally:
+            # the record ends at `stop`; adding up the streams' cells (a lock
+            # and a scan of the live ones) is the turn's, not the record's
+            clock.stop()
             clock.close(live=live, ctx=ctx, blocks=blocks,
-                        compile_s=compile_totals()[1] - compile_s0)
+                        compile_s=compile_totals()[1] - compile_s0,
+                        **self._stream_sums())
 
     def _step_decode(self) -> bool:
         """One pass of the decode loop, one step BEHIND itself: step k+1 is
@@ -532,7 +542,7 @@ class PagedLLMEngine(LLMEngine):
         clock.mark("copy")  # [B, 1] int32, and what the step counted
         ids = np.asarray(flight.ids)
         clock.note(**pool_counters(flight._asdict()))
-        clock.mark("sample")
+        t_put = clock.mark("sample")  # every row's put carries this one read
         late = 0
         with self._lock:
             for i, st in flight.rows.items():
@@ -542,7 +552,7 @@ class PagedLLMEngine(LLMEngine):
                 tok = int(ids[i, 0])
                 st.generated.append(tok)
                 if st.token_queue is not None:
-                    st.token_queue.put(tok)
+                    st.token_queue.emit(tok, t_put)
                 self.last_tokens[i, 0] = tok
         clock.mark("finish")
         if self._anatomy_pending:  # falsy-dict check: zero cost per step

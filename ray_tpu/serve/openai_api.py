@@ -137,13 +137,22 @@ def build_openai_app(config: "LLMConfig | None" = None, *,
             each step and emit the text delta, holding back a trailing
             partial character (multi-byte/multi-token chars must not split
             into replacement chars across chunks — vLLM's incremental
-            detokenizer behavior)."""
-            from ray_tpu.serve import anatomy
+            detokenizer behavior).
 
-            arid = anatomy.rid_of(body)
+            Under a profiler session the stream's cell (`serve/stream_cell.py`)
+            gains this thread's CPU in the detokeniser and from a yielded
+            delta to the resumption, which is the replica's store of the
+            chunk: the engine's `decode` records carry the sums."""
+            from ray_tpu.serve import anatomy
+            from ray_tpu.serve.stream_cell import stream_cell
+            from ray_tpu.util import timeline
+
+            arid = rid = anatomy.rid_of(body)
+            cell = stream_cell(rid)
             generated: list[int] = []
             emitted = ""
-            for tok_id in self.engine.generate_stream(ids, max_tokens):
+            for tok_id in self.engine.generate_stream(ids, max_tokens,
+                                                      rid=rid, cell=cell):
                 if arid is not None:
                     # replica-clock first-token stamp: closest observer to
                     # the engine, beats the proxy's first-SSE-frame clock
@@ -151,12 +160,18 @@ def build_openai_app(config: "LLMConfig | None" = None, *,
                                   anatomy.now_wall())
                     arid = None
                 generated.append(int(tok_id))
+                c0 = time.thread_time() if timeline.profiling() else None
                 text = self.tok.decode(generated)
+                if c0 is not None:
+                    c1 = time.thread_time()
+                    cell.detok_cpu += c1 - c0
                 if text.endswith("�"):
                     text = text[:-1]  # maybe-incomplete char: wait one token
                 if len(text) > len(emitted):
                     delta, emitted = text[len(emitted):], text
                     yield delta
+                    if c0 is not None:
+                        cell.relay_cpu += time.thread_time() - c1
             final = self.tok.decode(generated)
             if len(final) > len(emitted):
                 yield final[len(emitted):]

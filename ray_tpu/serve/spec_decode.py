@@ -88,9 +88,9 @@ class SpecDecodeLLMEngine(PagedLLMEngine):
         self.prev_tokens = np.zeros((self.config.max_batch_size, 1), dtype=np.int32)
 
     # ---- admission: also prefill the DRAFT pool for the slot ----
-    def _admit_one(self, prompt, max_new, fut, t_enq, tq, slot) -> bool:
+    def _admit_one(self, prompt, max_new, fut, t_enq, tq, rid, slot) -> bool:
         jnp = self._jnp
-        admitted = super()._admit_one(prompt, max_new, fut, t_enq, tq, slot)
+        admitted = super()._admit_one(prompt, max_new, fut, t_enq, tq, rid, slot)
         if not admitted or not self.active[slot]:
             # not admitted, rejected, or already finished (max_new reached)
             return admitted
@@ -184,7 +184,7 @@ class SpecDecodeLLMEngine(PagedLLMEngine):
             clock.mark("copy")
             logits_np = np.asarray(logits)  # [B, K+1, V]
             clock.note(**pool_counters(self.pool))  # what the verify pass counted
-            clock.mark("sample")
+            t_put = clock.mark("sample")
             target_preds = np.argmax(logits_np, axis=-1)  # [B, K+1]
             finished = []
             with self._lock:
@@ -205,7 +205,7 @@ class SpecDecodeLLMEngine(PagedLLMEngine):
                     for tok in committed:
                         st.generated.append(int(tok))
                         if st.token_queue is not None:
-                            st.token_queue.put(int(tok))
+                            st.token_queue.emit(int(tok), t_put)
                     self.lengths[i] = base_lengths[i] + len(committed)
                     if len(committed) >= 2:
                         self.prev_tokens[i, 0] = committed[-2]

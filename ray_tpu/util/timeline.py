@@ -111,6 +111,13 @@ def record_span(cat: str, name: str, t0_wall: float, dur_s: float,
 _annotation = None  # jax.profiler.TraceAnnotation, looked up at first use
 
 
+def profiling() -> bool:
+    """True while a profiler session is on in this process: what `profiled`
+    is made of. False where no `PhaseClock` was ever opened (no record could
+    carry what a stage would count, so the stage counts nothing)."""
+    return _annotation is not None and _annotation.is_enabled()
+
+
 class PhaseClock:
     """Clocks one step of a loop through its phases and leaves ONE
     ``record_span`` entry for it: ``args`` holds ``<phase>_s`` for every
@@ -118,7 +125,7 @@ class PhaseClock:
     learns it; a later value replaces an earlier one) and ``close`` are given,
     and ``profiled``. The phases tile the record: each ``mark`` ends the
     running phase and starts the next on the same ``time.monotonic()`` read,
-    and ``close`` ends the last one on the read that ends the record.
+    and ``stop`` ends the last one on the read that ends the record.
 
     The same intervals go to the profiler's clock as
     ``jax.profiler.TraceAnnotation``s, ``<cat>:<name>`` around
@@ -126,54 +133,138 @@ class PhaseClock:
     no profiler session is on. ``profiled`` is true only if one was on both
     when the clock opened and when it closed: such records are exactly those
     of the interval a device trace covers. jax is imported at first use, so
-    this module still imports without it."""
+    this module still imports without it.
 
-    __slots__ = ("_cat", "_name", "_outer", "_inner", "_key", "_noted",
-                 "_phases", "_profiled", "_t", "t0")
+    A clock that opens under a profiler session also reads
+    ``time.thread_time()`` beside each wall read (only then: the read is a
+    system call, and only profiled records are read for it): its record, if
+    it declares phases, holds ``<phase>_cpu`` for each and ``cpu`` for the
+    whole, seconds of THIS thread's CPU (none ends in ``_s``: those keys are
+    the parts of ``dur_s``). Wall less CPU of a phase that makes no blocking
+    call is time the thread was runnable and not running: the wait for the
+    interpreter lock, or a call that blocked after all.
 
-    def __init__(self, cat: str, name: str, phases: tuple = ()):
+    A clock opened by a ``PhaseLoop`` (``loop``) also notes ``turn``, and
+    ``turn_cpu`` under a session: see there."""
+
+    __slots__ = ("_c", "_c0", "_cat", "_cpu", "_inner", "_key", "_loop",
+                 "_name", "_noted", "_outer", "_phases", "_profiled", "_t",
+                 "_t1", "t0")
+
+    def __init__(self, cat: str, name: str, phases: tuple = (),
+                 loop: "PhaseLoop | None" = None):
         global _annotation
         if _annotation is None:
             from jax.profiler import TraceAnnotation
 
             _annotation = TraceAnnotation
-        self._cat, self._name = cat, name
+        self._cat, self._name, self._loop = cat, name, loop
         self._phases = {p + "_s": 0.0 for p in phases}
         self._noted: dict = {}
-        self._inner = None
+        self._inner = self._t1 = None
         self._profiled = _annotation.is_enabled()
+        self._cpu = {p + "_cpu": 0.0 for p in phases} if self._profiled else None
+        after = loop is not None and loop._turn is not None
+        if after:
+            loop._turn.__exit__(None, None, None)
+            loop._turn = None
         self._outer = _annotation(f"{cat}:{name}")
         self._outer.__enter__()
         self.t0 = self._t = time.monotonic()
+        self._c0 = self._c = time.thread_time() if self._profiled else None
+        if after:
+            self._noted["turn"] = self.t0 - loop._t
+            if self._profiled and loop._c is not None:
+                self._noted["turn_cpu"] = self._c0 - loop._c
         if phases:
             self._open(phases[0])
 
     def _open(self, phase: str) -> None:
-        self._key = phase + "_s"
+        self._key = phase
         self._inner = _annotation(f"{self._cat}:{self._name}.{phase}")
         self._inner.__enter__()
 
-    def _end_phase(self, t: float) -> None:
+    def _end_phase(self, t: float) -> "float | None":
+        """Ends the running phase on the wall read `t`; returns the CPU read
+        that goes with it (None outside a session)."""
+        c = time.thread_time() if self._profiled else None
         if self._inner is not None:
             self._inner.__exit__(None, None, None)
-            self._phases[self._key] = self._phases.get(self._key, 0.0) + (t - self._t)
-            self._t = t
+            self._inner = None
+            wall = self._key + "_s"
+            self._phases[wall] = self._phases.get(wall, 0.0) + (t - self._t)
+            if c is not None:
+                cpu = self._key + "_cpu"
+                self._cpu[cpu] = self._cpu.get(cpu, 0.0) + (c - self._c)
+            self._t, self._c = t, c
+        return c
 
-    def mark(self, phase: str) -> None:
-        self._end_phase(time.monotonic())
+    def mark(self, phase: str) -> float:
+        """Ends the running phase and starts `phase`; returns the
+        ``time.monotonic()`` read on which it did both."""
+        t = time.monotonic()
+        self._end_phase(t)
         self._open(phase)
+        return t
 
     def note(self, **args) -> None:
         self._noted.update(args)
 
-    def close(self, **args) -> None:
-        t = time.monotonic()
-        self._end_phase(t)
+    def stop(self) -> None:
+        """The record ends HERE: the last phase and the record's annotation
+        end on this read, and a loop's next turn begins on it. What the
+        caller does between `stop` and `close` (adding up what it wants
+        noted) is outside ``dur_s`` and inside that turn."""
+        t = self._t1 = time.monotonic()
+        c = self._end_phase(t)
         self._outer.__exit__(None, None, None)
+        if self._cpu is not None and self._phases:   # no phases, no CPU noted
+            self._cpu["cpu"] = c - self._c0
+        if self._loop is not None:
+            self._loop._closed(t, c)
+
+    def close(self, **args) -> None:
+        if self._t1 is None:
+            self.stop()
         args = {**self._noted, **args, **self._phases}
+        if self._cpu is not None and self._phases:
+            args.update(self._cpu)
         args["profiled"] = self._profiled and _annotation.is_enabled()
         record_span(self._cat, self._name, self.t0 + _MONO_ANCHOR,
-                    t - self.t0, args)
+                    self._t1 - self.t0, args)
+
+
+class PhaseLoop:
+    """The records of ONE thread's loop, in a row, so that the thread's time
+    is tiled: from a record's end to the opening of the next, while the
+    loop stays busy, the time is a TURN (the loop's own code between two
+    records, and whatever kept the thread from running it). `clock()` opens
+    the next record, which notes the turn that ends there as ``turn``
+    (seconds, outside its ``dur_s``) and, where both records read the
+    thread's CPU, ``turn_cpu``; the same interval is a ``<cat>:turn``
+    annotation on the profiler's clock. Both ends are reads the two records
+    make anyway. A turn makes no call into any runtime, so its wall less its
+    CPU is the interpreter lock's and the scheduler's alone. `rest()`, called
+    when the loop finds nothing to do, ends the turn under way unrecorded."""
+
+    __slots__ = ("_c", "_cat", "_t", "_turn")
+
+    def __init__(self, cat: str):
+        self._cat = cat
+        self._turn = None   # the annotation of the turn under way
+
+    def clock(self, name: str, phases: tuple = ()) -> PhaseClock:
+        return PhaseClock(self._cat, name, phases, loop=self)
+
+    def _closed(self, t: float, c: "float | None") -> None:
+        self._t, self._c = t, c
+        self._turn = _annotation(f"{self._cat}:turn")
+        self._turn.__enter__()
+
+    def rest(self) -> None:
+        if self._turn is not None:
+            self._turn.__exit__(None, None, None)
+            self._turn = None
 
 
 def drain_since(cursor: int) -> "tuple[list, int]":

@@ -1,15 +1,19 @@
 """The serving engine clocks its own step: one `engine` record per admission,
 decode step and PD op in the timeline ring, the same phases as
 TraceAnnotations in a profile, a compile counter, and a shutdown that ends
-queued requests too (ISSUE 24)."""
+queued requests too (ISSUE 24). Since ISSUE 35 the time between two records is
+the later one's `turn`, the `decode` records carry what the streams counted,
+and under a profiler session the clock reads the thread's CPU beside the wall."""
 
 import dataclasses
 import glob
+import json
 import os
 import subprocess
 import sys
 import threading
 import time
+import urllib.request
 
 import pytest
 
@@ -71,13 +75,28 @@ def test_one_request_leaves_one_admit_record(one_request):
     assert {"alloc_s", "prefill_s", "wait_s", "copy_s", "sample_s"} <= set(a)
 
 
-def test_admit_record_counts_the_one_row_the_copy_brought(one_request, shared_params):
+def test_admit_copies_the_one_row_the_prefill_hands_back(shared_params):
     """The prefill hands the host the row it samples from and no other: the
-    `copy` phase is still there (a metric reads `copy_s`), and `logits_bytes`
-    is one float32 row of the vocabulary, not the 32 bucket's."""
+    `copy` phase is still there (a metric reads `copy_s`), and what it copies
+    is one float32 row of the vocabulary, not the 32 bucket's (the record's
+    constant `logits_bytes` said so until ISSUE 35; the step's result does)."""
+    timeline.clear()
+    eng = _engine(shared_params)
+    prefill, handed = eng._prefill, []
+
+    def spy(*args):
+        logits, pool = prefill(*args)
+        handed.append((logits.shape, logits.dtype.name))
+        return logits, pool
+
+    eng._prefill = spy
+    try:
+        eng.generate_sync(list(range(1, 21)), 2)
+    finally:
+        eng.shutdown()
     (a, _), = _records("admit")
-    assert a["copy_s"] > 0
-    assert a["logits_bytes"] == 4 * shared_params[0].vocab_size
+    assert a["copy_s"] > 0 and "logits_bytes" not in a
+    assert handed == [((1, shared_params[0].vocab_size), "float32")]
 
 
 def test_decode_records_follow_the_request(one_request):
@@ -106,6 +125,303 @@ def test_phases_tile_the_record(one_request, name):
     assert recs
     for args, dur in recs:
         assert _phase_sum(args) == pytest.approx(dur, rel=0.01)
+
+
+def _cpu_tick():
+    """The grain of `time.thread_time()` on this machine: the smallest step it
+    makes while this thread spins for 50 ms (nanoseconds on Linux, 10 ms under
+    some sandboxes, where ONE record's `_cpu` is 0 or a whole tick)."""
+    end, last, tick = time.monotonic() + 0.05, time.thread_time(), 0.05
+    while time.monotonic() < end:
+        c = time.thread_time()
+        if c != last:
+            tick, last = min(tick, c - last), c
+    return tick
+
+
+@pytest.fixture(scope="module")
+def profiled_request(shared_params, tmp_path_factory):
+    """`one_request` inside a profiler session, after a request outside one
+    that took the compiles: -> ({name: [(args, dur_s)]}, the CPU clock's tick)."""
+    import jax
+
+    timeline.clear()
+    eng = _engine(shared_params)
+    try:
+        eng.generate_sync(list(range(1, 21)), 6)
+        while eng.stats()["active_slots"]:
+            time.sleep(0.01)
+        time.sleep(0.05)
+        seen = len(_records())
+        with jax.profiler.trace(str(tmp_path_factory.mktemp("trace"))):
+            eng.generate_sync(list(range(30, 50)), 6)
+            eng.shutdown()
+    finally:
+        eng.shutdown()
+    recs = [(e[3], e[7], e[6]) for e in timeline.local_events()
+            if e[0] == "span" and e[2] == "engine"][seen:]
+    assert [n for n, _, _ in recs] == ["admit"] + ["decode"] * 5
+    assert all(a["profiled"] for _, a, _ in recs)
+    return ({n: [(a, d) for m, a, d in recs if m == n] for n in ("admit", "decode")},
+            _cpu_tick())
+
+
+@pytest.mark.parametrize("name", ["admit", "decode"])
+def test_every_phase_has_its_cpu_beside_its_wall(profiled_request, name):
+    """Under a profiler session `<phase>_cpu` is this thread's CPU inside the
+    phase: never negative, never more than the wall by over a tick of the CPU
+    clock (and a millisecond for the two clocks' reads), and `cpu` is the
+    record's, the phases' sum."""
+    records, tick = profiled_request
+    for args, dur in records[name]:
+        phases = [k[:-2] for k in args if k.endswith("_s") and k not in NOT_PHASES]
+        assert phases
+        for p in phases:
+            assert 0.0 <= args[p + "_cpu"] <= args[p + "_s"] + tick + 1e-3, p
+        assert sum(args[p + "_cpu"] for p in phases) == pytest.approx(args["cpu"], abs=1e-6)
+        assert 0.0 <= args["cpu"] <= dur + tick + 1e-3
+    # a turn's CPU is read where both of its records read theirs
+    turns = [a for a, _ in records["decode"]]
+    assert all(0.0 <= a["turn_cpu"] <= a["turn"] + tick + 1e-3 for a in turns)
+
+
+@pytest.mark.parametrize("name", ["admit", "decode"])
+def test_no_cpu_is_read_outside_a_profiler_session(one_request, name):
+    """`time.thread_time()` is a system call (5.7 us on the benchmark's
+    machine): with tracing off a record costs none, and says so by holding
+    no `_cpu` key of its own rather than a zero (the streams' `st_*_cpu` sums
+    are there and 0.0: `test_stream_stages_cost_cpu_only_inside_...`)."""
+    for args, _ in _records(name):
+        assert args["profiled"] is False
+        assert not [k for k in args if k == "cpu"
+                    or k.endswith("_cpu") and not k.startswith("st_")]
+
+
+def _spans(name=None):
+    """(t0, dur_s, args) of the ring's engine records, oldest first."""
+    return [(e[5], e[6], e[7]) for e in timeline.local_events()
+            if e[0] == "span" and e[2] == "engine" and name in (None, e[3])]
+
+
+def test_records_and_turns_tile_a_busy_stretch(one_request):
+    """From the admission's opening to the last step's close the loop never
+    rested, so the records and the turns between them ARE that time: every
+    record but the first notes the turn that ended at its opening."""
+    spans = _spans()
+    assert len(spans) == 6 and "turn" not in spans[0][2]
+    assert all(a["turn"] >= 0.0 for _, _, a in spans[1:])
+    span = spans[-1][0] + spans[-1][1] - spans[0][0]
+    tiled = sum(dur for _, dur, _ in spans) + sum(a["turn"] for _, _, a in spans[1:])
+    assert tiled == pytest.approx(span, rel=0.01)
+    for (t0, dur, _), (t1, _, nxt) in zip(spans, spans[1:]):
+        assert t0 + dur + nxt["turn"] == pytest.approx(t1, abs=1e-5)
+
+
+def test_no_turn_after_an_idle_pass(shared_params):
+    """A pass that found nothing to do ends the turn unrecorded: the first
+    record after the loop slept has no `turn`, whatever follows it has."""
+    timeline.clear()
+    eng = _engine(shared_params)
+    try:
+        eng.generate_sync(list(range(1, 21)), 3)
+        time.sleep(0.1)   # dozens of idle passes, 2 ms each
+        eng.generate_sync(list(range(30, 50)), 3)
+    finally:
+        eng.shutdown()
+    recs = [(e[3], e[7]) for e in timeline.local_events() if e[2] == "engine"]
+    assert [n for n, _ in recs] == ["admit", "decode", "decode"] * 2
+    assert ["turn" in a for _, a in recs] == [False, True, True] * 2
+
+
+def _off_cpu(args):
+    return (args.get("turn", 0.0) - args.get("turn_cpu", 0.0)
+            + args["dispatch_s"] - args["dispatch_cpu"])
+
+
+def test_a_spinning_thread_shows_as_time_off_the_cpu(shared_params, tmp_path):
+    """The measurement sees contention: with a pure-Python thread spinning
+    beside it the engine thread waits for the interpreter lock between and
+    inside its records, and that is wall without CPU in `turn` and
+    `dispatch` (neither blocks by design). Under a profiler session, where
+    the CPU is read; without its Python hooks, which would trace the spin."""
+    import jax
+    from jax.profiler import ProfileOptions
+
+    options = ProfileOptions()
+    options.python_tracer_level = 0
+    timeline.clear()
+    stop = threading.Event()
+
+    def spin():
+        while not stop.is_set():
+            sum(i * i for i in range(2000))
+
+    spinner = threading.Thread(target=spin, daemon=True)
+    eng = _engine(shared_params)
+    try:
+        eng.generate_sync(list(range(1, 21)), 22)    # compiles: not measured
+        with jax.profiler.trace(str(tmp_path), profiler_options=options):
+            timeline.clear()
+            eng.generate_sync(list(range(60, 80)), 22)
+            quiet = [a for a, _ in _records("decode")][-20:]
+            timeline.clear()
+            spinner.start()
+            eng.generate_sync(list(range(30, 50)), 22)
+            stop.set()
+            eng.shutdown()
+    finally:
+        stop.set()
+        eng.shutdown()
+    spinner.join(timeout=10)
+    assert not spinner.is_alive()
+    loud = [a for a, _ in _records("decode")][-20:]
+    assert len(quiet) == len(loud) == 20
+    assert all(a["profiled"] for a in quiet + loud)
+    assert not any(a["compile_s"] for a in quiet + loud)
+    # on this backend a quiet `dispatch` blocks a little by itself (the CPU
+    # "device" runs on other threads); the spinner adds a lock wait of up to
+    # the switch interval, 5 ms, at every call that let the lock go: 20
+    # passes of it stand well clear of 5 ms and of the CPU clock's grain
+    assert sum(map(_off_cpu, loud)) > sum(map(_off_cpu, quiet)) + max(0.005, 3 * _cpu_tick())
+
+
+def test_stream_counts_ride_the_decode_records(shared_params):
+    """A stream of N tokens straight off the engine: the `decode` records'
+    `st_taken` sum to N once a later record has noted the stream's tail,
+    nothing is left on its queue, the admission carries the request's id,
+    and with no front end and no profiler session the other sums stay 0."""
+    timeline.clear()
+    eng = _engine(shared_params)
+    try:
+        toks = list(eng.generate_stream(list(range(1, 21)), 7, rid="req-0042"))
+        backlog = eng.stats()["stream_backlog"]
+        eng.generate_sync(list(range(30, 50)), 2)   # its records note the tail
+    finally:
+        eng.shutdown()
+    assert len(toks) == 7 and backlog == 0
+    assert [a.get("rid") for a, _ in _records("admit")] == ["req-0042", None]
+    steps = [a for a, _ in _records("decode")]
+    assert sum(s["st_taken"] for s in steps) == 7
+    assert sum(s["st_wake"] for s in steps) > 0
+    assert steps[-1]["st_backlog"] == 0 and all(s["st_backlog"] >= 0 for s in steps)
+    for name in ("st_detok_cpu", "st_relay_cpu", "st_fetch_cpu", "st_write_cpu"):
+        assert all(s[name] == 0 for s in steps), name
+    assert eng._streams == []   # the ended cell was folded away
+
+
+class _IdTokenizer:
+    """Text is the ids in decimal, one token a number: every token is a delta."""
+
+    def encode(self, text):
+        return [int(t) for t in text.split()]
+
+    def decode(self, ids):
+        return "".join(f"{i} " for i in ids)
+
+
+def _sse(url, body):
+    """The `data:` frames of one streamed completion, `[DONE]` left out."""
+    req = urllib.request.Request(url, data=json.dumps(body).encode(),
+                                 headers={"Content-Type": "application/json"})
+    frames = []
+    with urllib.request.urlopen(req, timeout=120) as r:
+        for raw in r:
+            line = raw.decode().strip()
+            if line.startswith("data: ") and line != "data: [DONE]":
+                frames.append(json.loads(line[len("data: "):]))
+    return frames
+
+
+def _post(url, body):
+    req = urllib.request.Request(url, data=json.dumps(body).encode(),
+                                 headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=120) as r:
+        return json.loads(r.read())
+
+
+@pytest.fixture(scope="module")
+def served_streams(shared_params, tmp_path_factory):
+    """Through the whole path (proxy, router, replica, `_stream_deltas`, the
+    engine, all in this process): one streamed completion of 6 tokens outside
+    a profiler session and one inside, each followed by a plain completion
+    whose `decode` records note the stream's tail. -> per stream the frames
+    the client got and the `decode` records written meanwhile; the requests'
+    ids as the proxy made them; the engine's `stream_backlog` at the end."""
+    import jax
+
+    import ray_tpu
+    from ray_tpu import serve
+    from ray_tpu.serve import anatomy
+
+    cfg, _ = shared_params
+    timeline.clear()
+    anatomy.clear()
+    ray_tpu.init(num_cpus=8, ignore_reinit_error=True)
+    out = {}
+    try:
+        app = serve.build_openai_app(
+            PagedLLMConfig(model_config=cfg, max_batch_size=4, max_seq_len=128,
+                           block_size=16), tokenizer=_IdTokenizer())
+        handle = serve.run(app, route_prefix="/v1")
+        proxy = serve.start_http_proxy(port=0)
+        url = f"http://127.0.0.1:{proxy.port}/v1/completions"
+        prompt = " ".join(map(str, range(1, 21)))
+
+        def one(key):
+            seen = len(_records("decode"))
+            frames = _sse(url, {"prompt": prompt, "max_tokens": 6, "stream": True})
+            _post(url, {"prompt": prompt, "max_tokens": 3})
+            out[key] = (frames, [a for a, _ in _records("decode")][seen:])
+
+        one("outside")
+        with jax.profiler.trace(str(tmp_path_factory.mktemp("trace"))):
+            one("inside")
+        out["backlog"] = ray_tpu.get(handle.stats.remote())["stream_backlog"]
+        out["rids"] = [e[2] for e in anatomy.local_events()
+                       if e[0] == "sp" and e[3] == "ingress_admit"]
+        out["admits"] = [a for a, _ in _records("admit")]
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()
+    return out
+
+
+@pytest.mark.parametrize("session", ["outside", "inside"])
+def test_served_stream_counts_its_tokens(served_streams, session):
+    """Six tokens taken off the queue for the seven frames the client got (six
+    deltas and the chunk that says `stop`), nothing left on the queue; the
+    frames themselves are counted where they always were, on the request's
+    ledger (`anatomy.complete(ntokens=)`)."""
+    frames, steps = served_streams[session]
+    assert len(frames) == 7
+    assert sum(s["st_taken"] for s in steps) == 6
+    assert steps[-1]["st_backlog"] == 0 and served_streams["backlog"] == 0
+
+
+def test_stream_stages_cost_cpu_only_inside_a_profiler_session(served_streams):
+    """Beside `test_profiled_only_inside_a_profiler_session`: the stages'
+    `thread_time()` pairs are taken under a session alone, since only
+    profiled records are read; counts and `st_wake` are kept always."""
+    stages = ("st_detok_cpu", "st_relay_cpu", "st_fetch_cpu", "st_write_cpu")
+    _, outside = served_streams["outside"]
+    _, inside = served_streams["inside"]
+    assert not any(s["profiled"] for s in outside)
+    assert any(s["profiled"] for s in inside)
+    fine = _cpu_tick() < 5e-6   # else six tokens' stages may fall between ticks
+    for name in stages:
+        assert sum(s[name] for s in outside) == 0.0, name
+        assert sum(s[name] for s in inside) >= 0.0, name
+        assert not fine or sum(s[name] for s in inside) > 0.0, name
+    assert sum(s["st_wake"] for s in outside) > 0.0
+
+
+def test_admit_record_carries_the_request_s_id(served_streams):
+    """The id the proxy gave the request (`serve/anatomy.py`) is on the
+    engine's `admit` record of a streamed request, so one request's spans
+    share an identifier; a plain completion's admission has none."""
+    rids, admits = served_streams["rids"], served_streams["admits"]
+    assert len(rids) == 4 and len(admits) == 4
+    assert [a.get("rid") for a in admits] == [rids[0], None, rids[2], None]
 
 
 def test_profiled_only_inside_a_profiler_session(shared_params, tmp_path):
@@ -143,7 +459,12 @@ def test_profiled_only_inside_a_profiler_session(shared_params, tmp_path):
                     (line.name, ev.start_ns, ev.start_ns + ev.duration_ns))
     assert {"engine:admit", "engine:admit.copy", "engine:decode",
             "engine:decode.dispatch", "engine:decode.wait", "engine:decode.copy",
-            "engine:decode.sample", "engine:decode.finish"} <= set(spans)
+            "engine:decode.sample", "engine:decode.finish",
+            "engine:turn"} <= set(spans)
+    # a turn lies between two records, inside neither
+    for line, s, e in spans["engine:turn"]:
+        assert not any(ln == line and s0 < e and s < e0
+                       for ln, s0, e0 in spans["engine:decode"] + spans["engine:admit"])
     for line, s, e in spans["engine:decode.wait"]:
         assert any(ln == line and s0 <= s and e <= e0
                    for ln, s0, e0 in spans["engine:decode"])

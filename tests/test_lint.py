@@ -569,10 +569,12 @@ def test_engine_step_is_a_declared_hot_path():
     engine = by_file["ray_tpu/serve/llm_paged.py"]
     assert {"_step_decode", "_enqueue", "_emit", "_admit_one"} <= set(engine.funcs)
     assert engine.ban_metric_construct and engine.ban_rpc
-    assert {"mark", "close"} <= set(by_file["ray_tpu/util/timeline.py"].funcs)
+    assert {"mark", "stop", "close", "clock", "rest"} <= set(
+        by_file["ray_tpu/util/timeline.py"].funcs)
+    assert "stream_cell" in by_file["ray_tpu/serve/stream_cell.py"].funcs
     ctx = FakeCtx({"ray_tpu/serve/llm_paged.py": '''
 def _decode_clock(self, phases):
-    yield PhaseClock("engine", "decode", phases)
+    yield self._records.clock("decode", phases)
 
 def _step_decode(self):
     with self._decode_clock(()) as clock:
@@ -585,13 +587,13 @@ def _enqueue(self, before):
 def _emit(self, flight, clock):
     stamp()
 
-def _admit_one(self, prompt, max_new, fut, t_enq, tq, slot):
+def _admit_one(self, prompt, max_new, fut, t_enq, tq, rid, slot):
     get_metric("admissions").inc()
 '''})
     keys = {f.key for f in hotpath.hot_path_findings(
         ctx, files={"ray_tpu/serve/llm_paged.py"})}
     assert keys == {"_step_decode:calls:notify", "_admit_one:calls:get_metric",
-                    "_admit_one:requires:PhaseClock"}
+                    "_admit_one:requires:clock"}
 
 
 def test_reactor_blocking_handler_fixture():
