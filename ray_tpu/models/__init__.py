@@ -18,8 +18,10 @@ class Model(NamedTuple):
     loss: Callable
     # -- what serving takes; None where the family does not serve that way.
     # The paged engines: (params, tokens, cfg, pool, tables, lengths,
-    # block_size, platform=, head_rows=) -> (logits [B, S, V], or [B, 1, V]
-    # of the positions `head_rows` [B] names, pool), and (cfg, num_blocks,
+    # block_size, platform=, head_rows=, fresh=) -> (logits [B, S, V], or
+    # [B, 1, V] of the positions `head_rows` [B] names, pool); `fresh`
+    # (static) says that every sequence starts at position 0, so a family may
+    # attend over the rows in hand and read nothing back; and (cfg, num_blocks,
     # block_size) -> the pool it reads and writes: a dict of page-shaped
     # arrays [L, num_blocks, block_size, row], the cache (a PD hand-off moves
     # them, whatever their names), and, if the family counts anything, one

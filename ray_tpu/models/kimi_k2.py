@@ -344,11 +344,14 @@ def init_kv_pool(cfg: KimiK2Config, num_blocks: int, block_size: int) -> dict:
 
 def forward_paged(params, tokens, cfg: KimiK2Config, pool: dict, tables, lengths,
                   block_size: int, use_kernel: bool | None = None,
-                  platform: str | None = None, head_rows=None):
+                  platform: str | None = None, head_rows=None, fresh: bool = False):
     """`llama.forward_paged`'s contract over the latent pool: tokens [B, S]
     append at positions [lengths, lengths + S) -> (logits, the updated pool).
     `use_kernel` (default: on a TPU at S == 1) reads the pool through the
-    latent kernel, interpreted off the TPU."""
+    latent kernel, interpreted off the TPU. `fresh` (every sequence starts at
+    position 0) changes nothing here: a prefill reads the latent rows back
+    through the table either way, because the flash forward takes no 192-wide
+    q/k beside 128-wide v (ROADMAP S6)."""
     B, S = tokens.shape
     if platform is None:
         platform = target_platform(tokens, pool["latent"])

@@ -204,7 +204,13 @@ def auto_attention(q, k, v, causal: bool = True, platform: str | None = None):
     path. Off-TPU the kernel only runs in interpret mode (slow), so dense is
     used there unconditionally. `platform` is where the computation runs:
     callers that know their mesh pass it (train/spmd.py default_attn_fn);
-    None derives it from q/k/v's placement (ops/platform.py)."""
+    None derives it from q/k/v's placement (ops/platform.py).
+
+    Who calls it: the training forward over the whole sequence
+    (`plain_attend`), and the paged prefill of prompts that start at position
+    0 over the rows it has just computed (`forward_paged(fresh=True)`: the
+    flash forward from the 1,024 bucket up on a TPU, [S, S] dense scores
+    under that)."""
     if platform is None:
         platform = target_platform(q, k, v)
     if causal and platform == "tpu" and q.shape[1] >= 1024:
@@ -535,7 +541,8 @@ def page_rows(tables, lengths, S: int, block_size: int):
 
 def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
                   block_size: int, use_kernel: bool | None = None,
-                  platform: str | None = None, mlp=dense_mlp, head_rows=None):
+                  platform: str | None = None, mlp=dense_mlp, head_rows=None,
+                  fresh: bool = False):
     """Cached forward over a PAGED pool (`init_kv_pool`'s layout). tokens
     [B,S] append at positions [lengths, lengths+S); tables [B, max_blocks] map
     sequence-block index -> pool block id. Returns (logits [B,S,V], updated
@@ -544,15 +551,37 @@ def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
 
     The pool rides whole in the layer scan's carry (`decoder_trunk`) and each
     layer touches only its own pages of it: new K/V rows scatter into
-    `pool[layer, block, offset]` in place, and the decode step (S==1) on a TPU
-    reads the live pages of `pool[layer]` through the block table in the
-    pallas kernel (ops/paged_attention.py). Prefill (and the off-TPU default)
-    reads a gathered per-sequence view (`pool[layer, tables]`). A step that
-    donates the pool compiles to a program with no pool-sized temporary
-    (tests/test_tpu_aot.py holds it to that). `platform` is where the
-    computation runs (the engine passes its own); None derives it from the
-    inputs' placement. It picks the default for `use_kernel` and, when the
-    kernel is used off-TPU (tests), interpret mode. `mlp` is `decoder_layer`'s
+    `pool[layer, block, offset]` in place (scope `attn/kv_write`), whatever
+    reads them. Which keys a layer's queries then read, three ways:
+
+    - `fresh`: every sequence starts at position 0 (`lengths` is all zero: a
+      prompt with no cached prefix), so every key a row may see is one of the
+      rows this call has just computed. The heads' outputs come from the q, k,
+      v in hand, `auto_attention(q, k, v, causal=True)`: the flash forward
+      kernel from S = 1,024 up on a TPU, dense [S, S] scores under that and
+      off the TPU (scope `attn/prompt_attend`). Nothing is read back from the
+      pool, no table is gathered, no column past S is scored. A bucket's
+      padding needs no mask of its own: it lies after the live rows, which
+      under the causal mask never see it, and its own outputs are dropped by
+      the caller. `lengths` is traced and the kernel lists its live tiles at
+      trace time, so this is a fact the CALLER states, statically: the engine
+      knows it on the host (`serve/llm_paged.py::prefill_reads`);
+    - the decode step (S == 1) on a TPU reads the live pages of `pool[layer]`
+      through the block table in the pallas kernel (ops/paged_attention.py;
+      scope `attn/kv_read`);
+    - everything else (a prompt that continues a cached prefix, a window of
+      several tokens, S == 1 off the TPU) reads the gathered per-sequence
+      view `pool[layer, tables]`, the whole table's `max_blocks * block_size`
+      positions whatever S is, with float32 scores against all of them
+      (scope `attn/kv_read`).
+
+    A step that donates the pool compiles to a program with no pool-sized
+    temporary (tests/test_tpu_aot.py holds it to that). `platform` is where
+    the computation runs (the engine passes its own); None derives it from the
+    inputs' placement. It picks the default for `use_kernel` (the paged kernel
+    at S == 1 on a TPU; `auto_attention`'s own crossover when `fresh`) and,
+    when a kernel is used off-TPU (tests: `use_kernel=True`, with `fresh` the
+    flash forward at any S), interpret mode. `mlp` is `decoder_layer`'s
     strategy (the expert layer of a MoE family). Where `cfg.loop_steps` > 1
     the pool holds `loop_steps * L` cache layers and `layer` below is the
     cache layer `pass * L + layer`."""
@@ -561,7 +590,7 @@ def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
     if platform is None:
         platform = target_platform(tokens, pool["k"])
     if use_kernel is None:
-        use_kernel = S == 1 and platform == "tpu"
+        use_kernel = S == 1 and platform == "tpu" and not fresh
     positions, blk_idx, blk_off = page_rows(tables, lengths, S, block_size)
     hd, dp = cfg.hd, pool_head_dim(cfg.hd)
 
@@ -578,6 +607,17 @@ def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
             # a row scatter: kp[layer, blk_idx[b,s], blk_off[b,s]] = k[b,s]
             kp = kp.at[layer, blk_idx, blk_off].set(rows(k, kp.dtype))
             vp = vp.at[layer, blk_idx, blk_off].set(rows(v, vp.dtype))
+        if fresh:
+            # it reads no pool, so it is not `kv_read`'s
+            with jax.named_scope("prompt_attend"):
+                if use_kernel:
+                    from ray_tpu.ops.flash_attention import flash_attention
+
+                    o = flash_attention(q, k, v, causal=True,
+                                        interpret=platform != "tpu")
+                else:
+                    o = auto_attention(q, k, v, causal=True, platform=platform)
+            return o, {"k": kp, "v": vp}
         with jax.named_scope("kv_read"):
             if use_kernel:
                 from ray_tpu.ops.paged_attention import paged_decode_attention

@@ -71,13 +71,22 @@ class PagedLLMConfig(LLMConfig):
 
 
 def paged_step(name: str, cfg, block_size: int, platform: str,
-               head, table_first: bool = False):
+               head, table_first: bool = False, fresh: bool = False):
     """The jitted step `name` of a paged engine: the `forward_paged` of the
     configuration's family (`model_of(cfg)`) over a pool it donates ->
     (logits, the pool). Called as (params, pool, tokens, lengths, tables); a
     prefill (`table_first`, B = 1) as (params, pool, tokens, table, span),
     span int32 [2]: where the tokens start in the sequence and how many of
     them are live (the rest pad the bucket).
+
+    `fresh` is the program of a prefill whose span starts at 0
+    (`forward_paged(fresh=True)`): its attention reads the rows it has just
+    computed, the flash forward from the 1,024 bucket up on a TPU, and nothing
+    of the pool. Without it a step reads the pool back through its table: the
+    kernel over live pages at S == 1, the whole gathered table at any other S
+    (a prompt that continues a cached prefix, the speculative window). The
+    caller that builds a `fresh` step is the one that knows where its spans
+    start (`prefill_step` chooses by the span itself).
 
     `head` is which positions' logits the caller reads, and the output head
     runs on those alone (`decoder_trunk`'s `head_rows`), so a step returns
@@ -102,13 +111,41 @@ def paged_step(name: str, cfg, block_size: int, platform: str,
             head_rows = None
         logits, pool = forward_paged(
             params, tokens, cfg, pool, tables, lengths, block_size,
-            platform=platform, head_rows=head_rows)
+            platform=platform, head_rows=head_rows, fresh=fresh)
         if head is None:
             return None, pool  # unused, so XLA drops the head with them
         return (logits if head == "all" else logits[:, 0]), pool
 
     step.__name__ = step.__qualname__ = name
     return jax.jit(step, donate_argnums=(1,))
+
+
+def prefill_reads(start: int) -> str:
+    """Which of a prefill's two programs a span that starts at `start` runs,
+    by what its attention reads: "own_rows" (no cached prefix: the rows the
+    call computes, `paged_step(fresh=True)`) or "table" (the suffix of a
+    prompt whose first blocks the prefix cache held: the pool through the
+    block table). An `admit` record notes it as `reads`. It names the program
+    the engine chose: a family whose forward does nothing with `fresh` (the
+    latent one, `models/kimi_k2.py`) reads its table in both."""
+    return "own_rows" if start == 0 else "table"
+
+
+def prefill_step(name: str, cfg, block_size: int, platform: str, head):
+    """A B = 1 prefill as ONE callable (params, pool, tokens, table, span) over
+    its two jitted programs (`paged_step(table_first=True)`, `fresh` and not),
+    both `name` to a profile. The choice is made here, on the host, from the
+    span's first entry, so no caller can choose wrongly: hand it the span as
+    the host array it is, and reading `span[0]` waits for no device. Each
+    program compiles at the first call that takes it, a bucket at a time; a
+    warm-up of fresh prompts compiles the `fresh` ones alone."""
+    step = partial(paged_step, name, cfg, block_size, platform, head, table_first=True)
+    programs = {"own_rows": step(fresh=True), "table": step()}
+
+    def prefill(params, pool, tokens, table, span):
+        return programs[prefill_reads(int(span[0]))](params, pool, tokens, table, span)
+
+    return prefill
 
 
 def pick_step(temperature: float):
@@ -207,8 +244,9 @@ class PagedLLMEngine(LLMEngine):
         self.slot_blocks: list[list[int]] = [[] for _ in range(B)]
         self.slot_prompts: list[Optional[list[int]]] = [None] * B
         step = partial(paged_step, cfg=cfg, block_size=bs, platform=self.platform)
-        # prefill: a B=1 row, the logits of the suffix's last live position
-        self._prefill = step("prefill", head="last", table_first=True)
+        # prefill: a B=1 row, the logits of the suffix's last live position;
+        # one callable over the own-rows program and the table one
+        self._prefill = prefill_step("prefill", cfg, bs, self.platform, head="last")
         self._decode = step("decode", head=0)
         self._pick = pick_step(self.config.temperature)
         self._carry = carry_step()
@@ -285,7 +323,6 @@ class PagedLLMEngine(LLMEngine):
 
     # ---- engine loop ----
     def _admit_one(self, prompt, max_new, fut, t_enq, tq, rid, slot) -> bool:
-        jnp = self._jnp
         bs = self.config.block_size
         # one engine/admit timeline record per call (PERF.md section 3): the
         # phases tile it; `failed` stands unless a path below says otherwise
@@ -326,18 +363,17 @@ class PagedLLMEngine(LLMEngine):
             # clamp the prefill bucket so padded positions stay inside the table
             bucket = min(self._bucket(len(suffix)),
                          self.config.max_seq_len - cached_len)
-            info["cached"], info["bucket"] = cached_len, bucket
+            info.update(cached=cached_len, bucket=bucket, reads=prefill_reads(cached_len))
             padded = np.zeros((1, bucket), dtype=np.int32)
             padded[0, : len(suffix)] = suffix
             table_row = np.zeros((1, self.max_blocks_per_seq), dtype=np.int32)
             table_row[0, : len(block_ids)] = block_ids
             try:
                 clock.mark("prefill")  # returns when the program is enqueued
+                # the host's arrays as they are: `_prefill` reads the span
                 logits, self.pool = self._prefill(
-                    self.params, self.pool, jnp.asarray(padded),
-                    jnp.asarray(table_row),
-                    jnp.asarray([cached_len, len(suffix)], np.int32),
-                )
+                    self.params, self.pool, padded, table_row,
+                    np.asarray([cached_len, len(suffix)], np.int32))
                 clock.mark("wait")  # the device's part; np.asarray would wait too
                 logits.block_until_ready()
                 clock.mark("copy")  # [1, vocab] float32 to the host
@@ -583,8 +619,6 @@ class PagedLLMEngine(LLMEngine):
         return fut
 
     def _do_prefill_extract(self, prompt_ids: list[int]) -> dict:
-        import jax.numpy as jnp
-
         bs = self.config.block_size
         err = self._validate(prompt_ids, 1)
         if err is not None:
@@ -598,10 +632,8 @@ class PagedLLMEngine(LLMEngine):
         table_row[0, :n_blocks] = block_ids
         try:
             logits, self.pool = self._prefill(
-                self.params, self.pool, jnp.asarray(padded),
-                jnp.asarray(table_row),
-                jnp.asarray([0, len(prompt_ids)], np.int32),
-            )
+                self.params, self.pool, padded, table_row,
+                np.asarray([0, len(prompt_ids)], np.int32))
             first_tok = self._sample(np.asarray(logits)[0])
             idx = np.asarray(block_ids, dtype=np.int32)
             kv = kv_ticket = kv_ref = None

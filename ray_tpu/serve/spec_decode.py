@@ -75,8 +75,11 @@ class SpecDecodeLLMEngine(PagedLLMEngine):
         self.draft_pool = llama.init_kv_pool(dcfg, self.pool_blocks, bs)
 
         step = partial(paged_step, block_size=bs, platform=self.platform)
-        # the draft's prefill fills its pool and proposes nothing: no head
-        self._draft_prefill = step("draft_prefill", dcfg, head=None, table_first=True)
+        # the draft's prefill fills its pool and proposes nothing: no head;
+        # it always takes the whole prompt from position 0, so it attends
+        # over its own rows (`_draft_prefill_slot`)
+        self._draft_prefill = step("draft_prefill", dcfg, head=None, table_first=True,
+                                   fresh=True)
         self._draft_decode = step("draft_decode", dcfg, head=0)
         # [B, 2] window: re-process [prev, last] so a fully-accepted prior
         # step's final proposal (whose draft KV was never written — the

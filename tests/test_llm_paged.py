@@ -197,7 +197,51 @@ def test_whole_prompt_cached_admission_samples_from_the_recomputed_blocks_last_r
     assert first == want and again == want
     admits = [e[7] for e in timeline.local_events()
               if e[0] == "span" and e[2] == "engine" and e[3] == "admit"]
-    assert [(a["cached"], a["bucket"]) for a in admits] == [(0, 32), (16, 32)]
+    assert [(a["cached"], a["bucket"], a["reads"]) for a in admits] == [
+        (0, 32, "own_rows"), (16, 32, "table")]
+
+
+@pytest.mark.parametrize("before, cached, reads", [
+    pytest.param([], 0, "own_rows", id="fresh"),
+    pytest.param([40, 41], 32, "table", id="prefix-hit"),
+])
+def test_an_admission_notes_which_prefill_it_ran_and_generates_the_plain_forwards_tokens(
+        shared_params, before, cached, reads):
+    """The engine's one `_prefill` chooses its program from the span it is
+    handed, on the host: a prompt no block of which is cached attends over its
+    own rows, one whose first two blocks another request left in the prefix
+    cache reads them back through the table. The `admit` record says which
+    (`reads`), every admission goes through the one callable with its five
+    arguments, the span a host array, and either way the tokens are the
+    cache-less forward's."""
+    from ray_tpu.util import timeline
+
+    cfg, params = shared_params
+    shared = [int(t) for t in np.random.default_rng(6).integers(1, cfg.vocab_size, 32)]
+    prompt = shared + [50, 51, 52]
+    eng = _paged(cfg, params)
+    prefill, spans = eng._prefill, []
+
+    def spy(params, pool, tokens, table, span):
+        spans.append(span)
+        return prefill(params, pool, tokens, table, span)
+
+    eng._prefill = spy
+    timeline.clear()
+    try:
+        if before:
+            eng.generate_sync(shared + before, 2)
+        got = eng.generate_sync(prompt, 6).token_ids
+    finally:
+        eng.shutdown()
+    assert got == _plain_greedy(cfg, params, prompt, 6)
+    admits = [e[7] for e in timeline.local_events()
+              if e[0] == "span" and e[2] == "engine" and e[3] == "admit"]
+    assert len(admits) == len(spans) == 1 + bool(before)
+    assert (admits[-1]["cached"], admits[-1]["reads"]) == (cached, reads)
+    assert admits[0]["reads"] == "own_rows"
+    assert all(isinstance(sp, np.ndarray) and sp.dtype == np.int32 for sp in spans)
+    assert spans[-1].tolist() == [cached, len(prompt) - cached]
 
 
 def test_prefill_extract_hands_over_the_first_token_of_the_plain_forward(shared_params):

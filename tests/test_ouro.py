@@ -102,6 +102,33 @@ def test_prefill_then_six_decode_steps_match_the_reference(tiny, use_kernel):
     assert _miss(got, want[n_prompt - 1:]) < TOL
 
 
+@pytest.mark.parametrize("branch", ["dense", "flash"])
+def test_a_fresh_prefill_over_its_own_rows_is_the_table_prefill(tiny, branch):
+    """A prompt that starts at position 0, prefilled as the engine admits it
+    (40 tokens live in a bucket of 48, the head on the last live row) and told
+    `fresh`: every pass of every layer attends over the rows it has in hand
+    and writes them to ITS cache layer, `pass * L + layer`. The reference's
+    logits, and the pool the table-reading prefill leaves, through the dense
+    [S, S] product and through the flash forward interpreted."""
+    model, cfg, params, tokens = tiny
+    live, bucket = 40, 48
+    toks = np.zeros((2, bucket), np.int32)
+    toks[1, :live] = tokens[:live]
+    tables = jnp.asarray([[0, 0, 0, 0], [3, 1, 7, 2]], jnp.int32)
+    step = jax.jit(lambda fresh, kernel: ouro.forward_paged(
+        params, jnp.asarray(toks), cfg, ouro.init_kv_pool(cfg, 9, BS), tables,
+        jnp.zeros(2, jnp.int32), BS, head_rows=jnp.asarray([0, live - 1], jnp.int32),
+        fresh=fresh, use_kernel=kernel), static_argnums=(0, 1))
+    want, want_pool = step(False, None)
+    got, got_pool = step(True, True if branch == "flash" else None)
+    assert _miss(got[1, 0], ouro_reference.logits(params, tokens[:live], model)[-1]) < TOL
+    assert _miss(got[1], want[1]) < TOL
+    # block 0 is the idle row's garbage; every cache layer of the 12 is written
+    for name in ("k", "v"):
+        assert np.asarray(want_pool[name][:, 1:]).reshape(12, -1).any(axis=1).all()
+        assert _miss(got_pool[name][:, 1:], want_pool[name][:, 1:]) < TOL
+
+
 def test_the_engine_with_two_sequences_live_matches_the_reference(tiny):
     """Through `PagedLLMEngine`: two prompts of different lengths admitted one
     after the other and decoded together; the logits each was sampled from

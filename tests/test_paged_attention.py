@@ -240,3 +240,67 @@ def test_prefill_then_decode_steps_give_the_cacheless_logits(family, use_kernel)
                                rtol=1e-4, atol=2e-4)
     # the garbage block took nothing: every position was inside the table
     assert not np.asarray(pool["k"][:, 0]).any()
+
+
+@pytest.mark.parametrize("branch", ["dense", "flash"])
+@pytest.mark.parametrize("family", ["llama", "olmoe"])
+def test_a_fresh_prefill_over_its_own_rows_is_the_gathered_table_prefill(family, branch):
+    """A prefill whose sequences all start at position 0, told so (`fresh`):
+    its attention reads the rows in hand and nothing of the pool, and gives
+    the logits of the prefill that reads the whole table back AND leaves the
+    same pool. Once through `auto_attention`, which off the TPU and under the
+    1,024 crossover is the dense [S, S] product, and once through the flash
+    forward interpreted (`use_kernel`), the branch a TPU takes from the 1,024
+    bucket up and no benchmark check reaches. The shape of an admission: a
+    bucket longer than what is live, the head on the last live row, pages out
+    of order, 64-wide heads in 128-wide tiles."""
+    cfg, init, forward_paged, _ = _families()[family]
+    params = init(jax.random.PRNGKey(0))
+    B, bucket, bs, mb = 2, 40, 4, 12
+    live = jnp.asarray([29, 38], jnp.int32)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, bucket), 0, cfg.vocab_size)
+    tokens = jnp.where(jnp.arange(bucket)[None] < live[:, None], tokens, 0)
+    tables = jnp.asarray(np.random.default_rng(0).permutation(
+        np.arange(1, 1 + B * mb)).reshape(B, mb), jnp.int32)
+    step = jax.jit(lambda fresh, kernel: forward_paged(
+        params, tokens, pool=llama.init_kv_pool(cfg, 1 + B * mb, bs), tables=tables,
+        lengths=jnp.zeros(B, jnp.int32), block_size=bs, head_rows=live - 1,
+        fresh=fresh, use_kernel=kernel), static_argnums=(0, 1))
+    kernel = True if branch == "flash" else None   # None: `auto_attention` decides
+    want, want_pool = step(False, None)
+    got, got_pool = step(True, kernel)
+    assert got.shape == (B, 1, cfg.vocab_size)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=2e-4)
+    for name in ("k", "v"):
+        assert np.asarray(want_pool[name][:, 1:]).any()
+        np.testing.assert_allclose(np.asarray(got_pool[name]), np.asarray(want_pool[name]),
+                                   rtol=1e-4, atol=2e-4)
+    # what names each read in a profile: no pool is read under `prompt_attend`
+    text = step.lower(True, kernel).as_text(debug_info=True)
+    assert "attn/prompt_attend" in text and "attn/kv_write" in text
+    assert "attn/kv_read" not in text
+    assert ("flash_attention_fwd" in text) == (branch == "flash")
+
+
+@pytest.mark.parametrize("family", ["llama", "olmoe"])
+def test_a_prompt_that_continues_a_cached_prefix_reads_it_through_the_table(family):
+    """The first two blocks of a prompt prefilled fresh (own rows), then its
+    suffix at positions 8.. through the program that reads the table: the
+    logits of the cache-less forward over the whole prompt, so what the fresh
+    prefill left in the pool is what the table program finds there."""
+    cfg, init, forward_paged, plain = _families()[family]
+    params = init(jax.random.PRNGKey(0))
+    B, S, bs, mb, cached = 2, 19, 4, 6, 8
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)
+    want = np.asarray(plain(params, tokens))
+    tables = jnp.asarray(np.random.default_rng(0).permutation(
+        np.arange(1, 1 + B * mb)).reshape(B, mb), jnp.int32)
+    step = jax.jit(lambda toks, pool, start, fresh: forward_paged(
+        params, toks, pool=pool, tables=tables, lengths=jnp.full((B,), start, jnp.int32),
+        block_size=bs, fresh=fresh), static_argnums=(2, 3))
+    head, pool = step(tokens[:, :cached], llama.init_kv_pool(cfg, 1 + B * mb, bs), 0, True)
+    tail, pool = step(tokens[:, cached:], pool, cached, False)
+    assert "attn/kv_read" in step.lower(tokens[:, cached:], pool, cached, False).as_text(
+        debug_info=True)
+    np.testing.assert_allclose(np.asarray(jnp.concatenate([head, tail], axis=1)), want,
+                               rtol=1e-4, atol=2e-4)

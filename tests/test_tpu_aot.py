@@ -418,6 +418,38 @@ def test_prefill_runs_the_head_on_the_one_row_it_hands_back(
         2 * math.prod(pool["k"].shape) * 2 + 2 ** 20)   # the pool and one row
 
 
+@pytest.mark.parametrize("bucket, fresh", [(2048, True), (128, True), (2048, False)],
+                         ids=["fresh-2048", "fresh-128", "continued-2048"])
+def test_a_fresh_prefill_reads_its_own_rows_and_a_continued_one_the_table(v5e, bucket, fresh):
+    """`serve-docs-batch`'s prefill as the engine builds its two programs
+    (`prefill_step`), at Mistral's widths, B = 1, the cell's 4,097-block pool
+    donated. The one a prompt with no cached prefix takes (`fresh`): from the
+    1,024 bucket up the flash forward kernel under `attn/prompt_attend`,
+    nothing under `attn/kv_read` (no gather of the table), no float32 array
+    with the bucket twice among its dimensions (the scores stay in the
+    kernel's VMEM; the table program forms `f32[8, 4, 2048, 2048]`, 537 MB,
+    a layer), and the pool where it is; at the 128 bucket the dense product
+    over [128, 128], no array as wide as the table. The one a prompt that
+    continues a cached prefix takes still compiles, reads the table, and is
+    what the detector finds the scores in."""
+    lowered, pool = _engine_step(v5e[0], _mistral_serve_16l(), "prefill", B=1, S=bucket,
+                                 pool_blocks=4097, head="last", table_first=True,
+                                 fresh=fresh)
+    compiled = lowered.compile()
+    assert_pool_stays_in_place(compiled, pool, scratch_under=2 ** 29)
+    text = compiled.as_text()
+    f32_dims = [m.group(1).split(",") for m in re.finditer(r"= f32\[([\d,]+)\]", text)]
+    scores = [d for d in f32_dims if d.count("2048") >= 2]
+    assert "attn/kv_write" in text
+    assert ("attn/prompt_attend" in text) == fresh
+    assert ("attn/kv_read" in text) == (not fresh)
+    assert ("flash_attention_fwd" in text) == (fresh and bucket >= 1024)
+    assert bool(scores) == (not fresh), scores[:3]
+    if bucket == 128:
+        assert not [d for d in f32_dims if "2048" in d]
+        assert ["1", "8", "4", "128", "128"] in f32_dims or ["8", "4", "128", "128"] in f32_dims
+
+
 def test_steps_that_read_every_row_or_none_keep_or_drop_the_head(v5e):
     """What `paged_step(head=)` makes of the other steps, at a small size:
     `decode` (S = 1, the only row is every row) is the all-positions program,
