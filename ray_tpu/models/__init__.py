@@ -25,8 +25,8 @@ class Model(NamedTuple):
     # block_size) -> the pool it reads and writes: a dict of page-shaped
     # arrays [L, num_blocks, block_size, row], the cache (a PD hand-off moves
     # them, whatever their names), and, if the family counts anything, one
-    # entry `counters`: {name: int32 scalar} that the last step left for the
-    # engine's records
+    # entry `counters`: {name: int32 or float32 scalar} that the last step left
+    # for the engine's records
     forward_paged: Optional[Callable] = None
     init_kv_pool: Optional[Callable] = None
     # the dense slot engine: (params, tokens, cfg, cache, lengths,

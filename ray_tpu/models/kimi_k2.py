@@ -344,14 +344,17 @@ def init_kv_pool(cfg: KimiK2Config, num_blocks: int, block_size: int) -> dict:
 
 def forward_paged(params, tokens, cfg: KimiK2Config, pool: dict, tables, lengths,
                   block_size: int, use_kernel: bool | None = None,
-                  platform: str | None = None, head_rows=None, fresh: bool = False):
+                  platform: str | None = None, head_rows=None, fresh: bool = False,
+                  residual: llama.HyperConnections | None = None):
     """`llama.forward_paged`'s contract over the latent pool: tokens [B, S]
     append at positions [lengths, lengths + S) -> (logits, the updated pool).
     `use_kernel` (default: on a TPU at S == 1) reads the pool through the
     latent kernel, interpreted off the TPU. `fresh` (every sequence starts at
     position 0) changes nothing here: a prefill reads the latent rows back
     through the table either way, because the flash forward takes no 192-wide
-    q/k beside 128-wide v (ROADMAP S6)."""
+    q/k beside 128-wide v (ROADMAP S6). `residual` is the trunk's (a family
+    that runs this block on several streams: `models/xing4.py`), and with it
+    the pool's counters gain `hc_residue`."""
     B, S = tokens.shape
     if platform is None:
         platform = target_platform(tokens, pool["latent"])
@@ -366,8 +369,12 @@ def forward_paged(params, tokens, cfg: KimiK2Config, pool: dict, tables, lengths
     logits, cache, stats = llama.decoder_trunk(
         {**params, "layers": layers}, tokens, cfg.base, attention,
         partial(moe.moe_mlp, cfg=cfg.experts, platform=platform, stacked=stacked),
-        cache={"latent": pool["latent"]}, positions=positions, head_rows=head_rows)
-    return logits, {**cache, "counters": {"moe_rows": stats["rows"].sum().astype(jnp.int32)}}
+        cache={"latent": pool["latent"]}, positions=positions, head_rows=head_rows,
+        residual=residual)
+    counters = {"moe_rows": stats["rows"].sum().astype(jnp.int32)}
+    if residual is not None:
+        counters["hc_residue"] = stats["hc_residue"].max()
+    return logits, {**cache, "counters": counters}
 
 
 # it serves paged, and does not train here: at 16 bytes a parameter four

@@ -18,7 +18,9 @@ TPU-native equivalent of the model stacks those engines provide.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
+import operator
 from functools import partial
 from typing import Any
 
@@ -269,8 +271,106 @@ def plain_attend(attn_fn=None):
     return gqa_attention(lambda q, k, v, cache, index: (attn_fn(q, k, v), None))
 
 
+def one_stream(x, layer, name: str):
+    """The residual of every family but one: a sub-layer reads x [B, S, H] and
+    its output is added to it -> (x, `write`: F(x) -> x + F(x), no residue)."""
+    return x, lambda out: x + out, None
+
+
+@dataclasses.dataclass(frozen=True)
+class HyperConnections:
+    """The residual strategy of a family whose residual is `n` streams wide
+    (manifold-constrained hyper-connections, arXiv:2512.24880;
+    `models/xing4.py`): the trunk's carry is X [B, S, n, H], and around EACH
+    sub-layer F (`name` "attn" or "mlp") three maps are computed from the
+    streams themselves, in float32 from the streams as they are:
+
+        x~     = RMSNorm(vec(X))               [n H], eps `eps`, no weight
+        H~     = alpha * (x~ phi) + bias       phi [n H, 2 n + n^2], the columns
+                                               [pre | post | res], alpha one
+                                               scalar a part, bias [2 n + n^2]
+        H_pre  = sigmoid(H~_pre) [n];  H_post = 2 sigmoid(H~_post) [n]
+        H_res  = Sinkhorn(exp(clip(H~_res, clamp))) [n, n]: `sinkhorn_iters`
+                 times rows / (their sums + eps), then columns / (theirs + eps)
+        u      = H_pre X                        the sub-layer's input [H]
+        X'     = H_res X + H_post^T F(u)
+
+    held by the layer as `hc_<name>_phi` (the model's dtype: the product takes
+    its operands as they are and accumulates in float32, as `moe.router_logits`
+    does), `hc_<name>_alpha` [3] and `hc_<name>_bias` (float32). Scopes, read
+    by name in a profile as siblings of `attn`, `mlp` and `moe/*`: `hc/map`
+    (the norm and the projection), `hc/sinkhorn` (the iterations), `hc/mix`
+    (`H_pre X`; and, inside the sub-layer's own scope, `H_res X`, `H_post^T
+    y`). `widen` copies the embedding into the n streams and `merge` sums them
+    for the head."""
+    n: int
+    sinkhorn_iters: int = 20
+    eps: float = 1e-6
+    clamp: tuple = (-30.0, 30.0)
+
+    def widen(self, x):
+        return jnp.broadcast_to(x[:, :, None, :], (*x.shape[:2], self.n, x.shape[-1]))
+
+    def merge(self, x):
+        return x.astype(jnp.float32).sum(axis=2).astype(x.dtype)
+
+    def sinkhorn(self, logits):
+        """float32 [n n, T] (row-major) -> (the doubly-stochastic maps as n
+        rows of n vectors [T], how far they are from it: the largest |row sum
+        - 1| or |column sum - 1| over T). Every entry is a vector of its own
+        and every sum n - 1 additions of such vectors, so the iterations are
+        elementwise work on one shape: XLA:TPU makes ~2 small fusions an
+        iteration of it, where sums over an axis of an [n, n, T] array are a
+        reduction and a kernel each, 6 an iteration (PERF.md section 6, PR 37).
+        A sum is inverted once and multiplied n times: n divisions in place of
+        one compile the CPU's program twenty times slower."""
+        n, eps = self.n, self.eps
+        total = lambda vs: functools.reduce(operator.add, vs)
+        m = [[jnp.exp(jnp.clip(logits[i * n + j], *self.clamp)) for j in range(n)]
+             for i in range(n)]
+        for _ in range(self.sinkhorn_iters):
+            by_row = [1.0 / (total(row) + eps) for row in m]
+            m = [[v * r for v in row] for row, r in zip(m, by_row)]
+            by_col = [1.0 / (total([row[j] for row in m]) + eps) for j in range(n)]
+            m = [[v * c for v, c in zip(row, by_col)] for row in m]
+        sums = [total(row) for row in m] + [total([row[j] for row in m]) for j in range(n)]
+        return m, jnp.abs(jnp.stack(sums) - 1.0).max()
+
+    def read(self, x, layer, name: str):
+        """X [B, S, n, H] -> (u [B, S, H], `write`: F(u) [B, S, H] -> X', the
+        map's residue) for the sub-layer `name` of `layer`. Inside, tokens are
+        one axis T = B S and a map is vectors [T], one an entry."""
+        B, S, n, H = x.shape
+        T = B * S
+        with jax.named_scope("hc/map"):
+            flat = x.reshape(T, n * H)
+            sq = jnp.square(flat.astype(jnp.float32)).mean(axis=-1, keepdims=True)
+            proj = jnp.dot(flat, layer[f"hc_{name}_phi"],
+                           preferred_element_type=jnp.float32) * jax.lax.rsqrt(sq + self.eps)
+            alpha = jnp.repeat(layer[f"hc_{name}_alpha"], np.array([n, n, n * n]),
+                               total_repeat_length=2 * n + n * n)
+            maps = (proj * alpha + layer[f"hc_{name}_bias"]).T        # [2 n + n^2, T]
+            h_pre = jax.nn.sigmoid(maps[:n])[..., None]
+            h_post = 2.0 * jax.nn.sigmoid(maps[n:2 * n])[..., None]
+        with jax.named_scope("hc/sinkhorn"):
+            h_res, residue = self.sinkhorn(maps[2 * n:])
+        streams = [x[:, :, i].reshape(T, H).astype(jnp.float32) for i in range(n)]
+        with jax.named_scope("hc/mix"):
+            u = sum(h_pre[i] * streams[i] for i in range(n)).astype(x.dtype).reshape(B, S, H)
+
+        def write(out):
+            out = out.reshape(T, H).astype(jnp.float32)
+            with jax.named_scope("hc/mix"):
+                mixed = [sum(h_res[i][j][:, None] * streams[j] for j in range(n))
+                         + h_post[i] * out for i in range(n)]
+                return jnp.stack(mixed, axis=1).astype(x.dtype).reshape(B, S, n, H)
+
+        return u, write, residue
+
+
 def decoder_layer(cfg: LlamaConfig, x, layer, cache, positions, attention,
-                  mlp=dense_mlp, reduce=lambda t: t, index=None):
+                  mlp=dense_mlp, reduce=lambda t: t, index=None,
+                  residual: HyperConnections | None = None):
     """x [B, S, H] through one pre-norm decoder block, the `index`-th of the
     stack -> (x, the updated cache, the MLP's stats). The one spelling that
     every family, both cached forwards and the pipeline's stage run; they
@@ -289,27 +389,42 @@ def decoder_layer(cfg: LlamaConfig, x, layer, cache, positions, attention,
     `reduce` is a tensor-sharded stage's sum over its axis of the two
     row-sharded products. What a layer does beyond that follows from the keys
     it holds: with `attn_out_norm` / `mlp_out_norm` (Ouro's sandwich) it
-    normalises a sub-layer's output before adding it to the residual."""
-    B, S, _ = x.shape
+    normalises a sub-layer's output before adding it to the residual.
+
+    `residual` is the third strategy, None (`one_stream`: `x + o`, the program
+    it was) but in a family whose residual is several streams wide: x is then
+    X [B, S, n, H], each sub-layer reads its input as a learned mix of the
+    streams and its output is written back into all of them
+    (`HyperConnections.read`; the write's `hc/mix` lies inside the sub-layer's
+    scope, where the one stream's add lies), and the stats gain `hc_residue`,
+    how far the worse of the layer's two stream-mixing maps is from doubly
+    stochastic."""
+    B, S = x.shape[:2]
     eps = cfg.rms_eps
+    read = one_stream if residual is None else residual.read
+    u, write, attn_residue = read(x, layer, "attn")
     # the scopes are names in a profile and in the HLO's op_name, no more
     with jax.named_scope("attn"):
-        y = rms_norm(x, layer["attn_norm"], eps)
+        y = rms_norm(u, layer["attn_norm"], eps)
         o, cache = attention(cfg, y, layer, cache, positions, index)
         o = reduce(o.reshape(B, S, -1) @ layer["wo"])
         if "attn_out_norm" in layer:
             o = rms_norm(o, layer["attn_out_norm"], eps)
-        x = x + o
+        x = write(o)
+    u, write, mlp_residue = read(x, layer, "mlp")
     # `mlp` is opened around the strategy, not over it: the expert layer's
     # scopes are read by name as siblings of `attn` and `mlp`, not children
     with jax.named_scope("mlp"):
-        y = rms_norm(x, layer["mlp_norm"], eps)
+        y = rms_norm(u, layer["mlp_norm"], eps)
     out, stats = mlp(y, layer)
     with jax.named_scope("mlp"):
         out = reduce(out)
         if "mlp_out_norm" in layer:
             out = rms_norm(out, layer["mlp_out_norm"], eps)
-        return x + out, cache, stats
+        x = write(out)
+    if residual is not None:
+        stats = {**stats, "hc_residue": jnp.maximum(attn_residue, mlp_residue)}
+    return x, cache, stats
 
 
 def remat_body(body, cfg: LlamaConfig):
@@ -333,7 +448,8 @@ def lm_head(params, x, cfg: LlamaConfig, normed: bool = False):
 
 
 def decoder_trunk(params, tokens, cfg: LlamaConfig, attention, mlp=dense_mlp,
-                  cache=None, positions=None, head_rows=None):
+                  cache=None, positions=None, head_rows=None,
+                  residual: HyperConnections | None = None):
     """Token ids [B, S] -> (float32 logits [B, S, V], the updated cache, the
     layers' stats stacked): embedding, `lax.scan` of `decoder_layer` over the
     layers, `lm_head`.
@@ -367,17 +483,27 @@ def decoder_trunk(params, tokens, cfg: LlamaConfig, attention, mlp=dense_mlp,
     over layers, the final norm at the end of every pass (the last pass's is
     the head's), and pass `r` of layer `l` at the cache index `r * L + l`, so
     the cache has `loop_steps * L` layers (scope `loop` a pass, `loop/norm`
-    the norm between passes; stats are stacked [passes, L, ...])."""
+    the norm between passes; stats are stacked [passes, L, ...]).
+
+    `residual` (`HyperConnections`; None: the one stream x) widens the carry
+    to n streams [B, S, n, H]: the embedding is copied into each before the
+    first layer, every layer mixes them around its two sub-layers, and they
+    are summed between the last layer and the head, after `head_rows` has
+    taken its rows. The stats' `hc_residue` then covers the leading dense
+    stack too: [lead + L]."""
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     x = params["embed"][tokens].astype(cfg.dtype)
+    if residual is not None:
+        x = residual.widen(x)
 
     def body(carry, layer_and_index, mlp=mlp):
         x, cache = carry
         layer, index = layer_and_index
         x, cache, stats = decoder_layer(
-            cfg, x, layer, cache, positions, attention, mlp, index=index)
+            cfg, x, layer, cache, positions, attention, mlp, index=index,
+            residual=residual)
         return (x, cache), stats
 
     cached = cache is not None
@@ -390,7 +516,10 @@ def decoder_trunk(params, tokens, cfg: LlamaConfig, attention, mlp=dense_mlp,
 
     def head(x, normed=False):
         if head_rows is not None:
-            x = jnp.take_along_axis(x, head_rows[:, None, None], axis=1)
+            rows = head_rows[:, None, None]
+            x = jnp.take_along_axis(x, rows if residual is None else rows[..., None], axis=1)
+        if residual is not None:
+            x = residual.merge(x)
         return lm_head(params, x, cfg, normed)
 
     lead = params.get("lead_layers")
@@ -401,10 +530,13 @@ def decoder_trunk(params, tokens, cfg: LlamaConfig, attention, mlp=dense_mlp,
         n_lead = jax.tree.leaves(lead)[0].shape[0]
         lead_body = partial(body, mlp=dense_mlp)
         with jax.named_scope("lead"):
-            (x, cache), _ = jax.lax.scan(
+            (x, cache), lead_stats = jax.lax.scan(
                 lead_body if cached else remat_body(lead_body, cfg), (x, cache),
                 (lead, jnp.arange(n_lead, dtype=jnp.int32) if cached else None))
         (x, cache), stats = stack(x, cache, n_lead if cached else None)
+        # what the leading layers count too (a residual strategy's residue;
+        # `dense_mlp` counts nothing) covers both stacks
+        stats = {**stats, **{k: jnp.concatenate([v, stats[k]]) for k, v in lead_stats.items()}}
         return head(x), cache, stats
 
     if cfg.loop_steps == 1:

@@ -195,10 +195,11 @@ def page_leaves(pool: dict) -> dict:
 
 
 def pool_counters(pool: dict) -> dict:
-    """{name: int} of the pool's `counters` entry: what the family's last step
-    counted (`kimi_k2`: `moe_rows`), for the engine's records. Empty, and
-    nothing is read from the device, for a pool that has none."""
-    return {name: int(v) for name, v in pool.get("counters", {}).items()}
+    """{name: number} of the pool's `counters` entry: what the family's last
+    step counted (`kimi_k2`: `moe_rows`, an int; `xing4`: `hc_residue` beside
+    it, a float), for the engine's records. Empty, and nothing is read from
+    the device, for a pool that has none."""
+    return {name: np.asarray(v).item() for name, v in pool.get("counters", {}).items()}
 
 
 def _n_pages(kv: dict) -> int:

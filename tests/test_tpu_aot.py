@@ -355,6 +355,67 @@ def test_kimi_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw, 
     assert "paged_attention_decode" not in text
 
 
+def _xing4_serve_ep8():
+    """The model of `xing4.0-29b-a4b-serve-ep8-1chip`, from the cell's own
+    file, and the file's engine section."""
+    import json
+    import os
+
+    from benchmarks.harness.families import xing4 as family
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmarks", "configs", "xing4.0-29b-a4b-serve-ep8-1chip.json")
+    with open(path) as f:
+        file = json.load(f)
+    return family.model_config({k: file[k] for k in family.MODEL_KEYS}), file["engine"]
+
+
+@pytest.mark.parametrize("name, B, S, kw, scratch_under, alloc_under", [
+    # the expert layers' sort allocates its words of scratch: s32[304], the
+    # rows of each of the 38 x 8 held experts
+    ("decode", 48, 1, dict(head=0), 0.3e9, 2048),
+    ("prefill", 1, 512, dict(head="last", table_first=True), 0.5e9, 2048),
+], ids=["decode-48", "prefill-512"])
+def test_xing4_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw, scratch_under,
+                                                            alloc_under):
+    """`serve-xing-midin-384-out`'s two largest programs as the engine builds
+    them, at Xing4.0-29B-A4B's published widths and FULL depth (2 dense + 38
+    expert layers, 8 of 64 experts, the whole vocabulary, four residual
+    streams): 48 slots, a 64-block table, the latent pool `bf16[40, 2689, 16,
+    640]` (2.20 GB) donated beside 12.15 GB of weights. They compile for a v5e
+    (an out-of-HBM or Mosaic refusal fails here, not on the chip); the pool,
+    carried through both scans beside FOUR streams, stays in place (PR 33's
+    first finding: the only pool-shaped instructions are the `latent_write`
+    scatters) and no layer's experts are copied (its second: scratch stays
+    under one layer's held experts, 176 MB); the text names the scopes a
+    profile is read by, the hyper-connections' three among them. The
+    compiler's bytes go into the configuration file's `num_blocks_note`."""
+    cfg, engine = _xing4_serve_ep8()
+    assert (engine["max_batch_size"], engine["num_blocks"], engine["block_size"]) == (48, 2689, 16)
+    assert engine["prefill_buckets"][-1] == 512 and cfg.cache_layers == 40 and cfg.hyper.n == 4
+    lowered, pool = _engine_step(v5e[0], cfg, name, B=B, S=S, max_blocks=64,
+                                 pool_blocks=engine["num_blocks"], **kw)
+    compiled = lowered.compile()
+    assert pool["latent"].shape == (40, 2689, 16, 640)
+    assert set(pool["counters"]) == {"moe_rows", "hc_residue"}
+    assert_pool_stays_in_place(compiled, pool, scratch_under=int(scratch_under),
+                               alloc_under=alloc_under)
+    ma = compiled.memory_analysis()
+    print(name, ma.argument_size_in_bytes, ma.output_size_in_bytes, ma.temp_size_in_bytes)
+    # weights 12.15 GB + pool 2.20 GB, and the step's scratch: under 15.0 GB
+    assert 14.3e9 < ma.argument_size_in_bytes < 14.4e9
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 15.0e9
+    # no copy of a layer's held experts (3 x 8 x 3584 x 1024 bf16 = 176 MB)
+    assert ma.temp_size_in_bytes < 3 * 8 * 3584 * 1024 * 2
+    text = compiled.as_text()
+    names = ["lead/", "hc/map", "hc/sinkhorn", "hc/mix", "attn/latent_write",
+             "attn/latent_read", "moe/route", "moe/dispatch", "moe/experts", "moe/combine",
+             "moe/shared", "grouped_matmul_fwd"]
+    for scope in names + (["attn/absorb", "latent_attention_decode"] if S == 1 else []):
+        assert scope in text, scope
+    assert ("latent_attention_decode" in text) == (S == 1)
+
+
 def _engine_step(d, cfg, name, *, B, S, max_blocks=128, pool_blocks, bs=16, **kw):
     """(lowered, the pool's shapes): the engines' own jitted step `name`
     (`serve/llm_paged.py::paged_step`, pool donated) told it runs on a TPU,
