@@ -336,10 +336,13 @@ def init_kv_pool(cfg: KimiK2Config, num_blocks: int, block_size: int) -> dict:
     expert layers together. Beside it `counters` (the `Model` record's
     contract: an engine reports them in its records and moves only the
     pages): `moe_rows`, the (token, choice) pairs the last forward routed to
-    experts held here, summed over layers."""
+    experts held here, summed over layers, and `moe_moved`, the rows its
+    expert layers gathered for them (`moe.held_rows_bound` a layer, more
+    where a router overflowed it)."""
     shape = (cfg.cache_layers, num_blocks, block_size, cfg.latent_row)
     return {"latent": jnp.zeros(shape, dtype=cfg.base.dtype),
-            "counters": {"moe_rows": jnp.zeros((), jnp.int32)}}
+            "counters": {"moe_rows": jnp.zeros((), jnp.int32),
+                         "moe_moved": jnp.zeros((), jnp.int32)}}
 
 
 def forward_paged(params, tokens, cfg: KimiK2Config, pool: dict, tables, lengths,
@@ -371,7 +374,8 @@ def forward_paged(params, tokens, cfg: KimiK2Config, pool: dict, tables, lengths
         partial(moe.moe_mlp, cfg=cfg.experts, platform=platform, stacked=stacked),
         cache={"latent": pool["latent"]}, positions=positions, head_rows=head_rows,
         residual=residual)
-    counters = {"moe_rows": stats["rows"].sum().astype(jnp.int32)}
+    counters = {"moe_rows": stats["rows"].sum().astype(jnp.int32),
+                "moe_moved": stats["moved"].sum().astype(jnp.int32)}
     if residual is not None:
         counters["hc_residue"] = stats["hc_residue"].max()
     return logits, {**cache, "counters": counters}

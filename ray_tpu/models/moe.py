@@ -38,7 +38,15 @@ it, OLMoE and Mixtral none):
   pairs whose expert it holds and computes those experts' part of the sum.
   What the absent experts would have added is left out, and that partial
   result goes on: on one chip the layer runs without the exchange that would
-  bring the other shares in, and nothing stands in for it.
+  bring the other shares in, and nothing stands in for it. A share MOVES the
+  rows it keeps, as the exchange would hand it only those: the held pairs
+  sort first, and where `held_rows_bound` (four times an even router's
+  share of the T * k pairs, in whole row tiles; static) is under T * k the
+  layer gathers, multiplies, masks and sums back that many rows at a time
+  (`_held_rows`): one chunk, or as many as hold every held pair, so nothing
+  is dropped at any routing and no array of T * k rows is built. Where the
+  bound reaches T * k (few tokens, or a large share) the layer is the text
+  it is without a share, with the rows in no group masked.
 
 With an `expert` mesh axis of more than one device the layer takes the dense
 path it takes off the TPU (a Mosaic kernel cannot be partitioned) and leaves
@@ -56,7 +64,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import Model, llama
-from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul
 from ray_tpu.ops.platform import target_platform
 
 
@@ -178,6 +186,60 @@ _dispatch.defvjp(lambda yt, order, inverse: (_dispatch(yt, order, inverse),
                  _dispatch_bwd)
 
 
+HELD_ROWS_OVER_EVEN = 4   # a share's bound over what an even router sends it
+
+
+def held_rows_bound(pairs: int, count: int, num_experts: int) -> int:
+    """C, the rows a share of `count` of `num_experts` experts moves at once
+    of a layer's `pairs` (token, choice) pairs: `HELD_ROWS_OVER_EVEN` times
+    what an evenly spread router sends it, in whole row tiles of the grouped
+    products, and never more than all of them. From shapes alone, so it is
+    static where the layer is traced."""
+    even = -(-pairs * count // num_experts)
+    return min(pairs, -(-HELD_ROWS_OVER_EVEN * even // ROW_TILE) * ROW_TILE)
+
+
+def _held_rows(yt, order, top_p, group_sizes, rows, bound: int, products):
+    """A share's part of the routed sum, moving the rows it computes: the
+    `rows` held pairs lie first in `order` (sorted by expert), and they are
+    taken `bound` at a time. A chunk gathers its pairs' tokens [bound, H],
+    runs `products` over them with the groups' sizes clipped to the chunk,
+    and adds its rows to their tokens as ONE product on the MXU,
+    `W [T, bound] @ ys [bound, H]` with `W[t, c]` the weight of pair c where
+    it is token t's (the same bf16 x bf16 products as a weighted sum over k,
+    accumulated in float32; a scatter-add of the rows is serial on a TPU).
+    One chunk unless the router sends this share more than `bound` pairs;
+    then as many as hold them all, so nothing is dropped at any routing, and
+    no array of T * k rows is ever built. -> (out [T, H], the rows gathered:
+    chunks * bound). Not differentiable (the trip count is data): no caller
+    trains a share."""
+    T, k = top_p.shape
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    order = jnp.pad(order, (0, -order.shape[0] % bound))
+    weights = top_p.reshape(T * k)
+    chunks = (rows + bound - 1) // bound
+
+    def chunk(i, out):
+        lo = i * bound
+        with jax.named_scope("moe/dispatch"):
+            pairs = jax.lax.dynamic_slice(order, (lo,), (bound,))
+            tokens = pairs // k
+            xs = yt[tokens]                                      # [bound, H]
+        with jax.named_scope("moe/experts"):
+            sizes = jnp.clip(ends - lo, 0, bound) - jnp.clip(starts - lo, 0, bound)
+            # rows past the held pairs are in no group: `grouped_matmul`
+            # computes nothing for them and says nothing of what they hold
+            live = lo + jnp.arange(bound) < rows
+            ys = jnp.where(live[:, None], products(xs, sizes), 0)
+        with jax.named_scope("moe/combine"):
+            w = jnp.where(tokens == jnp.arange(T)[:, None], weights[pairs], 0)
+            return out + jnp.dot(w.astype(yt.dtype), ys,
+                                 preferred_element_type=jnp.float32).astype(out.dtype)
+
+    return jax.lax.fori_loop(0, chunks, chunk, jnp.zeros_like(yt)), chunks * bound
+
+
 def router_logits(yt, router_w):
     """[T, H] x [H, E] -> float32 [T, E]: operands as they are (bfloat16 in
     training), accumulated AND returned in float32, so that the softmax and
@@ -211,8 +273,9 @@ def moe_mlp(y, layer, cfg: MoEConfig, platform: str | None = None,
     [T, k]}). `platform` goes to `grouped_matmul` (kernel on "tpu", dense
     otherwise; None: from the operands' placement). With `cfg.experts_held`
     the stats are of the experts held here: "load" [count], "rows" (the pairs
-    routed to them, which is what the products are computed for) and
-    "experts"; no "aux" (the share serves). With `stacked` (`unstacked_experts`)
+    routed to them, which is what the products are computed for), "moved"
+    (the rows the dispatch gathered for them: `held_rows_bound` a chunk, or
+    T * k) and "experts"; no "aux" (the share serves). With `stacked` (`unstacked_experts`)
     the experts' weights are EVERY layer's, [L, E, ...], and this layer's are
     those at `layer["stack_index"]`: the products run over all L * E matrices
     with every other layer's groups empty, so nothing is sliced out."""
@@ -248,37 +311,50 @@ def moe_mlp(y, layer, cfg: MoEConfig, platform: str | None = None,
             jnp.arange(T * k, dtype=jnp.int32), unique_indices=True)
         group_sizes = (choice_e[:, None] == jnp.arange(E, dtype=choice_e.dtype)
                        ).sum(axis=0, dtype=jnp.int32)            # [E]
-    with jax.named_scope("moe/dispatch"):
-        xs = _dispatch(yt, order, inverse)                       # [T * k, H]
-    with jax.named_scope("moe/experts"):
+
+    def products(xs, sizes):
+        """The three grouped products over rows sorted by group: xs [M, H]
+        with `sizes` [E] rows a group held -> [M, H]."""
         # under "dots" remat a Pallas call is no saveable dot: the backward
         # pass runs these three again. Saving their outputs by name was
         # measured (PERF.md section 6, PR 27): 2% faster at equal batch, but
         # it costs the memory of 3 of the 5 sequences a chip holds without.
-        experts, sizes = layer, group_sizes
+        experts = layer
         if stacked is not None:
             n = stacked["e_gate"].shape[0]
             experts = {k: v.reshape(n * E, *v.shape[2:]) for k, v in stacked.items()}
             sizes = jax.lax.dynamic_update_slice(
-                jnp.zeros((n * E,), jnp.int32), group_sizes, (layer["stack_index"] * E,))
+                jnp.zeros((n * E,), jnp.int32), sizes, (layer["stack_index"] * E,))
         gmm = partial(grouped_matmul, group_sizes=sizes, platform=platform)
         hidden = jax.nn.silu(gmm(xs, experts["e_gate"])) * gmm(xs, experts["e_up"])
-        ys = gmm(hidden, experts["e_down"])                      # [T * k, H]
-        if held is not None:
-            # rows past the held pairs are in no group: `grouped_matmul`
-            # computes nothing for them and says nothing of what they hold
-            rows = group_sizes.sum()
-            ys = jnp.where(jnp.arange(T * k)[:, None] < rows, ys, 0)
-    with jax.named_scope("moe/combine"):
-        per_choice = _permute(ys, inverse, order).reshape(T, k, H)
-        out = (per_choice * top_p[..., None].astype(y.dtype)).sum(axis=1)
+        return gmm(hidden, experts["e_down"])
+
+    bound = T * k if held is None else held_rows_bound(T * k, held[1], cfg.num_experts)
+    if bound == T * k:
+        with jax.named_scope("moe/dispatch"):
+            xs = _dispatch(yt, order, inverse)                   # [T * k, H]
+        with jax.named_scope("moe/experts"):
+            ys = products(xs, group_sizes)                       # [T * k, H]
+            if held is not None:
+                # rows past the held pairs are in no group: `grouped_matmul`
+                # computes nothing for them and says nothing of what they hold
+                rows = group_sizes.sum()
+                ys = jnp.where(jnp.arange(T * k)[:, None] < rows, ys, 0)
+        with jax.named_scope("moe/combine"):
+            per_choice = _permute(ys, inverse, order).reshape(T, k, H)
+            out = (per_choice * top_p[..., None].astype(y.dtype)).sum(axis=1)
+        moved = jnp.int32(T * k)
+    else:
+        rows = group_sizes.sum()
+        out, moved = _held_rows(yt, order, top_p, group_sizes, rows, bound, products)
     if "s_gate" in layer:
         with jax.named_scope("moe/shared"):
             out = out + (jax.nn.silu(yt @ layer["s_gate"]) * (yt @ layer["s_up"])
                          ) @ layer["s_down"]
     share = group_sizes.astype(jnp.float32) / (T * k)            # f_e
     if held is not None:
-        stats = {"load": share * cfg.num_experts, "rows": rows, "experts": top_e}
+        stats = {"load": share * cfg.num_experts, "rows": rows, "moved": moved,
+                 "experts": top_e}
     else:
         stats = {"aux": E * (share * probs.mean(axis=0)).sum(), "load": share * E,
                  "experts": top_e}

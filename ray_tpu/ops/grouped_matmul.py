@@ -9,12 +9,12 @@ mixture-of-experts model after its tokens were sorted by expert
 expert, nothing dropped. `sum(group_sizes)` is `M` where a layer holds every
 expert, and LESS where it holds a chip's share of them (PR 33): the rows past
 `sum(group_sizes)` are then in no group, no kernel visits a tile that holds
-only such rows, and what the result holds there is unspecified (the caller
-masks them: `moe_mlp`); a pass costs the live rows' tiles, not `M`'s. The
-backward kernels are held to `sum(group_sizes) == M` (no caller trains a
-share). `jax.lax.ragged_dot` has the same meaning, leaves those rows zero, and
-is the dense path off the TPU (`ops/platform.py` decides, as it does for
-attention).
+only such rows, and what the result holds there is unspecified (`moe_mlp` masks
+them, and its `M` is a bound of rows, not every pair); a pass costs the live
+rows' tiles, not `M`'s. The backward kernels are held to `sum(group_sizes) ==
+M` (no caller trains a share). `jax.lax.ragged_dot` has the same meaning,
+leaves those rows zero, and is the dense path off the TPU (`ops/platform.py`
+decides, as it does for attention).
 
 A `jax.custom_vjp`: d lhs is the same product against `rhs` transposed
 (contracted in the kernel, no transposed copy), d rhs is per group

@@ -199,47 +199,59 @@ def test_the_engine_with_two_sequences_live_matches_the_reference(tiny):
     admits = [r for r in records if "outcome" in r]
     steps = [r for r in records if "live" in r]
     assert len(admits) == 2 and all(r["moe_rows"] > 0 for r in admits)
+    # the rows the expert layers gathered for them: at this size every pair
+    # (a bucket's 16 or 32 tokens x 4 choices x 2 expert layers)
+    assert [r["moe_moved"] for r in admits] == [32 * 4 * 2, 16 * 4 * 2]
     # what a step counted comes to the host with its ids, a pass later: the
     # first pass enqueued a step and read nothing. Until then the count lives
     # in a copy of its own, because the next step is given (and donates) the pool
     assert len(steps) == 4 and "moe_rows" not in steps[0]
-    assert all(r["moe_rows"] > 0 for r in steps[1:])
+    assert all(0 < r["moe_rows"] <= r["moe_moved"] == 2 * 4 * 2 for r in steps[1:])
     assert len(in_flight) == 3 and all(mine is not pools for mine, pools in in_flight)
     assert all(int(mine) > 0 for mine, _ in in_flight)
     assert eng.kv_memory_bytes() == cfg.cache_layers * 9 * BS * cfg.latent_row * 4
 
 
-def test_the_shares_add_up_to_the_uncut_layer(tiny):
-    """(3) The guide's tie: the parts of the routed sum that both shares of
-    the 16 experts give (here through the PROGRAM's `moe_mlp`, each share
+@pytest.mark.parametrize("E, count, T", [(16, 8, 40), (32, 4, 256)],
+                         ids=["halves", "eighths-that-compact"])
+def test_the_shares_add_up_to_the_uncut_layer(tiny, E, count, T):
+    """(3) The guide's tie: the parts of the routed sum that the shares of
+    the E experts give (here through the PROGRAM's `moe_mlp`, each share
     with its own experts' weights), the shared expert counted once, add up
-    to what the uncut reference gives for the whole layer."""
+    to what the uncut reference gives for the whole layer. The halves of 16
+    move every pair (their bound is T x k); an eighth of 32 moves its held
+    pairs a bound of 512 rows at a time (`moe.held_rows_bound`)."""
     model, cfg, params, _ = tiny
-    h, m, E = 64, 32, 16
+    h, m = 64, 32
+    compacts = moe.held_rows_bound(T * 4, count, E) < T * 4
+    assert compacts == (E == 32)
     ks = jax.random.split(jax.random.PRNGKey(5), 9)
     dense = lambda k, *s: jax.random.normal(k, s, jnp.float32) / math.sqrt(s[-2])
     whole = {"router": dense(ks[0], h, E), "router_bias": 0.1 * jax.random.normal(ks[1], (E,)),
              "e_gate": dense(ks[2], E, h, m), "e_up": dense(ks[3], E, h, m),
              "e_down": dense(ks[4], E, m, h), "s_gate": dense(ks[5], h, m),
              "s_up": dense(ks[6], h, m), "s_down": dense(ks[7], m, h)}
-    y = jax.random.normal(ks[8], (1, 40, h), jnp.float32)
+    y = jax.random.normal(ks[8], (1, T, h), jnp.float32)
     with jax.default_matmul_precision("highest"):
         uncut = reference.expert_layer(y[0], whole, model, first=0)
         shared = reference.expert_layer(y[0], {**whole, **{
             k: whole[k][:0] for k in ("e_gate", "e_up", "e_down")}}, model, first=0)
-        parts, rows = [], 0
-        for first in (0, 8):
-            share = {k: v[first:first + 8] if k.startswith("e_") else v
+        parts, rows, moved = [], 0, 0
+        for first in range(0, E, count):
+            share = {k: v[first:first + count] if k.startswith("e_") else v
                      for k, v in whole.items() if not k.startswith("s_")}
-            held = dataclasses.replace(cfg.experts, experts_held=(first, 8))
+            held = dataclasses.replace(cfg.experts, num_experts=E, experts_held=(first, count))
             out, stats = moe.moe_mlp(y, share, held, platform="cpu")
             parts.append(out[0])
             rows += int(stats["rows"])
+            moved += int(stats["moved"])
             # the reference, given the same share, gives the same part
             assert _miss(out[0], reference.expert_layer(
                 y[0], {**whole, **share}, model, first=first, shared=False)) < TOL
-    assert rows == 40 * 4                    # every pair is some share's
-    assert _miss(parts[0] + parts[1] + shared, uncut) < TOL
+    assert rows == T * 4                     # every pair is some share's
+    # a share that compacts gathers its bound, the halves every pair each
+    assert moved == (E // count) * (512 if compacts else T * 4)
+    assert _miss(sum(parts) + shared, uncut) < TOL
     assert _miss(parts[0] + shared, uncut) > 0.1    # one share alone is not the layer
 
 

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.models import llama, model_of, ouro
+from ray_tpu.models import llama, model_of, moe, ouro
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.ops.paged_attention import paged_decode_attention
 from ray_tpu.parallel import sharding as shd
@@ -215,6 +215,27 @@ def pool_sized_instructions(text: str, pool_shape: tuple) -> list[tuple[str, str
     return out
 
 
+def array_lines(text: str, shape: tuple) -> list[str]:
+    """The instructions of a compiled text that produce an array of `shape`
+    (any dtype), bitcasts and fused ones too."""
+    dims = ",".join(map(str, shape))
+    return [ln.strip()[:160] for ln in text.splitlines()
+            if re.match(rf"\s*(?:ROOT )?%\S+ = \w+\[{dims}\]", ln)]
+
+
+def assert_a_share_moves_its_bound(text: str, cfg, tokens: int, compacts: bool) -> None:
+    """A compiled step of `tokens` rows whose expert layers hold a share:
+    where `moe.held_rows_bound` is under the T x k pairs no instruction
+    produces an array of T x k rows of the hidden width (the layers gather a
+    bound at a time, PR 38); where it reaches them the step has such arrays,
+    as it had."""
+    experts = cfg.experts
+    pairs = tokens * experts.top_k
+    assert (moe.held_rows_bound(pairs, experts.experts_held[1], experts.num_experts)
+            < pairs) == compacts
+    assert bool(array_lines(text, (pairs, cfg.base.hidden_size))) == (not compacts)
+
+
 def assert_pool_stays_in_place(compiled, pool, scratch_under: int | None = None,
                                alloc_under: int = 0) -> None:
     """What ISSUE 30 holds a paged step to. `pool` is the pool's shapes (its
@@ -315,8 +336,9 @@ def _kimi_serve_ep32():
     ("decode", 64, 1, dict(head=0), 0.5e9, 512),
     # and the prefill's map over 4 chunks of 16 heads its stacked output,
     # bf16[4, 1, 2048, 16, 128]: ONE attention output of 33.6 MB, a fifth of
-    # a layer's pages (167.8 MB)
-    ("prefill", 1, 2048, dict(head="last", table_first=True), 1.0e9,
+    # a layer's pages (167.8 MB). Scratch: 233.5 MB by the compiler, where it
+    # was 531.6 MB while the expert layer moved all 16,384 pairs (PR 38)
+    ("prefill", 1, 2048, dict(head="last", table_first=True), 0.3e9,
      2048 * 64 * 128 * 2 + 1),
 ], ids=["decode-64", "prefill-2048"])
 def test_kimi_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw, scratch_under,
@@ -331,14 +353,19 @@ def test_kimi_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw, 
     stays in place: the only pool-shaped instructions are the `latent_write`
     scatters; the text names the scopes a profile is read by, and at decode
     the latent kernel. The compiler's bytes are what the configuration
-    file's depth rule is decided by (`num_blocks_note`)."""
+    file's depth rule is decided by (`num_blocks_note`). The expert layers
+    move the rows they keep (`moe.held_rows_bound`: 2,048 of the prefill's
+    16,384 pairs, 256 of the decode step's 512, a bound at a time): NO
+    instruction of either program produces an array of T x k rows of the
+    hidden width, where the prefill had three `bf16[16384, 7168]` a layer."""
     cfg, engine = _kimi_serve_ep32()
     assert (engine["max_batch_size"], engine["num_blocks"], engine["block_size"]) == (64, 8193, 16)
     assert 2048 in engine["prefill_buckets"]
     lowered, pool = _engine_step(v5e[0], cfg, name, B=B, S=S,
                                  pool_blocks=engine["num_blocks"], **kw)
     compiled = lowered.compile()
-    assert pool["latent"].shape == (8, 8193, 16, 640) and pool["counters"]["moe_rows"].shape == ()
+    assert pool["latent"].shape == (8, 8193, 16, 640)
+    assert {k: v.shape for k, v in pool["counters"].items()} == {"moe_rows": (), "moe_moved": ()}
     assert_pool_stays_in_place(compiled, pool, scratch_under=int(scratch_under),
                                alloc_under=alloc_under)
     ma = compiled.memory_analysis()
@@ -353,6 +380,7 @@ def test_kimi_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw, 
         assert scope in text, scope
     assert ("latent_attention_decode" in text) == (S == 1)
     assert "paged_attention_decode" not in text
+    assert_a_share_moves_its_bound(text, cfg, B * S, compacts=True)
 
 
 def _xing4_serve_ep8():
@@ -397,7 +425,7 @@ def test_xing4_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw,
                                  pool_blocks=engine["num_blocks"], **kw)
     compiled = lowered.compile()
     assert pool["latent"].shape == (40, 2689, 16, 640)
-    assert set(pool["counters"]) == {"moe_rows", "hc_residue"}
+    assert set(pool["counters"]) == {"moe_rows", "moe_moved", "hc_residue"}
     assert_pool_stays_in_place(compiled, pool, scratch_under=int(scratch_under),
                                alloc_under=alloc_under)
     ma = compiled.memory_analysis()
@@ -414,6 +442,9 @@ def test_xing4_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw,
     for scope in names + (["attn/absorb", "latent_attention_decode"] if S == 1 else []):
         assert scope in text, scope
     assert ("latent_attention_decode" in text) == (S == 1)
+    # the prefill's expert layers gather 1,024 of their 2,048 pairs; the
+    # decode step's bound is its 192 pairs, and its text what it was
+    assert_a_share_moves_its_bound(text, cfg, B * S, compacts=S == 512)
 
 
 def _engine_step(d, cfg, name, *, B, S, max_blocks=128, pool_blocks, bs=16, **kw):
@@ -655,8 +686,6 @@ def test_moe_train_step_compiles_with_its_kernels_and_scopes(v5e):
     a length that takes the flash kernel: the expert layer reaches the
     grouped kernels from the mesh's platform (this host's backend is the
     CPU), and the compiled text carries the names a profile is read by."""
-    from ray_tpu.models import moe
-
     base = dataclasses.replace(llama.LlamaConfig.tiny(), dtype=jnp.bfloat16,
                                hidden_size=256, intermediate_size=128, head_dim=128, num_heads=2,
                                num_kv_heads=2,
