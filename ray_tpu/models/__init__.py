@@ -23,10 +23,16 @@ class Model(NamedTuple):
     # (static) says that every sequence starts at position 0, so a family may
     # attend over the rows in hand and read nothing back; and (cfg, num_blocks,
     # block_size) -> the pool it reads and writes: a dict of page-shaped
-    # arrays [L, num_blocks, block_size, row], the cache (a PD hand-off moves
-    # them, whatever their names), and, if the family counts anything, one
-    # entry `counters`: {name: int32 or float32 scalar} that the last step left
-    # for the engine's records
+    # arrays, pages on the second axis, [L, num_blocks, ...] (a row a token,
+    # [L, num_blocks, block_size, row], or a kind of state that keeps rows a
+    # BLOCK: `lfm2`'s `conv` [Lc, num_blocks, 2, H]; L is a leaf's own), the
+    # cache (a PD hand-off moves them, whatever their names and whatever
+    # follows the second axis: everything a sequence has lives in its pages),
+    # and, if the family counts anything, one entry `counters`: {name: int32
+    # or float32 scalar} that the last step left for the engine's records.
+    # `head_rows` also says where a call's LIVE tokens end: the tokens after
+    # position `head_rows[b]` are a bucket's padding, and a family whose state
+    # is no row a token writes nothing of them (`lfm2.forward_paged`)
     forward_paged: Optional[Callable] = None
     init_kv_pool: Optional[Callable] = None
     # the dense slot engine: (params, tokens, cfg, cache, lengths,

@@ -17,12 +17,13 @@ TPU-native equivalent of the model stacks those engines provide.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
 import operator
 from functools import partial
-from typing import Any
+from typing import Any, Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -234,11 +235,14 @@ def gqa_attention(attend):
     """The attention strategy of the grouped-query families, around a cache
     strategy `attend(q, k, v, cache, index) -> (o, cache)`: the three
     projections `wq`, `wk`, `wv` (head counts from the projected widths, so a
-    tensor-sharded stage passes its local weights), OLMoE's RMSNorm over the
-    WHOLE projected query and key vector where the layer holds `q_norm` and
-    `k_norm` (before the split into heads and before rope), rope on all of q
-    and k, and `attend` over the rotated heads q [B, S, Hq, D], k/v
-    [B, S, Hkv, D]. `cache` is the WHOLE cache, every layer's, and `index`
+    tensor-sharded stage passes its local weights), an RMSNorm of q and k
+    where the layer holds `q_norm` and `k_norm`, before rope, and the
+    weight's width says over what: OLMoE's over the WHOLE projected vector
+    (a weight of `heads * head_dim`, before the split into heads), or over
+    EACH HEAD's `head_dim` lanes with one weight of that width shared by the
+    heads (after the split), rope on all of q and k, and `attend` over the
+    rotated heads q [B, S, Hq, D], k/v [B, S, Hkv, D]. `cache` is the WHOLE
+    cache, every layer's (and every other kind of layer's), and `index`
     the layer's place in it: `attend` writes this layer's rows in place and
     reads them back (`forward_paged`: pages of the pool; `forward_with_cache`:
     slots), or keeps nothing (`plain_attend`: cache and index are None)."""
@@ -248,13 +252,19 @@ def gqa_attention(attend):
         eps, hd = cfg.rms_eps, cfg.hd
         qk_norm = "q_norm" in layer
         q = y @ layer["wq"]
-        if qk_norm:
+        # a weight as wide as the projection norms the whole vector, one of
+        # `head_dim` each head's lanes (one head: the two are the same)
+        per_head = qk_norm and layer["q_norm"].shape[-1] != q.shape[-1]
+        if qk_norm and not per_head:
             q = rms_norm(q, layer["q_norm"], eps)
         q = q.reshape(B, S, -1, hd)
         k = y @ layer["wk"]
-        if qk_norm:
+        if qk_norm and not per_head:
             k = rms_norm(k, layer["k_norm"], eps)
         k = k.reshape(B, S, -1, hd)
+        if per_head:
+            q = rms_norm(q, layer["q_norm"], eps)
+            k = rms_norm(k, layer["k_norm"], eps)
         v = (y @ layer["wv"]).reshape(B, S, -1, hd)
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
@@ -383,6 +393,9 @@ def decoder_layer(cfg: LlamaConfig, x, layer, cache, positions, attention,
       `gqa_attention(attend)` is the grouped-query families' (a cache
       strategy inside it); `models/kimi_k2.py::latent_attention` caches one
       latent row a token and has a prefill and an absorbed decode path;
+      `models/lfm2.py::short_conv` is no attention at all, a gated 3-tap
+      convolution whose cache is two rows a SEQUENCE, and names its scope
+      (`attention.scope`: the layer opens that in place of `attn`);
     - `mlp(y, layer) -> (out, stats)` on the normalised activations:
       `dense_mlp`, or `moe.moe_mlp`, whose scopes stand beside `mlp`.
 
@@ -403,8 +416,9 @@ def decoder_layer(cfg: LlamaConfig, x, layer, cache, positions, attention,
     eps = cfg.rms_eps
     read = one_stream if residual is None else residual.read
     u, write, attn_residue = read(x, layer, "attn")
-    # the scopes are names in a profile and in the HLO's op_name, no more
-    with jax.named_scope("attn"):
+    # the scopes are names in a profile and in the HLO's op_name, no more; a
+    # mixer that is no attention says its own (`models/lfm2.py::short_conv`)
+    with jax.named_scope(getattr(attention, "scope", "attn")):
         y = rms_norm(u, layer["attn_norm"], eps)
         o, cache = attention(cfg, y, layer, cache, positions, index)
         o = reduce(o.reshape(B, S, -1) @ layer["wo"])
@@ -447,18 +461,50 @@ def lm_head(params, x, cfg: LlamaConfig, normed: bool = False):
     return (x @ head.astype(cfg.dtype)).astype(jnp.float32)
 
 
-def decoder_trunk(params, tokens, cfg: LlamaConfig, attention, mlp=dense_mlp,
-                  cache=None, positions=None, head_rows=None,
-                  residual: HyperConnections | None = None):
-    """Token ids [B, S] -> (float32 logits [B, S, V], the updated cache, the
-    layers' stats stacked): embedding, `lax.scan` of `decoder_layer` over the
-    layers, `lm_head`.
+class Run(NamedTuple):
+    """`count` layers in a row of ONE kind, in a stack whose layers are of
+    several (`decoder_trunk(runs=)`): the kind's parameters are the stack
+    `params[stack]` ([n, ...] a leaf: one stack a kind of mixer and kind of
+    MLP) and the run is its layers [first, first + count); `attention` and
+    `mlp` are the kind's two strategies (`decoder_layer`); `cache_first` is
+    where the run starts in the cache of its kind of MIXER, which counts its
+    own layers (the 3rd attention layer of a stack is KV layer 2 wherever it
+    stands, and a convolution layer between two of them is none); `scope`
+    names the run in a profile, if anything does."""
+    stack: str
+    first: int
+    count: int
+    attention: Callable
+    mlp: Callable
+    cache_first: int = 0
+    scope: str | None = None
 
-    Where the parameters hold `lead_layers` (a family whose first layers are
-    dense ahead of its expert layers: `models/kimi_k2.py`), that shorter stack
-    runs first, a scan of its own with `dense_mlp` and the same attention
-    strategy, at the cache indices [0, its length); `layers` follows at the
-    indices after it, and the stats are the `layers` stack's.
+
+def decoder_trunk(params, tokens, cfg: LlamaConfig, attention=None, mlp=dense_mlp,
+                  cache=None, positions=None, head_rows=None,
+                  residual: HyperConnections | None = None,
+                  runs: Sequence[Run] | None = None):
+    """Token ids [B, S] -> (float32 logits [B, S, V], the updated cache, the
+    layers' stats stacked): embedding, the layers as RUNS of one kind each,
+    `lm_head`. A run is a `lax.scan` of `decoder_layer` over its layers with
+    its kind's two strategies; what every layer of the stack counts comes
+    back stacked over the layers that count it, in their order.
+
+    A family of one kind of layer names no runs: its stack is ONE run,
+    `params["layers"]` whole under `attention` and `mlp`, and the scan slices
+    it. Where the parameters also hold `lead_layers` (a family whose first
+    layers are dense ahead of its expert layers: `models/kimi_k2.py`), that
+    shorter stack is a run ahead of it (scope `lead`) with `dense_mlp` and the
+    same attention strategy, at the cache indices [0, its length), and
+    `layers` follows at the indices after it.
+
+    `runs` (a family whose layers are of several kinds in an order that is no
+    prefix and no period: `models/lfm2.py`, 13 runs of 1 to 3 layers over
+    three stacks) is the stack in order. A run that is PART of its stack scans
+    the layers' places in it and takes each layer out where it is used (what a
+    scan does with its `xs`, so a weight is read once and no slice of a stack
+    is copied for the loop); a run of one layer is the bare body at a constant
+    index. `attention` and `mlp` are then not read.
 
     `head_rows` is which positions' logits the caller reads: None for every
     one, or int32 [B] (traced: one program whatever its values) for position
@@ -497,22 +543,63 @@ def decoder_trunk(params, tokens, cfg: LlamaConfig, attention, mlp=dense_mlp,
     x = params["embed"][tokens].astype(cfg.dtype)
     if residual is not None:
         x = residual.widen(x)
-
-    def body(carry, layer_and_index, mlp=mlp):
-        x, cache = carry
-        layer, index = layer_and_index
-        x, cache, stats = decoder_layer(
-            cfg, x, layer, cache, positions, attention, mlp, index=index,
-            residual=residual)
-        return (x, cache), stats
-
     cached = cache is not None
-    layer_index = jnp.arange(cfg.num_layers, dtype=jnp.int32) if cached else None
+    if runs is None:
+        runs = [Run("layers", 0, cfg.num_layers, attention, mlp)]
+        lead = params.get("lead_layers")
+        if lead is not None:
+            n_lead = jax.tree.leaves(lead)[0].shape[0]
+            runs = [Run("lead_layers", 0, n_lead, attention, dense_mlp, scope="lead"),
+                    runs[0]._replace(cache_first=n_lead)]
+    if len(runs) > 1 and cfg.loop_steps != 1:
+        raise ValueError("a looped stack of more than one run: no family has "
+                         "both, and the cache's indices are unsaid")
+    # the layers' places, in the order the runs' scans were always traced:
+    # the last run's first
+    places = [jnp.arange(run.count, dtype=jnp.int32) for run in reversed(runs)][::-1]
 
-    def stack(x, cache, first_index=None):  # the layers once, over the carry
-        index = layer_index if first_index is None else first_index + layer_index
+    def run_layers(run: Run, place, x, cache, offset):
+        """One run over the carry -> ((x, cache), its stats [count, ...]);
+        `offset` (a pass of a looped stack) is added to its cache indices."""
+        stack = params[run.stack]
+        whole = run.first == 0 and run.count == jax.tree.leaves(stack)[0].shape[0]
+
+        def body(carry, layer_and_index):
+            x, cache = carry
+            layer, index = layer_and_index
+            if not whole:  # its place in the stack: the layer is taken out here
+                layer = jax.tree.map(partial(jax.lax.dynamic_index_in_dim, index=layer,
+                                             keepdims=False), stack)
+            x, cache, stats = decoder_layer(
+                cfg, x, layer, cache, positions, run.attention, run.mlp, index=index,
+                residual=residual)
+            return (x, cache), stats
+
+        index = None
+        if cached:
+            index = place if run.cache_first == 0 else run.cache_first + place
+            index = index if offset is None else offset + index
+        of_stack = stack if whole else run.first + place
+        if run.count == 1 and not whole:
+            carry, stats = body((x, cache), jax.tree.map(lambda a: a[0], (of_stack, index)))
+            return carry, jax.tree.map(lambda a: a[None], stats)
         return jax.lax.scan(body if cached else remat_body(body, cfg), (x, cache),
-                            (params["layers"], index))
+                            (of_stack, index))
+
+    def stack(x, cache, offset=None):  # the layers once, over the carry
+        counted = []
+        for run, place in zip(runs, places):
+            with jax.named_scope(run.scope) if run.scope else contextlib.nullcontext():
+                (x, cache), stats = run_layers(run, place, x, cache, offset)
+            counted.append(stats)
+        # what a layer counts, stacked over the runs whose layers count it
+        # (a leading dense run counts no expert rows)
+        by_name = {}
+        for stats in counted:
+            for name, value in stats.items():
+                by_name.setdefault(name, []).append(value)
+        return (x, cache), {name: values[0] if len(values) == 1 else jnp.concatenate(values)
+                            for name, values in by_name.items()}
 
     def head(x, normed=False):
         if head_rows is not None:
@@ -521,23 +608,6 @@ def decoder_trunk(params, tokens, cfg: LlamaConfig, attention, mlp=dense_mlp,
         if residual is not None:
             x = residual.merge(x)
         return lm_head(params, x, cfg, normed)
-
-    lead = params.get("lead_layers")
-    if lead is not None:
-        if cfg.loop_steps != 1:
-            raise ValueError("leading dense layers ahead of a looped stack: no "
-                             "family has both, and the cache's indices are unsaid")
-        n_lead = jax.tree.leaves(lead)[0].shape[0]
-        lead_body = partial(body, mlp=dense_mlp)
-        with jax.named_scope("lead"):
-            (x, cache), lead_stats = jax.lax.scan(
-                lead_body if cached else remat_body(lead_body, cfg), (x, cache),
-                (lead, jnp.arange(n_lead, dtype=jnp.int32) if cached else None))
-        (x, cache), stats = stack(x, cache, n_lead if cached else None)
-        # what the leading layers count too (a residual strategy's residue;
-        # `dense_mlp` counts nothing) covers both stacks
-        stats = {**stats, **{k: jnp.concatenate([v, stats[k]]) for k, v in lead_stats.items()}}
-        return head(x), cache, stats
 
     if cfg.loop_steps == 1:
         (x, cache), stats = stack(x, cache)
@@ -718,12 +788,27 @@ def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
     the pool holds `loop_steps * L` cache layers and `layer` below is the
     cache layer `pass * L + layer`."""
     B, S = tokens.shape
-    max_blocks = tables.shape[1]
     if platform is None:
         platform = target_platform(tokens, pool["k"])
     if use_kernel is None:
         use_kernel = S == 1 and platform == "tpu" and not fresh
     positions, blk_idx, blk_off = page_rows(tables, lengths, S, block_size)
+    attend = paged_attend(cfg, tables, lengths, positions, blk_idx, blk_off, block_size,
+                          use_kernel, platform, fresh)
+    return decoder_trunk(params, tokens, cfg, gqa_attention(attend), mlp,
+                         cache=pool, positions=positions, head_rows=head_rows)[:2]
+
+
+def paged_attend(cfg: LlamaConfig, tables, lengths, positions, blk_idx, blk_off,
+                 block_size: int, use_kernel: bool, platform: str, fresh: bool):
+    """`forward_paged`'s cache strategy (`gqa_attention(attend)`) over the
+    leaves `k` and `v` of a paged pool, for S new tokens a sequence that land
+    at (blk_idx, blk_off) [B, S] (`page_rows`): the write of their rows, and
+    the read by one of `forward_paged`'s three ways. What else the pool holds
+    (a family's second kind of cache: `models/lfm2.py`) goes through as it
+    is."""
+    B, S = positions.shape
+    max_blocks = tables.shape[1]
     hd, dp = cfg.hd, pool_head_dim(cfg.hd)
 
     def rows(t, dtype):  # [B, S, Hkv, D] -> the pool's rows [B, S, Hkv * Dp]
@@ -749,7 +834,7 @@ def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
                                         interpret=platform != "tpu")
                 else:
                     o = auto_attention(q, k, v, causal=True, platform=platform)
-            return o, {"k": kp, "v": vp}
+            return o, {**pool, "k": kp, "v": vp}
         with jax.named_scope("kv_read"):
             if use_kernel:
                 from ray_tpu.ops.paged_attention import paged_decode_attention
@@ -761,10 +846,9 @@ def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
                 view = lambda p: p[layer, tables].reshape(
                     B, max_blocks * block_size, -1, dp)[..., :hd]
                 o = _cached_attention(q, view(kp), view(vp), lengths, positions)
-        return o, {"k": kp, "v": vp}
+        return o, {**pool, "k": kp, "v": vp}
 
-    return decoder_trunk(params, tokens, cfg, gqa_attention(attend), mlp,
-                         cache=pool, positions=positions, head_rows=head_rows)[:2]
+    return attend
 
 
 def _cached_attention(q, k_cache, v_cache, lengths, q_positions):

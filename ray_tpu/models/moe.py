@@ -79,6 +79,7 @@ class MoEConfig:
     router_aux_coeff: float = 0.01
     score_func: str = "softmax"       # or "sigmoid", chosen with the layer's `router_bias`
     routed_scaling: float = 1.0       # the top_k weights times this
+    norm_topk_eps: float = 0.0        # added to the renormalisation's sum (lfm2: 1e-6)
     # (first, count): the experts whose weights this chip holds, of
     # `num_experts` that the router chooses over; None: all of them
     experts_held: tuple | None = None
@@ -295,7 +296,8 @@ def moe_mlp(y, layer, cfg: MoEConfig, platform: str | None = None,
         else:
             raise ValueError(f"unknown score_func {cfg.score_func!r}")
         if cfg.norm_topk_prob:
-            top_p = top_p / top_p.sum(axis=-1, keepdims=True)
+            total = top_p.sum(axis=-1, keepdims=True)
+            top_p = top_p / (total + cfg.norm_topk_eps if cfg.norm_topk_eps else total)
         if cfg.routed_scaling != 1.0:
             top_p = top_p * cfg.routed_scaling
         choice_e = top_e.reshape(T * k)

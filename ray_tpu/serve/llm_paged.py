@@ -188,9 +188,12 @@ def carry_step():
 
 def page_leaves(pool: dict) -> dict:
     """The cache of a family's pool (`Model.init_kv_pool`): every entry but
-    `counters`, each `[L, num_blocks, block_size, row]`, pages on the second
-    axis. They are what a PD hand-off moves, whatever the family names them
-    (`k` and `v`; a latent family's one `latent`)."""
+    `counters`, each with its pages on the SECOND axis, `[L, num_blocks, ...]`:
+    a row a token (`[L, num_blocks, block_size, row]`: `k` and `v`; a latent
+    family's one `latent`), or rows a block (`models/lfm2.py`'s `conv`,
+    `[Lc, num_blocks, 2, H]`: a convolution's state at the block's end), and
+    L a leaf's own. They are what a PD hand-off moves, `leaf[:, idx]`,
+    whatever the family names them and whatever follows the second axis."""
     return {name: leaf for name, leaf in pool.items() if name != "counters"}
 
 
@@ -638,7 +641,7 @@ class PagedLLMEngine(LLMEngine):
             first_tok = self._sample(np.asarray(logits)[0])
             idx = np.asarray(block_ids, dtype=np.int32)
             kv = kv_ticket = kv_ref = None
-            # the pool's own pages, leaf by leaf: [L, n, bs, row] each
+            # the pool's own pages, leaf by leaf: [L, n, ...] each
             pages = {name: leaf[:, idx] for name, leaf in page_leaves(self.pool).items()}
             if self.config.kv_transfer == "device":
                 # the gather creates independent device arrays (pool blocks
@@ -748,7 +751,7 @@ class PagedLLMEngine(LLMEngine):
             raise ValueError(
                 f"KV handoff carries the leaves {sorted(kv)}; this engine's pool "
                 f"has {sorted(page_leaves(self.pool))}")
-        n_prefill_blocks = _n_pages(kv)   # every leaf of the payload is [L, n, bs, row]
+        n_prefill_blocks = _n_pages(kv)   # every leaf of the payload is [L, n, ...]
         table = handoff.get("block_table")
         if table is not None and len(table) != n_prefill_blocks:
             # descriptor-vs-payload consistency: the block table is the

@@ -31,7 +31,8 @@ import numpy as np
 
 from ray_tpu.models import llama
 from ray_tpu.serve.llm_paged import (_DECODE_PHASES, PagedLLMConfig,
-                                     PagedLLMEngine, paged_step, pool_counters)
+                                     PagedLLMEngine, page_leaves, paged_step,
+                                     pool_counters)
 
 _SPEC_DECODE_PHASES = ("draft",) + _DECODE_PHASES
 
@@ -68,6 +69,20 @@ class SpecDecodeLLMEngine(PagedLLMEngine):
         cfg = self.config.model_config
         dcfg = self.config.draft_model_config
         bs = self.config.block_size
+        per_block = sorted(name for name, leaf in page_leaves(self.pool).items()
+                           if leaf.shape[2] != bs)
+        if per_block:
+            # a row a TOKEN can be rewound: a rejected window's rows lie past
+            # the committed length and the next window overwrites them. Rows a
+            # BLOCK (a recurrent state's last few positions) cannot: a window
+            # that ran K + 1 positions ahead has overwritten the rows the
+            # committed position must resume from (ROADMAP R8)
+            raise ValueError(
+                f"speculative decoding rewinds `lengths` after a rejected window, and "
+                f"this family's pool keeps {per_block} as rows a block, not a row a "
+                f"token ({self.pool[per_block[0]].shape[2]} rows where block_size is "
+                f"{bs}): the state at the committed position is overwritten by the "
+                f"window's later positions and cannot be stepped from again")
         self.draft_params = (self._draft_params_init
                              if self._draft_params_init is not None
                              else llama.init(dcfg, jax.random.PRNGKey(7)))

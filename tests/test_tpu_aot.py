@@ -238,15 +238,15 @@ def assert_a_share_moves_its_bound(text: str, cfg, tokens: int, compacts: bool) 
 
 def assert_pool_stays_in_place(compiled, pool, scratch_under: int | None = None,
                                alloc_under: int = 0) -> None:
-    """What ISSUE 30 holds a paged step to. `pool` is the pool's shapes (its
-    pages are alike: K and V, or a latent family's one leaf); `scratch_under`
-    bounds the step's scratch (default: one layer's pages). No buffer is
-    allocated inside the program, but those a caller names with `alloc_under`,
-    the bytes every one of them stays under."""
+    """What ISSUE 30 holds a paged step to. `pool` is the pool's shapes (K and
+    V, a latent family's one leaf, or leaves of several shapes: `lfm2`'s `conv`
+    beside its `k` and `v`); `scratch_under` bounds the step's scratch
+    (default: one layer's pages of the largest leaf). No buffer is allocated
+    inside the program, but those a caller names with `alloc_under`, the bytes
+    every one of them stays under."""
     text = compiled.as_text()
     pages = [leaf for name, leaf in pool.items() if name != "counters"]
-    page = pages[0]
-    layer_bytes = math.prod(page.shape[1:]) * page.dtype.itemsize
+    layer_bytes = max(math.prod(page.shape[1:]) * page.dtype.itemsize for page in pages)
     # the donation is honoured: every page-shaped leaf aliases an output
     aliases = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
     assert aliases and len(re.findall(r"(may|must)-alias", aliases.group(1))) >= len(pages)
@@ -261,9 +261,10 @@ def assert_pool_stays_in_place(compiled, pool, scratch_under: int | None = None,
     # parameters, tuple elements and bitcasts move nothing; every other
     # producer of a pool is a write into it, and the only one a step has is
     # the row scatter of the model's own `kv_write`
-    moved = [(op, ln[:200]) for op, ln in pool_sized_instructions(text, page.shape)
-             if op not in ("parameter", "get-tuple-element", "bitcast", "scatter")]
-    assert not moved, moved
+    for shape in {page.shape for page in pages}:
+        moved = [(op, ln[:200]) for op, ln in pool_sized_instructions(text, shape)
+                 if op not in ("parameter", "get-tuple-element", "bitcast", "scatter")]
+        assert not moved, moved
     # nothing the size of a layer's pages is scratch either
     assert compiled.memory_analysis().temp_size_in_bytes < (scratch_under or layer_bytes)
 
@@ -445,6 +446,72 @@ def test_xing4_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw,
     # the prefill's expert layers gather 1,024 of their 2,048 pairs; the
     # decode step's bound is its 192 pairs, and its text what it was
     assert_a_share_moves_its_bound(text, cfg, B * S, compacts=S == 512)
+
+
+def _lfm2_serve_ep2():
+    """The model of `lfm2-8b-a1b-serve-ep2-1chip`, from the cell's own file,
+    and the file's engine section."""
+    import json
+    import os
+
+    from benchmarks.harness.families import lfm2 as family
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmarks", "configs", "lfm2-8b-a1b-serve-ep2-1chip.json")
+    with open(path) as f:
+        file = json.load(f)
+    return family.model_config({k: file[k] for k in family.MODEL_KEYS}), file["engine"]
+
+
+@pytest.mark.parametrize("name, B, S, kw, scratch_under", [
+    ("decode", 32, 1, dict(head=0), 0.05e9),
+    ("prefill", 1, 2048, dict(head="last", table_first=True, fresh=True), 0.3e9),
+    ("prefill", 1, 4096, dict(head="last", table_first=True, fresh=True), 0.4e9),
+], ids=["decode-32", "prefill-2048", "prefill-4096"])
+def test_lfm2_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw, scratch_under):
+    """`serve-lfm2-docs4k-96-out`'s three programs as the engine builds them,
+    at LFM2-8B-A1B's published widths and FULL depth (18 convolution and 6
+    attention layers in 13 runs over three parameter stacks, 16 of 32 experts,
+    the whole vocabulary, the head tied): 32 slots, a 264-block table, the
+    pool's THREE leaves donated beside 8.93 GB of weights: `k`, `v`
+    `bf16[6, 8385, 16, 1024]` (64-wide heads in 128-lane tiles) and `conv`
+    `bf16[18, 8385, 2, 2048]`, 4.53 GB. They compile for a v5e; every leaf
+    stays in place through the runs' scans and bare bodies (the only
+    pool-shaped instructions are the row scatters of `attn/kv_write` and
+    `conv/state_write`), a run that is part of its stack copies no slice of
+    it and no layer's experts are copied (scratch stays under one layer's
+    held experts, 352 MB, and far under a stack's 16 convolution mixers); the
+    text names the scopes a profile is read by. The compiler's bytes go into
+    the configuration file's `num_blocks_note`."""
+    cfg, engine = _lfm2_serve_ep2()
+    assert (engine["max_batch_size"], engine["num_blocks"], engine["block_size"]) == (32, 8385, 16)
+    assert engine["prefill_buckets"] == [2048, 4096]
+    assert (cfg.cache_layers("conv"), cfg.cache_layers("attn"), cfg.base.hd) == (18, 6, 64)
+    lowered, pool = _engine_step(v5e[0], cfg, name, B=B, S=S, max_blocks=264,
+                                 pool_blocks=engine["num_blocks"], **kw)
+    compiled = lowered.compile()
+    assert pool["k"].shape == pool["v"].shape == (6, 8385, 16, 1024)
+    assert pool["conv"].shape == (18, 8385, 2, 2048)
+    assert set(pool["counters"]) == {"moe_rows", "moe_moved"}
+    assert_pool_stays_in_place(compiled, pool, scratch_under=int(scratch_under), alloc_under=2048)
+    ma = compiled.memory_analysis()
+    print(name, S, ma.argument_size_in_bytes, ma.output_size_in_bytes, ma.temp_size_in_bytes)
+    # weights 8.93 GB + pool 4.53 GB, and the step's scratch: under 15.0 GB
+    assert 13.4e9 < ma.argument_size_in_bytes < 13.5e9
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 15.0e9
+    text = compiled.as_text()
+    # the two rows of a block are not padded to a tile of 8 or 16 sublanes
+    assert "bf16[18,8385,2,2048]{3,2,1,0:T(2,128)(2,1)}" in text
+    names = ["conv/in_proj", "conv/mix", "conv/state_write", "attn/kv_write", "moe/route",
+             "moe/dispatch", "moe/experts", "moe/combine", "grouped_matmul_fwd", "mlp/"]
+    names += (["conv/state_read", "attn/kv_read", "paged_attention_decode"] if S == 1
+              else ["attn/prompt_attend", "flash_attention_fwd"])
+    for scope in names:
+        assert scope in text, scope
+    # a fresh prefill reads no state and no K/V back
+    assert ("conv/state_read" in text) == ("attn/kv_read" in text) == (S == 1)
+    # 16 of 32 held: the bound is every pair, the step moves T x k rows
+    assert_a_share_moves_its_bound(text, cfg, B * S, compacts=False)
 
 
 def _engine_step(d, cfg, name, *, B, S, max_blocks=128, pool_blocks, bs=16, **kw):
