@@ -20,12 +20,17 @@ of the published interleaved order's weight columns.
 
 The cache is the LATENT: a token's row is `[c_kv | k_r | zeros]`, `kv_lora_rank
 + rope` values in whole 128-lane tiles (576 in 640), one leaf `[L, NB, BS,
-row]`, token-major and written in place as `llama.init_kv_pool`'s. Two paths
-read it, and both multiply the SAME tensors `w_uk` [Hq, nope, rank] and `w_uv`
+row]`, token-major and written in place as `llama.init_kv_pool`'s. Three paths
+attend, and all multiply the SAME tensors `w_uk` [Hq, nope, rank] and `w_uv`
 [Hq, rank, v]:
 
-- prefill (S > 1): the gathered latent view of the sequence's pages is
-  up-projected to per-head keys and values and attended per head;
+- a prefill that continues a cached prefix (S > 1): the gathered latent view
+  of the sequence's pages is up-projected to per-head keys and values and
+  attended per head, in chunks of heads;
+- a fresh prefill (S > 1, every sequence at position 0): the S latent rows in
+  hand are up-projected and attended causally over themselves, nothing read
+  back: `ops/flash_attention.py`'s forward on 192-wide q/k beside 128-wide v
+  from S = 1,024 up on a TPU, the dense product over [S, S] under that;
 - decode (S == 1), the projection ABSORBED: `q_lat = q_nope W_uk^T` (rank a
   head), scores `q_lat . c_kv + q_rope . k_r`, `o_lat = p c_kv`, `o = o_lat
   W_uv`. Per-head keys are never formed: every head reads the one shared row
@@ -239,13 +244,20 @@ def _head_chunks(heads: int, q_len: int, k_len: int) -> int:
 
 
 def latent_attention(cfg: KimiK2Config, tables, lengths, blk_idx, blk_off,
-                     block_size: int, use_kernel: bool, interpret: bool):
+                     block_size: int, use_kernel: bool, interpret: bool,
+                     fresh: bool = False):
     """The attention strategy over the paged latent pool (`decoder_layer`'s
     `attention`): the projections, the rotation, the write of the tokens'
-    latent rows at (layer, blk_idx, blk_off) and the read back, absorbed at
-    S == 1 and up-projected otherwise. Scopes: `attn/latent_write`,
-    `attn/latent_read` (the kernel inside it), `attn/absorb` (the two
-    per-head products around it)."""
+    latent rows at (layer, blk_idx, blk_off) and the read, one of three:
+    absorbed through the table at S == 1; at S > 1 when `fresh` (every
+    sequence starts at position 0: `forward_paged`) up-projected from the S
+    rows IN HAND and attended causally over them, the flash forward kernel
+    with `use_kernel`, the dense float32-softmax product over [S, S]
+    without; otherwise up-projected from the gathered table in chunks of
+    heads. Scopes: `attn/latent_write`, then `attn/latent_read` (the decode
+    kernel inside it) with `attn/absorb` (the two per-head products around
+    it), or `attn/prompt_attend` (it reads no pool, so it is not
+    `latent_read`'s)."""
     nope, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     rank, row = cfg.kv_lora_rank, cfg.latent_row
     inv_freq = yarn_inv_freq(rd, cfg.base.rope_theta, cfg.yarn)
@@ -256,6 +268,18 @@ def latent_attention(cfg: KimiK2Config, tables, lengths, blk_idx, blk_off,
     def as_row(latent, rotary):  # [..., rank], [..., rope] -> the pool's row layout
         t = jnp.concatenate([latent, rotary], axis=-1)
         return jnp.pad(t, [(0, 0)] * (t.ndim - 1) + [(0, row - rank - rd)])
+
+    def may_see(positions, k_len):  # [B, 1, S, k_len]: key t is at or before query s
+        kpos = jax.lax.broadcasted_iota(jnp.int32, (B, k_len), 1)
+        return kpos[:, None, None, :] <= positions[:, None, :, None]
+
+    def attend(scores, valid, values, out):  # float32 softmax between two einsums
+        p = jax.nn.softmax(jnp.where(valid, scores.astype(jnp.float32) * scale,
+                                     -1e30), axis=-1)
+        return jnp.einsum(out, p.astype(values.dtype), values)
+
+    def up_project(c, w_uk, w_uv):  # latent rows -> per-head keys' nope lanes, values
+        return jnp.einsum("btc,hdc->bthd", c, w_uk), jnp.einsum("btc,hcd->bthd", c, w_uv)
 
     def attention(_, y, layer, pool, positions, index):
         S = y.shape[1]
@@ -273,14 +297,15 @@ def latent_attention(cfg: KimiK2Config, tables, lengths, blk_idx, blk_off,
 
         def gathered():  # the sequences' pages as one view, and who may see what
             view = lat[index, tables].reshape(B, max_blocks * block_size, row)
-            kpos = jax.lax.broadcasted_iota(jnp.int32, view.shape[:2], 1)
-            valid = kpos[:, None, None, :] <= positions[:, None, :, None]
+            valid = may_see(positions, view.shape[1])
             return view[..., :rank], view[..., rank:rank + rd], valid
 
-        def attend(scores, values, out):  # float32 softmax between two einsums
-            p = jax.nn.softmax(jnp.where(valid, scores.astype(jnp.float32) * scale,
-                                         -1e30), axis=-1)
-            return jnp.einsum(out, p.astype(values.dtype), values)
+        def per_head(args):  # a chunk of heads: up-project, attend
+            qn, qr, w_uk, w_uv = args
+            k_nope, v = up_project(c, w_uk, w_uv)
+            return attend(jnp.einsum("bshd,bthd->bhst", qn, k_nope)
+                          + jnp.einsum("bshr,btr->bhst", qr, kr),
+                          valid, v, "bhst,bthd->bshd")
 
         if S == 1:
             with jax.named_scope("absorb"):
@@ -296,23 +321,33 @@ def latent_attention(cfg: KimiK2Config, tables, lengths, blk_idx, blk_off,
                     c, kr, valid = gathered()
                     o_lat = attend(jnp.einsum("bshc,btc->bhst", q_lat, c)
                                    + jnp.einsum("bshr,btr->bhst", q_rope, kr),
-                                   c, "bhst,btc->bshc")
+                                   valid, c, "bhst,btc->bshc")
             with jax.named_scope("absorb"):
                 o = jnp.einsum("bshc,hcd->bshd", o_lat.astype(y.dtype), layer["w_uv"])
+            return o, {"latent": lat}
+        if fresh:
+            # every key a row may see is a row of this call, as the pool holds
+            # it; a bucket's padding lies after the live rows, which under the
+            # causal mask never see it. Every head at once: no chunk, no stack
+            with jax.named_scope("prompt_attend"):
+                c, kr = c_kv.astype(lat.dtype), k_r.astype(lat.dtype)
+                if use_kernel:
+                    from ray_tpu.ops.flash_attention import flash_attention
+
+                    k_nope, v = up_project(c, layer["w_uk"], layer["w_uv"])
+                    k = jnp.concatenate(  # the one rotated key under every head
+                        [k_nope, jnp.broadcast_to(kr[:, :, None], (*k_nope.shape[:3], rd))],
+                        axis=-1)
+                    o = flash_attention(jnp.concatenate([q_nope, q_rope], axis=-1), k, v,
+                                        causal=True, scale=scale, interpret=interpret)
+                else:
+                    valid = may_see(positions, S)
+                    o = per_head((q_nope, q_rope, layer["w_uk"], layer["w_uv"]))
             return o, {"latent": lat}
         with jax.named_scope("latent_read"):
             c, kr, valid = gathered()
             heads = q.shape[2]
             chunks = _head_chunks(heads, S, c.shape[1])
-
-            def per_head(args):  # a chunk of heads: up-project, attend
-                qn, qr, w_uk, w_uv = args
-                k_nope = jnp.einsum("btc,hdc->bthd", c, w_uk)
-                v = jnp.einsum("btc,hcd->bthd", c, w_uv)
-                return attend(jnp.einsum("bshd,bthd->bhst", qn, k_nope)
-                              + jnp.einsum("bshr,btr->bhst", qr, kr),
-                              v, "bhst,bthd->bshd")
-
             if chunks == 1:
                 o = per_head((q_nope, q_rope, layer["w_uk"], layer["w_uv"]))
             else:
@@ -351,21 +386,31 @@ def forward_paged(params, tokens, cfg: KimiK2Config, pool: dict, tables, lengths
                   residual: llama.HyperConnections | None = None):
     """`llama.forward_paged`'s contract over the latent pool: tokens [B, S]
     append at positions [lengths, lengths + S) -> (logits, the updated pool).
-    `use_kernel` (default: on a TPU at S == 1) reads the pool through the
-    latent kernel, interpreted off the TPU. `fresh` (every sequence starts at
-    position 0) changes nothing here: a prefill reads the latent rows back
-    through the table either way, because the flash forward takes no 192-wide
-    q/k beside 128-wide v (ROADMAP S6). `residual` is the trunk's (a family
-    that runs this block on several streams: `models/xing4.py`), and with it
-    the pool's counters gain `hc_residue`."""
+    Which rows a layer's queries read, three ways, as `llama.forward_paged`'s:
+    the decode step (S == 1) the live pages through the latent kernel,
+    absorbed; a prefill told `fresh` (every sequence starts at position 0: a
+    fact the CALLER states, statically, the engine from its span) the S rows
+    it has just computed, up-projected in hand and attended causally, nothing
+    read back from the pool, no table gathered, no column past S scored
+    (`ops/flash_attention.py` takes the 192-wide q/k beside the 128-wide v
+    since PR 41); everything else (a prompt that continues a cached prefix,
+    the speculative window) the gathered table in chunks of heads.
+    `use_kernel` reads through the family's kernels, interpreted off the TPU:
+    the latent decode kernel at S == 1, the flash forward over a fresh
+    prompt's rows; its default is a TPU's, S == 1 or `llama.flash_pays` (from
+    the 1,024 bucket up; the dense float32-softmax product over [S, S] under
+    that). `residual` is the trunk's (a family that runs this block on several
+    streams: `models/xing4.py`), and with it the pool's counters gain
+    `hc_residue`."""
     B, S = tokens.shape
     if platform is None:
         platform = target_platform(tokens, pool["latent"])
     if use_kernel is None:
-        use_kernel = S == 1 and platform == "tpu"
+        use_kernel = (llama.flash_pays(S, platform) if fresh and S > 1
+                      else S == 1 and platform == "tpu")
     positions, blk_idx, blk_off = llama.page_rows(tables, lengths, S, block_size)
     attention = latent_attention(cfg, tables, lengths, blk_idx, blk_off, block_size,
-                                 use_kernel, platform != "tpu")
+                                 use_kernel, platform != "tpu", fresh)
     # the experts' weights stay where they are: the scan hands a layer its
     # index into them, not a slice (`moe.unstacked_experts`)
     layers, stacked = moe.unstacked_experts(params["layers"])

@@ -198,14 +198,20 @@ def attention(q, k, v, causal: bool = True, mask=None):
     return out.reshape(B, S, Hq, D)
 
 
+def flash_pays(seq_len: int, platform: str) -> bool:
+    """The crossover of a causal attention over rows in hand, decided here for
+    every family: from S = 1,024 up on a TPU the [S, S] score matrix
+    dominates HBM traffic and the flash forward kernel wins; shorter
+    sequences fit XLA's fused dense path, and off the TPU the kernel only
+    runs interpreted."""
+    return platform == "tpu" and seq_len >= 1024
+
+
 def auto_attention(q, k, v, causal: bool = True, platform: str | None = None):
     """Pallas flash kernel for long causal sequences placed on a TPU, dense
     MXU attention otherwise.
 
-    The crossover: at S>=1024 the [S,S] score matrix dominates HBM traffic and
-    the blockwise-softmax kernel wins; short sequences fit XLA's fused dense
-    path. Off-TPU the kernel only runs in interpret mode (slow), so dense is
-    used there unconditionally. `platform` is where the computation runs:
+    The crossover is `flash_pays`'s. `platform` is where the computation runs:
     callers that know their mesh pass it (train/spmd.py default_attn_fn);
     None derives it from q/k/v's placement (ops/platform.py).
 
@@ -216,7 +222,7 @@ def auto_attention(q, k, v, causal: bool = True, platform: str | None = None):
     under that)."""
     if platform is None:
         platform = target_platform(q, k, v)
-    if causal and platform == "tpu" and q.shape[1] >= 1024:
+    if causal and flash_pays(q.shape[1], platform):
         from ray_tpu.ops.flash_attention import flash_attention
 
         return flash_attention(q, k, v, causal=True, interpret=False)

@@ -27,9 +27,16 @@ How the three kernels use the chip:
   index map (`head // g`), and dK/dV accumulate over the g query heads of a
   group inside the kernel (group member and query tile share the sequential
   axis). Nothing is repeated in HBM and nothing is summed afterwards.
-* `1/sqrt(D)` is folded into q before the kernels (XLA fuses it into the head
-  transpose that is there anyway; autodiff scales dQ the same way), so no
-  kernel multiplies a score tile by it.
+* `1/sqrt(D)`, or the caller's `scale`, is folded into q before the kernels
+  (XLA fuses it into the head transpose that is there anyway; autodiff scales
+  dQ the same way), so no kernel multiplies a score tile by it.
+* The FORWARD takes two widths: q and k [., S, D] beside v and o [., S, Dv]
+  (latent attention's 192 = 128 + 64 rotary beside 128). The kernel's body
+  reads its shapes from its refs; the v and o blocks, the accumulator and the
+  output are Dv wide, and `choose_tiles` counts both widths in whole 128-lane
+  tiles. 192 is no whole number of them and rides as the array's full last
+  dimension, which Mosaic compiles for a v5e (tests/test_tpu_aot.py). The
+  backward kernels keep one width; differentiating two raises.
 * dK/dV works on transposed tiles (S^T = K Q^T), so P^T dO and dS^T Q are plain
   products and `lse`/`delta` broadcast along sublanes as [1, block_q] rows;
   no tile is transposed in any kernel.
@@ -84,21 +91,24 @@ def padded_len(seq_len: int) -> int:
 
 
 def tile_vmem_bytes(kernel: str, block_q: int, block_k: int, head_dim: int,
-                    itemsize: int) -> int:
+                    itemsize: int, v_dim: int | None = None) -> int:
     """VMEM one grid step holds: double-buffered blocks, accumulators, and two
     float32 score-sized temporaries (what Mosaic keeps live of S, P, dP, dS;
     from lowering `vmem_limit_bytes` until the v5e compile fails, the three
     kernels need 10, 8 and 8 MiB at 1024 x 1024, D=128, bfloat16, where this
-    says 10.5, 11 and 12)."""
+    says 10.5, 11 and 12). Widths count in whole 128-lane tiles. `v_dim` is
+    the forward's second width (v and o beside q and k): one of its two
+    blocks a side, and its accumulator."""
     q_blocks, k_blocks, q_accs, k_accs = _TILE_USE[kernel]
     d = -(-head_dim // LANES) * LANES
-    blocks = 2 * itemsize * d * (q_blocks * block_q + k_blocks * block_k)
-    accs = 4 * d * (q_accs * block_q + k_accs * block_k)
+    dv = d if v_dim is None else -(-v_dim // LANES) * LANES
+    blocks = itemsize * (d + dv) * (q_blocks * block_q + k_blocks * block_k)
+    accs = 4 * dv * (q_accs * block_q + k_accs * block_k)
     return blocks + accs + 2 * block_q * block_k * 4
 
 
 def choose_tiles(seq_len: int, head_dim: int, itemsize: int,
-                 kernel: str) -> tuple[int, int]:
+                 kernel: str, v_dim: int | None = None) -> tuple[int, int]:
     """(block_q, block_k) for `kernel` ("fwd", "dq" or "dkv"): the fewest grid
     steps whose tiles divide `padded_len(seq_len)`, are multiples of 128 (or
     the whole padded sequence) and fit VMEM_BUDGET. Among equals the key tile
@@ -109,7 +119,7 @@ def choose_tiles(seq_len: int, head_dim: int, itemsize: int,
     edges = [t for t in range(LANES, min(S, MAX_TILE) + 1, LANES) if S % t == 0]
     edges = edges or [S]
     fits = [(bq * bk, bk, bq) for bq in edges for bk in edges
-            if tile_vmem_bytes(kernel, bq, bk, head_dim, itemsize) <= VMEM_BUDGET]
+            if tile_vmem_bytes(kernel, bq, bk, head_dim, itemsize, v_dim) <= VMEM_BUDGET]
     _, bk, bq = max(fits) if fits else (0, edges[0], edges[0])
     return bq, bk
 
@@ -301,30 +311,39 @@ def _by_query_tile(S, D, g, bq, bk, causal):
 
 
 def _fwd_call(qbh, kbh, vbh, causal, blocks, interpret, kv_len):
+    """The one kernel that takes two widths: q and k [., S, D], v and o
+    [., S, Dv]. The kernel's body reads its shapes from its refs."""
     BH, S, D = qbh.shape
+    Dv = vbh.shape[2]
     bq, bk = blocks
-    tables, q_spec, kv_spec, col_spec = _by_query_tile(
+    tables, q_spec, k_spec, col_spec = _by_query_tile(
         S, D, BH // kbh.shape[0], bq, bk, causal)
+    o_spec = pl.BlockSpec((1, bq, Dv), q_spec.index_map)   # q's tile, v's width
+    v_spec = pl.BlockSpec((1, bk, Dv), k_spec.index_map)
     kernel = functools.partial(_fwd_kernel, block_q=bq, block_k=bk, causal=causal,
                                kv_len=kv_len, seq_len=S)
     return _call(
         kernel, "flash_attention_fwd", tables,
-        [q_spec, kv_spec, kv_spec], [q_spec, col_spec],
-        [jax.ShapeDtypeStruct((BH, S, D), qbh.dtype),
+        [q_spec, k_spec, v_spec], [o_spec, col_spec],
+        [jax.ShapeDtypeStruct((BH, S, Dv), qbh.dtype),
          jax.ShapeDtypeStruct((BH, S, 1), jnp.float32)],
-        [(bq, 1), (bq, 1), (bq, D)], BH, interpret)(qbh, kbh, vbh)
+        [(bq, 1), (bq, 1), (bq, Dv)], BH, interpret)(qbh, kbh, vbh)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_bh(qbh, kbh, vbh, causal, blocks, interpret, kv_len):
-    """qbh [B*Hq, S, D] (already scaled by 1/sqrt(D)), kbh/vbh [B*Hkv, S, D]
-    -> [B*Hq, S, D]. `blocks`: (block_q, block_k) of fwd, dQ and dK/dV;
+    """qbh [B*Hq, S, D] (already scaled), kbh [B*Hkv, S, D], vbh [B*Hkv, S,
+    Dv] -> [B*Hq, S, Dv]. `blocks`: (block_q, block_k) of fwd, dQ and dK/dV;
     `kv_len` masks padded key rows."""
     o, _ = _fwd_call(qbh, kbh, vbh, causal, blocks[0], interpret, kv_len)
     return o
 
 
 def _flash_bh_fwd(qbh, kbh, vbh, causal, blocks, interpret, kv_len):
+    if vbh.shape[2] != qbh.shape[2]:
+        raise NotImplementedError(
+            f"flash_attention differentiates at one head width only: q and k are "
+            f"{qbh.shape[2]} wide, v {vbh.shape[2]} (the backward kernels keep one width)")
     o, lse = _fwd_call(qbh, kbh, vbh, causal, blocks[0], interpret, kv_len)
     return o, (qbh, kbh, vbh, o, lse)
 
@@ -375,11 +394,19 @@ _flash_bh.defvjp(_flash_bh_fwd, _flash_bh_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int | None = None,
-                    block_k: int | None = None, interpret: bool | None = None):
-    """Drop-in attn_fn for models.llama: q [B,S,Hq,D], k/v [B,S,Hkv,D] (GQA).
+                    block_k: int | None = None, interpret: bool | None = None,
+                    scale: float | None = None):
+    """Drop-in attn_fn for models.llama: q [B,S,Hq,D], k [B,S,Hkv,D], v
+    [B,S,Hkv,Dv] (GQA) -> [B,S,Hq,Dv].
 
-    Differentiable (custom VJP with flash backward kernels). Tiles come from
-    the shapes (`choose_tiles`) unless `block_q`/`block_k` name them.
+    Differentiable where Dv == D (custom VJP with flash backward kernels);
+    the FORWARD also takes a v of another width than q and k (latent
+    attention's 192-wide q/k beside 128-wide v, `models/kimi_k2.py`), and
+    differentiating that raises. `scale` multiplies the scores (None:
+    `1/sqrt(D)`). At equal widths and `scale=None` the function lowers to
+    the text it lowered to before it took either (tests/test_ops.py holds
+    the hashes). Tiles come from the shapes (`choose_tiles`) unless
+    `block_q`/`block_k` name them.
     `interpret=None` compiles the kernel when q/k/v are placed on a TPU and
     interprets it anywhere else (ops/platform.py); callers that know their
     mesh pass it.
@@ -387,10 +414,10 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int | None = None,
     if interpret is None:
         interpret = target_platform(q, k, v) != "tpu"
     B, S, Hq, D = q.shape
-    Hkv = k.shape[2]
+    Dv = v.shape[3]
     if block_q is None and block_k is None:
         S_pad = padded_len(S)
-        blocks = tuple(choose_tiles(S, D, q.dtype.itemsize, kernel)
+        blocks = tuple(choose_tiles(S, D, q.dtype.itemsize, kernel, Dv)
                        for kernel in ("fwd", "dq", "dkv"))
     else:
         bq, bk = min(block_q or block_k, S), min(block_k or block_q, S)
@@ -402,10 +429,10 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int | None = None,
         pad = [(0, 0), (0, S_pad - S), (0, 0), (0, 0)]
         q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
 
-    def heads_first(x):   # [B, S, H, D] -> [B*H, S, D]
-        return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2], S_pad, D)
+    def heads_first(x):   # [B, S, H, d] -> [B*H, S, d]
+        return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2], S_pad, x.shape[3])
 
-    scale = jnp.asarray(1.0 / math.sqrt(D), q.dtype)
+    scale = jnp.asarray(1.0 / math.sqrt(D) if scale is None else scale, q.dtype)
     obh = _flash_bh(heads_first(q * scale), heads_first(k), heads_first(v),
                     causal, blocks, interpret, S)
-    return obh.reshape(B, Hq, S_pad, D).transpose(0, 2, 1, 3)[:, :S]
+    return obh.reshape(B, Hq, S_pad, Dv).transpose(0, 2, 1, 3)[:, :S]

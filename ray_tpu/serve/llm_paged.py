@@ -126,8 +126,8 @@ def prefill_reads(start: int) -> str:
     call computes, `paged_step(fresh=True)`) or "table" (the suffix of a
     prompt whose first blocks the prefix cache held: the pool through the
     block table). An `admit` record notes it as `reads`. It names the program
-    the engine chose: a family whose forward does nothing with `fresh` (the
-    latent one, `models/kimi_k2.py`) reads its table in both."""
+    the engine chose, and every family's forward does what the name says
+    (the latent one, `models/kimi_k2.py`, since PR 41)."""
     return "own_rows" if start == 0 else "table"
 
 

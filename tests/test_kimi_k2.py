@@ -148,6 +148,47 @@ def test_a_prefill_cut_into_chunks_of_heads_is_the_uncut_one(tiny, monkeypatch, 
     assert int(cut_pool["counters"]["moe_rows"]) == int(uncut_pool["counters"]["moe_rows"])
 
 
+@pytest.mark.parametrize("use_kernel", [None, True], ids=["dense-own-rows", "flash-interpreted"])
+def test_a_fresh_prefill_over_its_own_rows_is_the_table_program_s(tiny, use_kernel):
+    """`forward_paged(fresh=True)`: a prompt that starts at position 0
+    attends over the rows it has just computed and reads nothing back. A
+    64-token bucket whose last 18 rows are padding, in a slot whose pages are
+    out of order beside an idle slot: every row's logits are the table
+    program's (the live ones the reference's) and the pool holds the SAME
+    rows: both write before they read, so the first layer's are the table
+    program's bit for bit and a later layer's follow its inputs, the
+    attention before it summed in another order. Under the crossover
+    and off the TPU (`use_kernel=None`) that is the family's dense product
+    over [S, S]; `use_kernel=True` is what the 1,024 bucket up runs on a TPU,
+    the flash forward on 48-wide q/k beside 32-wide v with the family's
+    `softmax_scale`, interpreted here (`llama.forward_paged`'s convention)."""
+    model, cfg, params, tokens = tiny
+    tables = jnp.asarray([[0, 0, 0, 0], [3, 1, 7, 2]], jnp.int32)
+    toks = np.zeros((2, 64), np.int32)
+    toks[1, :len(tokens)] = tokens
+    assert cfg.qk_nope_head_dim + cfg.qk_rope_head_dim == 48 and cfg.v_head_dim == 32
+
+    def prefill(**kw):
+        pool = kimi_k2.init_kv_pool(cfg, 9, BS)
+        return jax.jit(lambda pool: kimi_k2.forward_paged(
+            params, jnp.asarray(toks), cfg, pool, tables, jnp.zeros(2, jnp.int32), BS,
+            **kw))(pool)
+
+    by_table, table_pool = prefill()
+    fresh, fresh_pool = prefill(fresh=True, use_kernel=use_kernel)
+    want = reference.logits(params, tokens, model)
+    assert _miss(fresh[1, :len(tokens)], want) < TOL
+    assert _miss(fresh, by_table) < TOL
+    got, held = np.asarray(fresh_pool["latent"]), np.asarray(table_pool["latent"])
+    np.testing.assert_array_equal(got[0], held[0])
+    np.testing.assert_allclose(got, held, rtol=1e-5, atol=1e-5)
+    assert np.abs(held[:, [3, 1, 7], :, :cfg.kv_lora_rank]).min() > 0   # rows were written
+    assert int(fresh_pool["counters"]["moe_rows"]) == int(table_pool["counters"]["moe_rows"])
+    # what the default picks: the kernel from the 1,024 bucket up on a TPU alone
+    assert [llama.flash_pays(S, "tpu") for S in (512, 1024, 2048)] == [False, True, True]
+    assert not llama.flash_pays(2048, "cpu")
+
+
 def test_the_engine_with_two_sequences_live_matches_the_reference(tiny):
     """Through `PagedLLMEngine`: two prompts admitted one after the other and
     decoded together; the logits each was sampled from are the reference's,

@@ -389,7 +389,12 @@ def test_state_from_the_wrong_row_misses(tiny, wrong):
 # to at the PARENT of PR 40 (commit 3eb9ea4, `decoder_trunk` one scan over one
 # stack, `lead_layers` a special case), made by this file's `_one_kind_programs`
 # from that tree. A change that means to alter a family's program replaces
-# its lines here, and says why.
+# its lines here, and says why. PR 41 replaced two, `kimi_k2.paged.fresh` and
+# `xing4.paged.fresh`: until then the latent family's forward did nothing with
+# `fresh` (the lines were its table program's, fb85295f16b0a12c and
+# 94ca6dd2f464ef8e); now a fresh prefill attends over the rows in hand under
+# `attn/prompt_attend`. The table program, the decode step and `last` are the
+# parent's still.
 PARENT_TEXT = {
     "llama.paged.decode": "4d1666b4e529e110",
     "llama.paged.prefill": "fd8459cf291092a0",
@@ -415,11 +420,11 @@ PARENT_TEXT = {
     "ouro.paged.last": "f8df10e5b4953e6f",
     "kimi_k2.paged.decode": "7608cd7e4039bd6d",
     "kimi_k2.paged.prefill": "fb85295f16b0a12c",
-    "kimi_k2.paged.fresh": "fb85295f16b0a12c",
+    "kimi_k2.paged.fresh": "10adbe590e3aa7a9",
     "kimi_k2.paged.last": "46bab8d6b05745d6",
     "xing4.paged.decode": "805d238630f2ee06",
     "xing4.paged.prefill": "94ca6dd2f464ef8e",
-    "xing4.paged.fresh": "94ca6dd2f464ef8e",
+    "xing4.paged.fresh": "cbaf223401156ccb",
     "xing4.paged.last": "28834a2fee161468",
 }
 

@@ -1,5 +1,9 @@
 """Pallas kernel tests (interpret mode on CPU; compiled on TPU)."""
 
+import dataclasses
+import functools
+import hashlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -176,3 +180,131 @@ def test_flash_live_tiles_cover_exactly_the_live_entries(causal):
             has_live = not causal or ki * bk <= qi * bq + bq - 1
             assert ((qi, ki) in tiles) == has_live
     assert len(tiles) == (12 if causal else 18)
+
+
+# ---- the forward at two widths (q/k of one, v and o of another), and `scale=`
+
+def _dense_two_widths(q, k, v, scale):
+    """Causal attention in float32 with q/k of one width and v of another:
+    scores x `scale`, softmax, the weighted values; K/V by group."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    B, S, Hq, D = q.shape
+    g = Hq // k.shape[2]
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", q.reshape(B, S, -1, g, D), k) * scale
+    s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -1e30)
+    o = jnp.einsum("bhgqk,bkhd->bqhgd", jax.nn.softmax(s, axis=-1), v)
+    return o.reshape(B, S, Hq, v.shape[3])
+
+
+@pytest.mark.parametrize("D, Dv, Hq, Hkv, scale, dtype", [
+    (192, 128, 4, 4, 192 ** -0.5 * 1.4159 ** 2, jnp.bfloat16),   # Kimi's head, g = 1
+    (192, 128, 4, 2, 0.1, jnp.float32),
+    (96, 64, 4, 1, None, jnp.bfloat16),                           # grouped K/V
+    (96, 64, 2, 2, 0.2, jnp.float32),
+    (48, 32, 4, 4, None, jnp.float32),                            # the tiny preset's
+    (64, 64, 4, 2, 0.3, jnp.float32),                             # `scale=` at one width
+    (128, 128, 2, 2, 0.05, jnp.bfloat16),
+    (64, 64, 4, 2, "grad", jnp.float32),                          # one width still differentiates
+    (192, 128, 2, 2, "grad", jnp.float32),                        # two do not
+], ids=["192/128-bf16-g1", "192/128-f32-g2", "96/64-bf16-g4", "96/64-f32-g1", "48/32-f32",
+        "64/64-scale", "128/128-scale", "64/64-grad", "192/128-grad-raises"])
+def test_flash_forward_takes_v_of_another_width_and_a_scale(D, Dv, Hq, Hkv, scale, dtype):
+    """q and k [., S, ., D] beside v [., S, ., Dv] -> o [., S, ., Dv], against
+    the dense product in float32 with the same `scale` (None: 1/sqrt(D)),
+    causal, at S = 160 with 64 x 32 tiles (pads to 192: a tile edge, the
+    diagonal and the padded end are all crossed). Differentiating two widths
+    raises the plain error; one width with a scale gives the dense gradients."""
+    ks = jax.random.split(jax.random.PRNGKey(D + Dv), 3)
+    q = jax.random.normal(ks[0], (1, 160, Hq, D), dtype)
+    k = jax.random.normal(ks[1], (1, 160, Hkv, D), dtype)
+    v = jax.random.normal(ks[2], (1, 160, Hkv, Dv), dtype)
+    blocks = dict(block_q=64, block_k=32)
+    if scale == "grad":
+        loss = lambda f: lambda *a: (f(*a).astype(jnp.float32) ** 2).sum()
+        flash = loss(lambda *a: flash_attention(*a, scale=0.2, **blocks))
+        if D != Dv:
+            with pytest.raises(NotImplementedError, match="one head width only.*192 wide, v 128"):
+                jax.grad(flash)(q, k, v)
+            return
+        want = jax.grad(loss(lambda *a: _dense_two_widths(*a, 0.2)), (0, 1, 2))(q, k, v)
+        for got, ref in zip(jax.grad(flash, (0, 1, 2))(q, k, v), want):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-4, rtol=2e-4)
+        return
+    o = flash_attention(q, k, v, scale=scale, **blocks)
+    assert o.shape == (1, 160, Hq, Dv) and o.dtype == dtype
+    want = _dense_two_widths(q, k, v, D ** -0.5 if scale is None else scale)
+    vmax = float(jnp.max(jnp.abs(v.astype(jnp.float32))))
+    atol = 4 * BF16_EPS * vmax if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(o, np.float32), np.asarray(want), rtol=0, atol=atol)
+
+
+def test_choose_tiles_counts_both_widths():
+    """The forward's tiles at Kimi's 2,048 bucket, 192 / 128 in bfloat16: the
+    192-wide blocks count as 256 lanes, 1024 x 1024 still fits the budget (11.5
+    MiB of 12), and at one width the count is what it was."""
+    assert fa.tile_vmem_bytes("fwd", 1024, 1024, 128, 2) == int(10.5 * 2 ** 20)
+    assert fa.tile_vmem_bytes("fwd", 1024, 1024, 128, 2, 128) == int(10.5 * 2 ** 20)
+    assert fa.tile_vmem_bytes("fwd", 1024, 1024, 192, 2, 128) == int(11.5 * 2 ** 20)
+    assert fa.choose_tiles(2048, 192, 2, "fwd", 128) == (1024, 1024)
+    for kernel in ("fwd", "dq", "dkv"):
+        assert fa.choose_tiles(4096, 128, 2, kernel, 128) == fa.choose_tiles(4096, 128, 2, kernel)
+
+
+# sha256 (first 16 hex digits) of what `flash_attention` at ONE width and
+# `scale=None` traced and lowered to at the PARENT of PR 41 (commit 6699c67,
+# before the forward took a second width and a scale), made by this file's
+# `_one_width_programs` from that tree: `call`, the traced program with the
+# kernel NOT interpreted (the `pallas_call`'s name, grid, block specs, scratch
+# and body, as the chip's compiler gets them); `fwd` and `grad`, the StableHLO
+# text of the forward and of all three gradients, the kernels interpreted;
+# `train`, the gradient of a family's loss through it. A change that means to
+# alter one of them replaces its line here, and says why.
+PARENT_FLASH = {
+    "flash.call.128": "11a936a4951d4cb7",
+    "flash.fwd.128": "b2f709854d5fb2ab",
+    "flash.grad.128": "c51b08585a2ac0fc",
+    "flash.call.64": "a6de75c2e5998dfc",
+    "flash.fwd.64": "e31d806c353c065c",
+    "flash.grad.64": "52aef46eca1420a9",
+    "llama.train": "4c7b91d145a1abdc",
+    "olmoe.train": "6654e7abe641e3dd",
+}
+
+
+def _one_width_programs() -> dict:
+    from ray_tpu.models import model_of, moe
+
+    sds, out = jax.ShapeDtypeStruct, {}
+    for D in (128, 64):
+        q, kv = sds((1, 256, 4, D), jnp.bfloat16), sds((1, 256, 2, D), jnp.bfloat16)
+        fwd = functools.partial(flash_attention, causal=True, interpret=True)
+        out[f"flash.fwd.{D}"] = jax.jit(fwd).lower(q, kv, kv).as_text()
+        out[f"flash.grad.{D}"] = jax.jit(jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(), (0, 1, 2))).lower(q, kv, kv).as_text()
+        out[f"flash.call.{D}"] = str(jax.make_jaxpr(functools.partial(
+            flash_attention, causal=True, interpret=False))(q, kv, kv))
+    attn = functools.partial(flash_attention, interpret=True)
+    for name, cfg in (("llama", llama.LlamaConfig.tiny()),
+                      ("olmoe", dataclasses.replace(moe.MoEConfig.tiny(), qk_norm=True))):
+        model = model_of(cfg)
+        params = jax.eval_shape(lambda: model.init(cfg, jax.random.PRNGKey(0)))
+        loss = lambda p, t, y: model.loss(p, t, y, cfg, attn)[0]
+        tok = sds((2, 32), jnp.int32)
+        out[f"{name}.train"] = jax.jit(jax.grad(loss)).lower(params, tok, tok).as_text()
+    return out
+
+
+@pytest.fixture(scope="module")
+def one_width_programs():
+    return {k: hashlib.sha256(v.encode()).hexdigest()[:16]
+            for k, v in _one_width_programs().items()}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_FLASH))
+def test_at_one_width_the_flash_kernels_are_the_parent_s_text(one_width_programs, name):
+    """ADAPT, by what the code observes (v's width): with v as wide as q and k
+    and no `scale`, the function traces the `pallas_call` it traced and lowers
+    to the text it lowered to before it took either, at 128- and 64-wide
+    heads, forward and backward, and so do the train steps' gradients of
+    Llama and OLMoE through it."""
+    assert one_width_programs[name] == PARENT_FLASH[name]

@@ -66,6 +66,27 @@ def test_flash_fwd_bwd_compiles(v5e, D):
     assert text.count(MOSAIC) >= 3  # forward, dQ, dK/dV
 
 
+@pytest.mark.parametrize("S, Hq, Hkv, D, Dv", [(2048, 64, 64, 192, 128), (1024, 8, 2, 96, 64)],
+                         ids=["kimi-2048", "grouped-96/64"])
+def test_flash_forward_compiles_at_two_widths(v5e, S, Hq, Hkv, D, Dv):
+    """The forward with q/k of one width beside v of another, at the Kimi
+    cell's fresh prefill (64 heads of 192 = 128 + 64 rotary beside 128, the
+    2,048 bucket, the family's scale) and at a grouped shape: Mosaic takes a
+    width that is no whole number of 128-lane tiles as the array's full last
+    dimension (no zero lanes carried to 256), ONE kernel under its own name,
+    the output Dv wide; the kernel's tiles are the ones a single width gets."""
+    from ray_tpu.ops.flash_attention import choose_tiles
+
+    q, k, v = (_on(v5e[0], (1, S, h, d)) for h, d in ((Hq, D), (Hkv, D), (Hkv, Dv)))
+    attend = functools.partial(flash_attention, interpret=False, scale=192 ** -0.5 * 2.0)
+    text = jax.jit(attend).lower(q, k, v).compile().as_text()
+    kernels = _kernel_lines(text)
+    assert len(kernels) == 1 and re.match(r"(ROOT )?%flash_attention_fwd", kernels[0]), kernels
+    assert re.search(rf"ENTRY .*-> bf16\[1,{S},{Hq},{Dv}\]", text)
+    assert f"bf16[{Hq},{S},{D}]" in kernels[0] and "256]" not in kernels[0]
+    assert choose_tiles(S, D, 2, "fwd", Dv) == choose_tiles(S, 128, 2, "fwd") == (1024, 1024)
+
+
 def _kernel_lines(text: str) -> list[str]:
     return [ln.lstrip() for ln in text.splitlines() if MOSAIC in ln]
 
@@ -341,7 +362,11 @@ def _kimi_serve_ep32():
     # was 531.6 MB while the expert layer moved all 16,384 pairs (PR 38)
     ("prefill", 1, 2048, dict(head="last", table_first=True), 0.3e9,
      2048 * 64 * 128 * 2 + 1),
-], ids=["decode-64", "prefill-2048"])
+    # the program the cell runs since PR 41: a span that starts at 0 attends
+    # over its own rows through the flash forward, every head in one call, so
+    # the stacked output is gone and what is allocated is the sort's words
+    ("prefill", 1, 2048, dict(head="last", table_first=True, fresh=True), 0.3e9, 512),
+], ids=["decode-64", "prefill-2048", "prefill-2048-fresh"])
 def test_kimi_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw, scratch_under,
                                                            alloc_under):
     """`serve-kimi-longin-batch`'s two largest programs as the engine builds
@@ -375,13 +400,33 @@ def test_kimi_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw, 
     assert 12.3e9 < ma.argument_size_in_bytes < 12.5e9
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 13.5e9
     text = compiled.as_text()
-    names = ["lead/", "attn/latent_write", "attn/latent_read", "moe/route", "moe/dispatch",
+    fresh = kw.get("fresh", False)
+    names = ["lead/", "attn/latent_write", "moe/route", "moe/dispatch",
              "moe/experts", "moe/combine", "moe/shared", "grouped_matmul_fwd"]
+    names += ["attn/prompt_attend", "flash_attention_fwd"] if fresh else ["attn/latent_read"]
     for scope in names + (["attn/absorb", "latent_attention_decode"] if S == 1 else []):
         assert scope in text, scope
     assert ("latent_attention_decode" in text) == (S == 1)
     assert "paged_attention_decode" not in text
     assert_a_share_moves_its_bound(text, cfg, B * S, compacts=True)
+    assert_a_fresh_latent_prefill_reads_its_own_rows(text, S, fresh)
+
+
+def assert_a_fresh_latent_prefill_reads_its_own_rows(text: str, S: int, fresh: bool) -> None:
+    """A latent family's compiled step of S rows a sequence: told `fresh` it
+    has nothing under `attn/latent_read` (no gather of the table) and no
+    float32 array of heads x bucket x bucket (from the 1,024 bucket up the
+    scores stay in the flash kernel's VMEM; the table program forms
+    `f32[1, 16, 2048, 2048]` a chunk of heads and is what the detector finds
+    them in; the expert layers' combine weights `f32[2048, 2048]`, T x bound,
+    are in both)."""
+    assert ("attn/prompt_attend" in text) == fresh
+    assert ("attn/latent_read" in text) == (not fresh)
+    assert ("flash_attention_fwd" in text) == (fresh and S >= 1024)
+    if S >= 1024:
+        scores = [m.group(1) for m in re.finditer(r"= f32\[([\d,]+)\]", text)
+                  if m.group(1).count(",") >= 2 and m.group(1).split(",").count(str(S)) >= 2]
+        assert bool(scores) == (not fresh), scores[:3]
 
 
 def _xing4_serve_ep8():
@@ -404,7 +449,10 @@ def _xing4_serve_ep8():
     # rows of each of the 38 x 8 held experts
     ("decode", 48, 1, dict(head=0), 0.3e9, 2048),
     ("prefill", 1, 512, dict(head="last", table_first=True), 0.5e9, 2048),
-], ids=["decode-48", "prefill-512"])
+    # the program the cell runs since PR 41, under the crossover: the dense
+    # product over its own [512, 512], no column of the 1,024-wide table
+    ("prefill", 1, 512, dict(head="last", table_first=True, fresh=True), 0.5e9, 2048),
+], ids=["decode-48", "prefill-512", "prefill-512-fresh"])
 def test_xing4_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw, scratch_under,
                                                             alloc_under):
     """`serve-xing-midin-384-out`'s two largest programs as the engine builds
@@ -437,12 +485,17 @@ def test_xing4_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw,
     # no copy of a layer's held experts (3 x 8 x 3584 x 1024 bf16 = 176 MB)
     assert ma.temp_size_in_bytes < 3 * 8 * 3584 * 1024 * 2
     text = compiled.as_text()
+    fresh = kw.get("fresh", False)
     names = ["lead/", "hc/map", "hc/sinkhorn", "hc/mix", "attn/latent_write",
-             "attn/latent_read", "moe/route", "moe/dispatch", "moe/experts", "moe/combine",
-             "moe/shared", "grouped_matmul_fwd"]
+             "attn/prompt_attend" if fresh else "attn/latent_read", "moe/route",
+             "moe/dispatch", "moe/experts", "moe/combine", "moe/shared", "grouped_matmul_fwd"]
     for scope in names + (["attn/absorb", "latent_attention_decode"] if S == 1 else []):
         assert scope in text, scope
     assert ("latent_attention_decode" in text) == (S == 1)
+    assert_a_fresh_latent_prefill_reads_its_own_rows(text, S, fresh)
+    if S == 512:   # scores against the table's 1,024 columns, or against its own 512 rows
+        wide = re.findall(r"= f32\[[\d,]+,512,1024\]", text)   # by head: not the combine's
+        assert bool(wide) == (not fresh), wide[:3]
     # the prefill's expert layers gather 1,024 of their 2,048 pairs; the
     # decode step's bound is its 192 pairs, and its text what it was
     assert_a_share_moves_its_bound(text, cfg, B * S, compacts=S == 512)

@@ -71,7 +71,8 @@ TABLES = jnp.asarray([[0, 0, 0, 0], [3, 1, 7, 2]], jnp.int32)
 
 def _step(params, cfg):
     return jax.jit(lambda pool, toks, lengths, **kw: xing4.forward_paged(
-        params, toks, cfg, pool, TABLES, lengths, BS, **kw), static_argnames=("use_kernel",))
+        params, toks, cfg, pool, TABLES, lengths, BS, **kw),
+        static_argnames=("use_kernel", "fresh"))
 
 
 def _prefill_then_decode(params, tokens, cfg, n_prompt: int, **decode):
@@ -96,14 +97,30 @@ def _prefill_then_decode(params, tokens, cfg, n_prompt: int, **decode):
     return np.stack(rows), counted
 
 
-@pytest.mark.parametrize("path", ["cache-less", "decode-kernel", "decode-gathered"])
+@pytest.mark.parametrize("path", ["cache-less", "decode-kernel", "decode-gathered",
+                                  "prefill-own-rows"])
 def test_every_forward_gives_the_reference_s_logits(tiny, path):
     """The cache-less forward at every position; a prefill whose head runs on
     the one row `head_rows` names, then six decode steps through the latent
-    kernel (interpreted) or over the gathered view, four streams wide
+    kernel (interpreted) or over the gathered view; a fresh prefill over its
+    own rows (`kimi_k2.latent_attention`'s branch, which this family gets with
+    the function: a 64-token bucket with 18 rows of padding, the flash forward
+    interpreted), which is also the table program's; four streams wide
     throughout: all are the reference's logits."""
     model, cfg, params, tokens = tiny
     want = reference.logits(params, tokens, model)
+    if path == "prefill-own-rows":
+        toks = np.zeros((2, 64), np.int32)
+        toks[1, :len(tokens)] = tokens
+        run = lambda **kw: _step(params, cfg)(
+            xing4.init_kv_pool(cfg, 9, BS), jnp.asarray(toks), jnp.zeros(2, jnp.int32), **kw)
+        (got, pool), (by_table, table_pool) = run(fresh=True, use_kernel=True), run()
+        assert _miss(got[1, :len(tokens)], want) < TOL and _miss(got, by_table) < TOL
+        np.testing.assert_array_equal(np.asarray(pool["latent"][0]),
+                                      np.asarray(table_pool["latent"][0]))
+        np.testing.assert_allclose(np.asarray(pool["latent"]), np.asarray(table_pool["latent"]),
+                                   rtol=1e-5, atol=1e-5)
+        return
     if path == "cache-less":
         got = jax.jit(lambda t: xing4.forward(params, t, cfg))(jnp.asarray(tokens)[None])
         assert got.shape == (1, len(tokens), cfg.vocab_size) and _miss(got[0], want) < TOL
