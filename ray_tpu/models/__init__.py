@@ -21,7 +21,8 @@ class Model(NamedTuple):
     # block_size, platform=, head_rows=, fresh=) -> (logits [B, S, V], or
     # [B, 1, V] of the positions `head_rows` [B] names, pool); `fresh`
     # (static) says that every sequence starts at position 0, so a family may
-    # attend over the rows in hand and read nothing back; and (cfg, num_blocks,
+    # attend over the rows in hand and read nothing back, and write rows that
+    # fill whole blocks as whole pages (`llama.write_pages`); and (cfg, num_blocks,
     # block_size) -> the pool it reads and writes: a dict of page-shaped
     # arrays, pages on the second axis, [L, num_blocks, ...] (a row a token,
     # [L, num_blocks, block_size, row], or a kind of state that keeps rows a

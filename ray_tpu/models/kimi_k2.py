@@ -291,9 +291,13 @@ def latent_attention(cfg: KimiK2Config, tables, lengths, blk_idx, blk_off,
         # ONE rotated key a token, every head's
         k_r = llama.rope(ckv[:, :, None, rank:], positions, None, inv_freq)[:, :, 0]
         with jax.named_scope("latent_write"):
-            lat = pool["latent"]
-            # a row scatter: lat[layer, blk_idx[b,s], blk_off[b,s]] = row[b,s]
-            lat = lat.at[index, blk_idx, blk_off].set(as_row(c_kv, k_r).astype(lat.dtype))
+            lat, new = pool["latent"], as_row(c_kv, k_r)
+            if llama.writes_pages(fresh, S, block_size):
+                # whole pages: lat[layer, tables[b, j]] = row[b, j * BS:(j + 1) * BS]
+                lat = llama.write_pages(lat, index, tables, new, block_size)
+            else:
+                # a row scatter: lat[layer, blk_idx[b,s], blk_off[b,s]] = row[b,s]
+                lat = lat.at[index, blk_idx, blk_off].set(new.astype(lat.dtype))
 
         def gathered():  # the sequences' pages as one view, and who may see what
             view = lat[index, tables].reshape(B, max_blocks * block_size, row)

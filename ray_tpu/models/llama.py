@@ -721,7 +721,9 @@ def init_kv_pool(cfg: LlamaConfig, num_blocks: int, block_size: int) -> dict:
       carried pool in a layout with the written window minor-most and copies
       ALL of it back to the default layout for the kernel in every layer
       (PERF.md section 6, PR 30, the second ahead-of-time finding). Here the
-      write is a row scatter in the default layout, which the kernel reads."""
+      write is a row scatter in the default layout, which the kernel reads.
+      A prefill that starts at position 0 writes whole pages, each one
+      contiguous run, and scatters no row (`write_pages`)."""
     row = cfg.num_kv_heads * pool_head_dim(cfg.hd)
     shape = (cfg.loop_steps * cfg.num_layers, num_blocks, block_size, row)
     return {
@@ -747,6 +749,44 @@ def page_rows(tables, lengths, S: int, block_size: int):
     return positions, blk_idx, positions % block_size
 
 
+def writes_pages(fresh: bool, S: int, block_size: int) -> bool:
+    """Whether S new tokens a sequence are whole pages of a paged pool: told
+    `fresh` every sequence starts at position 0, block-aligned, so S rows that
+    fill whole blocks are S / block_size pages and page j is pool block
+    `tables[b, j]` (`write_pages`). Anything else lands row by row
+    (`page_rows`): a decode step's one row a slot, a suffix whose start is
+    traced, a length that ends inside a block. Both facts are static where a
+    step is traced, and the engine knows them on the host
+    (`serve/llm_paged.py::prefill_writes`)."""
+    return fresh and S % block_size == 0
+
+
+def write_pages(leaf, layer, tables, rows, block_size: int):
+    """The pool's write of a prefill that starts at position 0
+    (`writes_pages`): `leaf[layer, tables[b, j]] = rows[b, j * block_size:(j +
+    1) * block_size]` for every sequence b and page j; `leaf` `[L, NB, BS,
+    row]`, its new `rows` `[B, S, row]`. ONE scatter whose window is a page,
+    one contiguous `[block_size, row]` run of the pool, with S / block_size
+    indices a sequence where the row scatter has S: XLA:TPU walks a scatter's
+    indices one at a time, ~125 ns each whatever the window, so 2,048 rows of
+    1,024 lanes take 0.27 ms a leaf and their 128 pages 0.03 (PERF.md section
+    6, PR 43, where a page-copy kernel's 0.02 is weighed against it).
+
+    The SAME pool as the row scatter at `page_rows`'s places, bit for bit, in
+    every block but the garbage block 0: the bucket's padding rows lie at
+    their positions as they did, the tail of a last live block and a block
+    reserved for the first decoded token get the same rows, and a page past
+    the allocation (table entry 0) or past the table goes to block 0 as its
+    rows did, several of them, in any order."""
+    B, S, row = rows.shape
+    n_pages = S // block_size
+    pages = tables[:, :n_pages]
+    if n_pages > tables.shape[1]:   # padding past the table: block 0
+        pages = jnp.pad(pages, ((0, 0), (0, n_pages - tables.shape[1])))
+    return leaf.at[layer, pages].set(
+        rows.reshape(B, n_pages, block_size, row).astype(leaf.dtype))
+
+
 def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
                   block_size: int, use_kernel: bool | None = None,
                   platform: str | None = None, mlp=dense_mlp, head_rows=None,
@@ -760,7 +800,10 @@ def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
     The pool rides whole in the layer scan's carry (`decoder_trunk`) and each
     layer touches only its own pages of it: new K/V rows scatter into
     `pool[layer, block, offset]` in place (scope `attn/kv_write`), whatever
-    reads them. Which keys a layer's queries then read, three ways:
+    reads them; a `fresh` prefill whose S rows fill whole blocks writes them
+    as S / block_size whole pages into `pool[layer, tables[:, :S /
+    block_size]]` (`write_pages`: a scatter of pages), the same pool outside
+    the garbage block. Which keys a layer's queries then read, three ways:
 
     - `fresh`: every sequence starts at position 0 (`lengths` is all zero: a
       prompt with no cached prefix), so every key a row may see is one of the
@@ -827,9 +870,14 @@ def paged_attend(cfg: LlamaConfig, tables, lengths, positions, blk_idx, blk_off,
         # the scopes name, in a profile, the statement behind each pool-shaped
         # operation of a step: attn/kv_write or attn/kv_read
         with jax.named_scope("kv_write"):
-            # a row scatter: kp[layer, blk_idx[b,s], blk_off[b,s]] = k[b,s]
-            kp = kp.at[layer, blk_idx, blk_off].set(rows(k, kp.dtype))
-            vp = vp.at[layer, blk_idx, blk_off].set(rows(v, vp.dtype))
+            if writes_pages(fresh, S, block_size):
+                # whole pages: kp[layer, tables[b, j]] = k[b, j * BS:(j + 1) * BS]
+                kp = write_pages(kp, layer, tables, rows(k, kp.dtype), block_size)
+                vp = write_pages(vp, layer, tables, rows(v, vp.dtype), block_size)
+            else:
+                # a row scatter: kp[layer, blk_idx[b,s], blk_off[b,s]] = k[b,s]
+                kp = kp.at[layer, blk_idx, blk_off].set(rows(k, kp.dtype))
+                vp = vp.at[layer, blk_idx, blk_off].set(rows(v, vp.dtype))
         if fresh:
             # it reads no pool, so it is not `kv_read`'s
             with jax.named_scope("prompt_attend"):

@@ -86,7 +86,9 @@ def paged_step(name: str, cfg, block_size: int, platform: str,
     kernel over live pages at S == 1, the whole gathered table at any other S
     (a prompt that continues a cached prefix, the speculative window). The
     caller that builds a `fresh` step is the one that knows where its spans
-    start (`prefill_step` chooses by the span itself).
+    start (`prefill_step` chooses by the span itself). A `fresh` step whose
+    tokens fill whole blocks also WRITES the pool as whole pages, the rest row
+    by row (`prefill_writes`; an `admit` record's `writes`).
 
     `head` is which positions' logits the caller reads, and the output head
     runs on those alone (`decoder_trunk`'s `head_rows`), so a step returns
@@ -131,14 +133,31 @@ def prefill_reads(start: int) -> str:
     return "own_rows" if start == 0 else "table"
 
 
+def prefill_writes(start: int, bucket: int, block_size: int) -> str:
+    """How the prefill of `bucket` tokens from position `start` puts its keys
+    and values (a family's latent rows) into the pool: "pages" (the program
+    told `fresh` whose bucket fills whole blocks: bucket / block_size whole
+    pages a layer, `models/llama.py::write_pages`) or "rows" (a row a token,
+    `page_rows`: a suffix behind a cached prefix, a bucket that ends inside a
+    block). An `admit` record notes it as `writes` beside `reads`; the rule is
+    the model's own (`llama.writes_pages`), asked here with what the engine
+    knows on the host."""
+    from ray_tpu.models.llama import writes_pages
+
+    fresh = prefill_reads(start) == "own_rows"
+    return "pages" if writes_pages(fresh, bucket, block_size) else "rows"
+
+
 def prefill_step(name: str, cfg, block_size: int, platform: str, head):
     """A B = 1 prefill as ONE callable (params, pool, tokens, table, span) over
     its two jitted programs (`paged_step(table_first=True)`, `fresh` and not),
-    both `name` to a profile. The choice is made here, on the host, from the
-    span's first entry, so no caller can choose wrongly: hand it the span as
-    the host array it is, and reading `span[0]` waits for no device. Each
-    program compiles at the first call that takes it, a bucket at a time; a
-    warm-up of fresh prompts compiles the `fresh` ones alone."""
+    both `name` to a profile; which of the two runs also decides how the pool
+    is written (`prefill_writes`: an `admit` record's `writes`). The choice is
+    made here, on the host, from the span's first entry, so no caller can
+    choose wrongly: hand it the span as the host array it is, and reading
+    `span[0]` waits for no device. Each program compiles at the first call
+    that takes it, a bucket at a time; a warm-up of fresh prompts compiles the
+    `fresh` ones alone."""
     step = partial(paged_step, name, cfg, block_size, platform, head, table_first=True)
     programs = {"own_rows": step(fresh=True), "table": step()}
 
@@ -367,7 +386,8 @@ class PagedLLMEngine(LLMEngine):
             # clamp the prefill bucket so padded positions stay inside the table
             bucket = min(self._bucket(len(suffix)),
                          self.config.max_seq_len - cached_len)
-            info.update(cached=cached_len, bucket=bucket, reads=prefill_reads(cached_len))
+            info.update(cached=cached_len, bucket=bucket, reads=prefill_reads(cached_len),
+                        writes=prefill_writes(cached_len, bucket, bs))
             padded = np.zeros((1, bucket), dtype=np.int32)
             padded[0, : len(suffix)] = suffix
             table_row = np.zeros((1, self.max_blocks_per_seq), dtype=np.int32)

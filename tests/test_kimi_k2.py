@@ -23,6 +23,7 @@ from benchmarks.reference import kimi_k2_reference as reference
 from ray_tpu.models import kimi_k2, llama, model_of, moe
 from ray_tpu.ops.paged_attention import latent_decode_attention
 from ray_tpu.serve.llm_paged import PagedLLMConfig, PagedLLMEngine, page_leaves
+from tests.test_paged_attention import PAGE_WRITE_CASES, check_page_write_against_rows
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # Program and reference compute the same mathematics in float32 in another
@@ -146,6 +147,22 @@ def test_a_prefill_cut_into_chunks_of_heads_is_the_uncut_one(tiny, monkeypatch, 
     np.testing.assert_allclose(np.asarray(cut_pool["latent"]), np.asarray(uncut_pool["latent"]),
                                rtol=1e-5, atol=1e-5)
     assert int(cut_pool["counters"]["moe_rows"]) == int(uncut_pool["counters"]["moe_rows"])
+
+
+@pytest.mark.parametrize("case", PAGE_WRITE_CASES)
+def test_a_fresh_prefill_s_pages_leave_the_latent_pool_the_row_scatter_left(
+        monkeypatch, tiny, case):
+    """The latent family's fresh prefill writes its rows `[c_kv | k_rope |
+    zeros]` as whole pages (`llama.write_pages`, scope `attn/latent_write`):
+    the pool the row scatter left, exactly, outside the garbage block, the
+    same logits, and the same next decode step, in `tests/
+    test_paged_attention.py`'s four cases."""
+    _, cfg, params, _ = tiny
+    check_page_write_against_rows(
+        monkeypatch, lambda tokens, pool, tables, lengths, **kw: kimi_k2.forward_paged(
+            params, tokens, cfg, pool, tables, lengths, **kw),
+        lambda blocks, bs: kimi_k2.init_kv_pool(cfg, blocks, bs), cfg.base.vocab_size,
+        case)
 
 
 @pytest.mark.parametrize("use_kernel", [None, True], ids=["dense-own-rows", "flash-interpreted"])

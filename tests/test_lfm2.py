@@ -25,6 +25,7 @@ from benchmarks.reference import lfm2_reference as reference
 from ray_tpu.models import kimi_k2, lfm2, llama, model_of, moe, ouro
 from ray_tpu.serve.llm_paged import PagedLLMConfig, PagedLLMEngine, page_leaves
 from ray_tpu.serve.paged_kv import BlockPool
+from tests.test_paged_attention import PAGE_WRITE_CASES, check_page_write_against_rows
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # Program and reference compute the same mathematics in float32 in another
@@ -384,6 +385,23 @@ def test_state_from_the_wrong_row_misses(tiny, wrong):
     assert _miss(right, want[19:]) < TOL < 0.02 < _miss(got, want[19:])
 
 
+@pytest.mark.parametrize("case", PAGE_WRITE_CASES)
+def test_a_fresh_prefill_s_pages_leave_k_and_v_the_row_scatter_s_beside_the_state(
+        monkeypatch, tiny, case):
+    """The attention layers are `llama.paged_attend`'s, so a fresh prefill
+    writes their keys and values as whole pages; the convolutions' state (2
+    rows a block, the last live position of each parity, nothing of the
+    padding) is written as it was. All three leaves hold what the row
+    scatter's program left, exactly, outside the garbage block; the same
+    logits and the same next decode step, which reads the state back."""
+    _, cfg, params, _ = tiny
+    check_page_write_against_rows(
+        monkeypatch, lambda tokens, pool, tables, lengths, **kw: lfm2.forward_paged(
+            params, tokens, cfg, pool, tables, lengths, **kw),
+        lambda blocks, bs: lfm2.init_kv_pool(cfg, blocks, bs), cfg.base.vocab_size,
+        case)
+
+
 # ------------------------------------------- the trunk over runs is the old trunk
 # sha256 (first 16 hex digits) of the StableHLO text that each program lowered
 # to at the PARENT of PR 40 (commit 3eb9ea4, `decoder_trunk` one scan over one
@@ -394,37 +412,43 @@ def test_state_from_the_wrong_row_misses(tiny, wrong):
 # `fresh` (the lines were its table program's, fb85295f16b0a12c and
 # 94ca6dd2f464ef8e); now a fresh prefill attends over the rows in hand under
 # `attn/prompt_attend`. The table program, the decode step and `last` are the
-# parent's still.
+# parent's still. PR 43 replaced all six `*.paged.fresh` lines and no other: a
+# fresh prefill whose 32 tokens fill two blocks of 16 scatters two whole pages
+# a leaf and layer where it scattered 32 rows (`llama.write_pages`; until then
+# 9faf4950b73482ea, b9032e5101a13d5c, f354ba46d4742441, 1e13fa5a3563dfaa,
+# 10adbe590e3aa7a9, cbaf223401156ccb in the dictionary's order); the decode
+# step, the table prefill, `last` (not told `fresh`), losses and gradients are
+# the parent's text.
 PARENT_TEXT = {
     "llama.paged.decode": "4d1666b4e529e110",
     "llama.paged.prefill": "fd8459cf291092a0",
-    "llama.paged.fresh": "9faf4950b73482ea",
+    "llama.paged.fresh": "e4819840a997529d",
     "llama.paged.last": "af6f1abee32d97ef",
     "llama.loss": "0fb9d6ad11474b55",
     "llama.grad": "1351d9f4c3001991",
     "moe.paged.decode": "906cc7b9fe5efe59",
     "moe.paged.prefill": "a695e7509cb75b45",
-    "moe.paged.fresh": "b9032e5101a13d5c",
+    "moe.paged.fresh": "b179923a4d114772",
     "moe.paged.last": "0492ef996a21c340",
     "moe.loss": "03710b5fb6249ca2",
     "moe.grad": "66721486a6cbfac2",
     "olmoe.paged.decode": "fd15da110c2c1b9b",
     "olmoe.paged.prefill": "db9bf0e36bc145f6",
-    "olmoe.paged.fresh": "f354ba46d4742441",
+    "olmoe.paged.fresh": "82027e3150d180ae",
     "olmoe.paged.last": "656dd949f7c87504",
     "olmoe.loss": "3d7e9ee7a5ae9813",
     "olmoe.grad": "a450411da25cf0a8",
     "ouro.paged.decode": "e5fc4c4767ca19dc",
     "ouro.paged.prefill": "5fe0d29dd78c063f",
-    "ouro.paged.fresh": "1e13fa5a3563dfaa",
+    "ouro.paged.fresh": "6932f8c8b2bf340b",
     "ouro.paged.last": "f8df10e5b4953e6f",
     "kimi_k2.paged.decode": "7608cd7e4039bd6d",
     "kimi_k2.paged.prefill": "fb85295f16b0a12c",
-    "kimi_k2.paged.fresh": "10adbe590e3aa7a9",
+    "kimi_k2.paged.fresh": "1da06d0983b09b0f",
     "kimi_k2.paged.last": "46bab8d6b05745d6",
     "xing4.paged.decode": "805d238630f2ee06",
     "xing4.paged.prefill": "94ca6dd2f464ef8e",
-    "xing4.paged.fresh": "cbaf223401156ccb",
+    "xing4.paged.fresh": "a83c509a1b1686ef",
     "xing4.paged.last": "28834a2fee161468",
 }
 

@@ -7,7 +7,7 @@ import pytest
 
 from ray_tpu.models import llama
 from ray_tpu.serve.llm import LLMConfig, LLMEngine
-from ray_tpu.serve.llm_paged import PagedLLMConfig, PagedLLMEngine
+from ray_tpu.serve.llm_paged import PagedLLMConfig, PagedLLMEngine, prefill_writes
 
 
 @pytest.fixture(scope="module")
@@ -197,8 +197,42 @@ def test_whole_prompt_cached_admission_samples_from_the_recomputed_blocks_last_r
     assert first == want and again == want
     admits = [e[7] for e in timeline.local_events()
               if e[0] == "span" and e[2] == "engine" and e[3] == "admit"]
-    assert [(a["cached"], a["bucket"], a["reads"]) for a in admits] == [
-        (0, 32, "own_rows"), (16, 32, "table")]
+    assert [(a["cached"], a["bucket"], a["reads"], a["writes"]) for a in admits] == [
+        (0, 32, "own_rows", "pages"), (16, 32, "table", "rows")]
+
+
+@pytest.mark.parametrize("start, bucket, block_size, writes", [
+    (0, 2048, 16, "pages"), (0, 32, 16, "pages"), (0, 16, 16, "pages"),
+    (0, 40, 16, "rows"),       # a bucket that ends inside a block
+    (16, 2048, 16, "rows"),    # a suffix behind a cached prefix: its start is traced
+    (0, 8, 16, "rows"),
+])
+def test_prefill_writes_names_the_write_the_program_takes(start, bucket, block_size, writes):
+    """`prefill_writes` says on the host what the traced program decides from
+    the same two static facts (`llama.writes_pages`): a prefill told `fresh`
+    whose tokens fill whole blocks writes pages, anything else rows."""
+    assert prefill_writes(start, bucket, block_size) == writes
+
+
+def test_an_engine_whose_bucket_is_no_whole_blocks_writes_rows_and_generates_the_same(
+        shared_params):
+    """A bucket of 40 over blocks of 16: the fresh program's rows are no whole
+    pages, so it scatters them (`writes: rows`), and the tokens are the plain
+    forward's all the same."""
+    from ray_tpu.util import timeline
+
+    cfg, params = shared_params
+    prompt = [int(t) for t in np.random.default_rng(8).integers(1, cfg.vocab_size, 35)]
+    eng = _paged(cfg, params, prefill_buckets=(40, 128))
+    timeline.clear()
+    try:
+        got = eng.generate_sync(prompt, 5).token_ids
+    finally:
+        eng.shutdown()
+    assert got == _plain_greedy(cfg, params, prompt, 5)
+    admits = [e[7] for e in timeline.local_events()
+              if e[0] == "span" and e[2] == "engine" and e[3] == "admit"]
+    assert [(a["bucket"], a["reads"], a["writes"]) for a in admits] == [(40, "own_rows", "rows")]
 
 
 @pytest.mark.parametrize("before, cached, reads", [
@@ -240,6 +274,8 @@ def test_an_admission_notes_which_prefill_it_ran_and_generates_the_plain_forward
     assert len(admits) == len(spans) == 1 + bool(before)
     assert (admits[-1]["cached"], admits[-1]["reads"]) == (cached, reads)
     assert admits[0]["reads"] == "own_rows"
+    # a span that starts at 0 in a bucket of whole blocks writes whole pages
+    assert [a["writes"] for a in admits] == ["pages", "rows"][:len(admits)]
     assert all(isinstance(sp, np.ndarray) and sp.dtype == np.int32 for sp in spans)
     assert spans[-1].tolist() == [cached, len(prompt) - cached]
 

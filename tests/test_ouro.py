@@ -17,6 +17,7 @@ from benchmarks.harness.families import ouro as family
 from benchmarks.reference import ouro_reference
 from ray_tpu.models import llama, model_of, ouro
 from ray_tpu.serve.llm_paged import PagedLLMConfig, PagedLLMEngine
+from tests.test_paged_attention import PAGE_WRITE_CASES, check_page_write_against_rows
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # Program and reference compute the same mathematics in float32 in another
@@ -127,6 +128,21 @@ def test_a_fresh_prefill_over_its_own_rows_is_the_table_prefill(tiny, branch):
     for name in ("k", "v"):
         assert np.asarray(want_pool[name][:, 1:]).reshape(12, -1).any(axis=1).all()
         assert _miss(got_pool[name][:, 1:], want_pool[name][:, 1:]) < TOL
+
+
+@pytest.mark.parametrize("case", PAGE_WRITE_CASES)
+def test_a_fresh_prefill_s_pages_leave_every_pass_s_cache_layer_the_row_scatter_s(
+        monkeypatch, tiny, case):
+    """`loop_steps` > 1: every pass of every layer writes its pages to ITS
+    cache layer, `pass * L + layer` (the layer index is traced, inside two
+    scans), and all 12 hold what the row scatter left, exactly, outside the
+    garbage block; the same logits and the same next decode step."""
+    _, cfg, params, _ = tiny
+    assert cfg.loop_steps > 1 and ouro.init_kv_pool(cfg, 2, 4)["k"].shape[0] == 12
+    check_page_write_against_rows(
+        monkeypatch, lambda tokens, pool, tables, lengths, **kw: ouro.forward_paged(
+            params, tokens, cfg, pool, tables, lengths, **kw),
+        lambda blocks, bs: ouro.init_kv_pool(cfg, blocks, bs), cfg.vocab_size, case)
 
 
 def test_the_engine_with_two_sequences_live_matches_the_reference(tiny):

@@ -17,6 +17,7 @@ the serving cells' exact shape.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -280,6 +281,161 @@ def test_a_fresh_prefill_over_its_own_rows_is_the_gathered_table_prefill(family,
     assert "attn/prompt_attend" in text and "attn/kv_write" in text
     assert "attn/kv_read" not in text
     assert ("flash_attention_fwd" in text) == (branch == "flash")
+
+
+# a fresh prefill's write (PR 43): (live tokens, bucket, blocks allocated,
+# table width), block 4
+PAGE_WRITE_CASES = [
+    pytest.param((29, 32, 8, 10), id="ends-mid-block"),
+    # and a ninth block reserved for the first decoded token
+    pytest.param((32, 32, 9, 10), id="fills-its-last-block"),
+    pytest.param((9, 32, 3, 10), id="padding-spills-past-the-allocation"),
+    pytest.param((21, 32, 6, 6), id="padding-spills-past-the-table"),
+]
+
+
+def assert_same_pool_but_block_0(got: dict, want: dict, written, bs: int) -> None:
+    """Two pools hold the SAME values, exactly, in every block but the garbage
+    block 0; `written`: the block ids in which a leaf of a row a token holds
+    something, and it holds nothing in any other."""
+    for name in want:
+        if name == "counters":
+            continue
+        g, w = np.asarray(got[name]), np.asarray(want[name])
+        np.testing.assert_array_equal(g[:, 1:], w[:, 1:])
+        if w.shape[2] == bs:
+            held = np.flatnonzero(w[:, 1:].reshape(w.shape[0], w.shape[1] - 1, -1)
+                                  .any(axis=(0, 2))) + 1
+            assert sorted(held) == sorted(written), (name, held)
+
+
+def scatter_index_shapes(text: str) -> set:
+    """The index operands' shapes of the scatters in a lowered StableHLO
+    text: `2x8x2` is 8 pages a sequence by (layer, block), `2x32x3` 32 rows
+    a sequence by (layer, block, row)."""
+    return set(re.findall(r"\(tensor<[\dx]+x\w+>, tensor<([\dx]+)xi32>, tensor<[\dx]+x\w+>\) ->",
+                          text))
+
+
+def check_page_write_against_rows(monkeypatch, forward_paged, init_pool, vocab: int, case,
+                                  bs: int = 4):
+    """What every family's fresh prefill is held to (PR 43). `forward_paged`
+    (tokens, pool, tables, lengths, **kw) and `init_pool` (num_blocks, bs) are
+    the family's with its weights and configuration bound; `case` one of
+    `PAGE_WRITE_CASES`. B = 2 sequences prefilled `fresh`, which writes whole
+    pages (the lowered text scatters bucket / bs indices a sequence, not
+    bucket), then one decode step; the same again with `llama.writes_pages`
+    saying no, which is the row scatter's program: the pools EQUAL outside
+    block 0, only the touched blocks written, and the prefill's and the
+    step's logits equal."""
+    live, bucket, blocks, mb = case
+    B = 2
+    ids = np.random.default_rng(live).permutation(np.arange(1, 1 + B * blocks)).reshape(B, blocks)
+    table = np.zeros((B, mb), np.int32)
+    table[:, :blocks] = ids
+    tables = jnp.asarray(table)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, bucket), 1, vocab)
+    tokens = jnp.where(jnp.arange(bucket)[None] < live, tokens, 0)
+
+    def run():
+        prefill = jax.jit(lambda pool: forward_paged(
+            tokens, pool, tables, jnp.zeros(B, jnp.int32), block_size=bs,
+            head_rows=jnp.full((B,), live - 1, jnp.int32), fresh=True))
+        text = prefill.lower(init_pool(1 + B * blocks, bs)).as_text()
+        logits, pool = prefill(init_pool(1 + B * blocks, bs))
+        nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)[:, None]
+        step, _ = jax.jit(lambda pool: forward_paged(
+            nxt, pool, tables, jnp.full((B,), live, jnp.int32), block_size=bs))(pool)
+        return scatter_index_shapes(text), logits, pool, step
+
+    by_page, by_row = f"{B}x{bucket // bs}x2", f"{B}x{bucket}x3"
+    scatters, logits, pool, step = run()
+    assert by_page in scatters and by_row not in scatters, scatters
+    monkeypatch.setattr(llama, "writes_pages", lambda *a: False)
+    scatters, rows_logits, rows_pool, rows_step = run()
+    assert by_row in scatters and by_page not in scatters, scatters
+    touched = -(-min(bucket, mb * bs) // bs)
+    assert_same_pool_but_block_0(pool, rows_pool, ids[:, :touched].ravel(), bs)
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(rows_logits))
+    np.testing.assert_array_equal(np.asarray(step), np.asarray(rows_step))
+
+
+@pytest.mark.parametrize("S, mb", [(32, 8), (32, 5), (16, 1)],
+                         ids=["inside-the-table", "past-the-table", "one-page"])
+def test_write_pages_is_the_row_scatter_at_page_rows_places(S, mb):
+    """`llama.write_pages` alone, on a bfloat16 leaf with float32 rows (cast
+    as the row scatter casts them), the layer traced: `leaf.at[layer, blk_idx,
+    blk_off].set(rows)` at `page_rows`'s places from position 0, exactly, in
+    every block but block 0, which takes the pages whose table entry is 0 and
+    those past the table's end; the other layers are not touched."""
+    B, bs, row = 2, 16, 256
+    leaf = jax.random.normal(jax.random.PRNGKey(0), (3, 12, bs, row), jnp.bfloat16)
+    rows = jax.random.normal(jax.random.PRNGKey(1), (B, S, row), jnp.float32)
+    table = np.zeros((B, mb), np.int32)
+    table[0, :1], table[1, :min(mb, 2)] = [7], [3, 9][:min(mb, 2)]
+    tables, zero = jnp.asarray(table), jnp.zeros(B, jnp.int32)
+    got = jax.jit(lambda leaf, layer: llama.write_pages(leaf, layer, tables, rows, bs))(leaf, 1)
+    _, blk_idx, blk_off = llama.page_rows(tables, zero, S, bs)
+    want = leaf.at[1, blk_idx, blk_off].set(rows.astype(leaf.dtype))
+    assert got.dtype == leaf.dtype
+    f32 = lambda a: np.asarray(a, np.float32)
+    got, want, was = f32(got), f32(want), f32(leaf)
+    np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+    np.testing.assert_array_equal(got[::2], was[::2])
+    assert (got[1, 7] != was[1, 7]).any() and (got[1, 3] != was[1, 3]).any()
+    # block 0 holds ONE of the pages sent there, whole
+    sent = [f32(rows[b, j * bs:(j + 1) * bs].astype(leaf.dtype))
+            for b in range(B) for j in range(S // bs) if j >= mb or table[b, j] == 0]
+    if sent:
+        assert any((got[1, 0] == page).all() for page in sent)
+    else:
+        np.testing.assert_array_equal(got[1, 0], was[1, 0])
+
+
+@pytest.mark.parametrize("case", PAGE_WRITE_CASES)
+@pytest.mark.parametrize("family", ["llama", "olmoe"])
+def test_a_fresh_prefill_s_pages_leave_the_pool_the_row_scatter_left(monkeypatch, family, case):
+    """A prefill told `fresh` whose bucket fills whole blocks writes its keys
+    and values as bucket / block_size whole pages (`llama.write_pages`) and
+    leaves the pool the row scatter left, EXACTLY, in every block but the
+    garbage block 0: a prompt that ends inside a block (the padding behind it
+    lies in the block as it did), one that fills its last block beside a block
+    reserved for decoding (untouched), a bucket whose padding runs past the
+    allocation (table entries 0) or past the table itself (all to block 0).
+    Then the next decode step reads either pool to the same logits."""
+    cfg, init, forward_paged, _ = _families()[family]
+    params = init(jax.random.PRNGKey(0))
+    check_page_write_against_rows(
+        monkeypatch, lambda tokens, pool, tables, lengths, **kw: forward_paged(
+            params, tokens, pool=pool, tables=tables, lengths=lengths, **kw),
+        functools.partial(llama.init_kv_pool, cfg), cfg.vocab_size, case)
+
+
+@pytest.mark.parametrize("family", ["llama", "olmoe"])
+def test_a_fresh_prefill_that_ends_inside_a_block_writes_rows(family):
+    """`fresh` with S % block_size != 0 (an engine whose bucket is no multiple
+    of its block size): the rows are no whole pages, so the write is the row
+    scatter (`llama.writes_pages`), and the pool and the logits are the table
+    program's."""
+    cfg, init, forward_paged, _ = _families()[family]
+    params = init(jax.random.PRNGKey(0))
+    B, S, bs, mb = 2, 30, 4, 8
+    assert not llama.writes_pages(True, S, bs) and llama.writes_pages(True, 32, bs)
+    assert not llama.writes_pages(False, 32, bs)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)
+    tables = jnp.asarray(np.random.default_rng(0).permutation(
+        np.arange(1, 1 + B * mb)).reshape(B, mb), jnp.int32)
+    step = jax.jit(lambda fresh: forward_paged(
+        params, tokens, pool=llama.init_kv_pool(cfg, 1 + B * mb, bs), tables=tables,
+        lengths=jnp.zeros(B, jnp.int32), block_size=bs, fresh=fresh), static_argnums=0)
+    scatters = scatter_index_shapes(step.lower(True).as_text())
+    assert f"{B}x{S}x3" in scatters and not [sh for sh in scatters if sh.endswith("x2")], scatters
+    (got, got_pool), (want, want_pool) = step(True), step(False)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=2e-4)
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(got_pool[name][0]), np.asarray(want_pool[name][0]))
+        np.testing.assert_allclose(np.asarray(got_pool[name]), np.asarray(want_pool[name]),
+                                   rtol=1e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize("family", ["llama", "olmoe"])

@@ -257,6 +257,36 @@ def assert_a_share_moves_its_bound(text: str, cfg, tokens: int, compacts: bool) 
     assert bool(array_lines(text, (pairs, cfg.base.hidden_size))) == (not compacts)
 
 
+def pool_scatter_updates(text: str, leaf_shape: tuple) -> list[tuple]:
+    """The shape of the UPDATES operand of every scatter of a compiled text
+    that produces an array the size of the pool leaf `leaf_shape`, in whatever
+    view XLA took of it: `(2048, 1024)` is 2,048 rows of a token each (the
+    row scatter sees the pool as `[L * NB * BS, row]`), `(128, 16, 1024)` 128
+    whole pages."""
+    dims = lambda s: tuple(int(d) for d in s.split(",") if d)
+    shape_of = {name: dims(d) for name, d in re.findall(r"%(\S+) = \w+\[([\d,]*)\]", text)}
+    return [shape_of[m.group(2)] for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* scatter\(%\S+, %\S+, %([^\s,)]+)\)", text)
+        if math.prod(dims(m.group(1))) == math.prod(leaf_shape)]
+
+
+def assert_a_fresh_prefill_writes_whole_pages(text: str, leaf_shape: tuple, S: int,
+                                              scope: str, leaves: int) -> None:
+    """What ISSUE 43 holds a compiled fresh prefill of S rows to: the writes
+    of its row-a-token leaves (`leaves` of them, each `leaf_shape`) are
+    scatters of S / block_size whole `[block_size, row]` pages under `scope`,
+    and NO scatter of S updates is left (XLA:TPU walks a scatter's indices one
+    at a time: 2,048 rows of 1,024 lanes took 0.27 ms a leaf and layer, their
+    128 pages take 0.03; PERF.md section 6, PR 43). The scatter keeps the
+    pool's own four dimensions and its `op_name`, which the row scatter's
+    fusion lost."""
+    bs, row = leaf_shape[2:]
+    updates = pool_scatter_updates(text, leaf_shape)
+    assert updates and set(updates) == {(S // bs, bs, row)}, updates
+    assert len(updates) >= leaves
+    assert f"{scope}/scatter" in text
+
+
 def assert_pool_stays_in_place(compiled, pool, scratch_under: int | None = None,
                                alloc_under: int = 0) -> None:
     """What ISSUE 30 holds a paged step to. `pool` is the pool's shapes (K and
@@ -281,7 +311,7 @@ def assert_pool_stays_in_place(compiled, pool, scratch_under: int | None = None,
             assert elems * width < alloc_under, ln[:200]
     # parameters, tuple elements and bitcasts move nothing; every other
     # producer of a pool is a write into it, and the only one a step has is
-    # the row scatter of the model's own `kv_write`
+    # the scatter of the model's own `kv_write`, of rows or of whole pages
     for shape in {page.shape for page in pages}:
         moved = [(op, ln[:200]) for op, ln in pool_sized_instructions(text, shape)
                  if op not in ("parameter", "get-tuple-element", "bitcast", "scatter")]
@@ -410,6 +440,11 @@ def test_kimi_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw, 
     assert "paged_attention_decode" not in text
     assert_a_share_moves_its_bound(text, cfg, B * S, compacts=True)
     assert_a_fresh_latent_prefill_reads_its_own_rows(text, S, fresh)
+    if fresh:
+        assert_a_fresh_prefill_writes_whole_pages(text, pool["latent"].shape, S,
+                                                  "attn/latent_write", leaves=1)
+    else:   # a row a token: the table prefill's 2,048, the decode step's 64
+        assert set(pool_scatter_updates(text, pool["latent"].shape)) == {(B * S, 640)}
 
 
 def assert_a_fresh_latent_prefill_reads_its_own_rows(text: str, S: int, fresh: bool) -> None:
@@ -493,6 +528,9 @@ def test_xing4_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw,
         assert scope in text, scope
     assert ("latent_attention_decode" in text) == (S == 1)
     assert_a_fresh_latent_prefill_reads_its_own_rows(text, S, fresh)
+    if fresh:
+        assert_a_fresh_prefill_writes_whole_pages(text, pool["latent"].shape, S,
+                                                  "attn/latent_write", leaves=1)
     if S == 512:   # scores against the table's 1,024 columns, or against its own 512 rows
         wide = re.findall(r"= f32\[[\d,]+,512,1024\]", text)   # by head: not the combine's
         assert bool(wide) == (not fresh), wide[:3]
@@ -563,6 +601,11 @@ def test_lfm2_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw, 
         assert scope in text, scope
     # a fresh prefill reads no state and no K/V back
     assert ("conv/state_read" in text) == ("attn/kv_read" in text) == (S == 1)
+    # and writes its six attention layers' keys and values as whole pages (the
+    # state's two rows a block stay a row scatter, `conv/state_write`)
+    if S > 1:
+        assert_a_fresh_prefill_writes_whole_pages(text, pool["k"].shape, S, "attn/kv_write",
+                                                  leaves=2)
     # 16 of 32 held: the bound is every pair, the step moves T x k rows
     assert_a_share_moves_its_bound(text, cfg, B * S, compacts=False)
 
@@ -653,6 +696,11 @@ def test_a_fresh_prefill_reads_its_own_rows_and_a_continued_one_the_table(v5e, b
     f32_dims = [m.group(1).split(",") for m in re.finditer(r"= f32\[([\d,]+)\]", text)]
     scores = [d for d in f32_dims if d.count("2048") >= 2]
     assert "attn/kv_write" in text
+    if fresh:
+        assert_a_fresh_prefill_writes_whole_pages(text, pool["k"].shape, bucket, "attn/kv_write",
+                                                  leaves=2)
+    else:   # the continued prefix's start is traced: a row a token, as the parent's
+        assert set(pool_scatter_updates(text, pool["k"].shape)) == {(bucket, 1024)}
     assert ("attn/prompt_attend" in text) == fresh
     assert ("attn/kv_read" in text) == (not fresh)
     assert ("flash_attention_fwd" in text) == (fresh and bucket >= 1024)
@@ -660,6 +708,24 @@ def test_a_fresh_prefill_reads_its_own_rows_and_a_continued_one_the_table(v5e, b
     if bucket == 128:
         assert not [d for d in f32_dims if "2048" in d]
         assert ["1", "8", "4", "128", "128"] in f32_dims or ["8", "4", "128", "128"] in f32_dims
+
+
+def test_ouro_s_fresh_prefill_writes_every_pass_s_pages(v5e):
+    """`serve-ouro-shortin-batch`'s largest prefill as the engine builds it
+    for a prompt with no cached prefix, at Ouro-2.6B's published widths: the
+    256 bucket is 16 pages a cache layer, `pass * L + layer` traced inside two
+    scans, 192 of them an admission (384 scatters of 256 rows, 14 ms of every
+    ~96 ms admission, before PR 43). Whole pages, the pool `bf16[192, 321, 16,
+    2048]` where it is, scratch under the bound the table program has."""
+    cfg = dataclasses.replace(ouro.OuroConfig.ouro_2_6b(), max_seq_len=2048)
+    lowered, pool = _engine_step(v5e[0], cfg, "prefill", B=1, S=256, pool_blocks=321,
+                                 head="last", table_first=True, fresh=True)
+    compiled = lowered.compile()
+    assert_pool_stays_in_place(compiled, pool, scratch_under=2 ** 30)
+    text = compiled.as_text()
+    assert_a_fresh_prefill_writes_whole_pages(text, pool["k"].shape, 256, "attn/kv_write",
+                                              leaves=2)
+    assert "attn/prompt_attend" in text and "attn/kv_read" not in text
 
 
 def test_steps_that_read_every_row_or_none_keep_or_drop_the_head(v5e):
