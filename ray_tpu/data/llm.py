@@ -3,7 +3,7 @@
 Parity: python/ray/data/llm.py (ProcessorConfig :26, build_llm_processor :104)
 and the staged batch pipeline in ray.llm _internal/batch/stages/
 (chat_template → tokenize → engine → detokenize). The engine stage runs the
-same continuous-batching LLMEngine the serve path uses — one engine per
+same continuous-batching engine the serve path uses (`PagedLLMEngine`) — one per
 processor, shared across blocks, so the MXU sees full decode batches even when
 dataset blocks are small.
 
@@ -26,7 +26,8 @@ import numpy as np
 
 from ray_tpu.data.block import Block
 from ray_tpu.data.dataset import Dataset
-from ray_tpu.serve.llm import LLMConfig, LLMEngine
+from ray_tpu.serve.llm import LLMConfig
+from ray_tpu.serve.llm_paged import PagedLLMEngine
 
 
 @dataclasses.dataclass
@@ -53,13 +54,13 @@ class Processor:
     streaming executor (blocks in flight bounded, prompts submitted as
     blocks land, outputs yielded in input order)."""
 
-    def __init__(self, config: ProcessorConfig, engine: LLMEngine | None = None):
+    def __init__(self, config: ProcessorConfig, engine: PagedLLMEngine | None = None):
         self.config = config
         self._engine = engine
 
-    def _get_engine(self) -> LLMEngine:
+    def _get_engine(self) -> PagedLLMEngine:
         if self._engine is None:
-            self._engine = LLMEngine(self.config.llm_config)
+            self._engine = PagedLLMEngine(self.config.llm_config)
         return self._engine
 
     def _tokenize(self, prompts) -> list[list[int]]:
@@ -72,7 +73,7 @@ class Processor:
                 token_lists.append([int(t) for t in np.asarray(p).tolist()])
         return token_lists
 
-    def _submit_batch(self, engine: LLMEngine, batch: dict):
+    def _submit_batch(self, engine: PagedLLMEngine, batch: dict):
         """Submit every prompt of one batch; continuous batching interleaves
         them with whatever earlier batches are still decoding."""
         toks = self._tokenize(batch[self.config.prompt_column])

@@ -1,7 +1,7 @@
 """Model families. What the SPMD train step (train/spmd.py) and the serving
-engines (serve/llm.py, serve/llm_paged.py) take of one is a `Model`: each
-family module exports its own as `MODEL`, and `model_of(cfg)` finds it from a
-family's configuration, so no engine names a family."""
+engine (serve/llm_paged.py) take of one is a `Model`: each family module
+exports its own as `MODEL`, and `model_of(cfg)` finds it from a family's
+configuration, so the engine names no family."""
 
 from __future__ import annotations
 
@@ -16,9 +16,9 @@ class Model(NamedTuple):
     # the objective, and the model's own scalars for the step's metrics dict;
     # `mesh` is the mesh the step is sharded over, for a kernel-or-dense choice
     loss: Callable
-    # -- what serving takes; None where the family does not serve that way.
-    # The paged engines: (params, tokens, cfg, pool, tables, lengths,
-    # block_size, platform=, head_rows=, fresh=) -> (logits [B, S, V], or
+    # -- what serving takes; None where the family does not serve.
+    # The engine's one cache contract: (params, tokens, cfg, pool, tables,
+    # lengths, block_size, platform=, head_rows=, fresh=) -> (logits [B, S, V], or
     # [B, 1, V] of the positions `head_rows` [B] names, pool); `fresh`
     # (static) says that every sequence starts at position 0, so a family may
     # attend over the rows in hand and read nothing back, and write rows that
@@ -36,10 +36,6 @@ class Model(NamedTuple):
     # is no row a token writes nothing of them (`lfm2.forward_paged`)
     forward_paged: Optional[Callable] = None
     init_kv_pool: Optional[Callable] = None
-    # the dense slot engine: (params, tokens, cfg, cache, lengths,
-    # head_rows=) -> (logits, cache), and (cfg, batch, max_len) -> its cache
-    forward_with_cache: Optional[Callable] = None
-    init_kv_cache: Optional[Callable] = None
 
 
 def model_of(cfg) -> Model:

@@ -250,8 +250,8 @@ def gqa_attention(attend):
     rotated heads q [B, S, Hq, D], k/v [B, S, Hkv, D]. `cache` is the WHOLE
     cache, every layer's (and every other kind of layer's), and `index`
     the layer's place in it: `attend` writes this layer's rows in place and
-    reads them back (`forward_paged`: pages of the pool; `forward_with_cache`:
-    slots), or keeps nothing (`plain_attend`: cache and index are None)."""
+    reads them back (`forward_paged`: pages of the pool), or keeps nothing
+    (`plain_attend`: cache and index are None)."""
 
     def attention(cfg, y, layer, cache, positions, index):
         B, S, _ = y.shape
@@ -674,21 +674,6 @@ def param_count_analytic(cfg: LlamaConfig) -> int:
 
 
 # ---------------------------------------------------------------- KV-cached inference
-def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
-    """Preallocated KV cache for continuous batching: [L, B, S, Hkv, D].
-
-    Static shapes keep XLA happy (one compile per engine); slot reuse gives
-    continuous batching without re-compiles. (The reference delegates this to
-    vLLM's paged KV; a pallas ragged-paged-attention variant is the planned
-    upgrade per PAPERS.md.)
-    """
-    shape = (cfg.loop_steps * cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.hd)
-    return {
-        "k": jnp.zeros(shape, dtype=cfg.dtype),
-        "v": jnp.zeros(shape, dtype=cfg.dtype),
-    }
-
-
 def pool_head_dim(head_dim: int) -> int:
     """A head's width in a row of the paged pool: whole 128-lane tiles."""
     return -(-head_dim // 128) * 128
@@ -701,7 +686,7 @@ def init_kv_pool(cfg: LlamaConfig, num_blocks: int, block_size: int) -> dict:
     (K, V) pairs a token caches: the model's layers, or passes x layers where
     the stack runs several times (`cfg.loop_steps`, `decoder_trunk`).
 
-    Unlike the dense per-slot cache (init_kv_cache), HBM is allocated in
+    Unlike a dense per-slot cache ([L, B, Smax, Hkv, D]), HBM is allocated in
     block_size-token pages handed out on demand by a host-side allocator
     (serve/paged_kv.py), so memory scales with ACTUAL tokens, full prefix
     blocks are shareable across sequences, and capacity admits many short
@@ -922,32 +907,7 @@ def _cached_attention(q, k_cache, v_cache, lengths, q_positions):
     return out.reshape(B, S, Hq, D)
 
 
-def forward_with_cache(params, tokens, cfg: LlamaConfig, cache: dict, lengths,
-                       head_rows=None):
-    """Append `tokens` [B,S] at positions [lengths, lengths+S) and return
-    (logits[B,S,V], updated cache); `head_rows` as `forward_paged`'s. Works
-    for prefill (S=prompt, lengths=0) and decode (S=1). The slot cache
-    [L, B, Smax, Hkv, D] rides in the layer scan's carry like the paged pool
-    (`decoder_trunk`): each layer scatters its new rows at
-    `[layer, b, position]` and attends over its own slice. A position past
-    Smax is not written."""
-    B = tokens.shape[0]
-    positions = lengths[:, None] + jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
-    slot = jnp.arange(B)[:, None]
-
-    def attend(q, k, v, cache, layer):  # the whole cache and this layer's index
-        kc, vc = cache["k"], cache["v"]
-        kc = kc.at[layer, slot, positions].set(k.astype(kc.dtype))
-        vc = vc.at[layer, slot, positions].set(v.astype(vc.dtype))
-        o = _cached_attention(q, kc[layer], vc[layer], lengths, positions)
-        return o, {"k": kc, "v": vc}
-
-    return decoder_trunk(params, tokens, cfg, gqa_attention(attend), cache=cache,
-                         positions=positions, head_rows=head_rows)[:2]
-
-
-# what train/spmd.py and the serving engines take of a model
+# what train/spmd.py and the serving engine take of a model
 # (ray_tpu/models/__init__.py)
 MODEL = Model(init=init, logical_axes=logical_axes, loss=_model_loss,
-              forward_paged=forward_paged, init_kv_pool=init_kv_pool,
-              forward_with_cache=forward_with_cache, init_kv_cache=init_kv_cache)
+              forward_paged=forward_paged, init_kv_pool=init_kv_pool)

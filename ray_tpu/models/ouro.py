@@ -80,7 +80,6 @@ def init(cfg: OuroConfig, key: jax.Array) -> dict:
 # The trunk runs `cfg.loop_steps` passes and the pool holds `loop_steps * L`
 # cache layers from the configuration alone, and the layer applies the output
 # norms it holds: the forwards, the loss and the pool are the Llama family's.
-# The dense slot engine's entries are left out: Ouro serves paged.
 forward = llama.forward
 forward_paged = llama.forward_paged
 init_kv_pool = llama.init_kv_pool

@@ -5,12 +5,17 @@ caching in the engine, PD disaggregation in
 llm/_internal/serve/serving_patterns/prefill_decode/pd_server.py). Here both
 are native:
 
-- ``PagedLLMEngine``: LLMEngine's continuous-batching shell (scheduling,
-  streaming, sampling, finish/fail paths are inherited) over a block-pool KV
-  (the family's `Model.forward_paged` + serve/paged_kv.py allocator). Memory scales
-  with actual tokens reserved per request — many short sequences or few long
-  ones share one pool — and full prompt blocks are content-addressed so
-  shared prefixes prefill once and occupy memory once.
+- ``PagedLLMEngine``: THE serving engine, the one every builder constructs
+  (`build_openai_app`, `build_llm_deployment`, `data/llm.py::Processor`): the
+  request lifecycle (`generate`, `generate_stream`, cancel, finish and fail
+  paths, shutdown), admission and the decode loop over a block-pool KV (the
+  family's `Model.forward_paged` + serve/paged_kv.py allocator). Slots
+  join and leave the batched decode step without a recompile (static shapes:
+  one program for decode, one a prefill bucket). Memory scales with actual
+  tokens reserved per request — many short sequences or few long ones share
+  one pool — and full prompt blocks are content-addressed so shared prefixes
+  prefill once and occupy memory once. With `num_blocks` 0 every slot can
+  hold `max_seq_len`, so the allocator refuses nothing a slot is free for.
 - ``prefill_extract`` / ``attach_sequence``: the KV handoff pair backing PD
   disaggregation — a prefill engine computes a sequence's KV pages and ships
   them (host numpy; cross-host this rides the object plane), a decode engine
@@ -24,8 +29,8 @@ never preempts mid-sequence (vLLM-style preemption is a later refinement).
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import queue
+import threading
 import time
 from concurrent.futures import Future
 from functools import partial
@@ -34,10 +39,12 @@ from typing import NamedTuple, Optional
 import numpy as np
 
 from ray_tpu.models import model_of
+from ray_tpu.ops.platform import target_platform
 from ray_tpu.serve import anatomy
-from ray_tpu.serve.llm import LLMConfig, LLMEngine, _Slot
+from ray_tpu.serve.llm import GenerationResult, LLMConfig, _Slot, _TokenQueue
 from ray_tpu.serve.paged_kv import BlockPool, NoFreeBlocks
-from ray_tpu.util.compile_cache import compile_totals
+from ray_tpu.serve.stream_cell import StreamCell
+from ray_tpu.util.compile_cache import compile_totals, ensure_compile_cache
 from ray_tpu.util.timeline import PhaseClock, PhaseLoop
 
 # the phases of the engine's timeline records, in the order they run; each
@@ -54,20 +61,10 @@ class _Flight(NamedTuple):
     counters: dict   # the pool's `counters` as that step left them, copied on the device
 
 
-@dataclasses.dataclass
-class PagedLLMConfig(LLMConfig):
-    block_size: int = 16
-    num_blocks: int = 0  # 0 = dense-parity capacity (B * Smax / block_size)
-    # PD handoff transport: "host" ships KV as numpy in the handoff dict;
-    # "device" keeps KV device-resident and ships only a transfer TICKET —
-    # the decode engine pulls the pages device->device over the jax transfer
-    # server (experimental/rdt.py offer_device/pull_device; reference:
-    # rdt/nixl_tensor_transport.py); "plane" publishes the pages as a sealed
-    # object-plane entry (serve/kv_transport.py) and ships only the compact
-    # descriptor — a decode engine on ANY node pulls them with zero-copy
-    # BLOB frames straight into its own store (reference: NIXL/RDT KV
-    # transfer riding the shared object plane)
-    kv_transfer: str = "host"
+# the engine's one configuration class, under the name `ray_tpu.serve` exports
+# and the benchmark imports (ROADMAP D14: the name goes with the next
+# `benchmark` issue that edits `harness/families/llama.py`)
+PagedLLMConfig = LLMConfig
 
 
 def paged_step(name: str, cfg, block_size: int, platform: str,
@@ -228,11 +225,44 @@ def _n_pages(kv: dict) -> int:
     return next(iter(kv.values())).shape[1]
 
 
-class PagedLLMEngine(LLMEngine):
-    """Continuous batching over a paged KV pool with prefix caching."""
+class PagedLLMEngine:
+    """Continuous batching over a paged KV pool with prefix caching (the
+    vLLM-engine equivalent, jax-native)."""
 
-    def __init__(self, config: PagedLLMConfig | None = None, params=None, seed: int = 0,
+    def __init__(self, config: LLMConfig | None = None, params=None, seed: int = 0,
                  external_step: bool = False):
+        import jax
+        import jax.numpy as jnp
+
+        self.config = config = config or LLMConfig()
+        cfg = config.model_config
+        self._jax = jax
+        self._jnp = jnp
+        key = jax.random.PRNGKey(seed)
+        self.model = model_of(cfg)
+        self.params = params if params is not None else self.model.init(cfg, key)
+        # Where the weights actually live, so where every step runs: a
+        # CPU-pinned worker process reports "cpu" here however many chips the
+        # host has (stats() carries it to whoever has to check)
+        self.platform = target_platform(*jax.tree.leaves(self.params))
+        ensure_compile_cache(self.platform)
+        B = config.max_batch_size
+        self.lengths = np.zeros(B, dtype=np.int32)
+        self.last_tokens = np.zeros((B, 1), dtype=np.int32)
+        self.active = np.zeros(B, dtype=bool)
+        self.slots: list[Optional[_Slot]] = [None] * B
+        # (prompt, max_new, future, enqueue time, token queue | None, request id | None)
+        self._pending: "queue.Queue[tuple]" = queue.Queue()
+        self._lock = threading.Lock()
+        # the cells of the live streams (`generate_stream` appends its own),
+        # the sums of those folded away, and the sums as the last `decode`
+        # record noted them (`_stream_sums`, the engine thread's alone)
+        self._streams: list[StreamCell] = []
+        self._streams_lock = threading.Lock()
+        self._st_folded = self._st_noted = (0,) * len(StreamCell.COUNTS)
+        self._running = True
+        self._sample_key = key  # `pick` draws from it on the device
+        self._rng = np.random.default_rng(seed)  # `_sample`'s: an admission's first token
         # PD ops (prefill_extract / attach) processed on the engine thread
         self._ops: "queue.Queue" = queue.Queue()
         # slot -> anatomy rid awaiting its first DECODED token (the attach
@@ -249,8 +279,14 @@ class PagedLLMEngine(LLMEngine):
         # the engine thread's records in a row (PERF.md section 3): the time
         # between two of them, while the loop is busy, is the later one's `turn`
         self._records = PhaseLoop("engine")
-        super().__init__(config or PagedLLMConfig(), params=params, seed=seed,
-                         external_step=external_step)
+        self._init_backend()  # the pool + the jitted programs; a subclass adds its own
+        # external_step: no internal loop thread — a coordinator drives the
+        # engine via step_once() (DP-attention rank lockstep, dp_attention.py)
+        self._loop_thread = None
+        if not external_step:
+            self._loop_thread = threading.Thread(target=self._loop, daemon=True,
+                                                 name=type(self).__name__)
+            self._loop_thread.start()
 
     def _init_backend(self) -> None:
         cfg = self.config.model_config
@@ -276,6 +312,14 @@ class PagedLLMEngine(LLMEngine):
         # the decode step enqueued and not yet read (`_step_decode`); the
         # engine thread's alone
         self._flight: Optional[_Flight] = None
+
+    def step_once(self) -> bool:
+        """One admit/decode round under external control; True if work ran."""
+        try:
+            return self._loop_step()
+        except Exception as e:  # noqa: BLE001 - engine must survive any request
+            self._fail_all_active(e)
+            return True
 
     def dummy_decode(self) -> None:
         """Cadence-keeping round for DP-attention lockstep (dp_attention.py):
@@ -307,7 +351,8 @@ class PagedLLMEngine(LLMEngine):
         the stale row is overwritten or never read. The id that step chose for
         this row is dropped when it is read: the row's slot is no longer the
         one it was enqueued for."""
-        super()._release_slot(i)
+        self.active[i] = False
+        self.slots[i] = None
         self._anatomy_pending.pop(i, None)
         self.tables[i] = 0
         self.lengths[i] = 0
@@ -317,13 +362,114 @@ class PagedLLMEngine(LLMEngine):
             self.slot_blocks[i] = []
         self.slot_prompts[i] = None
 
+    # ---- public API ----
+    def _validate(self, prompt_ids, max_new) -> Optional[Exception]:
+        if not prompt_ids:
+            return ValueError("prompt_ids must be non-empty")
+        vocab = self.config.model_config.vocab_size
+        if not all(isinstance(t, (int, np.integer)) and 0 <= t < vocab
+                   for t in prompt_ids):
+            return ValueError("prompt_ids must be ints within the vocabulary")
+        if len(prompt_ids) + max_new > self.config.max_seq_len:
+            return ValueError(
+                f"prompt ({len(prompt_ids)}) + max_new_tokens ({max_new}) exceeds "
+                f"max_seq_len {self.config.max_seq_len}"
+            )
+        return None
+
+    def generate(self, prompt_ids: list[int], max_new_tokens: int | None = None) -> Future:
+        fut: Future = Future()
+        max_new = self.config.max_new_tokens_default if max_new_tokens is None else max_new_tokens
+        err = self._validate(prompt_ids, max_new)
+        if err is not None:
+            fut.set_exception(err)
+            return fut
+        if max_new <= 0:
+            fut.set_result(GenerationResult([], len(prompt_ids), 0, 0.0, 0.0))
+            return fut
+        self._pending.put((list(prompt_ids), max_new, fut, time.monotonic(), None, None))
+        return fut
+
+    def generate_stream(self, prompt_ids: list[int], max_new_tokens: int | None = None,
+                        rid: str | None = None, cell: StreamCell | None = None):
+        """Yield token ids as they are decoded (streaming TTFT path).
+
+        Validation matches generate(); every engine path (completion, request
+        failure, engine failure, shutdown) terminates the stream via the None
+        sentinel so consumers never hang.
+
+        `rid` is the request's id where it has one (`serve/anatomy.py`): the
+        `admit` record carries it. The stream counts what it
+        costs into `cell`: the caller's, if it has stages of its own to count
+        there (`openai_api.py::_stream_deltas`), else a new one."""
+        fut: Future = Future()
+        max_new = self.config.max_new_tokens_default if max_new_tokens is None else max_new_tokens
+        err = self._validate(prompt_ids, max_new)
+        if err is not None:
+            raise err
+        if max_new <= 0:
+            return
+        if cell is None:
+            cell = StreamCell()
+        tq = _TokenQueue(cell)
+        with self._streams_lock:
+            self._streams.append(cell)
+        self._pending.put((list(prompt_ids), max_new, fut, time.monotonic(), tq, rid))
+        try:
+            while True:
+                item = tq.get(timeout=300)
+                if item is None:
+                    if fut.done() and fut.exception() is not None:
+                        raise fut.exception()
+                    return
+                tok, t_put = item
+                cell.taken += 1
+                cell.wake += time.monotonic() - t_put
+                yield tok
+        finally:
+            cell.ended = True
+
+    def generate_sync(self, prompt_ids: list[int], max_new_tokens: int | None = None,
+                      timeout: float = 120.0) -> GenerationResult:
+        return self.generate(prompt_ids, max_new_tokens).result(timeout)
+
     def stats(self) -> dict:
-        # the base engine's schema (dashboards read active_slots/max_slots
-        # regardless of engine type) plus the allocator's fields
-        return {**super().stats(), **self.allocator.stats()}
+        # compiles / compile_s are the PROCESS's (util/compile_cache.py): a
+        # step that compiles after warm-up shows as a rise between two reads
+        compiles, compile_s = compile_totals()[:2]
+        with self._lock:
+            out = {
+                "active_slots": int(self.active.sum()),
+                "max_slots": self.config.max_batch_size,
+                "pending": self._pending.qsize(),
+                # tokens put on their streams' queues and not yet taken
+                "stream_backlog": self._stream_totals()[1],
+                "platform": self.platform,
+                "compiles": compiles,
+                "compile_s": compile_s,
+            }
+        return {**out, **self.allocator.stats()}
 
     def shutdown(self) -> None:
-        super().shutdown()  # stops the loop + fails active slots
+        """Stop the loop and wait for it: a daemon thread still inside a
+        jitted call when the interpreter tears down aborts the process
+        (status 134) with the device open. Requests still queued end too:
+        nothing will admit them, and a stream would wait out its poll."""
+        self._running = False
+        t = self._loop_thread
+        if t is not None and t is not threading.current_thread():
+            t.join()
+        exc = RuntimeError("LLM engine shut down")
+        self._fail_all_active(exc)
+        while True:
+            try:
+                _, _, fut, _, tq, _ = self._pending.get_nowait()
+            except queue.Empty:
+                break
+            if not fut.done():
+                fut.set_exception(exc)
+            if tq is not None:
+                tq.put(None)
         # a step the loop left in flight: its slots are failed, so its ids go
         # unread, but the device is not left with work behind the exit
         flight, self._flight = self._flight, None
@@ -337,7 +483,7 @@ class PagedLLMEngine(LLMEngine):
             except queue.Empty:
                 break
             if not fut.done():
-                fut.set_exception(RuntimeError("LLM engine shut down"))
+                fut.set_exception(exc)
 
     def kv_memory_bytes(self) -> int:
         """Persistent KV pool footprint (the headroom metric vs dense): the
@@ -345,6 +491,109 @@ class PagedLLMEngine(LLMEngine):
         return sum(leaf.nbytes for leaf in page_leaves(self.pool).values())
 
     # ---- engine loop ----
+    def _bucket(self, n: int) -> int:
+        for b in self.config.prefill_buckets:
+            if n <= b:
+                return b
+        return self.config.max_seq_len
+
+    def _sample(self, logits_np: np.ndarray) -> int:
+        if self.config.temperature <= 0:
+            return int(np.argmax(logits_np))
+        z = logits_np / self.config.temperature
+        z = z - z.max()
+        p = np.exp(z) / np.exp(z).sum()
+        return int(self._rng.choice(len(p), p=p))
+
+    def _stream_totals(self) -> tuple[tuple, int]:
+        """(the sums of `StreamCell.COUNTS` over every stream this engine has
+        fed, the tokens put on live streams' queues and not yet taken). A
+        cell that has ended with no sink open is folded into `_st_folded`
+        here and dropped, so nothing of its tail is lost: whether it is over
+        is read BEFORE its counts, so the counts folded are its last."""
+        with self._streams_lock:
+            rows, ended, live, backlog = [self._st_folded], [], [], 0
+            for cell in self._streams:
+                over = cell.ended and cell.sink != 1
+                row = cell.counts()
+                rows.append(row)
+                if over:
+                    ended.append(row)
+                else:
+                    live.append(cell)
+                    if not cell.ended:
+                        backlog += cell.put - cell.taken
+            if ended:
+                self._st_folded = tuple(map(sum, zip(self._st_folded, *ended)))
+                self._streams = live
+        return tuple(map(sum, zip(*rows))), backlog
+
+    def _stream_sums(self) -> dict:
+        """For a `decode` record: `st_<count>` for each of `StreamCell.COUNTS`,
+        what this engine's streams gained since the record before, and
+        `st_backlog` as it stands. The engine thread's alone."""
+        totals, backlog = self._stream_totals()
+        noted, self._st_noted = self._st_noted, totals
+        out = {"st_" + name: now - was for name, now, was in
+               zip(StreamCell.COUNTS, totals, noted)}
+        out["st_backlog"] = backlog
+        return out
+
+    def _loop(self) -> None:
+        while self._running:
+            try:
+                did_work = self._loop_step()
+            except Exception as e:  # noqa: BLE001 - engine must survive any request
+                self._fail_all_active(e)
+                did_work = True
+            if not did_work:
+                time.sleep(0.002)
+
+    def cancel_future(self, fut) -> bool:
+        """Cancel the in-flight request whose slot holds `fut`: release the
+        slot and its KV blocks under the engine lock.
+        Public so callers (DP ranks, routers) never touch slot internals.
+        Returns False if the future holds no slot (finished or still queued)."""
+        with self._lock:
+            for i, st in enumerate(self.slots):
+                if st is not None and st.future is fut:
+                    self._release_slot(i)
+                    return True
+        return False
+
+    def _fail_all_active(self, exc: Exception) -> None:
+        with self._lock:
+            for i in range(self.config.max_batch_size):
+                st = self.slots[i]
+                if st is not None:
+                    self._release_slot(i)
+                    if not st.future.done():
+                        st.future.set_exception(exc)
+                    if st.token_queue is not None:
+                        st.token_queue.put(None)
+
+    def _maybe_finish(self, slot: int, last_tok: int) -> None:
+        st = self.slots[slot]
+        if st is None:
+            return
+        eos = self.config.eos_token_id >= 0 and last_tok == self.config.eos_token_id
+        if eos or len(st.generated) >= st.max_new:
+            now = time.monotonic()
+            result = GenerationResult(
+                token_ids=list(st.generated),
+                num_prompt_tokens=st.prompt_len,
+                num_generated=len(st.generated),
+                ttft_s=(st.first_token_time or now) - st.start,
+                total_s=now - st.start,
+                finish_reason="stop" if eos else "length",
+            )
+            with self._lock:
+                self._release_slot(slot)
+            if st.token_queue is not None:
+                st.token_queue.put(None)  # end-of-stream
+            if not st.future.done():
+                st.future.set_result(result)
+
     def _admit_one(self, prompt, max_new, fut, t_enq, tq, rid, slot) -> bool:
         bs = self.config.block_size
         # one engine/admit timeline record per call (PERF.md section 3): the

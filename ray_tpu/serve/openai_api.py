@@ -59,7 +59,7 @@ def build_openai_app(config: "LLMConfig | None" = None, *,
     """An OpenAI-API-shaped deployment over the native engine
     (reference: ray.serve.llm build_openai_app). jax-heavy imports stay inside
     this builder so `import ray_tpu.serve` never pays them."""
-    from ray_tpu.serve.llm import LLMConfig, LLMEngine
+    from ray_tpu.serve.llm import LLMConfig
     from ray_tpu.serve.pd import _ReplicaLifecycle
 
     cfg = config or LLMConfig()
@@ -69,11 +69,9 @@ def build_openai_app(config: "LLMConfig | None" = None, *,
                  ray_actor_options={"num_tpus": 0.0}, max_ongoing_requests=64)
     class OpenAIServer(_ReplicaLifecycle):
         def __init__(self, llm_config, tokenizer, model_id: str):
-            from ray_tpu.serve.llm import LLMEngine as _Dense
-            from ray_tpu.serve.llm_paged import PagedLLMConfig, PagedLLMEngine
+            from ray_tpu.serve.llm_paged import PagedLLMEngine
 
-            _Engine = PagedLLMEngine if isinstance(llm_config, PagedLLMConfig) else _Dense
-            self.engine = _Engine(llm_config)
+            self.engine = PagedLLMEngine(llm_config)
             self.tok = tokenizer
             self.model_id = model_id
 

@@ -167,7 +167,7 @@ class SpecDecodeLLMEngine(PagedLLMEngine):
             return False
         K = self.config.num_speculative_tokens
         B = self.config.max_batch_size
-        # the base engine's engine/decode record, with the proposals as one
+        # the engine's own engine/decode record, with the proposals as one
         # more phase in front; `wait` is the verify pass on the device
         with self._decode_clock(_SPEC_DECODE_PHASES) as clock:
             proposals = np.zeros((B, K), dtype=np.int32)

@@ -3,7 +3,7 @@
 Since ISSUE 35 each stage of a token's way from the engine thread to the
 socket counts in place, around its own block, into its stream's `StreamCell`;
 the engine thread adds up what its cells gained since its last `decode`
-record and notes the sums there (`serve/llm.py::LLMEngine._stream_sums`), so
+record and notes the sums there (`serve/llm_paged.py::PagedLLMEngine._stream_sums`), so
 they carry `profiled` like everything else on the record. The stages that
 know a stream only by its request (`openai_api.py::_stream_deltas`,
 `api.py::_stream_response`) find the cell by the PR-16 request id

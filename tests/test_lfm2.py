@@ -6,11 +6,10 @@ On LOGITS, in float32: the cache-less forward; prefill then decode through
 `forward_paged` and a real `BlockPool` at the state rows' edges; a cached
 prefix resuming from the pages through the engine; the PD hand-off; a freed
 page's stale state; the two shares adding up; the per-head norm; wrong
-programs that must miss; and the trunk over runs being the old trunk, letter
-for letter, for every family of one kind."""
+programs that must miss. (That the trunk over runs is the old trunk, letter
+for letter, for every family of one kind is tests/test_lowered_text.py's.)"""
 
 import dataclasses
-import hashlib
 import json
 import math
 import os
@@ -22,7 +21,7 @@ import pytest
 
 from benchmarks.harness.families import lfm2 as family
 from benchmarks.reference import lfm2_reference as reference
-from ray_tpu.models import kimi_k2, lfm2, llama, model_of, moe, ouro
+from ray_tpu.models import lfm2, llama, moe
 from ray_tpu.serve.llm_paged import PagedLLMConfig, PagedLLMEngine, page_leaves
 from ray_tpu.serve.paged_kv import BlockPool
 from tests.test_paged_attention import PAGE_WRITE_CASES, check_page_write_against_rows
@@ -400,110 +399,6 @@ def test_a_fresh_prefill_s_pages_leave_k_and_v_the_row_scatter_s_beside_the_stat
             params, tokens, cfg, pool, tables, lengths, **kw),
         lambda blocks, bs: lfm2.init_kv_pool(cfg, blocks, bs), cfg.base.vocab_size,
         case)
-
-
-# ------------------------------------------- the trunk over runs is the old trunk
-# sha256 (first 16 hex digits) of the StableHLO text that each program lowered
-# to at the PARENT of PR 40 (commit 3eb9ea4, `decoder_trunk` one scan over one
-# stack, `lead_layers` a special case), made by this file's `_one_kind_programs`
-# from that tree. A change that means to alter a family's program replaces
-# its lines here, and says why. PR 41 replaced two, `kimi_k2.paged.fresh` and
-# `xing4.paged.fresh`: until then the latent family's forward did nothing with
-# `fresh` (the lines were its table program's, fb85295f16b0a12c and
-# 94ca6dd2f464ef8e); now a fresh prefill attends over the rows in hand under
-# `attn/prompt_attend`. The table program, the decode step and `last` are the
-# parent's still. PR 43 replaced all six `*.paged.fresh` lines and no other: a
-# fresh prefill whose 32 tokens fill two blocks of 16 scatters two whole pages
-# a leaf and layer where it scattered 32 rows (`llama.write_pages`; until then
-# 9faf4950b73482ea, b9032e5101a13d5c, f354ba46d4742441, 1e13fa5a3563dfaa,
-# 10adbe590e3aa7a9, cbaf223401156ccb in the dictionary's order); the decode
-# step, the table prefill, `last` (not told `fresh`), losses and gradients are
-# the parent's text.
-PARENT_TEXT = {
-    "llama.paged.decode": "4d1666b4e529e110",
-    "llama.paged.prefill": "fd8459cf291092a0",
-    "llama.paged.fresh": "e4819840a997529d",
-    "llama.paged.last": "af6f1abee32d97ef",
-    "llama.loss": "0fb9d6ad11474b55",
-    "llama.grad": "1351d9f4c3001991",
-    "moe.paged.decode": "906cc7b9fe5efe59",
-    "moe.paged.prefill": "a695e7509cb75b45",
-    "moe.paged.fresh": "b179923a4d114772",
-    "moe.paged.last": "0492ef996a21c340",
-    "moe.loss": "03710b5fb6249ca2",
-    "moe.grad": "66721486a6cbfac2",
-    "olmoe.paged.decode": "fd15da110c2c1b9b",
-    "olmoe.paged.prefill": "db9bf0e36bc145f6",
-    "olmoe.paged.fresh": "82027e3150d180ae",
-    "olmoe.paged.last": "656dd949f7c87504",
-    "olmoe.loss": "3d7e9ee7a5ae9813",
-    "olmoe.grad": "a450411da25cf0a8",
-    "ouro.paged.decode": "e5fc4c4767ca19dc",
-    "ouro.paged.prefill": "5fe0d29dd78c063f",
-    "ouro.paged.fresh": "6932f8c8b2bf340b",
-    "ouro.paged.last": "f8df10e5b4953e6f",
-    "kimi_k2.paged.decode": "7608cd7e4039bd6d",
-    "kimi_k2.paged.prefill": "fb85295f16b0a12c",
-    "kimi_k2.paged.fresh": "1da06d0983b09b0f",
-    "kimi_k2.paged.last": "46bab8d6b05745d6",
-    "xing4.paged.decode": "805d238630f2ee06",
-    "xing4.paged.prefill": "94ca6dd2f464ef8e",
-    "xing4.paged.fresh": "a83c509a1b1686ef",
-    "xing4.paged.last": "28834a2fee161468",
-}
-
-
-def _one_kind_programs() -> dict:
-    """{name: lowered StableHLO text}: every family of ONE kind of layer at
-    its tiny preset, its paged forward four ways (a decode step, a prefill
-    through the table, one over its own rows, one with `head_rows`) and, where
-    it trains, its loss and gradient."""
-    from benchmarks.harness.families import xing4 as xing4_family
-
-    with open(os.path.join(ROOT, "benchmarks", "tests", "fixtures", "tiny",
-                           "xing4-serve.json")) as f:
-        file = json.load(f)
-    cfgs = {"llama": llama.LlamaConfig.tiny(), "moe": moe.MoEConfig.tiny(),
-            "olmoe": dataclasses.replace(moe.MoEConfig.tiny(), qk_norm=True),
-            "ouro": ouro.OuroConfig.tiny(), "kimi_k2": kimi_k2.KimiK2Config.tiny(),
-            "xing4": xing4_family.model_config(
-                {k: file[k] for k in xing4_family.MODEL_KEYS}, remat=False)}
-    sds, i32, out = jax.ShapeDtypeStruct, jnp.int32, {}
-    for name, cfg in cfgs.items():
-        model = model_of(cfg)
-        params = jax.eval_shape(lambda: model.init(cfg, jax.random.PRNGKey(0)))
-        pool = jax.eval_shape(lambda: model.init_kv_pool(cfg, 9, BS))
-        for tag, B, S, kw in (("decode", 3, 1, {}), ("prefill", 1, 32, {}),
-                              ("fresh", 1, 32, {"fresh": True}), ("last", 1, 32, {"last": True})):
-            def step(params, pool, tokens, tables, lengths, rows, kw=kw):
-                kw = {"head_rows": rows} if kw.get("last") else kw
-                return model.forward_paged(params, tokens, cfg, pool, tables, lengths, BS,
-                                           platform="cpu", **kw)
-            out[f"{name}.paged.{tag}"] = jax.jit(step).lower(
-                params, pool, sds((B, S), i32), sds((B, 4), i32), sds((B,), i32),
-                sds((B,), i32)).as_text()
-        if model.loss is not None and name != "ouro":
-            loss = lambda p, t, y: model.loss(p, t, y, cfg, None)[0]
-            args = (params, sds((2, 16), i32), sds((2, 16), i32))
-            out[f"{name}.loss"] = jax.jit(loss).lower(*args).as_text()
-            out[f"{name}.grad"] = jax.jit(jax.grad(loss)).lower(*args).as_text()
-    return out
-
-
-@pytest.fixture(scope="module")
-def lowered():
-    return {k: hashlib.sha256(v.encode()).hexdigest()[:16]
-            for k, v in _one_kind_programs().items()}
-
-
-@pytest.mark.parametrize("name", sorted(PARENT_TEXT))
-def test_with_one_kind_of_layer_the_trunk_lowers_to_the_parent_s_text(lowered, name):
-    """`decoder_trunk(runs=)`, `gqa_attention`'s per-head norm, `paged_attend`
-    and the mixer's scope changed no program that was there: Mistral's,
-    OLMoE's, Ouro's, Kimi's and Xing's tiny presets lower to the StableHLO
-    text they lowered to on the parent, letter for letter (`lead_layers` is
-    now two runs and still the same text)."""
-    assert lowered[name] == PARENT_TEXT[name]
 
 
 def test_speculative_decoding_refuses_a_pool_with_rows_a_block(tiny):

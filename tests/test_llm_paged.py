@@ -1,13 +1,18 @@
-"""Paged-KV engine tests: correctness vs the dense engine, prefix caching,
-memory headroom, PD disaggregation handoff (reference: vLLM paged KV /
-automatic prefix caching / pd_server.py — native here)."""
+"""The serving engine (`serve/llm_paged.py::PagedLLMEngine`, the one every
+builder constructs): correctness vs the plain forward, what a plain
+`LLMConfig` gets from each builder, prefix caching, memory headroom, PD
+disaggregation handoff (reference: vLLM paged KV / automatic prefix caching /
+pd_server.py — native here)."""
+
+import dataclasses
 
 import numpy as np
 import pytest
 
-from ray_tpu.models import llama
-from ray_tpu.serve.llm import LLMConfig, LLMEngine
+from ray_tpu.models import Model, llama
+from ray_tpu.serve.llm import LLMConfig, build_llm_deployment
 from ray_tpu.serve.llm_paged import PagedLLMConfig, PagedLLMEngine, prefill_writes
+from ray_tpu.serve.openai_api import build_openai_app
 
 
 @pytest.fixture(scope="module")
@@ -24,22 +29,91 @@ def _paged(cfg, params, **kw):
     return PagedLLMEngine(pc, params=params)
 
 
-def test_paged_matches_dense_greedy(shared_params):
-    cfg, params = shared_params
-    dense = LLMEngine(
-        LLMConfig(model_config=cfg, max_batch_size=4, max_seq_len=128),
-        params=params,
-    )
-    paged = _paged(cfg, params)
-    prompts = [[5, 9, 13, 2, 7], [3, 3, 8], list(range(1, 40))]
+# ------------------------------------------------- one engine, whoever builds it
+def _replica(app):
+    """The replica object a bound application would run, made in place."""
+    d = app.deployment
+    return d.func_or_class(*d.init_args, **d.init_kwargs)
+
+
+def _from_app(build):
+    """`build` is a builder of a bound application whose replica holds `engine`."""
+    def make(config):
+        replica = _replica(build(config))
+        return replica.engine, replica.shutdown
+    return make
+
+
+def _from_processor(config):
+    from ray_tpu.data.llm import Processor, ProcessorConfig
+
+    engine = Processor(ProcessorConfig(llm_config=config))._get_engine()
+    return engine, engine.shutdown
+
+
+@pytest.mark.parametrize("build", [
+    pytest.param(_from_app(build_llm_deployment), id="build_llm_deployment"),
+    pytest.param(_from_app(build_openai_app), id="build_openai_app"),
+    pytest.param(_from_processor, id="data.llm.Processor"),
+])
+def test_a_plain_config_is_served_by_the_one_engine(build):
+    """Every builder hands a plain `LLMConfig` the engine the benchmark
+    measures: the class object itself, its pool, its records' fields."""
+    engine, shutdown = build(LLMConfig(max_batch_size=2, max_seq_len=64))
     try:
-        for p in prompts:
-            a = dense.generate_sync(p, 12)
-            b = paged.generate_sync(p, 12)
-            assert a.token_ids == b.token_ids, f"prompt {p[:5]}..."
+        assert type(engine) is PagedLLMEngine
+        assert engine.generate_sync([5, 9, 13], 4).num_generated == 4
+        stats = engine.stats()
+        assert stats["num_blocks"] == 2 * 64 // 16 + 1 and stats["prefix_queries"] == 1
     finally:
-        dense.shutdown()
-        paged.shutdown()
+        shutdown()
+
+
+def test_one_config_class_and_one_cache_contract():
+    """`PagedLLMConfig` is a name of `LLMConfig`, whose ten fields and
+    defaults are what the two classes had between them; the model record has
+    the paged contract alone; the engine has no base class to hop through."""
+    assert PagedLLMConfig is LLMConfig
+    fields = {f.name: f.default for f in dataclasses.fields(LLMConfig)}
+    assert list(fields) == [
+        "model_config", "max_batch_size", "max_seq_len", "max_new_tokens_default",
+        "temperature", "eos_token_id", "prefill_buckets", "block_size", "num_blocks",
+        "kv_transfer"]
+    assert list(fields.values())[1:] == [8, 256, 32, 0.0, -1, (32, 128), 16, 0, "host"]
+    assert Model._fields == ("init", "logical_axes", "loss", "forward_paged", "init_kv_pool")
+    assert PagedLLMEngine.__mro__ == (PagedLLMEngine, object)
+
+
+def test_a_max_seq_len_that_is_no_multiple_of_the_block_size_is_refused_by_name():
+    with pytest.raises(ValueError, match="max_seq_len 40 must be a block_size 16 multiple"):
+        PagedLLMEngine(LLMConfig(max_batch_size=2, max_seq_len=40))
+
+
+def test_the_default_pool_holds_every_slot_at_max_seq_len(shared_params):
+    """`num_blocks` 0 is the old slot cache's capacity: `max_batch_size`
+    requests of `max_seq_len` tokens each, prompts that share nothing, are
+    all admitted at once and none waits for blocks (no `requeued` record)."""
+    from ray_tpu.util import timeline
+
+    cfg, params = shared_params
+    B, S, new = 3, 64, 8
+    eng = PagedLLMEngine(LLMConfig(model_config=cfg, max_batch_size=B, max_seq_len=S),
+                         params=params, external_step=True)
+    rng = np.random.default_rng(5)
+    timeline.clear()
+    try:
+        futs = [eng.generate([int(t) for t in rng.integers(1, cfg.vocab_size, S - new)], new)
+                for _ in range(B)]
+        eng.step_once()
+        assert eng.stats()["pending"] == 0 and all(s is not None for s in eng.slots)
+        while not all(f.done() for f in futs):
+            eng.step_once()
+        assert [f.result().num_generated for f in futs] == [new] * B
+    finally:
+        eng.shutdown()
+    outcomes = [e[7]["outcome"] for e in timeline.local_events()
+                if e[0] == "span" and e[2] == "engine" and e[3] == "admit"]
+    assert outcomes == ["admitted"] * B
 
 
 def test_prefix_cache_reuses_blocks(shared_params):
@@ -157,8 +231,6 @@ def _spec(cfg, params):
 
 @pytest.mark.parametrize("make", [
     pytest.param(_paged, id="paged"),
-    pytest.param(lambda cfg, params: LLMEngine(LLMConfig(
-        model_config=cfg, max_batch_size=4, max_seq_len=128), params=params), id="slot"),
     pytest.param(_spec, id="speculative"),
 ])
 def test_engines_greedy_output_is_the_plain_forwards(shared_params, make):

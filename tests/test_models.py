@@ -95,16 +95,15 @@ def test_train_step_with_ring_attention(tiny):
     assert np.isfinite(float(metrics["loss"]))
 
 
-@pytest.mark.parametrize("path, prefill, qk_norm", [
-    pytest.param("paged", 12, False, id="paged-prefill"),
-    pytest.param("paged", 8, False, id="paged-prefill-then-decode"),
-    pytest.param("slot", 8, False, id="slot-prefill-then-decode"),
-    pytest.param("paged", 8, True, id="paged-qk-norm"),
+@pytest.mark.parametrize("prefill, qk_norm", [
+    pytest.param(12, False, id="paged-prefill"),
+    pytest.param(8, False, id="paged-prefill-then-decode"),
+    pytest.param(8, True, id="paged-qk-norm"),
 ])
-def test_cached_forwards_give_the_plain_forwards_logits(tiny, path, prefill, qk_norm):
+def test_cached_forwards_give_the_plain_forwards_logits(tiny, prefill, qk_norm):
     """The first `prefill` positions in one cached call, the rest a token a
     call: the logits of `llama.forward` at the same positions. One layer
-    function runs in all three, so a layer that holds `q_norm` and `k_norm`
+    function runs in both, so a layer that holds `q_norm` and `k_norm`
     is normalised through the paged path too."""
     cfg, params = tiny
     B, S, bs = 2, 12, 4
@@ -121,15 +120,10 @@ def test_cached_forwards_give_the_plain_forwards_logits(tiny, path, prefill, qk_
             {**params, "layers": {k: v for k, v in params["layers"].items()
                                   if k not in ("q_norm", "k_norm")}}, tokens, cfg)), atol=1e-2)
 
-    if path == "paged":
-        cache = llama.init_kv_pool(cfg, 1 + B * 4, bs)     # block 0 is the garbage block
-        tables = 1 + jnp.arange(B * 4, dtype=jnp.int32).reshape(B, 4)
-        step = lambda toks, cache, lengths: llama.forward_paged(
-            params, toks, cfg, cache, tables, lengths, bs)
-    else:
-        cache = llama.init_kv_cache(cfg, B, 16)
-        step = lambda toks, cache, lengths: llama.forward_with_cache(
-            params, toks, cfg, cache, lengths)
+    cache = llama.init_kv_pool(cfg, 1 + B * 4, bs)     # block 0 is the garbage block
+    tables = 1 + jnp.arange(B * 4, dtype=jnp.int32).reshape(B, 4)
+    step = lambda toks, cache, lengths: llama.forward_paged(
+        params, toks, cfg, cache, tables, lengths, bs)
     got = []
     for start, stop in [(0, prefill)] + [(i, i + 1) for i in range(prefill, S)]:
         logits, cache = step(tokens[:, start:stop], cache, jnp.full((B,), start, jnp.int32))
@@ -147,13 +141,12 @@ def _noisy_norms(params, key=5):
     return {**params, "layers": layers, "final_norm": noisy(99, params["final_norm"])}
 
 
-@pytest.mark.parametrize("cfg, path", [
-    pytest.param(llama.LlamaConfig.tiny(), "paged", id="llama"),
-    pytest.param(moe.MoEConfig.tiny(), "paged", id="moe"),
-    pytest.param(ouro.OuroConfig.tiny(), "paged", id="ouro"),
-    pytest.param(llama.LlamaConfig.tiny(), "slot", id="llama-slot"),
+@pytest.mark.parametrize("cfg", [
+    pytest.param(llama.LlamaConfig.tiny(), id="llama"),
+    pytest.param(moe.MoEConfig.tiny(), id="moe"),
+    pytest.param(ouro.OuroConfig.tiny(), id="ouro"),
 ])
-def test_head_rows_gives_that_row_of_the_all_positions_logits(cfg, path):
+def test_head_rows_gives_that_row_of_the_all_positions_logits(cfg):
     """`head_rows=r` is the head run on position `r[b]` of sequence b alone:
     logits [B, 1, V] equal to row `r[b]` of the all-positions logits, another
     row a sequence, and the same cache written. Through every family's cached
@@ -165,13 +158,9 @@ def test_head_rows_gives_that_row_of_the_all_positions_logits(cfg, path):
     vocab = getattr(cfg, "base", cfg).vocab_size
     tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, vocab)
     start = jnp.asarray([0, 4, 1], jnp.int32)    # appended at another offset each
-    if path == "paged":
-        cache = model.init_kv_pool(cfg, 1 + B * 4, bs)
-        tables = 1 + jnp.arange(B * 4, dtype=jnp.int32).reshape(B, 4)
-        step = lambda **kw: model.forward_paged(params, tokens, cfg, cache, tables, start, bs, **kw)
-    else:
-        cache = model.init_kv_cache(cfg, B, 16)
-        step = lambda **kw: model.forward_with_cache(params, tokens, cfg, cache, start, **kw)
+    cache = model.init_kv_pool(cfg, 1 + B * 4, bs)
+    tables = 1 + jnp.arange(B * 4, dtype=jnp.int32).reshape(B, 4)
+    step = lambda **kw: model.forward_paged(params, tokens, cfg, cache, tables, start, bs, **kw)
     want, want_cache = step()
     assert want.shape == (B, S, vocab)
     rows = jnp.asarray([S - 1, 0, 5], jnp.int32)

@@ -246,13 +246,28 @@ def test_a_wrong_program_fails_the_comparison(tiny, monkeypatch, wrong):
         assert _miss(got[:1], want[n_prompt - 1:n_prompt]) < TOL
 
 
-def test_a_configuration_without_a_family_and_a_family_without_a_slot_cache_are_named():
-    """`model_of` on something that is no family's configuration, and the
-    dense slot engine on a family that serves paged only, say so by name
-    (not a KeyError or a call of None)."""
-    from ray_tpu.serve.llm import LLMConfig, LLMEngine
+def test_a_configuration_without_a_family_is_named_and_a_plain_config_serves_ouro():
+    """`model_of` on something that is no family's configuration says so by
+    name (not a KeyError or a call of None). A plain `LLMConfig` of Ouro's
+    configuration is SERVED by the default builder: the one engine takes any
+    family that gives `forward_paged`, and gives the plain forward's greedy
+    tokens."""
+    from ray_tpu.serve.llm import LLMConfig, build_llm_deployment
+    from tests.test_llm_paged import _replica
 
     with pytest.raises(TypeError, match="no model family's configuration"):
         model_of(object())
-    with pytest.raises(TypeError, match="OuroConfig gives no `forward_with_cache`"):
-        LLMEngine(LLMConfig(model_config=ouro.OuroConfig.tiny()))
+    cfg = ouro.OuroConfig.tiny()
+    server = _replica(build_llm_deployment(LLMConfig(
+        model_config=cfg, max_batch_size=2, max_seq_len=64)))
+    try:
+        assert type(server.engine) is PagedLLMEngine
+        prompt = [int(t) for t in np.random.default_rng(6).integers(1, cfg.vocab_size, 9)]
+        got = server({"prompt_ids": prompt, "max_tokens": 5})["token_ids"]
+        seq = list(prompt)
+        for _ in range(5):
+            logits = ouro.forward(server.engine.params, jnp.asarray([seq], jnp.int32), cfg)
+            seq.append(int(np.argmax(np.asarray(logits[0, -1]))))
+        assert got == seq[len(prompt):]
+    finally:
+        server.shutdown()

@@ -183,9 +183,10 @@ def test_autoscaling_scale_up():
 
 
 def test_llm_engine_continuous_batching():
-    from ray_tpu.serve.llm import LLMConfig, LLMEngine
+    from ray_tpu.serve.llm import LLMConfig
+    from ray_tpu.serve.llm_paged import PagedLLMEngine
 
-    eng = LLMEngine(LLMConfig(max_batch_size=4, max_seq_len=64))
+    eng = PagedLLMEngine(LLMConfig(max_batch_size=4, max_seq_len=64))
     futs = [eng.generate([1, 2, 3], 6) for _ in range(6)]
     results = [f.result(120) for f in futs]
     assert all(r.num_generated == 6 for r in results)
@@ -196,9 +197,10 @@ def test_llm_engine_continuous_batching():
 
 
 def test_llm_prompt_too_long_rejected():
-    from ray_tpu.serve.llm import LLMConfig, LLMEngine
+    from ray_tpu.serve.llm import LLMConfig
+    from ray_tpu.serve.llm_paged import PagedLLMEngine
 
-    eng = LLMEngine(LLMConfig(max_batch_size=2, max_seq_len=32))
+    eng = PagedLLMEngine(LLMConfig(max_batch_size=2, max_seq_len=32))
     with pytest.raises(ValueError, match="exceeds"):
         eng.generate(list(range(30)), 16).result(10)
     eng.shutdown()
@@ -264,9 +266,10 @@ def test_autoscaling_scales_down_when_idle():
 
 
 def test_llm_empty_prompt_rejected():
-    from ray_tpu.serve.llm import LLMConfig, LLMEngine
+    from ray_tpu.serve.llm import LLMConfig
+    from ray_tpu.serve.llm_paged import PagedLLMEngine
 
-    eng = LLMEngine(LLMConfig(max_batch_size=2, max_seq_len=32))
+    eng = PagedLLMEngine(LLMConfig(max_batch_size=2, max_seq_len=32))
     with pytest.raises(ValueError, match="non-empty"):
         eng.generate([], 4).result(10)
     eng.shutdown()
@@ -291,9 +294,10 @@ def test_batch_never_exceeds_max_size():
 
 
 def test_llm_engine_survives_bad_request():
-    from ray_tpu.serve.llm import LLMConfig, LLMEngine
+    from ray_tpu.serve.llm import LLMConfig
+    from ray_tpu.serve.llm_paged import PagedLLMEngine
 
-    eng = LLMEngine(LLMConfig(max_batch_size=2, max_seq_len=32))
+    eng = PagedLLMEngine(LLMConfig(max_batch_size=2, max_seq_len=32))
     with pytest.raises(ValueError):
         eng.generate(["a", "b"], 4).result(10)  # non-int tokens rejected up front
     # engine still serves afterwards
@@ -303,9 +307,10 @@ def test_llm_engine_survives_bad_request():
 
 
 def test_llm_max_tokens_zero():
-    from ray_tpu.serve.llm import LLMConfig, LLMEngine
+    from ray_tpu.serve.llm import LLMConfig
+    from ray_tpu.serve.llm_paged import PagedLLMEngine
 
-    eng = LLMEngine(LLMConfig(max_batch_size=2, max_seq_len=32))
+    eng = PagedLLMEngine(LLMConfig(max_batch_size=2, max_seq_len=32))
     res = eng.generate([1, 2], 0).result(10)
     assert res.num_generated == 0 and res.token_ids == []
     eng.shutdown()
@@ -367,9 +372,10 @@ def test_sse_streaming_over_http():
 
 
 def test_llm_token_streaming():
-    from ray_tpu.serve.llm import LLMConfig, LLMEngine
+    from ray_tpu.serve.llm import LLMConfig
+    from ray_tpu.serve.llm_paged import PagedLLMEngine
 
-    eng = LLMEngine(LLMConfig(max_batch_size=2, max_seq_len=64))
+    eng = PagedLLMEngine(LLMConfig(max_batch_size=2, max_seq_len=64))
     toks = list(eng.generate_stream([1, 2, 3], 5))
     assert len(toks) == 5
     # matches the non-streaming result (greedy determinism)

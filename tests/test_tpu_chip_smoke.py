@@ -101,7 +101,7 @@ def test_phase_train(session, sizes):
 def test_phase_serve(session, sizes):
     import gc
 
-    from ray_tpu.serve.llm import LLMEngine
+    from ray_tpu.serve.llm_paged import PagedLLMEngine
 
     out = chip_smoke.phase_serve(sizes)
     assert out["platform"] == "cpu" and out["prefix_hits"] >= 1
@@ -110,7 +110,7 @@ def test_phase_serve(session, sizes):
     # (by type, not isinstance: a dead `weakref.proxy` that another test of
     # this process left behind, `serve/kv_transport.py`'s, raises ReferenceError
     # when asked for its class)
-    assert not [o for o in gc.get_objects() if issubclass(type(o), LLMEngine)]
+    assert not [o for o in gc.get_objects() if issubclass(type(o), PagedLLMEngine)]
 
 
 def test_phase_four_chip(session, sizes):
