@@ -36,6 +36,17 @@ class Model(NamedTuple):
     # is no row a token writes nothing of them (`lfm2.forward_paged`)
     forward_paged: Optional[Callable] = None
     init_kv_pool: Optional[Callable] = None
+    # The pool's second CLASS of page: the names of the leaves whose pages are
+    # a SEQUENCE's, not a span of tokens' ([L, num_sequences, ...]: a state
+    # that sums over the whole past, `nemotron_h`'s `ssm` and `conv`). A
+    # family that names any takes `init_kv_pool(..., num_sequences=)` (page 0
+    # of the class its garbage page, as block 0 is) and `forward_paged(...,
+    # state_pages=)`, int32 [B]: the page each sequence's state lives in, 0
+    # for a dead row. The engine hands a sequence one such page at admission,
+    # carries its id as the LAST column of the sequence's table row, and
+    # keeps no prefix cache over such a pool (a block's hash says nothing of
+    # a running sum)
+    sequence_leaves: tuple = ()
 
 
 def model_of(cfg) -> Model:

@@ -237,7 +237,7 @@ def dense_mlp(y, layer):
         return (gate * (y @ layer["w_up"])) @ layer["w_down"], {}
 
 
-def gqa_attention(attend):
+def gqa_attention(attend, rotary: bool = True):
     """The attention strategy of the grouped-query families, around a cache
     strategy `attend(q, k, v, cache, index) -> (o, cache)`: the three
     projections `wq`, `wk`, `wv` (head counts from the projected widths, so a
@@ -246,7 +246,9 @@ def gqa_attention(attend):
     weight's width says over what: OLMoE's over the WHOLE projected vector
     (a weight of `heads * head_dim`, before the split into heads), or over
     EACH HEAD's `head_dim` lanes with one weight of that width shared by the
-    heads (after the split), rope on all of q and k, and `attend` over the
+    heads (after the split), rope on all of q and k (none where `rotary` is
+    False: a family whose attention layers take no positional embedding,
+    `models/nemotron_h.py`), and `attend` over the
     rotated heads q [B, S, Hq, D], k/v [B, S, Hkv, D]. `cache` is the WHOLE
     cache, every layer's (and every other kind of layer's), and `index`
     the layer's place in it: `attend` writes this layer's rows in place and
@@ -272,19 +274,20 @@ def gqa_attention(attend):
             q = rms_norm(q, layer["q_norm"], eps)
             k = rms_norm(k, layer["k_norm"], eps)
         v = (y @ layer["wv"]).reshape(B, S, -1, hd)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        if rotary:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
         return attend(q, k, v, cache, index)
 
     return attention
 
 
-def plain_attend(attn_fn=None):
+def plain_attend(attn_fn=None, rotary: bool = True):
     """The attention strategy that keeps no cache (training): grouped-query
     projections and `attn_fn` (default: `auto_attention`, causal) over the
     whole sequence."""
     attn_fn = attn_fn or partial(auto_attention, causal=True)
-    return gqa_attention(lambda q, k, v, cache, index: (attn_fn(q, k, v), None))
+    return gqa_attention(lambda q, k, v, cache, index: (attn_fn(q, k, v), None), rotary)
 
 
 def one_stream(x, layer, name: str):
@@ -417,33 +420,42 @@ def decoder_layer(cfg: LlamaConfig, x, layer, cache, positions, attention,
     (`HyperConnections.read`; the write's `hc/mix` lies inside the sub-layer's
     scope, where the one stream's add lies), and the stats gain `hc_residue`,
     how far the worse of the layer's two stream-mixing maps is from doubly
-    stochastic."""
+    stochastic.
+
+    A block that is ONE sub-layer (`models/nemotron_h.py`: a mixer OR a
+    feed-forward part, one norm) passes None for the strategy it does not
+    have: that sub-layer is not run and its norm is not held."""
     B, S = x.shape[:2]
     eps = cfg.rms_eps
     read = one_stream if residual is None else residual.read
-    u, write, attn_residue = read(x, layer, "attn")
-    # the scopes are names in a profile and in the HLO's op_name, no more; a
-    # mixer that is no attention says its own (`models/lfm2.py::short_conv`)
-    with jax.named_scope(getattr(attention, "scope", "attn")):
-        y = rms_norm(u, layer["attn_norm"], eps)
-        o, cache = attention(cfg, y, layer, cache, positions, index)
-        o = reduce(o.reshape(B, S, -1) @ layer["wo"])
-        if "attn_out_norm" in layer:
-            o = rms_norm(o, layer["attn_out_norm"], eps)
-        x = write(o)
-    u, write, mlp_residue = read(x, layer, "mlp")
-    # `mlp` is opened around the strategy, not over it: the expert layer's
-    # scopes are read by name as siblings of `attn` and `mlp`, not children
-    with jax.named_scope("mlp"):
-        y = rms_norm(u, layer["mlp_norm"], eps)
-    out, stats = mlp(y, layer)
-    with jax.named_scope("mlp"):
-        out = reduce(out)
-        if "mlp_out_norm" in layer:
-            out = rms_norm(out, layer["mlp_out_norm"], eps)
-        x = write(out)
+    stats, residues = {}, []
+    if attention is not None:
+        u, write, residue = read(x, layer, "attn")
+        residues.append(residue)
+        # the scopes are names in a profile and in the HLO's op_name, no more; a
+        # mixer that is no attention says its own (`models/lfm2.py::short_conv`)
+        with jax.named_scope(getattr(attention, "scope", "attn")):
+            y = rms_norm(u, layer["attn_norm"], eps)
+            o, cache = attention(cfg, y, layer, cache, positions, index)
+            o = reduce(o.reshape(B, S, -1) @ layer["wo"])
+            if "attn_out_norm" in layer:
+                o = rms_norm(o, layer["attn_out_norm"], eps)
+            x = write(o)
+    if mlp is not None:
+        u, write, residue = read(x, layer, "mlp")
+        residues.append(residue)
+        # `mlp` is opened around the strategy, not over it: the expert layer's
+        # scopes are read by name as siblings of `attn` and `mlp`, not children
+        with jax.named_scope("mlp"):
+            y = rms_norm(u, layer["mlp_norm"], eps)
+        out, stats = mlp(y, layer)
+        with jax.named_scope("mlp"):
+            out = reduce(out)
+            if "mlp_out_norm" in layer:
+                out = rms_norm(out, layer["mlp_out_norm"], eps)
+            x = write(out)
     if residual is not None:
-        stats = {**stats, "hc_residue": jnp.maximum(attn_residue, mlp_residue)}
+        stats = {**stats, "hc_residue": functools.reduce(jnp.maximum, residues)}
     return x, cache, stats
 
 
@@ -472,18 +484,25 @@ class Run(NamedTuple):
     several (`decoder_trunk(runs=)`): the kind's parameters are the stack
     `params[stack]` ([n, ...] a leaf: one stack a kind of mixer and kind of
     MLP) and the run is its layers [first, first + count); `attention` and
-    `mlp` are the kind's two strategies (`decoder_layer`); `cache_first` is
+    `mlp` are the kind's two strategies (`decoder_layer`), either of them None
+    in a kind whose block is one sub-layer; `cache_first` is
     where the run starts in the cache of its kind of MIXER, which counts its
     own layers (the 3rd attention layer of a stack is KV layer 2 wherever it
     stands, and a convolution layer between two of them is none); `scope`
-    names the run in a profile, if anything does."""
+    names the run in a profile, if anything does; `held` holds the run's x
+    behind an `optimization_barrier`, as a scan's loop edge holds its carry:
+    over a stack unrolled into 52 runs of one XLA:TPU re-associates the
+    residual's chain of adds into ONE sum at the program's end and keeps every
+    block's output until then (30 x 22 MB of a 4,096-token prefill, scratch
+    1.54 GB where it is 0.64 held; PERF.md section 6, PR 45)."""
     stack: str
     first: int
     count: int
-    attention: Callable
-    mlp: Callable
+    attention: Callable | None
+    mlp: Callable | None
     cache_first: int = 0
     scope: str | None = None
+    held: bool = False
 
 
 def decoder_trunk(params, tokens, cfg: LlamaConfig, attention=None, mlp=dense_mlp,
@@ -592,11 +611,14 @@ def decoder_trunk(params, tokens, cfg: LlamaConfig, attention=None, mlp=dense_ml
         return jax.lax.scan(body if cached else remat_body(body, cfg), (x, cache),
                             (of_stack, index))
 
+
     def stack(x, cache, offset=None):  # the layers once, over the carry
         counted = []
         for run, place in zip(runs, places):
             with jax.named_scope(run.scope) if run.scope else contextlib.nullcontext():
                 (x, cache), stats = run_layers(run, place, x, cache, offset)
+                if run.held:
+                    x = jax.lax.optimization_barrier(x)
             counted.append(stats)
         # what a layer counts, stacked over the runs whose layers count it
         # (a leading dense run counts no expert rows)

@@ -32,6 +32,13 @@ it, OLMoE and Mixtral none):
 - `routed_scaling`: the weights, after the renormalisation, times a constant;
 - a shared expert, where the layer holds `s_gate`, `s_up`, `s_down`: one
   SwiGLU that every token takes, added unweighted (scope `moe/shared`);
+- experts that are NOT gated: where a layer holds no `e_gate` an expert is
+  two products, `act(y @ up[e]) @ down[e]`, with the up-projection held
+  TRANSPOSED as `e_up_t` [E, m, H] (an `[E, H, m]` parameter whose m is no
+  whole number of lane tiles is laid out H-minor by the TPU, and the kernel
+  would be handed a copy of it a step), and the shared expert likewise
+  where it holds no `s_gate`; `activation` names `act` ("relu2": the squared
+  ReLU of `models/nemotron_h.py`), which a gated expert applies to its gate;
 - `experts_held` = (first, count): this chip's SHARE of the experts. The
   router scores and chooses over ALL `num_experts`; the layer holds the
   weights of experts [first, first + count) alone, keeps the (token, choice)
@@ -80,6 +87,7 @@ class MoEConfig:
     score_func: str = "softmax"       # or "sigmoid", chosen with the layer's `router_bias`
     routed_scaling: float = 1.0       # the top_k weights times this
     norm_topk_eps: float = 0.0        # added to the renormalisation's sum (lfm2: 1e-6)
+    activation: str = "silu"          # of `ACTIVATIONS`: the experts' and the shared expert's
     # (first, count): the experts whose weights this chip holds, of
     # `num_experts` that the router chooses over; None: all of them
     experts_held: tuple | None = None
@@ -248,7 +256,9 @@ def router_logits(yt, router_w):
     return jnp.dot(yt, router_w, preferred_element_type=jnp.float32)
 
 
-_EXPERT_LEAVES = ("e_gate", "e_up", "e_down")
+# a gated expert's three, or an un-gated one's two (`e_up_t`, `e_down`)
+_EXPERT_LEAVES = ("e_gate", "e_up", "e_up_t", "e_down")
+ACTIVATIONS = {"silu": jax.nn.silu, "relu2": lambda t: jnp.square(jax.nn.relu(t))}
 
 
 def unstacked_experts(layers: dict) -> tuple[dict, dict]:
@@ -263,7 +273,7 @@ def unstacked_experts(layers: dict) -> tuple[dict, dict]:
     n = jax.tree.leaves(layers)[0].shape[0]
     rest = {k: v for k, v in layers.items() if k not in _EXPERT_LEAVES}
     return ({**rest, "stack_index": jnp.arange(n, dtype=jnp.int32)},
-            {k: layers[k] for k in _EXPERT_LEAVES})
+            {k: layers[k] for k in _EXPERT_LEAVES if k in layers})
 
 
 def moe_mlp(y, layer, cfg: MoEConfig, platform: str | None = None,
@@ -314,21 +324,27 @@ def moe_mlp(y, layer, cfg: MoEConfig, platform: str | None = None,
         group_sizes = (choice_e[:, None] == jnp.arange(E, dtype=choice_e.dtype)
                        ).sum(axis=0, dtype=jnp.int32)            # [E]
 
+    act = ACTIVATIONS[cfg.activation]
+
     def products(xs, sizes):
-        """The three grouped products over rows sorted by group: xs [M, H]
-        with `sizes` [E] rows a group held -> [M, H]."""
+        """The grouped products over rows sorted by group (three of a gated
+        expert, two of one that holds no `e_gate`): xs [M, H] with `sizes`
+        [E] rows a group held -> [M, H]."""
         # under "dots" remat a Pallas call is no saveable dot: the backward
         # pass runs these three again. Saving their outputs by name was
         # measured (PERF.md section 6, PR 27): 2% faster at equal batch, but
         # it costs the memory of 3 of the 5 sequences a chip holds without.
         experts = layer
         if stacked is not None:
-            n = stacked["e_gate"].shape[0]
+            n = stacked["e_down"].shape[0]
             experts = {k: v.reshape(n * E, *v.shape[2:]) for k, v in stacked.items()}
             sizes = jax.lax.dynamic_update_slice(
                 jnp.zeros((n * E,), jnp.int32), sizes, (layer["stack_index"] * E,))
         gmm = partial(grouped_matmul, group_sizes=sizes, platform=platform)
-        hidden = jax.nn.silu(gmm(xs, experts["e_gate"])) * gmm(xs, experts["e_up"])
+        if "e_gate" in experts:
+            hidden = act(gmm(xs, experts["e_gate"])) * gmm(xs, experts["e_up"])
+        else:
+            hidden = act(gmm(xs, experts["e_up_t"], transpose_rhs=True))
         return gmm(hidden, experts["e_down"])
 
     bound = T * k if held is None else held_rows_bound(T * k, held[1], cfg.num_experts)
@@ -349,10 +365,13 @@ def moe_mlp(y, layer, cfg: MoEConfig, platform: str | None = None,
     else:
         rows = group_sizes.sum()
         out, moved = _held_rows(yt, order, top_p, group_sizes, rows, bound, products)
-    if "s_gate" in layer:
+    if "s_up" in layer:
         with jax.named_scope("moe/shared"):
-            out = out + (jax.nn.silu(yt @ layer["s_gate"]) * (yt @ layer["s_up"])
-                         ) @ layer["s_down"]
+            if "s_gate" in layer:
+                hidden = act(yt @ layer["s_gate"]) * (yt @ layer["s_up"])
+            else:
+                hidden = act(yt @ layer["s_up"])
+            out = out + hidden @ layer["s_down"]
     share = group_sizes.astype(jnp.float32) / (T * k)            # f_e
     if held is not None:
         stats = {"load": share * cfg.num_experts, "rows": rows, "moved": moved,

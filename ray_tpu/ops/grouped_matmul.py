@@ -299,11 +299,18 @@ _gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 def grouped_matmul(lhs, rhs, group_sizes, *, platform: str | None = None,
                    tiles: tuple[int, int, int] | None = None,
-                   interpret: bool = False):
+                   interpret: bool = False, transpose_rhs: bool = False):
     """lhs [M, K] (rows sorted by group), rhs [E, K, N], group_sizes [E]
     (whole numbers that sum to M, or to less: the rows past the sum are in no
     group and their result is unspecified) -> [M, N]. Differentiable in lhs
     and rhs where the sizes sum to M.
+
+    `transpose_rhs`: rhs is [E, N, K] and a group's product is `lhs @
+    rhs[e].T` (the d lhs kernel's product, as a forward; not differentiable).
+    For a weight whose N is no whole number of 128-lane tiles (1,856): the
+    TPU's own layout of an `[E, K, 1856]` parameter puts K on the lanes, and a
+    Mosaic call that takes it row-major makes XLA copy the whole stack a step
+    (PERF.md section 6, PR 45); stored `[E, 1856, K]` it is read in place.
 
     The Pallas kernels where the computation is placed on a TPU, or anywhere
     with `interpret=True` (the tests); `jax.lax.ragged_dot` otherwise.
@@ -313,5 +320,9 @@ def grouped_matmul(lhs, rhs, group_sizes, *, platform: str | None = None,
     if platform is None:
         platform = target_platform(lhs, rhs)
     if platform != "tpu" and not interpret:
-        return jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32))
+        return jax.lax.ragged_dot(lhs, rhs.swapaxes(1, 2) if transpose_rhs else rhs,
+                                  group_sizes.astype(jnp.int32))
+    if transpose_rhs:
+        return _rows_call(lhs, rhs.astype(lhs.dtype), group_sizes, transpose_rhs=True,
+                          name="grouped_matmul_fwd_nt", tiles=tiles, interpret=interpret)
     return _gmm(lhs, rhs.astype(lhs.dtype), group_sizes, tiles, interpret)

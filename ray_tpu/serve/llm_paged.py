@@ -93,11 +93,21 @@ def paged_step(name: str, cfg, block_size: int, platform: str,
     live position, or an int, that position of every sequence -> [B, vocab];
     None -> no logits (None in their place, and no head in the program). A
     profile knows the step by `name` (`jit(decode)/while/...`). The step holds
-    its arguments only, never the engine."""
+    its arguments only, never the engine.
+
+    For a family whose pool has pages a SEQUENCE's (`Model.sequence_leaves`) a
+    table row is one column wider: its LAST entry is the sequence's state
+    page, which the step takes off and hands to `forward_paged(state_pages=)`.
+    So a sequence stays what it was to every caller, a table row and a
+    length, and a row of zeros is a dead row in both classes of page."""
     import jax
     import jax.numpy as jnp
 
-    forward_paged = model_of(cfg).forward_paged
+    model = model_of(cfg)
+    forward_paged = model.forward_paged
+    if model.sequence_leaves:
+        forward_paged = lambda params, tokens, cfg, pool, tables, *a, **kw: model.forward_paged(
+            params, tokens, cfg, pool, tables[:, :-1], *a, state_pages=tables[:, -1], **kw)
 
     def step(params, pool, tokens, first, second):
         tables, lengths = (first, second[:1]) if table_first else (second, first)
@@ -208,8 +218,12 @@ def page_leaves(pool: dict) -> dict:
     a row a token (`[L, num_blocks, block_size, row]`: `k` and `v`; a latent
     family's one `latent`), or rows a block (`models/lfm2.py`'s `conv`,
     `[Lc, num_blocks, 2, H]`: a convolution's state at the block's end), and
-    L a leaf's own. They are what a PD hand-off moves, `leaf[:, idx]`,
-    whatever the family names them and whatever follows the second axis."""
+    L a leaf's own. A leaf the family's `Model.sequence_leaves` names has
+    pages of the OTHER class, one a sequence (`[L, num_sequences, ...]`:
+    `models/nemotron_h.py`'s `ssm` and `conv`), so a leaf's page count is its
+    own. They are what a PD hand-off moves, `leaf[:, idx]` with the ids of the
+    leaf's own class, whatever the family names them and whatever follows the
+    second axis."""
     return {name: leaf for name, leaf in pool.items() if name != "counters"}
 
 
@@ -221,8 +235,10 @@ def pool_counters(pool: dict) -> dict:
     return {name: np.asarray(v).item() for name, v in pool.get("counters", {}).items()}
 
 
-def _n_pages(kv: dict) -> int:
-    return next(iter(kv.values())).shape[1]
+def _n_pages(kv: dict, sequence_leaves: tuple = ()) -> int:
+    """Token pages (blocks) of a hand-off's payload: the second axis of a leaf
+    of that class (a leaf of the other class carries ONE page, the sequence's)."""
+    return next(leaf for name, leaf in kv.items() if name not in sequence_leaves).shape[1]
 
 
 class PagedLLMEngine:
@@ -297,9 +313,15 @@ class PagedLLMEngine:
         self.max_blocks_per_seq = S // bs
         n_blocks = self.config.num_blocks or (B * self.max_blocks_per_seq + 1)
         self.pool_blocks = n_blocks
-        self.pool = self.model.init_kv_pool(cfg, n_blocks, bs)
-        self.allocator = BlockPool(n_blocks, bs)
-        self.tables = np.zeros((B, self.max_blocks_per_seq), dtype=np.int32)
+        # a pool with pages a SEQUENCE's (`Model.sequence_leaves`): one a
+        # slot and the garbage page 0, and a table row's last column its id
+        self.sequence_leaves = tuple(self.model.sequence_leaves)
+        n_seq = B + 1 if self.sequence_leaves else 0
+        self.pool = self.model.init_kv_pool(
+            cfg, n_blocks, bs, **({"num_sequences": n_seq} if n_seq else {}))
+        self.allocator = BlockPool(n_blocks, bs, num_sequences=n_seq)
+        self.slot_state_page = [0] * B
+        self.tables = self._table_rows(B)
         self.slot_blocks: list[list[int]] = [[] for _ in range(B)]
         self.slot_prompts: list[Optional[list[int]]] = [None] * B
         step = partial(paged_step, cfg=cfg, block_size=bs, platform=self.platform)
@@ -312,6 +334,20 @@ class PagedLLMEngine:
         # the decode step enqueued and not yet read (`_step_decode`); the
         # engine thread's alone
         self._flight: Optional[_Flight] = None
+
+    def _table_rows(self, n: int, block_ids=(), state_page: int = 0) -> np.ndarray:
+        """`n` table rows [n, max_blocks (+ 1)], each `block_ids` then zeros
+        and, where the pool has sequence pages, `state_page` last."""
+        rows = np.zeros((n, self.max_blocks_per_seq + bool(self.sequence_leaves)), np.int32)
+        rows[:, :len(block_ids)] = block_ids
+        if self.sequence_leaves:
+            rows[:, -1] = state_page
+        return rows
+
+    def _page_ids(self, name: str, block_ids, state_page: int) -> np.ndarray:
+        """The ids of a sequence's pages of the leaf `name`'s own class."""
+        ids = [state_page] if name in self.sequence_leaves else block_ids
+        return np.asarray(ids, dtype=np.int32)
 
     def step_once(self) -> bool:
         """One admit/decode round under external control; True if work ran."""
@@ -360,6 +396,11 @@ class PagedLLMEngine:
         if self.slot_blocks[i]:
             self.allocator.free(self.slot_blocks[i])
             self.slot_blocks[i] = []
+        if self.slot_state_page[i]:
+            # the step in flight still writes this page once; whoever gets it
+            # next trusts nothing in it (a fresh prefill reads no state)
+            self.allocator.free_sequence(self.slot_state_page[i])
+            self.slot_state_page[i] = 0
         self.slot_prompts[i] = None
 
     # ---- public API ----
@@ -447,6 +488,9 @@ class PagedLLMEngine:
                 "platform": self.platform,
                 "compiles": compiles,
                 "compile_s": compile_s,
+                # no prefix is looked up or registered over a pool with pages
+                # a sequence's: a block's hash says nothing of a running sum
+                "prefix_cache": not self.sequence_leaves,
             }
         return {**out, **self.allocator.stats()}
 
@@ -617,7 +661,9 @@ class PagedLLMEngine:
                     tq.put(None)
                 info["outcome"] = "rejected"
                 return True
-            hit_ids, cached_len = self.allocator.lookup_prefix(prompt)
+            hit_ids, cached_len, state_page = [], 0, 0
+            if not self.sequence_leaves:
+                hit_ids, cached_len = self.allocator.lookup_prefix(prompt)
             if cached_len >= len(prompt):
                 # whole prompt block-aligned-cached: recompute the last block so
                 # we still have logits to sample the first token from
@@ -625,6 +671,12 @@ class PagedLLMEngine:
                 cached_len -= bs
             try:
                 fresh = self.allocator.alloc(total_blocks - len(hit_ids))
+                if self.sequence_leaves:
+                    try:
+                        state_page = self.allocator.alloc_sequence()
+                    except NoFreeBlocks:
+                        self.allocator.free(fresh)
+                        raise
             except NoFreeBlocks:
                 for b in hit_ids:
                     self.allocator.free([b])
@@ -639,8 +691,7 @@ class PagedLLMEngine:
                         writes=prefill_writes(cached_len, bucket, bs))
             padded = np.zeros((1, bucket), dtype=np.int32)
             padded[0, : len(suffix)] = suffix
-            table_row = np.zeros((1, self.max_blocks_per_seq), dtype=np.int32)
-            table_row[0, : len(block_ids)] = block_ids
+            table_row = self._table_rows(1, block_ids, state_page)
             try:
                 clock.mark("prefill")  # returns when the program is enqueued
                 # the host's arrays as they are: `_prefill` reads the span
@@ -656,13 +707,16 @@ class PagedLLMEngine:
                 tok = self._sample(logits_np[0])
             except Exception as e:  # noqa: BLE001 - bad request: fail, keep serving
                 self.allocator.free(block_ids)
+                if state_page:
+                    self.allocator.free_sequence(state_page)
                 if not fut.done():
                     fut.set_exception(e)
                 if tq is not None:
                     tq.put(None)
                 return True
-            self.allocator.register_prefix(prompt, block_ids,
-                                           skip_blocks=cached_len // bs)
+            if not self.sequence_leaves:
+                self.allocator.register_prefix(prompt, block_ids,
+                                               skip_blocks=cached_len // bs)
             with self._lock:
                 st = _Slot(fut, max_new, len(prompt), t_enq, tq)
                 st.generated.append(tok)
@@ -675,6 +729,7 @@ class PagedLLMEngine:
                 self.last_tokens[slot, 0] = tok
                 self.tables[slot] = table_row[0]
                 self.slot_blocks[slot] = block_ids
+                self.slot_state_page[slot] = state_page
                 self.slot_prompts[slot] = list(prompt)
             self._maybe_finish(slot, tok)
             info["outcome"] = "admitted"
@@ -746,14 +801,16 @@ class PagedLLMEngine:
         # the allocator's running count: `stats()` walks every cached block,
         # 1% of a step with a 4,097-block pool of cached prompts (PERF.md
         # section 6, PR 31)
-        blocks = self.allocator.in_use
+        pages = {"blocks": self.allocator.in_use}
+        if self.sequence_leaves:
+            pages["state_pages_used"] = self.allocator.sequences_in_use
         try:
             yield clock
         finally:
             # the record ends at `stop`; adding up the streams' cells (a lock
             # and a scan of the live ones) is the turn's, not the record's
             clock.stop()
-            clock.close(live=live, ctx=ctx, blocks=blocks,
+            clock.close(live=live, ctx=ctx, **pages,
                         compile_s=compile_totals()[1] - compile_s0,
                         **self._stream_sums())
 
@@ -898,20 +955,23 @@ class PagedLLMEngine:
             raise err
         n_blocks = -(-len(prompt_ids) // bs)
         block_ids = self.allocator.alloc(n_blocks)
+        state_page = 0
         padded_len = min(self._bucket(len(prompt_ids)), self.config.max_seq_len)
         padded = np.zeros((1, padded_len), dtype=np.int32)
         padded[0, : len(prompt_ids)] = prompt_ids
-        table_row = np.zeros((1, self.max_blocks_per_seq), dtype=np.int32)
-        table_row[0, :n_blocks] = block_ids
         try:
+            if self.sequence_leaves:
+                state_page = self.allocator.alloc_sequence()
+            table_row = self._table_rows(1, block_ids, state_page)
             logits, self.pool = self._prefill(
                 self.params, self.pool, padded, table_row,
                 np.asarray([0, len(prompt_ids)], np.int32))
             first_tok = self._sample(np.asarray(logits)[0])
-            idx = np.asarray(block_ids, dtype=np.int32)
             kv = kv_ticket = kv_ref = None
-            # the pool's own pages, leaf by leaf: [L, n, ...] each
-            pages = {name: leaf[:, idx] for name, leaf in page_leaves(self.pool).items()}
+            # the pool's own pages, leaf by leaf, by the ids of the leaf's own
+            # class: [L, n, ...] a leaf of token pages, [L, 1, ...] a sequence's
+            pages = {name: leaf[:, self._page_ids(name, block_ids, state_page)]
+                     for name, leaf in page_leaves(self.pool).items()}
             if self.config.kv_transfer == "device":
                 # the gather creates independent device arrays (pool blocks
                 # free below); only a tiny ticket crosses the control plane —
@@ -939,6 +999,8 @@ class PagedLLMEngine:
                 kv = {name: np.asarray(leaf) for name, leaf in pages.items()}
         finally:
             self.allocator.free(block_ids)
+            if state_page:
+                self.allocator.free_sequence(state_page)
         return {
             "kv": kv,
             "kv_ticket": kv_ticket,
@@ -996,9 +1058,9 @@ class PagedLLMEngine:
                     "bound to a KVTransport.pull")
             kv, ack = self.kv_pull(handoff["kv_ref"])
             expect = handoff.get("n_prefill_blocks")
-            if expect is not None and _n_pages(kv) != expect:
+            if expect is not None and _n_pages(kv, self.sequence_leaves) != expect:
                 raise ValueError(
-                    f"KV handoff shape mismatch: pulled {_n_pages(kv)} "
+                    f"KV handoff shape mismatch: pulled {_n_pages(kv, self.sequence_leaves)} "
                     f"blocks, handoff says {expect}")
         if kv is None and handoff.get("kv_ticket") is not None:
             # device path: pull the pages straight into THIS process's
@@ -1012,15 +1074,16 @@ class PagedLLMEngine:
 
             kv = rdt.pull_device(handoff["kv_ticket"])
             expect = handoff.get("n_prefill_blocks")
-            if expect is not None and _n_pages(kv) != expect:
+            if expect is not None and _n_pages(kv, self.sequence_leaves) != expect:
                 raise ValueError(
-                    f"KV ticket shape mismatch: pulled {_n_pages(kv)} "
+                    f"KV ticket shape mismatch: pulled {_n_pages(kv, self.sequence_leaves)} "
                     f"blocks, handoff says {expect}")
         if set(kv) != set(page_leaves(self.pool)):
             raise ValueError(
                 f"KV handoff carries the leaves {sorted(kv)}; this engine's pool "
                 f"has {sorted(page_leaves(self.pool))}")
-        n_prefill_blocks = _n_pages(kv)   # every leaf of the payload is [L, n, ...]
+        # a leaf of the payload is [L, n, ...], n its own class's pages
+        n_prefill_blocks = _n_pages(kv, self.sequence_leaves)
         table = handoff.get("block_table")
         if table is not None and len(table) != n_prefill_blocks:
             # descriptor-vs-payload consistency: the block table is the
@@ -1032,9 +1095,12 @@ class PagedLLMEngine:
                 f"transferred entry carries {n_prefill_blocks}")
         total_blocks = -(-(prompt_len + max_new_tokens) // bs)
         block_ids = self.allocator.alloc(total_blocks)
+        state_page = 0
         try:
-            idx = np.asarray(block_ids[:n_prefill_blocks], dtype=np.int32)
+            if self.sequence_leaves:
+                state_page = self.allocator.alloc_sequence()   # any free page takes it
             for name, leaf in kv.items():
+                idx = self._page_ids(name, block_ids[:n_prefill_blocks], state_page)
                 self.pool[name] = self.pool[name].at[:, idx].set(jnp.asarray(leaf))
             with self._lock:
                 st = _Slot(fut, max_new_tokens, prompt_len, time.monotonic())
@@ -1044,12 +1110,13 @@ class PagedLLMEngine:
                 self.active[slot] = True
                 self.lengths[slot] = prompt_len
                 self.last_tokens[slot, 0] = handoff["first_token"]
-                row = np.zeros(self.max_blocks_per_seq, dtype=np.int32)
-                row[: len(block_ids)] = block_ids
-                self.tables[slot] = row
+                self.tables[slot] = self._table_rows(1, block_ids, state_page)[0]
                 self.slot_blocks[slot] = block_ids
+                self.slot_state_page[slot] = state_page
         except BaseException:
             self.allocator.free(block_ids)
+            if state_page:
+                self.allocator.free_sequence(state_page)
             raise
         if ack is not None:
             try:

@@ -10,6 +10,13 @@ Prefix caching: FULL prompt blocks are content-addressed by a rolling hash of
 the token chain (hash(prev_chain, block_tokens)); a new request reuses the
 longest cached block-aligned prefix (refcount++) and only prefills its suffix
 — the vLLM automatic-prefix-caching design.
+
+Two CLASSES of page: blocks (a span of `block_size` tokens: keys and values,
+a latent row, a convolution's rows at a block's end) and, for a family whose
+state sums over the whole past (`Model.sequence_leaves`), SEQUENCE pages, one
+a sequence whatever its length (`alloc_sequence` / `free_sequence`). Page 0 of
+either class is its garbage page. A sequence page is held by one sequence,
+never shared and never content-addressed.
 """
 
 from __future__ import annotations
@@ -24,12 +31,16 @@ class NoFreeBlocks(RuntimeError):
 
 
 class BlockPool:
-    def __init__(self, num_blocks: int, block_size: int):
+    def __init__(self, num_blocks: int, block_size: int, num_sequences: int = 0):
         # block 0 is reserved as the garbage target for unallocated table
         # entries (reads of it are masked in attention)
         self.num_blocks = num_blocks
         self.block_size = block_size
         self._free: list[int] = list(range(num_blocks - 1, 0, -1))
+        # the second class: `num_sequences` pages a sequence's, page 0 the
+        # garbage page (0: the pool has no such leaf)
+        self.num_sequences = num_sequences
+        self._free_sequences: list[int] = list(range(num_sequences - 1, 0, -1))
         self._ref: dict[int, int] = {}
         # blocks a sequence holds (refcount > 0), kept as they change: what
         # `stats()["allocated_blocks"]` finds by walking every cached block
@@ -95,6 +106,21 @@ class BlockPool:
             self._ref.pop(bid, None)
             self._free.append(bid)
 
+    # ------------------------------------------------------------ sequence pages
+    def alloc_sequence(self) -> int:
+        with self._lock:
+            if not self._free_sequences:
+                raise NoFreeBlocks("no free sequence page")
+            return self._free_sequences.pop()
+
+    def free_sequence(self, page: int) -> None:
+        with self._lock:
+            self._free_sequences.append(page)
+
+    @property
+    def sequences_in_use(self) -> int:
+        return max(self.num_sequences - 1, 0) - len(self._free_sequences)
+
     # ------------------------------------------------------------ prefix cache
     @staticmethod
     def _chain(prev: int, tokens: tuple) -> int:
@@ -150,4 +176,6 @@ class BlockPool:
                 "cached_blocks": len(self._prefix),
                 "prefix_hits": self.prefix_hits,
                 "prefix_queries": self.prefix_queries,
+                "state_pages": max(self.num_sequences - 1, 0),
+                "state_pages_used": self.sequences_in_use,
             }

@@ -69,6 +69,15 @@ class SpecDecodeLLMEngine(PagedLLMEngine):
         cfg = self.config.model_config
         dcfg = self.config.draft_model_config
         bs = self.config.block_size
+        if self.sequence_leaves:
+            # a state that sums over the whole past has no earlier position to
+            # go back to at all (ROADMAP R6: a state rewind under speculation)
+            raise ValueError(
+                f"speculative decoding rewinds `lengths` after a rejected window, and "
+                f"this family's pool keeps {sorted(self.sequence_leaves)} as ONE page a "
+                f"sequence, a running state that every position of the window has "
+                f"advanced: the state at the committed position is gone and cannot be "
+                f"stepped from again")
         per_block = sorted(name for name, leaf in page_leaves(self.pool).items()
                            if leaf.shape[2] != bs)
         if per_block:

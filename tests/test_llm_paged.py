@@ -80,7 +80,8 @@ def test_one_config_class_and_one_cache_contract():
         "temperature", "eos_token_id", "prefill_buckets", "block_size", "num_blocks",
         "kv_transfer"]
     assert list(fields.values())[1:] == [8, 256, 32, 0.0, -1, (32, 128), 16, 0, "host"]
-    assert Model._fields == ("init", "logical_axes", "loss", "forward_paged", "init_kv_pool")
+    assert Model._fields == ("init", "logical_axes", "loss", "forward_paged", "init_kv_pool",
+                             "sequence_leaves")
     assert PagedLLMEngine.__mro__ == (PagedLLMEngine, object)
 
 

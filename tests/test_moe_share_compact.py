@@ -169,3 +169,56 @@ def test_where_the_bound_reaches_every_pair_the_layer_is_what_it_was(
     gathers = re.findall(rf'"stablehlo\.gather".*-> tensor<{pairs}x{H}xf32>', text)
     assert len(gathers) == (0 if compacts else 2), gathers
     assert bool(wide) == (not compacts)
+
+
+def _dense_loop(y, w, cfg, held, act):
+    """The layer as a loop over experts, every expert on every token: the
+    router's weights as a [T, E] matrix that is zero where an expert was not
+    chosen, a gated expert `act(y G) * (y U)`, an un-gated one `act(y U^T)`
+    with its up-projection held transposed, the shared expert unweighted."""
+    s = jax.nn.sigmoid(y @ w["router"])
+    chosen = jax.lax.top_k(s + w["router_bias"], cfg.top_k)[1]
+    picked = jnp.take_along_axis(s, chosen, axis=-1)
+    picked = picked / picked.sum(axis=-1, keepdims=True) * cfg.routed_scaling
+    weights = jnp.zeros_like(s).at[jnp.arange(y.shape[0])[:, None], chosen].set(picked)
+    first, count = held or (0, cfg.num_experts)
+    out = jnp.zeros_like(y)
+    for e in range(count):
+        hidden = (act(y @ w["e_gate"][e]) * (y @ w["e_up"][e]) if "e_gate" in w
+                  else act(y @ w["e_up_t"][e].T))
+        out = out + weights[:, first + e, None] * (hidden @ w["e_down"][e])
+    hidden = (act(y @ w["s_gate"]) * (y @ w["s_up"]) if "s_gate" in w else act(y @ w["s_up"]))
+    return out + hidden @ w["s_down"]
+
+
+@pytest.mark.parametrize("held", [None, (4, 2), (8, 4)], ids=["whole", "2-of-32", "4-of-32"])
+@pytest.mark.parametrize("form", ["gated-silu", "ungated-relu2"])
+def test_gated_and_ungated_experts_against_a_dense_loop(layer32, form, held):
+    """The expert's FORM is read from the keys a layer holds: with `e_gate` /
+    `s_gate` three products and `activation` on the gate; without, two
+    products, `relu(y U)^2 D`, the up-projection transposed as `e_up_t`, and a
+    shared expert of the same un-gated form. Whole, and as a share that
+    compacts (2 of 32: one bound of 256 rows; 4 of 32: 512)."""
+    whole, y = layer32
+    ks = jax.random.split(jax.random.PRNGKey(45), 3)
+    dense = lambda k, *s: jax.random.normal(k, s, jnp.float32) / math.sqrt(s[-2])
+    w = {**whole, "s_gate": dense(ks[0], H, 48), "s_up": dense(ks[1], H, 48),
+         "s_down": dense(ks[2], 48, H)}
+    cfg = EXPERTS
+    if form == "ungated-relu2":
+        cfg = dataclasses.replace(cfg, activation="relu2")
+        w = {k: v for k, v in w.items() if k not in ("e_gate", "s_gate", "e_up")}
+        w["e_up_t"] = whole["e_up"].swapaxes(1, 2)
+    cfg = dataclasses.replace(cfg, experts_held=held)
+    if held:
+        w = {k: v[held[0]:held[0] + held[1]] if k.startswith("e_") else v for k, v in w.items()}
+    with jax.default_matmul_precision("highest"):
+        out, stats = moe.moe_mlp(y, w, cfg, platform="cpu")
+        want = _dense_loop(y[0], w, cfg, held, moe.ACTIVATIONS[cfg.activation])
+    assert _miss(out[0], want) < TOL
+    if held:
+        assert int(stats["moved"]) == moe.held_rows_bound(T * K, held[1], E) < T * K
+    # the other form's arithmetic on the same weights is another function
+    other = moe.ACTIVATIONS["silu" if form == "ungated-relu2" else "relu2"]
+    with jax.default_matmul_precision("highest"):
+        assert _miss(_dense_loop(y[0], w, cfg, held, other), want) > 0.05

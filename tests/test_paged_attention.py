@@ -76,6 +76,12 @@ _CASES = {
     "last-of-3-layers": _case([1, 16, 17, 96], max_blocks=6, layers=3, layer=2),
     "middle-layer-f32-d64": _case([1, 8, 9, 48], g=2, Hkv=2, D=64, BS=8,
                                   max_blocks=6, dtype=jnp.float32, layers=3, layer=1),
+    # Nemotron-H's heads: 32 query heads over 2 key-value heads of 128, a
+    # group of 16, one of several cache layers, a context past four page groups
+    "nemotron-bf16-g16": _case([1, 16, 17, 257, 1130], g=16, Hkv=2, max_blocks=72,
+                               layers=2, layer=1),
+    "nemotron-f32-g16": _case([1, 9, 300], g=16, Hkv=2, D=128, BS=8, max_blocks=40,
+                              dtype=jnp.float32),
 }
 
 

@@ -288,13 +288,15 @@ def assert_a_fresh_prefill_writes_whole_pages(text: str, leaf_shape: tuple, S: i
 
 
 def assert_pool_stays_in_place(compiled, pool, scratch_under: int | None = None,
-                               alloc_under: int = 0) -> None:
+                               alloc_under: int = 0, writes: tuple = ("scatter",)) -> None:
     """What ISSUE 30 holds a paged step to. `pool` is the pool's shapes (K and
     V, a latent family's one leaf, or leaves of several shapes: `lfm2`'s `conv`
     beside its `k` and `v`); `scratch_under` bounds the step's scratch
     (default: one layer's pages of the largest leaf). No buffer is allocated
     inside the program, but those a caller names with `alloc_under`, the bytes
-    every one of them stays under."""
+    every one of them stays under. `writes` are the opcodes a write into the
+    pool compiles to: the scatter, and for a family that writes ONE page a
+    sequence at B = 1 (`nemotron_h`'s state) also `dynamic-update-slice`."""
     text = compiled.as_text()
     pages = [leaf for name, leaf in pool.items() if name != "counters"]
     layer_bytes = max(math.prod(page.shape[1:]) * page.dtype.itemsize for page in pages)
@@ -314,7 +316,7 @@ def assert_pool_stays_in_place(compiled, pool, scratch_under: int | None = None,
     # the scatter of the model's own `kv_write`, of rows or of whole pages
     for shape in {page.shape for page in pages}:
         moved = [(op, ln[:200]) for op, ln in pool_sized_instructions(text, shape)
-                 if op not in ("parameter", "get-tuple-element", "bitcast", "scatter")]
+                 if op not in ("parameter", "get-tuple-element", "bitcast", *writes)]
         assert not moved, moved
     # nothing the size of a layer's pages is scratch either
     assert compiled.memory_analysis().temp_size_in_bytes < (scratch_under or layer_bytes)
@@ -608,6 +610,101 @@ def test_lfm2_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw, 
                                                   leaves=2)
     # 16 of 32 held: the bound is every pair, the step moves T x k rows
     assert_a_share_moves_its_bound(text, cfg, B * S, compacts=False)
+
+
+def _nemotron_serve_ep8():
+    """The model of `nemotron-3-nano-30b-a3b-serve-ep8-1chip`, from the cell's
+    own file, and the file's engine section."""
+    import json
+    import os
+
+    from benchmarks.harness.families import nemotron_h as family
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmarks", "configs", "nemotron-3-nano-30b-a3b-serve-ep8-1chip.json")
+    with open(path) as f:
+        file = json.load(f)
+    return family.model_config({k: file[k] for k in family.MODEL_KEYS}), file["engine"]
+
+
+@pytest.mark.parametrize("name, S, kw, scratch_under", [
+    ("decode", 1, dict(head=0), 0.35e9),
+    ("prefill", 4096, dict(head="last", table_first=True, fresh=True), 0.8e9),
+], ids=["decode", "prefill-4096"])
+def test_nemotron_engine_steps_compile_beside_weights_and_pool(v5e, name, S, kw, scratch_under):
+    """`serve-nemotron-tools4k-256-out`'s decode step and its 4,096 prefill as
+    the engine builds them, at Nemotron-3-Nano-30B-A3B's published widths and
+    FULL depth (52 blocks of one sub-layer: 23 Mamba-2, 6 attention, 23 expert
+    blocks of 16 held un-gated experts; 52 unrolled runs), the engine's slots
+    and blocks, a 272-block table with the state page as its last column, the
+    pool's FOUR leaves of two classes donated beside 10.5 GB of weights. They
+    compile for a v5e; every leaf stays in place (the only pool-shaped
+    instructions are the scatters of `attn/kv_write` and `ssm/state_write`:
+    no copy of the state leaf, no pool-sized temporary), no layer's experts
+    are copied (the un-gated up-projection is held transposed: as `[E, H,
+    1856]` the TPU lays it H-minor and the kernel is handed a 3.5 GB copy of
+    the stack), and the text names the scopes a profile is read by. The
+    compiler's bytes go into the configuration file's `num_blocks_note`."""
+    cfg, engine = _nemotron_serve_ep8()
+    slots, blocks = engine["max_batch_size"], engine["num_blocks"]
+    assert engine["block_size"] == 16 and engine["prefill_buckets"] == [2048, 4096]
+    assert blocks == slots * 272 + 1
+    assert (cfg.count("mamba"), cfg.count("attn"), cfg.count("experts")) == (23, 6, 23)
+    assert model_of(cfg).sequence_leaves == ("ssm", "conv")
+    from ray_tpu.serve.llm_paged import paged_step
+
+    d = v5e[0]
+    place = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
+    model = model_of(cfg)
+    pool = jax.eval_shape(lambda: model.init_kv_pool(cfg, blocks, 16, num_sequences=slots + 1))
+    params = place(jax.eval_shape(lambda: model.init(cfg, jax.random.PRNGKey(0))))
+    i32, B = jnp.int32, slots if S == 1 else 1
+    rest = ((_on(d, (1, 273), i32), _on(d, (2,), i32)) if S > 1
+            else (_on(d, (B,), i32), _on(d, (B, 273), i32)))
+    compiled = paged_step(name, cfg, 16, "tpu", **kw).lower(
+        params, place(pool), _on(d, (B, S), i32), *rest).compile()
+    assert pool["k"].shape == pool["v"].shape == (6, blocks, 16, 256)
+    assert pool["ssm"].shape == (23, slots + 1, 64, 64, 128) and pool["ssm"].dtype == jnp.float32
+    assert pool["conv"].shape == (23, slots + 1, 3 * 6144)
+    assert params["experts"]["e_up_t"].shape == (23, 16, 1856, 2688)
+    # `conv` is 41 MB, and XLA:TPU moves a leaf that small into the core's 128
+    # MiB of VMEM (`S(1)`) and back, once around a prefill, a few times in a
+    # decode step: it is held to a count, the three large leaves to staying
+    # where they are. The scan's zero carry
+    # f32[1, 8, 8, 64, 128] is the one buffer allocated inside, 2 MB
+    large = {k: v for k, v in pool.items() if k != "conv"}
+    writes = ("scatter", "dynamic-update-slice")
+    assert_pool_stays_in_place(compiled, large, scratch_under=int(scratch_under),
+                               alloc_under=2 ** 22, writes=writes)
+    moved = [op for op, ln in pool_sized_instructions(compiled.as_text(), pool["conv"].shape)
+             if op not in ("parameter", "get-tuple-element", "bitcast", *writes)]
+    assert len(moved) < 23, moved    # a few moves a step, not one a layer
+    ma = compiled.memory_analysis()
+    print(name, S, ma.argument_size_in_bytes, ma.output_size_in_bytes, ma.temp_size_in_bytes)
+    weights = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert 10.5e9 < weights < 10.55e9
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 15.0e9
+    text = compiled.as_text()
+    # no copy of a layer's (or the stack's) experts, in any layout
+    assert not re.search(r"= bf16\[(23,)?16,(1856,2688|2688,1856)\]\S* (copy|fusion|transpose)\(", text)
+    names = ["ssm/in_proj", "ssm/conv", "ssm/state_write", "ssm/gate_norm", "attn/kv_write",
+             "moe/route", "moe/dispatch", "moe/experts", "moe/combine", "moe/shared",
+             "grouped_matmul_fwd_nt", "grouped_matmul_fwd"]
+    names += (["ssm/state_read", "ssm/step", "ssm_state_step", "attn/kv_read",
+               "paged_attention_decode"] if S == 1
+              else ["ssm/scan", "attn/prompt_attend", "flash_attention_fwd"])
+    for scope in names:
+        assert scope in text, scope
+    # a fresh prefill reads no state and no K/V back; no rotation anywhere
+    assert ("ssm/state_read" in text) == ("attn/kv_read" in text) == (S == 1)
+    assert "cosine" not in text and "sine" not in text
+    if S > 1:
+        assert_a_fresh_prefill_writes_whole_pages(text, pool["k"].shape, S, "attn/kv_write",
+                                                  leaves=2)
+    else:
+        # the decode step's state update is the in-place kernel: no gathered
+        # copy of the live pages, f32[slots, 64, 64, 128], exists
+        assert not array_lines(text, (slots, 64, 64, 128))
 
 
 def _engine_step(d, cfg, name, *, B, S, max_blocks=128, pool_blocks, bs=16, **kw):
