@@ -284,7 +284,7 @@ def latent_attention(cfg: KimiK2Config, tables, lengths, blk_idx, blk_off,
     def attention(_, y, layer, pool, positions, index):
         S = y.shape[1]
         c_q = llama.rms_norm(y @ layer["w_dq"], layer["q_a_norm"], eps)
-        q = (c_q @ layer["w_uq"]).reshape(B, S, -1, nope + rd)
+        q = llama.project_heads(c_q, layer["w_uq"], nope + rd)
         q_nope, q_rope = q[..., :nope], llama.rope(q[..., nope:], positions, None, inv_freq)
         ckv = y @ layer["w_dkv"]
         c_kv = llama.rms_norm(ckv[..., :rank], layer["kv_a_norm"], eps)

@@ -237,10 +237,38 @@ def dense_mlp(y, layer):
         return (gate * (y @ layer["w_up"])) @ layer["w_down"], {}
 
 
+def project_heads(y, w, head_dim: int, norm=None):
+    """y [B, S, H] x w [H, N] -> [B, S, N / head_dim, head_dim]: a projection
+    whose result is split into heads (`wq`, `wk`, `wv` here, the latent
+    family's `w_uq`); `norm`, where given, is applied to the whole projected
+    vector before the split. In a decode step (S == 1, a static shape) the
+    product's result is held by an `optimization_barrier` before anything
+    reads it: the weight is then hundreds of times the rows, and without the
+    barrier XLA:TPU folds the split into the product, lays the result out
+    heads-first for the rotation and the decode kernel, and carries that
+    layout back through the product to the WEIGHT, which it then stages in
+    VMEM and transposes, a layer's `wq` every layer of every step (Ouro:
+    the whole stacks of `wq` and `wk`, hoisted out of the passes' scan, 403
+    MB each a step). Held, the product is the plain `[B, H] x [H, N]` that
+    reads the scan's slice of its stack in place, as `dense_mlp`'s and `wo`'s
+    do, and the layout is given to its small result (PERF.md section 6, PR
+    46). At S > 1 the rows are as large as the weight and a materialised
+    result would cost a pass over them for nothing: no barrier, the text every
+    prefill and training program had. The barrier is the identity."""
+    B, S, _ = y.shape
+    out = y @ w
+    if S == 1:
+        out = jax.lax.optimization_barrier(out)
+    if norm is not None:
+        out = norm(out)
+    return out.reshape(B, S, -1, head_dim)
+
+
 def gqa_attention(attend, rotary: bool = True):
     """The attention strategy of the grouped-query families, around a cache
     strategy `attend(q, k, v, cache, index) -> (o, cache)`: the three
-    projections `wq`, `wk`, `wv` (head counts from the projected widths, so a
+    projections `wq`, `wk`, `wv` split into heads (`project_heads`; head
+    counts from the projected widths, so a
     tensor-sharded stage passes its local weights), an RMSNorm of q and k
     where the layer holds `q_norm` and `k_norm`, before rope, and the
     weight's width says over what: OLMoE's over the WHOLE projected vector
@@ -256,24 +284,19 @@ def gqa_attention(attend, rotary: bool = True):
     (`plain_attend`: cache and index are None)."""
 
     def attention(cfg, y, layer, cache, positions, index):
-        B, S, _ = y.shape
         eps, hd = cfg.rms_eps, cfg.hd
         qk_norm = "q_norm" in layer
-        q = y @ layer["wq"]
         # a weight as wide as the projection norms the whole vector, one of
         # `head_dim` each head's lanes (one head: the two are the same)
-        per_head = qk_norm and layer["q_norm"].shape[-1] != q.shape[-1]
-        if qk_norm and not per_head:
-            q = rms_norm(q, layer["q_norm"], eps)
-        q = q.reshape(B, S, -1, hd)
-        k = y @ layer["wk"]
-        if qk_norm and not per_head:
-            k = rms_norm(k, layer["k_norm"], eps)
-        k = k.reshape(B, S, -1, hd)
+        per_head = qk_norm and layer["q_norm"].shape[-1] != layer["wq"].shape[-1]
+        whole = lambda name: (partial(rms_norm, weight=layer[name], eps=eps)
+                              if qk_norm and not per_head else None)
+        q = project_heads(y, layer["wq"], hd, whole("q_norm"))
+        k = project_heads(y, layer["wk"], hd, whole("k_norm"))
         if per_head:
             q = rms_norm(q, layer["q_norm"], eps)
             k = rms_norm(k, layer["k_norm"], eps)
-        v = (y @ layer["wv"]).reshape(B, S, -1, hd)
+        v = project_heads(y, layer["wv"], hd)
         if rotary:
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)

@@ -48,35 +48,44 @@ BS = 16
 # 10adbe590e3aa7a9, cbaf223401156ccb in the dictionary's order); the decode
 # step, the table prefill, `last` (not told `fresh`), losses and gradients are
 # the parent's text. PR 44 moved the dictionary here from tests/test_lfm2.py
-# (ROADMAP D18) and changed no line of it.
+# (ROADMAP D18) and changed no line of it. PR 46 replaced the six
+# `*.paged.decode` lines and no other: at S == 1 the projections that are
+# split into heads (`wq`, `wk`, `wv`; the latent family's `w_uq`) hold their
+# product's result behind an `optimization_barrier` before the split
+# (`llama.project_heads`), so that XLA:TPU gives the heads-first layout to the
+# result and reads the weight in place (until then 4d1666b4e529e110,
+# 906cc7b9fe5efe59, fd15da110c2c1b9b, e5fc4c4767ca19dc, 7608cd7e4039bd6d,
+# 805d238630f2ee06 in the dictionary's order); every program at S > 1 (the
+# table prefill, the fresh one, `last`) and every loss and gradient is the
+# parent's text.
 PARENT_TEXT = {
-    "llama.paged.decode": "4d1666b4e529e110",
+    "llama.paged.decode": "1fe969061e80fc29",
     "llama.paged.prefill": "fd8459cf291092a0",
     "llama.paged.fresh": "e4819840a997529d",
     "llama.paged.last": "af6f1abee32d97ef",
     "llama.loss": "0fb9d6ad11474b55",
     "llama.grad": "1351d9f4c3001991",
-    "moe.paged.decode": "906cc7b9fe5efe59",
+    "moe.paged.decode": "b4cbd8e0616a0260",
     "moe.paged.prefill": "a695e7509cb75b45",
     "moe.paged.fresh": "b179923a4d114772",
     "moe.paged.last": "0492ef996a21c340",
     "moe.loss": "03710b5fb6249ca2",
     "moe.grad": "66721486a6cbfac2",
-    "olmoe.paged.decode": "fd15da110c2c1b9b",
+    "olmoe.paged.decode": "202959ac7e3159f5",
     "olmoe.paged.prefill": "db9bf0e36bc145f6",
     "olmoe.paged.fresh": "82027e3150d180ae",
     "olmoe.paged.last": "656dd949f7c87504",
     "olmoe.loss": "3d7e9ee7a5ae9813",
     "olmoe.grad": "a450411da25cf0a8",
-    "ouro.paged.decode": "e5fc4c4767ca19dc",
+    "ouro.paged.decode": "e076f47bbb470aa3",
     "ouro.paged.prefill": "5fe0d29dd78c063f",
     "ouro.paged.fresh": "6932f8c8b2bf340b",
     "ouro.paged.last": "f8df10e5b4953e6f",
-    "kimi_k2.paged.decode": "7608cd7e4039bd6d",
+    "kimi_k2.paged.decode": "d2362407b299555d",
     "kimi_k2.paged.prefill": "fb85295f16b0a12c",
     "kimi_k2.paged.fresh": "1da06d0983b09b0f",
     "kimi_k2.paged.last": "46bab8d6b05745d6",
-    "xing4.paged.decode": "805d238630f2ee06",
+    "xing4.paged.decode": "9dc47410ce02f9d6",
     "xing4.paged.prefill": "94ca6dd2f464ef8e",
     "xing4.paged.fresh": "a83c509a1b1686ef",
     "xing4.paged.last": "28834a2fee161468",
@@ -87,8 +96,11 @@ PARENT_TEXT = {
 # the PARENT of PR 44 (commit 0cac273, `PagedLLMEngine` a subclass of the slot
 # engine) by `_engine_programs` run on that tree. PR 44 folded the two engine
 # classes into one and moved the steps' callers; these say it changed no step.
+# PR 46 replaced `engine.decode` and no other (9a54b835ebc1bab2 until then):
+# the decode step's three projections hold their results, as above; both
+# prefill programs, `pick` and `carry` are the text they were.
 ENGINE_TEXT = {
-    "engine.decode": "9a54b835ebc1bab2",
+    "engine.decode": "6d694d3df493ef66",
     "engine.prefill.own_rows": "d5b6793788239c97",
     "engine.prefill.table": "c0f9ac373d237b85",
     "engine.pick": "44ef53bffaab689a",

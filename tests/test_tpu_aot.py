@@ -236,6 +236,41 @@ def pool_sized_instructions(text: str, pool_shape: tuple) -> list[tuple[str, str
     return out
 
 
+def weight_relayouts(text: str, params) -> list[tuple[str, str]]:
+    """(opcode, line) of every instruction of a compiled program, fused
+    computations included, that re-lays out or stages a layer's WEIGHT: a
+    `copy` or `transpose` wherever it lands, or a `dynamic-slice` or `fusion`
+    placed in VMEM (`S(1)` in its layout), whose result has the dimensions
+    (in any order, ones dropped) of a whole parameter stack or of one layer's
+    slice of it. A stack is a dict-valued entry of `params` (`layers`,
+    `lead_layers`, a family's stacks by kind), a weight a leaf of it whose
+    layer's slice has 2^20 elements or more (2 MB: LFM2's `wk`; under that a
+    weight's dimensions are a step's rows' too, LFM2's router `[2048, 32]`
+    beside `bf16[32, 2048]`, and staging it costs a microsecond). What it finds
+    in a decode step built without `llama.project_heads`' barrier: the
+    scan's slice of `wq` staged in VMEM and transposed there a layer, Ouro's
+    whole `wq` and `wk` stacks re-laid out a step (PERF.md section 6, PR 46).
+    NOT counted: `copy-start` / `copy-done`, `slice-start` / `slice-done` and
+    their `ConcatBitcast`, the memory-space assignment's asynchronous
+    prefetch of a small stack into VMEM (Kimi's one leading layer, LFM2's and
+    Nemotron's stacks of six): the same bytes read from HBM once, under
+    other work, which a product then reads in place."""
+    key = lambda shape: tuple(sorted(d for d in shape if d != 1))
+    stacks = [v for v in params.values() if isinstance(v, dict)]
+    weights = {key(shape) for stack in stacks for leaf in jax.tree.leaves(stack)
+               if math.prod(leaf.shape[1:]) >= 2 ** 20 for shape in (leaf.shape, leaf.shape[1:])}
+    instr = re.compile(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\](\S*) ([\w-]+)\(")
+    out = []
+    for ln in text.splitlines():
+        m = instr.match(ln)
+        if not m or key(map(int, m.group(1).split(","))) not in weights:
+            continue
+        op, in_vmem = m.group(3), "S(1)" in m.group(2)
+        if op in ("copy", "transpose") or (in_vmem and op in ("dynamic-slice", "fusion")):
+            out.append((op, ln.strip()[:200]))
+    return out
+
+
 def array_lines(text: str, shape: tuple) -> list[str]:
     """The instructions of a compiled text that produce an array of `shape`
     (any dtype), bitcasts and fused ones too."""
@@ -345,8 +380,9 @@ def test_paged_step_leaves_the_pool_where_it_is(v5e, B, S):
     assert_pool_stays_in_place(*_compiled_pool_step(v5e[0], B=B, S=S))
 
 
-@pytest.mark.parametrize("B, S", [(32, 1), (1, 256)], ids=["decode", "prefill-256"])
-def test_ouro_paged_step_at_its_published_widths(v5e, B, S):
+@pytest.mark.parametrize("B, S, scratch_under", [(32, 1, 2 ** 20), (1, 256, 2 ** 30)],
+                         ids=["decode", "prefill-256"])
+def test_ouro_paged_step_at_its_published_widths(v5e, B, S, scratch_under):
     """`serve-ouro-shortin-batch`'s two largest programs at Ouro-2.6B's
     published widths, whole: 48 layers run 4 times, 16 heads of 128, 32 slots,
     a 128-block table, the 321-block pool `bf16[192, 321, 16, 2048]` (8.08 GB
@@ -354,16 +390,18 @@ def test_ouro_paged_step_at_its_published_widths(v5e, B, S):
     a v5e (an out-of-HBM or Mosaic refusal fails here, not on the chip), the
     pool carried through TWO nested scans (passes, layers) stays in place (the
     only pool-shaped instructions are the `kv_write` scatters), and the text
-    names the scopes a profile is read by. Scratch is 806 MB, none of it the
-    pool's: XLA hoists a re-layout of the whole stacked `wq` and `wk`
-    (`bf16[48, 2048, 2048]`, 403 MB each) out of both loops, once a step where
-    a single scan does it a layer at a time (PERF.md section 6, PR 31)."""
+    names the scopes a profile is read by. The PREFILL's scratch is 806 MB,
+    none of it the pool's: XLA hoists a re-layout of the whole stacked `wq`
+    and `wk` (`bf16[48, 2048, 2048]`, 403 MB each) out of both loops, once an
+    admission (PERF.md section 6, PR 31); the decode step did the same once a
+    STEP until its projections held their results (`llama.project_heads`, PR
+    46) and has 0.4 MB since."""
     cfg = dataclasses.replace(ouro.OuroConfig.ouro_2_6b(), max_seq_len=2048)
     compiled = _paged_step(v5e[0], cfg, B=B, S=S, max_blocks=128,
                            pool_blocks=321).compile()
     pool = jax.eval_shape(lambda: ouro.init_kv_pool(cfg, 321, 16))
     assert pool["k"].shape == (192, 321, 16, 2048)
-    assert_pool_stays_in_place(compiled, pool, scratch_under=2 ** 30)
+    assert_pool_stays_in_place(compiled, pool, scratch_under=scratch_under)
     text = compiled.as_text()
     names = ["/loop/", "loop/norm", "attn/kv_write", "attn/kv_read"]
     for name in names + (["paged_attention_decode", MOSAIC] if S == 1 else []):
@@ -627,6 +665,23 @@ def _nemotron_serve_ep8():
     return family.model_config({k: file[k] for k in family.MODEL_KEYS}), file["engine"]
 
 
+def _nemotron_engine_step(d, cfg, engine, name, S, **kw):
+    """(lowered, the pool's shapes): a step of `serve-nemotron-tools4k-256-out`
+    as the engine builds it (`paged_step`), the state page the last column
+    of the 273-wide table: every slot at S == 1, one sequence at a prefill."""
+    from ray_tpu.serve.llm_paged import paged_step
+
+    slots, blocks = engine["max_batch_size"], engine["num_blocks"]
+    place = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
+    model, i32, B = model_of(cfg), jnp.int32, slots if S == 1 else 1
+    pool = jax.eval_shape(lambda: model.init_kv_pool(cfg, blocks, 16, num_sequences=slots + 1))
+    params = place(jax.eval_shape(lambda: model.init(cfg, jax.random.PRNGKey(0))))
+    rest = ((_on(d, (1, 273), i32), _on(d, (2,), i32)) if S > 1
+            else (_on(d, (B,), i32), _on(d, (B, 273), i32)))
+    return paged_step(name, cfg, 16, "tpu", **kw).lower(
+        params, place(pool), _on(d, (B, S), i32), *rest), pool
+
+
 @pytest.mark.parametrize("name, S, kw, scratch_under", [
     ("decode", 1, dict(head=0), 0.35e9),
     ("prefill", 4096, dict(head="last", table_first=True, fresh=True), 0.8e9),
@@ -651,18 +706,9 @@ def test_nemotron_engine_steps_compile_beside_weights_and_pool(v5e, name, S, kw,
     assert blocks == slots * 272 + 1
     assert (cfg.count("mamba"), cfg.count("attn"), cfg.count("experts")) == (23, 6, 23)
     assert model_of(cfg).sequence_leaves == ("ssm", "conv")
-    from ray_tpu.serve.llm_paged import paged_step
-
-    d = v5e[0]
-    place = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
-    model = model_of(cfg)
-    pool = jax.eval_shape(lambda: model.init_kv_pool(cfg, blocks, 16, num_sequences=slots + 1))
-    params = place(jax.eval_shape(lambda: model.init(cfg, jax.random.PRNGKey(0))))
-    i32, B = jnp.int32, slots if S == 1 else 1
-    rest = ((_on(d, (1, 273), i32), _on(d, (2,), i32)) if S > 1
-            else (_on(d, (B,), i32), _on(d, (B, 273), i32)))
-    compiled = paged_step(name, cfg, 16, "tpu", **kw).lower(
-        params, place(pool), _on(d, (B, S), i32), *rest).compile()
+    lowered, pool = _nemotron_engine_step(v5e[0], cfg, engine, name, S, **kw)
+    params = lowered.args_info[0][0]
+    compiled = lowered.compile()
     assert pool["k"].shape == pool["v"].shape == (6, blocks, 16, 256)
     assert pool["ssm"].shape == (23, slots + 1, 64, 64, 128) and pool["ssm"].dtype == jnp.float32
     assert pool["conv"].shape == (23, slots + 1, 3 * 6144)
@@ -875,6 +921,84 @@ def test_pool_sized_instruction_detector_sees_a_copy(v5e):
         assert_pool_stays_in_place(compiled, pool)
     assert any(op.startswith("copy") for op, _ in pool_sized_instructions(
         compiled.as_text(), pool["k"].shape))
+
+
+def _cell_decode_step(d, cell: str):
+    """(the lowered decode step, its weights' shapes) of a serving cell as
+    the engine builds the step (`head=0`), at the cell's slots, table and
+    pool; `olmoe-1b-7b` is no cell's, the published OLMoE-1B-7B at 32 slots."""
+    if cell == "nemotron":
+        lowered = _nemotron_engine_step(d, *_nemotron_serve_ep8(), "decode", 1, head=0)[0]
+        return lowered, lowered.args_info[0][0]
+    if cell == "mistral-16l":
+        cfg, size = _mistral_serve_16l(), dict(B=32, pool_blocks=4097)
+    elif cell == "ouro-2.6b":
+        cfg = dataclasses.replace(ouro.OuroConfig.ouro_2_6b(), max_seq_len=2048)
+        size = dict(B=32, pool_blocks=321)
+    elif cell == "olmoe-1b-7b":
+        cfg, size = moe.MoEConfig.olmoe_1b_7b(), dict(B=32, pool_blocks=1025)
+    else:
+        cfg, engine = {"kimi": _kimi_serve_ep32, "xing4": _xing4_serve_ep8,
+                       "lfm2": _lfm2_serve_ep2}[cell]()
+        size = dict(B=engine["max_batch_size"], pool_blocks=engine["num_blocks"],
+                    max_blocks={"kimi": 128, "xing4": 64, "lfm2": 264}[cell])
+    lowered = _engine_step(d, cfg, "decode", S=1, head=0, **size)[0]
+    return lowered, lowered.args_info[0][0]
+
+
+@pytest.mark.parametrize("cell, slots, scratch_under", [
+    ("mistral-16l", 32, 2 ** 20),
+    # 387,584 B where the two hoisted re-layouts of `wq` and `wk` were 806,016,512
+    ("ouro-2.6b", 32, 2 ** 20),
+    ("kimi", 64, 2 ** 21),
+    ("xing4", 48, 2 ** 24),
+    ("lfm2", 32, 2 ** 24),
+    ("nemotron", 48, 2 ** 25),
+    ("olmoe-1b-7b", 32, 2 ** 20),
+])
+def test_a_decode_step_reads_its_projections_weights_in_place(v5e, cell, slots, scratch_under):
+    """Every serving cell's decode step as the engine builds it, at the
+    published widths: NO instruction copies, transposes or stages in VMEM a
+    layer's slice of a stacked weight or a whole stack, and scratch stays
+    small. The projections that are split into heads hold their RESULT
+    (`llama.project_heads`, S == 1), so the layout rope and the decode
+    kernels want goes to `bf16[slots, N]` and the product takes the scan's
+    slice of its stack as a fused operand, as the MLP's and `wo`'s do. Built
+    the old way Mistral's step staged and transposed `wq` and `wk` a layer,
+    Ouro's re-laid both whole stacks out a step (403 MB read and written
+    each), Kimi's and Xing's did `w_uq`'s in both runs, LFM2's and Nemotron's
+    copied `wq` (LFM2: and `wk`, `wv`) of their six attention layers; OLMoE's
+    whole-vector norm stood between product and split and its step had none
+    (PERF.md section 6, PR 46)."""
+    lowered, params = _cell_decode_step(v5e[0], cell)
+    assert lowered.args_info[0][2].shape == (slots, 1)
+    compiled = lowered.compile()
+    found = weight_relayouts(compiled.as_text(), params)
+    assert not found, found
+    assert compiled.memory_analysis().temp_size_in_bytes < scratch_under
+
+
+@pytest.mark.parametrize("cell, copied", [
+    # the scan's slices of `wq` and `wk` staged in VMEM, then transposed there: a layer
+    ("mistral-16l", ["bf16[1,4096,1024]", "bf16[1,4096,4096]"]),
+    # both stacks whole, hoisted out of the passes' scan: a step
+    ("ouro-2.6b", ["bf16[48,2048,2048]", "bf16[48,2048,2048]"]),
+])
+def test_weight_relayout_detector_sees_the_old_projection(v5e, monkeypatch, cell, copied):
+    """The detector is not vacuous: the decode step built the way it was
+    until PR 46 (product, then split, nothing held) has the copies of `wq`
+    and `wk` the ledger's breakdowns named, and they are found."""
+    from tests.test_project_heads import unheld_project_heads
+
+    monkeypatch.setattr(llama, "project_heads", unheld_project_heads)
+    lowered, params = _cell_decode_step(v5e[0], cell)
+    compiled = lowered.compile()
+    found = weight_relayouts(compiled.as_text(), params)
+    assert {op for op, _ in found} == {"copy", "fusion", "dynamic-slice"}, found
+    assert sorted(re.search(r"= (\w+\[[\d,]+\])", ln).group(1)
+                  for op, ln in found if op == "copy") == copied, found
+    if cell == "ouro-2.6b":
+        assert compiled.memory_analysis().temp_size_in_bytes > 8e8
 
 
 def _train_attention_text(d) -> str:
