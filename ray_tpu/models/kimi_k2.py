@@ -376,8 +376,8 @@ def init_kv_pool(cfg: KimiK2Config, num_blocks: int, block_size: int) -> dict:
     contract: an engine reports them in its records and moves only the
     pages): `moe_rows`, the (token, choice) pairs the last forward routed to
     experts held here, summed over layers, and `moe_moved`, the rows its
-    expert layers gathered for them (`moe.held_rows_bound` a layer, more
-    where a router overflowed it)."""
+    expert layers gathered for them (`moe.held_rows_trip` a layer, as many
+    trips of it as hold the pairs where a router sent the share more)."""
     shape = (cfg.cache_layers, num_blocks, block_size, cfg.latent_row)
     return {"latent": jnp.zeros(shape, dtype=cfg.base.dtype),
             "counters": {"moe_rows": jnp.zeros((), jnp.int32),

@@ -47,13 +47,15 @@ it, OLMoE and Mixtral none):
   result goes on: on one chip the layer runs without the exchange that would
   bring the other shares in, and nothing stands in for it. A share MOVES the
   rows it keeps, as the exchange would hand it only those: the held pairs
-  sort first, and where `held_rows_bound` (four times an even router's
-  share of the T * k pairs, in whole row tiles; static) is under T * k the
-  layer gathers, multiplies, masks and sums back that many rows at a time
-  (`_held_rows`): one chunk, or as many as hold every held pair, so nothing
-  is dropped at any routing and no array of T * k rows is built. Where the
-  bound reaches T * k (few tokens, or a large share) the layer is the text
-  it is without a share, with the rows in no group masked.
+  sort first, and a share of under a quarter of the T * k pairs gathers,
+  multiplies, masks and sums back `held_rows_trip` rows at a time (what an
+  even router sends it and a quarter more, in whole row tiles; static) in
+  `_held_rows`: one trip where the router sends it that or less, as many as
+  hold every held pair where it sends more, so nothing is dropped at any
+  routing, no array of T * k rows is built, and what a layer costs follows
+  the pairs it holds. Where four even shares reach T * k (few tokens, or a
+  large share) the layer is the text it is without a share, with the rows
+  in no group masked.
 
 With an `expert` mesh axis of more than one device the layer takes the dense
 path it takes off the TPU (a Mosaic kernel cannot be partitioned) and leaves
@@ -195,58 +197,69 @@ _dispatch.defvjp(lambda yt, order, inverse: (_dispatch(yt, order, inverse),
                  _dispatch_bwd)
 
 
-HELD_ROWS_OVER_EVEN = 4   # a share's bound over what an even router sends it
+COMPACTS_OVER_EVEN = 4   # a share compacts where the pairs are over this many even shares
+TRIP_OVER_EVEN = (5, 4)  # and moves an even share and a quarter more a trip
 
 
-def held_rows_bound(pairs: int, count: int, num_experts: int) -> int:
-    """C, the rows a share of `count` of `num_experts` experts moves at once
-    of a layer's `pairs` (token, choice) pairs: `HELD_ROWS_OVER_EVEN` times
-    what an evenly spread router sends it, in whole row tiles of the grouped
-    products, and never more than all of them. From shapes alone, so it is
-    static where the layer is traced."""
+def held_rows_trip(pairs: int, count: int, num_experts: int) -> int:
+    """The rows a share of `count` of `num_experts` experts moves at once of
+    a layer's `pairs` (token, choice) pairs: what an evenly spread router
+    sends it and a quarter more (a layer's router favours or slights a share
+    by that much, and a second trip costs what ~1,000 rows do), in whole row
+    tiles of the grouped products; or all of them, where
+    `COMPACTS_OVER_EVEN` even shares (in whole tiles) reach `pairs` and the
+    layer is not worth compacting. From shapes alone, so it is static where
+    the layer is traced."""
     even = -(-pairs * count // num_experts)
-    return min(pairs, -(-HELD_ROWS_OVER_EVEN * even // ROW_TILE) * ROW_TILE)
+    tiles = lambda rows: -(-rows // ROW_TILE) * ROW_TILE
+    if tiles(COMPACTS_OVER_EVEN * even) >= pairs:
+        return pairs
+    over, under = TRIP_OVER_EVEN
+    return tiles(-(-over * even // under))
 
 
-def _held_rows(yt, order, top_p, group_sizes, rows, bound: int, products):
+def _held_rows(yt, order, top_p, group_sizes, rows, trip: int, products):
     """A share's part of the routed sum, moving the rows it computes: the
     `rows` held pairs lie first in `order` (sorted by expert), and they are
-    taken `bound` at a time. A chunk gathers its pairs' tokens [bound, H],
-    runs `products` over them with the groups' sizes clipped to the chunk,
-    and adds its rows to their tokens as ONE product on the MXU,
-    `W [T, bound] @ ys [bound, H]` with `W[t, c]` the weight of pair c where
+    taken `trip` at a time. A trip gathers its pairs' tokens [trip, H], runs
+    `products` over them with the groups' sizes clipped to the trip, and
+    adds its rows to their tokens as ONE product on the MXU,
+    `W [T, trip] @ ys [trip, H]` with `W[t, c]` the weight of pair c where
     it is token t's (the same bf16 x bf16 products as a weighted sum over k,
     accumulated in float32; a scatter-add of the rows is serial on a TPU).
-    One chunk unless the router sends this share more than `bound` pairs;
-    then as many as hold them all, so nothing is dropped at any routing, and
-    no array of T * k rows is ever built. -> (out [T, H], the rows gathered:
-    chunks * bound). Not differentiable (the trip count is data): no caller
-    trains a share."""
+    That product is 2 x T x H operations a row moved, as many as an expert's
+    own where T is its width, so the trip stays near the even share and is
+    no multiple of it (PERF.md section 6, PR 47). One trip unless the router
+    sends this share more than `trip` pairs; then as many as hold them all
+    (the carried sum rounded once a trip), so nothing is dropped at any
+    routing, and no array of T * k rows is ever built. -> (out [T, H], the
+    rows gathered: trips * trip). Not differentiable (the trip count is
+    data): no caller trains a share."""
     T, k = top_p.shape
     ends = jnp.cumsum(group_sizes)
     starts = ends - group_sizes
-    order = jnp.pad(order, (0, -order.shape[0] % bound))
+    order = jnp.pad(order, (0, -order.shape[0] % trip))
     weights = top_p.reshape(T * k)
-    chunks = (rows + bound - 1) // bound
+    trips = (rows + trip - 1) // trip
 
-    def chunk(i, out):
-        lo = i * bound
+    def one(i, out):
+        lo = i * trip
         with jax.named_scope("moe/dispatch"):
-            pairs = jax.lax.dynamic_slice(order, (lo,), (bound,))
+            pairs = jax.lax.dynamic_slice(order, (lo,), (trip,))
             tokens = pairs // k
-            xs = yt[tokens]                                      # [bound, H]
+            xs = yt[tokens]                                      # [trip, H]
         with jax.named_scope("moe/experts"):
-            sizes = jnp.clip(ends - lo, 0, bound) - jnp.clip(starts - lo, 0, bound)
+            sizes = jnp.clip(ends - lo, 0, trip) - jnp.clip(starts - lo, 0, trip)
             # rows past the held pairs are in no group: `grouped_matmul`
             # computes nothing for them and says nothing of what they hold
-            live = lo + jnp.arange(bound) < rows
+            live = lo + jnp.arange(trip) < rows
             ys = jnp.where(live[:, None], products(xs, sizes), 0)
         with jax.named_scope("moe/combine"):
             w = jnp.where(tokens == jnp.arange(T)[:, None], weights[pairs], 0)
             return out + jnp.dot(w.astype(yt.dtype), ys,
                                  preferred_element_type=jnp.float32).astype(out.dtype)
 
-    return jax.lax.fori_loop(0, chunks, chunk, jnp.zeros_like(yt)), chunks * bound
+    return jax.lax.fori_loop(0, trips, one, jnp.zeros_like(yt)), trips * trip
 
 
 def router_logits(yt, router_w):
@@ -285,7 +298,7 @@ def moe_mlp(y, layer, cfg: MoEConfig, platform: str | None = None,
     otherwise; None: from the operands' placement). With `cfg.experts_held`
     the stats are of the experts held here: "load" [count], "rows" (the pairs
     routed to them, which is what the products are computed for), "moved"
-    (the rows the dispatch gathered for them: `held_rows_bound` a chunk, or
+    (the rows the dispatch gathered for them: `held_rows_trip` a trip, or
     T * k) and "experts"; no "aux" (the share serves). With `stacked` (`unstacked_experts`)
     the experts' weights are EVERY layer's, [L, E, ...], and this layer's are
     those at `layer["stack_index"]`: the products run over all L * E matrices
@@ -347,8 +360,8 @@ def moe_mlp(y, layer, cfg: MoEConfig, platform: str | None = None,
             hidden = act(gmm(xs, experts["e_up_t"], transpose_rhs=True))
         return gmm(hidden, experts["e_down"])
 
-    bound = T * k if held is None else held_rows_bound(T * k, held[1], cfg.num_experts)
-    if bound == T * k:
+    trip = T * k if held is None else held_rows_trip(T * k, held[1], cfg.num_experts)
+    if trip == T * k:
         with jax.named_scope("moe/dispatch"):
             xs = _dispatch(yt, order, inverse)                   # [T * k, H]
         with jax.named_scope("moe/experts"):
@@ -364,7 +377,7 @@ def moe_mlp(y, layer, cfg: MoEConfig, platform: str | None = None,
         moved = jnp.int32(T * k)
     else:
         rows = group_sizes.sum()
-        out, moved = _held_rows(yt, order, top_p, group_sizes, rows, bound, products)
+        out, moved = _held_rows(yt, order, top_p, group_sizes, rows, trip, products)
     if "s_up" in layer:
         with jax.named_scope("moe/shared"):
             if "s_gate" in layer:

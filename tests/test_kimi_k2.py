@@ -277,11 +277,12 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny, E, count, T):
     the E experts give (here through the PROGRAM's `moe_mlp`, each share
     with its own experts' weights), the shared expert counted once, add up
     to what the uncut reference gives for the whole layer. The halves of 16
-    move every pair (their bound is T x k); an eighth of 32 moves its held
-    pairs a bound of 512 rows at a time (`moe.held_rows_bound`)."""
+    move every pair; an eighth of 32 moves its held pairs an even share and a
+    quarter at a time, one 256-row tile (`moe.held_rows_trip`)."""
     model, cfg, params, _ = tiny
     h, m = 64, 32
-    compacts = moe.held_rows_bound(T * 4, count, E) < T * 4
+    trip = moe.held_rows_trip(T * 4, count, E)
+    compacts = trip < T * 4
     assert compacts == (E == 32)
     ks = jax.random.split(jax.random.PRNGKey(5), 9)
     dense = lambda k, *s: jax.random.normal(k, s, jnp.float32) / math.sqrt(s[-2])
@@ -294,7 +295,7 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny, E, count, T):
         uncut = reference.expert_layer(y[0], whole, model, first=0)
         shared = reference.expert_layer(y[0], {**whole, **{
             k: whole[k][:0] for k in ("e_gate", "e_up", "e_down")}}, model, first=0)
-        parts, rows, moved = [], 0, 0
+        parts, rows, moved, trips = [], 0, 0, 0
         for first in range(0, E, count):
             share = {k: v[first:first + count] if k.startswith("e_") else v
                      for k, v in whole.items() if not k.startswith("s_")}
@@ -303,12 +304,13 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny, E, count, T):
             parts.append(out[0])
             rows += int(stats["rows"])
             moved += int(stats["moved"])
+            trips += -(-int(stats["rows"]) // trip)
             # the reference, given the same share, gives the same part
             assert _miss(out[0], reference.expert_layer(
                 y[0], {**whole, **share}, model, first=first, shared=False)) < TOL
     assert rows == T * 4                     # every pair is some share's
-    # a share that compacts gathers its bound, the halves every pair each
-    assert moved == (E // count) * (512 if compacts else T * 4)
+    # a share that compacts gathers whole trips, the halves every pair each
+    assert moved == trips * trip and trips >= E // count
     assert _miss(sum(parts) + shared, uncut) < TOL
     assert _miss(parts[0] + shared, uncut) > 0.1    # one share alone is not the layer
 

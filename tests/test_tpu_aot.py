@@ -281,13 +281,13 @@ def array_lines(text: str, shape: tuple) -> list[str]:
 
 def assert_a_share_moves_its_bound(text: str, cfg, tokens: int, compacts: bool) -> None:
     """A compiled step of `tokens` rows whose expert layers hold a share:
-    where `moe.held_rows_bound` is under the T x k pairs no instruction
-    produces an array of T x k rows of the hidden width (the layers gather a
-    bound at a time, PR 38); where it reaches them the step has such arrays,
-    as it had."""
+    where `moe.held_rows_trip` is under the T x k pairs no instruction
+    produces an array of T x k rows of the hidden width (the layers gather
+    an even share at a time, PR 38 and PR 47); where it reaches them the step
+    has such arrays, as it had."""
     experts = cfg.experts
     pairs = tokens * experts.top_k
-    assert (moe.held_rows_bound(pairs, experts.experts_held[1], experts.num_experts)
+    assert (moe.held_rows_trip(pairs, experts.experts_held[1], experts.num_experts)
             < pairs) == compacts
     assert bool(array_lines(text, (pairs, cfg.base.hidden_size))) == (not compacts)
 
@@ -450,8 +450,8 @@ def test_kimi_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw, 
     scatters; the text names the scopes a profile is read by, and at decode
     the latent kernel. The compiler's bytes are what the configuration
     file's depth rule is decided by (`num_blocks_note`). The expert layers
-    move the rows they keep (`moe.held_rows_bound`: 2,048 of the prefill's
-    16,384 pairs, 256 of the decode step's 512, a bound at a time): NO
+    move the rows they keep (`moe.held_rows_trip`: 768 of the prefill's
+    16,384 pairs, 256 of the decode step's 512, a trip at a time): NO
     instruction of either program produces an array of T x k rows of the
     hidden width, where the prefill had three `bf16[16384, 7168]` a layer."""
     cfg, engine = _kimi_serve_ep32()
@@ -493,8 +493,8 @@ def assert_a_fresh_latent_prefill_reads_its_own_rows(text: str, S: int, fresh: b
     float32 array of heads x bucket x bucket (from the 1,024 bucket up the
     scores stay in the flash kernel's VMEM; the table program forms
     `f32[1, 16, 2048, 2048]` a chunk of heads and is what the detector finds
-    them in; the expert layers' combine weights `f32[2048, 2048]`, T x bound,
-    are in both)."""
+    them in; the expert layers' combine weights `[2048, 768]`, T x trip, are
+    two-dimensional and in both)."""
     assert ("attn/prompt_attend" in text) == fresh
     assert ("attn/latent_read" in text) == (not fresh)
     assert ("flash_attention_fwd" in text) == (fresh and S >= 1024)
@@ -574,8 +574,8 @@ def test_xing4_engine_steps_compile_beside_weights_and_pool(v5e, name, B, S, kw,
     if S == 512:   # scores against the table's 1,024 columns, or against its own 512 rows
         wide = re.findall(r"= f32\[[\d,]+,512,1024\]", text)   # by head: not the combine's
         assert bool(wide) == (not fresh), wide[:3]
-    # the prefill's expert layers gather 1,024 of their 2,048 pairs; the
-    # decode step's bound is its 192 pairs, and its text what it was
+    # the prefill's expert layers gather 512 of their 2,048 pairs a trip; the
+    # decode step moves its 192 pairs, and its text is what it was
     assert_a_share_moves_its_bound(text, cfg, B * S, compacts=S == 512)
 
 
@@ -747,6 +747,17 @@ def test_nemotron_engine_steps_compile_beside_weights_and_pool(v5e, name, S, kw,
     if S > 1:
         assert_a_fresh_prefill_writes_whole_pages(text, pool["k"].shape, S, "attn/kv_write",
                                                   leaves=2)
+        # an expert block sums its held rows back an even share and a quarter
+        # at a time (PR 47): the combine's one-hot is [4096, 3840], where four
+        # even shares made it bf16[4096, 12288], 100.7 MB written and read and
+        # 270.6 GFLOP a block: 6.22 of the program's 18.85 TFLOP for ~2,864
+        # live rows
+        trip = moe.held_rows_trip(S * 6, 16, 128)
+        assert trip == 3840 and array_lines(text, (S, trip)) and not array_lines(text, (S, 12288))
+        assert_a_share_moves_its_bound(text, cfg, S, compacts=True)
+        flops = compiled.cost_analysis()["flops"]
+        print("flops", flops)
+        assert 12e12 < flops < 15e12, flops
     else:
         # the decode step's state update is the in-place kernel: no gathered
         # copy of the live pages, f32[slots, 64, 64, 128], exists
