@@ -45,7 +45,10 @@ class Model(NamedTuple):
     # for a dead row. The engine hands a sequence one such page at admission,
     # carries its id as the LAST column of the sequence's table row, and
     # keeps no prefix cache over such a pool (a block's hash says nothing of
-    # a running sum)
+    # a running sum). Such a page may as well be a window layer's RING of rows
+    # (`laguna`'s `k_win` and `v_win`, `window` rows a sequence whatever its
+    # length): the allocator's stats and the engine's records count either as
+    # `state_pages_used`
     sequence_leaves: tuple = ()
 
 

@@ -179,8 +179,9 @@ def rope(x, positions, theta, inv_freq=None):
     return out.astype(x.dtype)
 
 
-def attention(q, k, v, causal: bool = True, mask=None):
-    """Dense MXU attention. q:[B,S,Hq,D], k/v:[B,S,Hkv,D] (GQA broadcast)."""
+def attention(q, k, v, causal: bool = True, mask=None, window: int | None = None):
+    """Dense MXU attention. q:[B,S,Hq,D], k/v:[B,S,Hkv,D] (GQA broadcast).
+    `window` (causal): query i sees key j only where i - j < window."""
     B, S, Hq, D = q.shape
     Hkv = k.shape[2]
     group = Hq // Hkv
@@ -190,6 +191,8 @@ def attention(q, k, v, causal: bool = True, mask=None):
         qi = jax.lax.broadcasted_iota(jnp.int32, (S, S), 0)
         ki = jax.lax.broadcasted_iota(jnp.int32, (S, S), 1)
         cmask = qi >= ki
+        if window is not None:
+            cmask &= qi - ki < window
         scores = jnp.where(cmask[None, None, None], scores, -1e30)
     if mask is not None:
         scores = jnp.where(mask[:, None, None, None, :], scores, -1e30)
@@ -207,9 +210,12 @@ def flash_pays(seq_len: int, platform: str) -> bool:
     return platform == "tpu" and seq_len >= 1024
 
 
-def auto_attention(q, k, v, causal: bool = True, platform: str | None = None):
+def auto_attention(q, k, v, causal: bool = True, platform: str | None = None,
+                   window: int | None = None):
     """Pallas flash kernel for long causal sequences placed on a TPU, dense
-    MXU attention otherwise.
+    MXU attention otherwise; `window` is a sliding-window layer's (query i
+    sees the `window` keys that end at its own: the kernel's band, the dense
+    path's second mask).
 
     The crossover is `flash_pays`'s. `platform` is where the computation runs:
     callers that know their mesh pass it (train/spmd.py default_attn_fn);
@@ -225,8 +231,8 @@ def auto_attention(q, k, v, causal: bool = True, platform: str | None = None):
     if causal and flash_pays(q.shape[1], platform):
         from ray_tpu.ops.flash_attention import flash_attention
 
-        return flash_attention(q, k, v, causal=True, interpret=False)
-    return attention(q, k, v, causal=causal)
+        return flash_attention(q, k, v, causal=True, interpret=False, window=window)
+    return attention(q, k, v, causal=causal, window=window)
 
 
 def dense_mlp(y, layer):
@@ -264,7 +270,7 @@ def project_heads(y, w, head_dim: int, norm=None):
     return out.reshape(B, S, -1, head_dim)
 
 
-def gqa_attention(attend, rotary: bool = True):
+def gqa_attention(attend, rotary: bool | Callable = True):
     """The attention strategy of the grouped-query families, around a cache
     strategy `attend(q, k, v, cache, index) -> (o, cache)`: the three
     projections `wq`, `wk`, `wv` split into heads (`project_heads`; head
@@ -276,12 +282,19 @@ def gqa_attention(attend, rotary: bool = True):
     EACH HEAD's `head_dim` lanes with one weight of that width shared by the
     heads (after the split), rope on all of q and k (none where `rotary` is
     False: a family whose attention layers take no positional embedding,
-    `models/nemotron_h.py`), and `attend` over the
+    `models/nemotron_h.py`; where it is a function `(x, positions) -> x`, a
+    kind of layer's own rotation: `models/laguna.py`, whose full layers
+    rotate half of a head's lanes by scaled frequencies and whose window
+    layers all of them by plain ones), and `attend` over the
     rotated heads q [B, S, Hq, D], k/v [B, S, Hkv, D]. `cache` is the WHOLE
     cache, every layer's (and every other kind of layer's), and `index`
     the layer's place in it: `attend` writes this layer's rows in place and
     reads them back (`forward_paged`: pages of the pool), or keeps nothing
-    (`plain_attend`: cache and index are None)."""
+    (`plain_attend`: cache and index are None). Where the layer holds
+    `w_head_gate` [H, Hq] the heads' outputs are GATED, a sigmoid scalar a
+    head from the layer's input (arXiv:2505.06708's head-wise gate): `o[..., h,
+    :] *= sigmoid(y w_head_gate)[..., h]` (scope `gate`), before the layer's
+    `wo`."""
 
     def attention(cfg, y, layer, cache, positions, index):
         eps, hd = cfg.rms_eps, cfg.hd
@@ -293,19 +306,26 @@ def gqa_attention(attend, rotary: bool = True):
                               if qk_norm and not per_head else None)
         q = project_heads(y, layer["wq"], hd, whole("q_norm"))
         k = project_heads(y, layer["wk"], hd, whole("k_norm"))
-        if per_head:
+        if per_head:   # (`laguna`'s configuration file: assumed (c))
             q = rms_norm(q, layer["q_norm"], eps)
             k = rms_norm(k, layer["k_norm"], eps)
         v = project_heads(y, layer["wv"], hd)
-        if rotary:
+        if callable(rotary):
+            q, k = rotary(q, positions), rotary(k, positions)
+        elif rotary:
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
-        return attend(q, k, v, cache, index)
+        o, cache = attend(q, k, v, cache, index)
+        if "w_head_gate" in layer:   # (`laguna`'s configuration file: assumed (a))
+            with jax.named_scope("gate"):
+                gate = jax.nn.sigmoid((y @ layer["w_head_gate"]).astype(jnp.float32))
+                o = o * gate[..., None].astype(o.dtype)
+        return o, cache
 
     return attention
 
 
-def plain_attend(attn_fn=None, rotary: bool = True):
+def plain_attend(attn_fn=None, rotary: bool | Callable = True):
     """The attention strategy that keeps no cache (training): grouped-query
     projections and `attn_fn` (default: `auto_attention`, causal) over the
     whole sequence."""
@@ -878,6 +898,15 @@ def forward_paged(params, tokens, cfg: LlamaConfig, pool: dict, tables, lengths,
                          cache=pool, positions=positions, head_rows=head_rows)[:2]
 
 
+def pool_rows(t, dtype, head_dim: int):
+    """t [B, S, Hkv, D] -> a paged pool's (or a ring's) rows [B, S, Hkv * Dp]:
+    each head padded to its whole 128-lane tiles (`pool_head_dim`)."""
+    dp = pool_head_dim(head_dim)
+    if dp != head_dim:
+        t = jnp.pad(t, [(0, 0)] * 3 + [(0, dp - head_dim)])
+    return t.reshape(*t.shape[:2], -1).astype(dtype)
+
+
 def paged_attend(cfg: LlamaConfig, tables, lengths, positions, blk_idx, blk_off,
                  block_size: int, use_kernel: bool, platform: str, fresh: bool):
     """`forward_paged`'s cache strategy (`gqa_attention(attend)`) over the
@@ -890,10 +919,7 @@ def paged_attend(cfg: LlamaConfig, tables, lengths, positions, blk_idx, blk_off,
     max_blocks = tables.shape[1]
     hd, dp = cfg.hd, pool_head_dim(cfg.hd)
 
-    def rows(t, dtype):  # [B, S, Hkv, D] -> the pool's rows [B, S, Hkv * Dp]
-        if dp != hd:
-            t = jnp.pad(t, [(0, 0)] * 3 + [(0, dp - hd)])
-        return t.reshape(B, S, -1).astype(dtype)
+    rows = partial(pool_rows, head_dim=hd)
 
     def attend(q, k, v, pool, layer):  # the whole pool and this layer's index
         kp, vp = pool["k"], pool["v"]
@@ -931,6 +957,94 @@ def paged_attend(cfg: LlamaConfig, tables, lengths, positions, blk_idx, blk_off,
                     B, max_blocks * block_size, -1, dp)[..., :hd]
                 o = _cached_attention(q, view(kp), view(vp), lengths, positions)
         return o, {**pool, "k": kp, "v": vp}
+
+    return attend
+
+
+def ring_rows(n, window: int):
+    """Which position each row of a window layer's ring holds once a
+    sequence has `n` [B] tokens: row r holds the LAST position p < n with p %
+    window == r -> int32 [B, window], negative where the ring has not come
+    round to the row yet (n <= r: whatever lies there is not the sequence's)."""
+    r = jnp.arange(window, dtype=jnp.int32)[None, :]
+    last = n[:, None] - 1
+    return last - (last - r) % window
+
+
+def window_attend(cfg: LlamaConfig, window: int, rings, lengths, live, use_kernel: bool,
+                  platform: str, fresh: bool):
+    """The cache strategy (`gqa_attention(attend)`) of a SLIDING-WINDOW layer
+    (query i sees key j iff j <= i and i - j < window) over the pool's leaves
+    `k_win` and `v_win`, `[Lw, NS, window, Hkv * Dp]`: a RING a sequence, ring `rings[b]`
+    [B] (0: the garbage ring, a dead row's), position p at row p % window. A
+    sequence's window layers hold `window` rows whatever its length, where
+    `paged_attend`'s hold a row a token; writing position p overwrites p -
+    window, which the window has just passed. `lengths` [B] are the sequences'
+    lengths before the call and `live` [B] how many of its S tokens are live
+    (the rest pad a bucket and are written nowhere: their rows would displace
+    live positions). Two ways, by what a call is:
+
+    - `fresh` (every sequence starts at position 0): attention over the rows
+      in hand under the band (`auto_attention(window=)`: the banded flash
+      forward from S = 1,024 up on a TPU; scope `prompt_attend`), and the
+      ring written WHOLE, one page a sequence: row r takes the last live
+      position of its residue (`ring_rows`), or anything at all where there
+      is none yet (scope `kv_write`);
+    - a decode step (S == 1): the token's row to `lengths % window`, then the
+      ring's live rows read in place by the window kernel
+      (`ops/paged_attention.py::window_decode_attention`) or, off the TPU, the
+      gathered ring with the rows it has not come round to masked (scope
+      `kv_read`). A ring is never trusted to hold zeros or its last owner's
+      rows: what is read is masked by the sequence's OWN length.
+
+    More than one token over a ring that holds a past (a prompt behind a
+    cached prefix, a speculative window) is refused: the engine hands a pool
+    with `Model.sequence_leaves` neither (ROADMAP R2)."""
+    hd, dp = cfg.hd, pool_head_dim(cfg.hd)
+    rows = partial(pool_rows, head_dim=hd)
+
+    def attend(q, k, v, pool, layer):
+        B, S = q.shape[:2]
+        kr, vr = pool["k_win"], pool["v_win"]
+        if fresh:
+            with jax.named_scope("kv_write"):
+                held = jnp.clip(ring_rows(live, window), 0, S - 1)[..., None]   # [B, W, 1]
+                take = lambda t, leaf: leaf.at[layer, rings].set(
+                    jnp.take_along_axis(rows(t, leaf.dtype), held, axis=1))
+                kr, vr = take(k, kr), take(v, vr)
+            with jax.named_scope("prompt_attend"):
+                if use_kernel:
+                    from ray_tpu.ops.flash_attention import flash_attention
+
+                    o = flash_attention(q, k, v, causal=True, window=window,
+                                        interpret=platform != "tpu")
+                else:
+                    o = auto_attention(q, k, v, causal=True, platform=platform,
+                                       window=window)
+            return o, {**pool, "k_win": kr, "v_win": vr}
+        if S != 1:
+            raise NotImplementedError(
+                f"{S} tokens a sequence over a window layer's ring that holds a past: a "
+                f"ring keeps the last {window} positions and no earlier state to resume "
+                f"from; a prefill starts at position 0 (`fresh`) and a decode step "
+                f"appends one token")
+        with jax.named_scope("kv_write"):
+            at = lengths % window
+            kr = kr.at[layer, rings, at].set(rows(k, kr.dtype)[:, 0])
+            vr = vr.at[layer, rings, at].set(rows(v, vr.dtype)[:, 0])
+        with jax.named_scope("kv_read"):
+            live_rows = jnp.minimum(lengths + 1, window)   # the token's own among them
+            if use_kernel:
+                from ray_tpu.ops.paged_attention import window_decode_attention
+
+                o = window_decode_attention(q[:, 0], kr, vr, rings, live_rows, layer=layer,
+                                            interpret=platform != "tpu")[:, None]
+            else:
+                # rows [0, live) of the gathered ring, in ring order
+                view = lambda ring: ring[layer, rings].reshape(B, window, -1, dp)[..., :hd]
+                o = _cached_attention(q, view(kr), view(vr), live_rows - 1,
+                                      (live_rows - 1)[:, None])
+        return o, {**pool, "k_win": kr, "v_win": vr}
 
     return attend
 

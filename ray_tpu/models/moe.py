@@ -290,7 +290,7 @@ def unstacked_experts(layers: dict) -> tuple[dict, dict]:
 
 
 def moe_mlp(y, layer, cfg: MoEConfig, platform: str | None = None,
-            stacked: dict | None = None):
+            stacked: dict | None = None, live=None):
     """The expert layer on normalised activations y [B, S, H] -> ([B, S, H],
     {"aux": the layer's load-balancing term, "load": rows each expert
     received over the mean T * k / E, [E], "experts": the chosen experts
@@ -302,7 +302,12 @@ def moe_mlp(y, layer, cfg: MoEConfig, platform: str | None = None,
     T * k) and "experts"; no "aux" (the share serves). With `stacked` (`unstacked_experts`)
     the experts' weights are EVERY layer's, [L, E, ...], and this layer's are
     those at `layer["stack_index"]`: the products run over all L * E matrices
-    with every other layer's groups empty, so nothing is sliced out."""
+    with every other layer's groups empty, so nothing is sliced out. With
+    `live` (bool [B, S]; a share's layer) the rows that are not live, a
+    bucket's padding, go to NO expert held here: they all hold one token and so
+    choose the same experts, and where those are held they alone can fill a
+    trip and force a second one (PERF.md section 6, PR 48); the shared expert
+    still runs over them, and what a padding row holds is never read."""
     B, S, H = y.shape
     E, k, T = cfg.num_experts, cfg.top_k, B * S
     yt = y.reshape(T, H)
@@ -329,6 +334,8 @@ def moe_mlp(y, layer, cfg: MoEConfig, platform: str | None = None,
             # here, in no group, and weighs nothing
             E = held[1]
             mine = (choice_e >= held[0]) & (choice_e < held[0] + E)
+            if live is not None:
+                mine &= jnp.repeat(live.reshape(T), k)
             choice_e = jnp.where(mine, choice_e - held[0], E)
             top_p = jnp.where(mine.reshape(T, k), top_p, 0.0)
         order = jnp.argsort(choice_e, stable=True).astype(jnp.int32)

@@ -124,17 +124,33 @@ def choose_tiles(seq_len: int, head_dim: int, itemsize: int,
     return bq, bk
 
 
-def _live_tiles(seq_len: int, block_q: int, block_k: int, causal: bool):
-    """(query tile, key tile) of every pair with a live entry, row-major."""
+def _live_tiles(seq_len: int, block_q: int, block_k: int, causal: bool,
+                window: int | None = None):
+    """(query tile, key tile) of every pair with a live entry, row-major.
+    With a `window` (query i sees key j iff i - j < window, under the causal
+    mask) the live tiles are a BAND: a tile whose last key the tile's first
+    query no longer sees is wholly behind it."""
     return [(qi, ki) for qi in range(seq_len // block_q)
             for ki in range(seq_len // block_k)
-            if not causal or ki * block_k <= qi * block_q + block_q - 1]
+            if (not causal or ki * block_k <= qi * block_q + block_q - 1)
+            and (window is None or ki * block_k + block_k - 1 > qi * block_q - window)]
 
 
-def _masked(s, q0, k0, *, causal: bool, kv_len: int, q_axis: int):
-    """Scores with dead entries at NEG_INF: keys past the real length and,
-    under a causal mask, keys after their query. `q_axis` is the axis of `s`
-    that runs over queries (0, or 1 in the transposed dK/dV tile)."""
+def _masked(s, q0, k0, *, causal: bool, kv_len: int, q_axis: int,
+            window: int | None = None):
+    """Scores with dead entries at NEG_INF: keys past the real length, under
+    a causal mask keys after their query and, with a `window`, keys that many
+    positions or more before it. `q_axis` is the axis of `s` that runs over
+    queries (0, or 1 in the transposed dK/dV tile)."""
+    if window is not None:
+        # ONE predicate, 0 <= qpos - kpos < window, as an unsigned compare (a
+        # key after its query wraps past any window): under a band EVERY tile
+        # is masked, and three selects and their compares cost the vector
+        # unit more than the tile's softmax (PERF.md section 6, PR 48). A
+        # padded key lies after every real query, so the causal edge hides it
+        ahead = (q0 - k0) + (jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+                             - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis))
+        return jnp.where(jax.lax.bitcast_convert_type(ahead, jnp.uint32) < window, s, NEG_INF)
     kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     s = jnp.where(kpos < kv_len, s, NEG_INF)
     if causal:
@@ -143,21 +159,32 @@ def _masked(s, q0, k0, *, causal: bool, kv_len: int, q_axis: int):
     return s
 
 
-def _for_tile(body, qi, ki, *, block_q, block_k, causal, kv_len, seq_len):
-    """Run `body(mask)`: with the masks in a tile that crosses the diagonal
-    or the padded end, without them everywhere else."""
+def _for_tile(body, qi, ki, *, block_q, block_k, causal, kv_len, seq_len, window=None):
+    """Run `body(mask)`: with the masks in a tile that crosses the diagonal,
+    the band's far edge or the padded end, without them everywhere else."""
     crosses = []
     if kv_len < seq_len:
         crosses.append((ki + 1) * block_k > kv_len)
     if causal:
         crosses.append(ki * block_k + block_k - 1 > qi * block_q)
+    if window is not None:   # the tile's last query no longer sees its first key
+        crosses.append(qi * block_q + block_q - 1 - ki * block_k >= window)
     if not crosses:
         return body(None)
     crosses = functools.reduce(jnp.logical_or, crosses)
     mask = functools.partial(_masked, q0=qi * block_q, k0=ki * block_k,
-                             causal=causal, kv_len=kv_len)
+                             causal=causal, kv_len=kv_len, window=window)
     pl.when(crosses)(lambda: body(mask))
     pl.when(jnp.logical_not(crosses))(lambda: body(None))
+
+
+def _first_key_tile(qi, ki, *, block_q, block_k, window=None, **_):
+    """The first key tile a query tile visits: tile 0, or with a `window` the
+    one whose predecessor is wholly behind the band."""
+    first = ki == 0
+    if window is not None:
+        first |= ki * block_k - 1 <= qi * block_q - window
+    return first
 
 
 def _last_key_tile(qi, ki, *, block_q, block_k, causal, seq_len, **_):
@@ -174,7 +201,7 @@ def _fwd_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, o_ref, lse_ref,
     t = pl.program_id(1)
     qi, ki = qi_tab[t], ki_tab[t]
 
-    @pl.when(ki == 0)
+    @pl.when(_first_key_tile(qi, ki, **geom))
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -299,31 +326,33 @@ def _call(kernel, name, tables, in_specs, out_specs, out_shape, scratch, heads,
     return functools.partial(call, *tables)
 
 
-def _by_query_tile(S, D, g, bq, bk, causal):
+def _by_query_tile(S, D, g, bq, bk, causal, window=None):
     """What the kernels that walk live tiles by query tile (forward, dQ)
     share: their tables, and the specs of a [bq, D] query-side block, a
     [bk, D] block of the head's group's K or V, and a [bq, 1] column."""
-    tables = tuple(zip(*_live_tiles(S, bq, bk, causal)))
+    tables = tuple(zip(*_live_tiles(S, bq, bk, causal, window)))
     q_spec = pl.BlockSpec((1, bq, D), lambda b, t, qt, kt: (b, qt[t], 0))
     kv_spec = pl.BlockSpec((1, bk, D), lambda b, t, qt, kt: (b // g, kt[t], 0))
     col_spec = pl.BlockSpec((1, bq, 1), lambda b, t, qt, kt: (b, qt[t], 0))
     return tables, q_spec, kv_spec, col_spec
 
 
-def _fwd_call(qbh, kbh, vbh, causal, blocks, interpret, kv_len):
+def _fwd_call(qbh, kbh, vbh, causal, blocks, interpret, kv_len, window=None):
     """The one kernel that takes two widths: q and k [., S, D], v and o
-    [., S, Dv]. The kernel's body reads its shapes from its refs."""
+    [., S, Dv]. The kernel's body reads its shapes from its refs. With a
+    `window` it walks the band's tiles and is `flash_attention_window` to a
+    profile: its time is never counted with the triangle's."""
     BH, S, D = qbh.shape
     Dv = vbh.shape[2]
     bq, bk = blocks
     tables, q_spec, k_spec, col_spec = _by_query_tile(
-        S, D, BH // kbh.shape[0], bq, bk, causal)
+        S, D, BH // kbh.shape[0], bq, bk, causal, window)
     o_spec = pl.BlockSpec((1, bq, Dv), q_spec.index_map)   # q's tile, v's width
     v_spec = pl.BlockSpec((1, bk, Dv), k_spec.index_map)
     kernel = functools.partial(_fwd_kernel, block_q=bq, block_k=bk, causal=causal,
-                               kv_len=kv_len, seq_len=S)
+                               kv_len=kv_len, seq_len=S, window=window)
     return _call(
-        kernel, "flash_attention_fwd", tables,
+        kernel, "flash_attention_fwd" if window is None else "flash_attention_window", tables,
         [q_spec, k_spec, v_spec], [o_spec, col_spec],
         [jax.ShapeDtypeStruct((BH, S, Dv), qbh.dtype),
          jax.ShapeDtypeStruct((BH, S, 1), jnp.float32)],
@@ -393,9 +422,41 @@ def _flash_bh_bwd(causal, blocks, interpret, kv_len, res, do):
 _flash_bh.defvjp(_flash_bh_fwd, _flash_bh_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_window_bh(qbh, kbh, vbh, window, blocks, interpret, kv_len):
+    """`_flash_bh` under a causal mask with a `window`: the forward alone."""
+    return _fwd_call(qbh, kbh, vbh, True, blocks, interpret, kv_len, window)[0]
+
+
+def _no_window_backward(*_):
+    raise NotImplementedError(
+        "flash_attention(window=) has no backward: the dQ and dK/dV kernels walk the "
+        "causal triangle's tiles, and nothing trains a sliding-window layer yet "
+        "(ROADMAP R2: a windowed backward)")
+
+
+_flash_window_bh.defvjp(_no_window_backward, _no_window_backward)
+
+
+def window_tiles(seq_len: int, window: int, head_dim: int, itemsize: int,
+                 v_dim: int | None = None) -> tuple[int, int]:
+    """(block_q, block_k) of the banded forward: `choose_tiles`'s, with both
+    edges held to the window (in whole lane tiles). A band `window` keys wide
+    under tiles of edge t costs `window + t` keys a query and more, so a tile
+    past the window only adds masked work: 512 x 512 at a window of 512 visits
+    1,024 keys a query where 1,024 x 1,024 visits 2,048."""
+    S = padded_len(seq_len)
+    if S <= LANES:
+        return S, S
+    cap = -(-window // LANES) * LANES
+    held = lambda t: max(e for e in range(LANES, min(t, cap) + 1, LANES) if S % e == 0)
+    bq, bk = choose_tiles(seq_len, head_dim, itemsize, "fwd", v_dim)
+    return held(bq), held(bk)
+
+
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int | None = None,
                     block_k: int | None = None, interpret: bool | None = None,
-                    scale: float | None = None):
+                    scale: float | None = None, window: int | None = None):
     """Drop-in attn_fn for models.llama: q [B,S,Hq,D], k [B,S,Hkv,D], v
     [B,S,Hkv,Dv] (GQA) -> [B,S,Hq,Dv].
 
@@ -410,15 +471,28 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int | None = None,
     `interpret=None` compiles the kernel when q/k/v are placed on a TPU and
     interprets it anywhere else (ops/platform.py); callers that know their
     mesh pass it.
+
+    `window` (causal only): query i sees key j iff j <= i and i - j < window,
+    the query's own key among the `window`. The forward then visits the
+    BAND's tiles alone (`_live_tiles`) under tiles no wider than the window
+    (`window_tiles`), masks the tiles the band's far edge crosses, and is
+    another kernel to a profile (`flash_attention_window`); it has no
+    backward and differentiating it raises. `window=None` is the text it was.
     """
     if interpret is None:
         interpret = target_platform(q, k, v) != "tpu"
     B, S, Hq, D = q.shape
     Dv = v.shape[3]
+    if window is not None and not causal:
+        raise ValueError("flash_attention(window=) is a causal mask's: query i sees "
+                         "the `window` keys that end at its own")
     if block_q is None and block_k is None:
         S_pad = padded_len(S)
-        blocks = tuple(choose_tiles(S, D, q.dtype.itemsize, kernel, Dv)
-                       for kernel in ("fwd", "dq", "dkv"))
+        if window is not None:
+            blocks = (window_tiles(S, window, D, q.dtype.itemsize, Dv),)
+        else:
+            blocks = tuple(choose_tiles(S, D, q.dtype.itemsize, kernel, Dv)
+                           for kernel in ("fwd", "dq", "dkv"))
     else:
         bq, bk = min(block_q or block_k, S), min(block_k or block_q, S)
         S_pad = -(-S // math.lcm(bq, bk)) * math.lcm(bq, bk)
@@ -433,6 +507,10 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int | None = None,
         return x.transpose(0, 2, 1, 3).reshape(B * x.shape[2], S_pad, x.shape[3])
 
     scale = jnp.asarray(1.0 / math.sqrt(D) if scale is None else scale, q.dtype)
-    obh = _flash_bh(heads_first(q * scale), heads_first(k), heads_first(v),
-                    causal, blocks, interpret, S)
+    if window is not None:
+        obh = _flash_window_bh(heads_first(q * scale), heads_first(k), heads_first(v),
+                               window, blocks[0], interpret, S)
+    else:
+        obh = _flash_bh(heads_first(q * scale), heads_first(k), heads_first(v),
+                        causal, blocks, interpret, S)
     return obh.reshape(B, Hq, S_pad, Dv).transpose(0, 2, 1, 3)[:, :S]

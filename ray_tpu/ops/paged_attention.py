@@ -213,9 +213,10 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref,   # scalar-prefetch (SMEM)
 
 
 def _decode_call(q4, k_pool, v_pool, tables, lengths, layer, *, pages: int,
-                 scale: float, interpret: bool):
+                 scale: float, interpret: bool, name: str = "paged_attention_decode"):
     """q4 [B, Hkv, Gp, Dp] -> [B, Hkv, Gp, Dp]: softmax(scale * q k^T) v over
-    layer `layer` of the pools, `pages` pages a group."""
+    layer `layer` of the pools, `pages` pages a group; `name` is the kernel's
+    in a profile."""
     B, Hkv, gp, dp = q4.shape
     BS = k_pool.shape[2]
     max_blocks = tables.shape[1]
@@ -239,7 +240,7 @@ def _decode_call(q4, k_pool, v_pool, tables, lengths, layer, *, pages: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
         interpret=interpret,
-        name="paged_attention_decode",
+        name=name,
         **({} if interpret else {"compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel",))}),
     )(tables.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
@@ -247,7 +248,8 @@ def _decode_call(q4, k_pool, v_pool, tables, lengths, layer, *, pages: int,
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, lengths, *, layer,
-                           interpret: bool | None = None):
+                           interpret: bool | None = None,
+                           name: str = "paged_attention_decode"):
     """q [B, Hq, D]; k/v_pool [L, NB, BS, Hkv * Dp], the whole pools, of which
     layer `layer` (an int or a traced scalar) is read; tables [B, max_blocks]
     (pool block id per sequence block; entries past a sequence's last live
@@ -270,8 +272,38 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, *, layer,
     q4 = jnp.pad(q4, [(0, 0), (0, 0), (0, gp - g), (0, dp - D)])
     pages = pages_per_group(BS, dp, Hkv, k_pool.dtype.itemsize, tables.shape[1])
     out = _decode_call(q4, k_pool, v_pool, tables, lengths, layer, pages=pages,
-                       scale=1.0 / math.sqrt(D), interpret=interpret)
+                       scale=1.0 / math.sqrt(D), interpret=interpret, name=name)
     return out[:, :, :g, :D].reshape(B, Hq, D).astype(q.dtype)
+
+
+# A window layer's rows (`models/llama.py::window_attend`) are a RING a
+# sequence, `[L, NS, window, Hkv * Dp]`: position p at row p % window, so the
+# ring holds the last `window` positions and nothing else, whatever the
+# sequence's length. Softmax does not care in what order it meets its keys
+# (they were rotated before they were cached), so the ring is read as
+# `window / RING_PAGE` pages of a pool `[L, NS * window / RING_PAGE, RING_PAGE,
+# row]` (a reshape that moves nothing) by the kernel above, under ITS OWN name
+# in a profile: a window call's time is divided by a window's bytes, never by
+# a whole context's.
+RING_PAGE = 128
+
+
+def window_decode_attention(q, k_ring, v_ring, rings, rows, *, layer,
+                            interpret: bool | None = None):
+    """q [B, Hq, D]; k/v_ring [L, NS, window, Hkv * Dp], every layer's and
+    every sequence's rings, of which ring `rings[b]` [B] of layer `layer` is
+    read; rows [B] = the ring's live rows, `min(tokens, window)` with the
+    token being decoded among them: rows [0, rows[b]) are attended to, the
+    rest (a ring that has not come round yet) are neither copied nor seen.
+    Returns [B, Hq, D]."""
+    L, NS, W, row = k_ring.shape
+    page = math.gcd(W, RING_PAGE)
+    per_ring = W // page
+    as_pages = lambda ring: ring.reshape(L, NS * per_ring, page, row)
+    tables = rings[:, None] * per_ring + jnp.arange(per_ring, dtype=jnp.int32)[None, :]
+    return paged_decode_attention(q, as_pages(k_ring), as_pages(v_ring), tables, rows,
+                                  layer=layer, interpret=interpret,
+                                  name="paged_attention_window")
 
 
 # ------------------------------------------------------------ latent (MLA)

@@ -489,7 +489,8 @@ class PagedLLMEngine:
                 "compiles": compiles,
                 "compile_s": compile_s,
                 # no prefix is looked up or registered over a pool with pages
-                # a sequence's: a block's hash says nothing of a running sum
+                # a sequence's: a block's hash says nothing of a running sum,
+                # nor of the window's rows at the prefix's end
                 "prefix_cache": not self.sequence_leaves,
             }
         return {**out, **self.allocator.stats()}
@@ -688,7 +689,9 @@ class PagedLLMEngine:
             bucket = min(self._bucket(len(suffix)),
                          self.config.max_seq_len - cached_len)
             info.update(cached=cached_len, bucket=bucket, reads=prefill_reads(cached_len),
-                        writes=prefill_writes(cached_len, bucket, bs))
+                        writes=prefill_writes(cached_len, bucket, bs), blocks=total_blocks)
+            if state_page:   # the other class's reservation: ONE page, whatever the length
+                info["state_page"] = state_page
             padded = np.zeros((1, bucket), dtype=np.int32)
             padded[0, : len(suffix)] = suffix
             table_row = self._table_rows(1, block_ids, state_page)

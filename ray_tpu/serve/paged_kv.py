@@ -12,11 +12,12 @@ longest cached block-aligned prefix (refcount++) and only prefills its suffix
 — the vLLM automatic-prefix-caching design.
 
 Two CLASSES of page: blocks (a span of `block_size` tokens: keys and values,
-a latent row, a convolution's rows at a block's end) and, for a family whose
-state sums over the whole past (`Model.sequence_leaves`), SEQUENCE pages, one
-a sequence whatever its length (`alloc_sequence` / `free_sequence`). Page 0 of
-either class is its garbage page. A sequence page is held by one sequence,
-never shared and never content-addressed.
+a latent row, a convolution's rows at a block's end) and, for a family that
+names `Model.sequence_leaves`, SEQUENCE pages, one a sequence whatever its
+length (`alloc_sequence` / `free_sequence`): a state that sums over the whole
+past, or a sliding-window layer's ring of rows (`stats()` counts either as
+`state_pages`). Page 0 of either class is its garbage page. A sequence page is
+held by one sequence, never shared and never content-addressed.
 """
 
 from __future__ import annotations

@@ -71,13 +71,15 @@ class SpecDecodeLLMEngine(PagedLLMEngine):
         bs = self.config.block_size
         if self.sequence_leaves:
             # a state that sums over the whole past has no earlier position to
-            # go back to at all (ROADMAP R6: a state rewind under speculation)
+            # go back to at all (ROADMAP R6: a state rewind under speculation),
+            # and a window layer's ring has lost the rows that the window's later
+            # positions wrote over (ROADMAP R2: a speculative window over a ring)
             raise ValueError(
                 f"speculative decoding rewinds `lengths` after a rejected window, and "
                 f"this family's pool keeps {sorted(self.sequence_leaves)} as ONE page a "
-                f"sequence, a running state that every position of the window has "
-                f"advanced: the state at the committed position is gone and cannot be "
-                f"stepped from again")
+                f"sequence (a running state, or a window layer's ring), which every position "
+                f"of the window has advanced or written over: what the committed position "
+                f"resumes from is gone and cannot be stepped from again")
         per_block = sorted(name for name, leaf in page_leaves(self.pool).items()
                            if leaf.shape[2] != bs)
         if per_block:

@@ -308,3 +308,66 @@ def test_at_one_width_the_flash_kernels_are_the_parent_s_text(one_width_programs
     heads, forward and backward, and so do the train steps' gradients of
     Llama and OLMoE through it."""
     assert one_width_programs[name] == PARENT_FLASH[name]
+
+
+# ---- the banded forward: `flash_attention(window=)` (models/laguna.py's window layers)
+
+def _dense_window(q, k, v, window):
+    """Causal attention in float32 where query i sees key j iff i - j < window."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    B, S, Hq, D = q.shape
+    g = Hq // k.shape[2]
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", q.reshape(B, S, -1, g, D), k) / np.sqrt(D)
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    s = jnp.where((j <= i) & (i - j < window), s, -1e30)
+    o = jnp.einsum("bhgqk,bkhd->bqhgd", jax.nn.softmax(s, axis=-1), v)
+    return o.reshape(B, S, Hq, D)
+
+
+@pytest.mark.parametrize("S, window, bq, bk, g", [
+    (256, 64, 64, 64, 9),        # a window of whole tiles, Laguna's window group
+    (256, 64, 32, 64, 6),        # the key tile larger, its full group
+    (300, 100, 128, 128, 9),     # neither the length nor the window is whole tiles
+    (384, 50, 128, 64, 6),       # a window under a tile: every band tile is masked
+    (256, 1, 64, 64, 1),         # a query sees its own key alone
+    (192, 1000, 64, 64, 3),      # a window longer than the sequence: the triangle
+    (1100, 512, None, None, 9),  # the cell's window under DEFAULT tiles, a padded end
+], ids=["whole-tiles", "k>q", "ragged", "under-a-tile", "window-1", "longer-than-S", "default"])
+def test_flash_window_is_the_dense_band(S, window, bq, bk, g):
+    """The banded forward, interpreted, against a dense masked softmax in
+    float32: tiles wholly behind the band are not visited, the tiles its far
+    edge crosses are masked, and a row whose first visited tile holds none of
+    its keys is put right by the tiles that follow."""
+    ks = jax.random.split(jax.random.PRNGKey(S + window), 3)
+    D = 128 if bq is None else 32
+    q = jax.random.normal(ks[0], (1 if bq is None else 2, S, 2 * g, D))
+    k, v = (jax.random.normal(key, (q.shape[0], S, 2, D)) for key in ks[1:])
+    got = fa.flash_attention(q, k, v, window=window, block_q=bq, block_k=bk, interpret=True)
+    want = _dense_window(q, k, v, window)
+    assert float(jnp.abs(got - want).max()) < 1e-5
+    assert float(jnp.abs(llama.attention(q, k, v, window=window) - want).max()) < 1e-5
+
+
+def test_flash_window_live_tiles_are_the_band_and_its_tiles_keep_to_the_window():
+    bq, bk, S, W = 64, 32, 256, 80
+    tiles = set(fa._live_tiles(S, bq, bk, True, W))
+    for qi in range(S // bq):
+        for ki in range(S // bk):
+            live = any(j <= i and i - j < W for i in range(qi * bq, qi * bq + bq)
+                       for j in range(ki * bk, ki * bk + bk))
+            assert ((qi, ki) in tiles) == live, (qi, ki)
+    assert tiles < set(fa._live_tiles(S, bq, bk, True))
+    # at the cell's shapes: 512 x 512 under a window of 512, 31 tiles a head at
+    # 8,192 where the triangle under 1,024 x 1,024 has 36 of four times the size
+    assert fa.window_tiles(8192, 512, 128, 2) == fa.window_tiles(2048, 512, 128, 2) == (512, 512)
+    assert len(fa._live_tiles(8192, 512, 512, True, 512)) == 31
+    assert len(fa._live_tiles(8192, 1024, 1024, True)) == 36
+    assert fa.window_tiles(1100, 512, 128, 2) == (384, 384) and fa.window_tiles(64, 512, 32, 4) == (64, 64)
+
+
+def test_flash_window_has_no_backward_and_no_meaning_without_causal():
+    q = k = v = jnp.ones((1, 64, 2, 32))
+    with pytest.raises(NotImplementedError, match="no backward"):
+        jax.grad(lambda q: fa.flash_attention(q, k, v, window=16, interpret=True).sum())(q)
+    with pytest.raises(ValueError, match="causal"):
+        fa.flash_attention(q, k, v, causal=False, window=16, interpret=True)

@@ -82,6 +82,11 @@ _CASES = {
                                layers=2, layer=1),
     "nemotron-f32-g16": _case([1, 9, 300], g=16, Hkv=2, D=128, BS=8, max_blocks=40,
                               dtype=jnp.float32),
+    # Laguna's heads: 48 and 72 query heads over 8 key-value heads of 128,
+    # groups of 6 and 9 (no power of two; padded to 8 and 16 sublanes)
+    "laguna-full-bf16-g6": _case([1, 17, 257, 300], g=6, max_blocks=20, layers=2, layer=1),
+    "laguna-window-f32-g9": _case([1, 9, 300], g=9, Hkv=2, D=128, BS=8, max_blocks=40,
+                                  dtype=jnp.float32),
 }
 
 
@@ -466,3 +471,40 @@ def test_a_prompt_that_continues_a_cached_prefix_reads_it_through_the_table(fami
         debug_info=True)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([head, tail], axis=1)), want,
                                rtol=1e-4, atol=2e-4)
+
+
+# ---- a window layer's ring (models/laguna.py)
+
+@pytest.mark.parametrize("window, g, dtype", [(512, 9, jnp.bfloat16), (24, 9, jnp.float32),
+                                              (40, 6, jnp.float32)],
+                         ids=["cell-512-g9", "24-g9", "40-g6"])
+def test_window_decode_reads_a_ring_s_live_rows(window, g, dtype):
+    """`window_decode_attention` over rings [L, NS, window, row], layer 1 of
+    3: a ring that has not come round (1 row, window - 1 rows: the rest hold
+    another sequence's rows and are not seen), full rings, a dead row on the
+    garbage ring; keys in ring order against a dense softmax over the same
+    rows. A window of 512 is four pages of 128 rows, 24 three pages of 8."""
+    from ray_tpu.ops.paged_attention import window_decode_attention
+
+    rng = np.random.default_rng(window)
+    Hkv, D, L, NS = 2, 128, 3, 6
+    to = lambda a: jnp.asarray(a).astype(dtype)
+    exact = lambda shape: np.asarray(to(rng.standard_normal(shape, np.float32)).astype(jnp.float32))
+    rings = np.array([3, 0, 5, 1, 4], np.int32)
+    rows = np.array([1, 1, window - 1, window, window], np.int32)
+    q = exact((len(rings), Hkv * g, D))
+    k_ring, v_ring = exact((L, NS, window, Hkv * D)), exact((L, NS, window, Hkv * D))
+    got = jax.jit(lambda layer: window_decode_attention(
+        to(q), to(k_ring), to(v_ring), jnp.asarray(rings), jnp.asarray(rows), layer=layer,
+        interpret=True))(jnp.int32(1))
+    view = lambda ring: jnp.asarray(ring[1, rings]).reshape(len(rings), window, Hkv, D)
+    want = llama._cached_attention(
+        jnp.asarray(q)[:, None], view(k_ring), view(v_ring), jnp.asarray(rows - 1),
+        jnp.asarray(rows - 1)[:, None])[:, 0]
+    np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)), np.asarray(want), rtol=0,
+                               atol=2e-5 if dtype == jnp.float32 else 3e-2)
+    # another name than the full layers' kernel in a profile
+    text = str(jax.make_jaxpr(lambda: window_decode_attention(
+        to(q), to(k_ring), to(v_ring), jnp.asarray(rings), jnp.asarray(rows), layer=1,
+        interpret=False))())
+    assert "paged_attention_window" in text and "paged_attention_decode" not in text

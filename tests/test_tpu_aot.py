@@ -764,6 +764,111 @@ def test_nemotron_engine_steps_compile_beside_weights_and_pool(v5e, name, S, kw,
         assert not array_lines(text, (slots, 64, 64, 128))
 
 
+def _laguna_serve_ep8():
+    """The model of `laguna-s-2.1-serve-ep8-12l-1chip`, from the cell's own
+    file, and the file's engine section."""
+    import json
+    import os
+
+    from benchmarks.harness.families import laguna as family
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmarks", "configs", "laguna-s-2.1-serve-ep8-12l-1chip.json")
+    with open(path) as f:
+        file = json.load(f)
+    return family.model_config({k: file[k] for k in family.MODEL_KEYS}), file["engine"]
+
+
+def _laguna_engine_step(d, cfg, engine, name, S, **kw):
+    """(lowered, the pool's shapes): a step of `serve-laguna-code8k-256-out` as
+    the engine builds it (`paged_step`), the ring's id the last column of the
+    529-wide table: every slot at S == 1, one sequence at a prefill."""
+    from ray_tpu.serve.llm_paged import paged_step
+
+    slots, blocks = engine["max_batch_size"], engine["num_blocks"]
+    place = lambda tree: jax.tree.map(lambda a: _on(d, a.shape, a.dtype), tree)
+    model, i32, B = model_of(cfg), jnp.int32, slots if S == 1 else 1
+    pool = jax.eval_shape(lambda: model.init_kv_pool(cfg, blocks, 16, num_sequences=slots + 1))
+    params = place(jax.eval_shape(lambda: model.init(cfg, jax.random.PRNGKey(0))))
+    rest = ((_on(d, (1, 529), i32), _on(d, (2,), i32)) if S > 1
+            else (_on(d, (B,), i32), _on(d, (B, 529), i32)))
+    return paged_step(name, cfg, 16, "tpu", **kw).lower(
+        params, place(pool), _on(d, (B, S), i32), *rest), pool
+
+
+@pytest.mark.parametrize("name, S, kw, scratch_under", [
+    ("decode", 1, dict(head=0), 2 ** 25),
+    ("prefill", 8192, dict(head="last", table_first=True, fresh=True), 1.5e9),
+], ids=["decode", "prefill-8192"])
+def test_laguna_engine_steps_compile_beside_weights_and_pool(v5e, name, S, kw, scratch_under):
+    """`serve-laguna-code8k-256-out`'s decode step and its 8,192 prefill as the
+    engine builds them, at Laguna-S-2.1's published widths and the first
+    stage's 12 layers (layer 0 dense, 2 full and 9 sliding expert layers of 32
+    held experts; 48 query heads in a full layer and 72 in a window one over 8
+    key-value heads: groups of 6 and 9), the engine's slots and blocks, a
+    528-block table with the ring's id as its last column, the pool's FOUR
+    leaves of two classes donated beside 8.65 GB of weights. They compile for a
+    v5e; every leaf stays in place (the only pool-shaped instructions are the
+    writes of `kv_write`: a row scatter a step, whole pages and one ring a
+    prefill; the ring read as pages of 128 rows is a bitcast), no layer's
+    experts are copied, a decode step re-lays out no weight, and the text
+    names the scopes and kernels a profile is read by. The compiler's bytes go
+    into the configuration file's `num_blocks_note`."""
+    cfg, engine = _laguna_serve_ep8()
+    slots, blocks = engine["max_batch_size"], engine["num_blocks"]
+    assert engine["block_size"] == 16 and engine["prefill_buckets"] == [2048, 4096, 8192]
+    assert blocks == slots * 528 + 1
+    assert (cfg.cache_layers("full"), cfg.cache_layers("win")) == (3, 9)
+    model = model_of(cfg)
+    assert model.sequence_leaves == ("k_win", "v_win")
+    lowered, pool = _laguna_engine_step(v5e[0], cfg, engine, name, S, **kw)
+    params = lowered.args_info[0][0]
+    compiled = lowered.compile()
+    assert pool["k"].shape == pool["v"].shape == (3, blocks, 16, 1024)
+    assert pool["k_win"].shape == pool["v_win"].shape == (9, slots + 1, 512, 1024)
+    assert params["win"]["e_gate"].shape == (9, 32, 3072, 1024)
+    assert params["win"]["wq"].shape == (9, 3072, 72 * 128)
+    assert params["full"]["wq"].shape == (2, 3072, 48 * 128)
+    writes = ("scatter", "dynamic-update-slice")
+    assert_pool_stays_in_place(compiled, pool, scratch_under=int(scratch_under),
+                               alloc_under=2 ** 22, writes=writes)
+    ma = compiled.memory_analysis()
+    print(name, S, ma.argument_size_in_bytes, ma.output_size_in_bytes, ma.temp_size_in_bytes)
+    weights = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert 8.65e9 < weights < 8.66e9
+    assert 13.57e9 < ma.argument_size_in_bytes < 13.59e9
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 15.0e9
+    text = compiled.as_text()
+    # no copy of a layer's (or a stack's) experts, in any layout
+    assert not re.search(
+        r"= bf16\[(9,|2,)?32,(3072,1024|1024,3072)\]\S* (copy|fusion|transpose)\(", text)
+    names = ["attn_full/attn/kv_write", "attn_win/attn/kv_write", "attn_full/attn/gate",
+             "attn_win/attn/gate", "moe/route", "moe/dispatch", "moe/experts", "moe/combine",
+             "moe/shared", "grouped_matmul_fwd", "cosine"]
+    names += (["attn_full/attn/kv_read", "attn_win/attn/kv_read", "paged_attention_decode",
+               "paged_attention_window"] if S == 1
+              else ["attn_full/attn/prompt_attend", "attn_win/attn/prompt_attend",
+                    "flash_attention_fwd", "flash_attention_window"])
+    for scope in names:
+        assert scope in text, scope
+    # a fresh prefill reads no K/V back; a decode step runs no flash kernel
+    assert ("attn/kv_read" in text) == ("paged_attention_window" in text) == (S == 1)
+    assert ("attn/prompt_attend" in text) == ("flash_attention_window" in text) == (S > 1)
+    if S == 1:
+        assert not weight_relayouts(text, params)
+        # the ring is read where it lies: as 164 x 4 pages of 128 rows it is a bitcast
+        ring_pages = array_lines(text, (9, (slots + 1) * 4, 128, 1024))
+        assert ring_pages and all(" bitcast(" in ln for ln in ring_pages), ring_pages[:3]
+    else:
+        assert_a_fresh_prefill_writes_whole_pages(text, pool["k"].shape, S,
+                                                  "attn_full/attn/kv_write", leaves=2)
+        # a sparse layer sums its held rows back an even share and a quarter at
+        # a time: 12,800 of the 81,920 pairs
+        trip = moe.held_rows_trip(S * 10, 32, 256)
+        assert trip == 12800 and array_lines(text, (S, trip))
+        assert_a_share_moves_its_bound(text, cfg, S, compacts=True)
+
+
 def _engine_step(d, cfg, name, *, B, S, max_blocks=128, pool_blocks, bs=16, **kw):
     """(lowered, the pool's shapes): the engines' own jitted step `name`
     (`serve/llm_paged.py::paged_step`, pool donated) told it runs on a TPU,
@@ -941,6 +1046,9 @@ def _cell_decode_step(d, cell: str):
     if cell == "nemotron":
         lowered = _nemotron_engine_step(d, *_nemotron_serve_ep8(), "decode", 1, head=0)[0]
         return lowered, lowered.args_info[0][0]
+    if cell == "laguna":
+        lowered = _laguna_engine_step(d, *_laguna_serve_ep8(), "decode", 1, head=0)[0]
+        return lowered, lowered.args_info[0][0]
     if cell == "mistral-16l":
         cfg, size = _mistral_serve_16l(), dict(B=32, pool_blocks=4097)
     elif cell == "ouro-2.6b":
@@ -965,6 +1073,7 @@ def _cell_decode_step(d, cell: str):
     ("xing4", 48, 2 ** 24),
     ("lfm2", 32, 2 ** 24),
     ("nemotron", 48, 2 ** 25),
+    ("laguna", 40, 2 ** 25),
     ("olmoe-1b-7b", 32, 2 ** 20),
 ])
 def test_a_decode_step_reads_its_projections_weights_in_place(v5e, cell, slots, scratch_under):
